@@ -32,20 +32,20 @@ bench-smoke:
 # Shard-cache cold/warm A/B over the throttled backend, full geometry
 # (docs/CACHING.md; headline = warm/cold speedup).
 cache-bench:
-	DDL_BENCH_MODE=cache JAX_PLATFORMS=cpu $(PY) bench.py
+	DDL_BENCH_MODE=cache DDL_BENCH_PLATFORM=cpu $(PY) bench.py
 
 # ICI distribution A/B (Pallas fan-out + redistribution vs the XLA
-# scatter; docs/PERF_NOTES.md "ICI ingest").  On a TPU pod this is the
-# real-DMA measurement; elsewhere it runs interpret-mode on the
-# virtual mesh and the JSON carries the last_tpu_artifact trail.
+# scatter; docs/PERF_NOTES.md "ICI ingest").  Needs a multi-chip TPU
+# host; DDL_BENCH_PLATFORM=cpu asks for the interpret-mode contract run
+# on the virtual mesh instead (not a device measurement).
 ici-bench:
 	DDL_BENCH_MODE=ici $(PY) bench.py
 
-# Fan-out kernel dry run on whatever devices exist (interpret mode on
-# CPU: per-hop bytes/s for both modes + one full redistribution) —
-# the mirror of tools/probe_ingest.py for the post-H2D hop.
+# Fan-out kernel dry run in interpret mode on the CPU virtual mesh
+# (both modes + one full redistribution) — the mirror of
+# tools/probe_ingest.py for the post-H2D hop.
 ici-dryrun:
-	$(PY) tools/probe_ici.py
+	DDL_BENCH_PLATFORM=cpu $(PY) tools/probe_ici.py
 
 # Distributed-optimizer A/B (zero1 vs replicated state, fp32 vs int8
 # grad comm; docs/PERF_NOTES.md "Distributed optimizer").  Loss parity
@@ -53,24 +53,23 @@ ici-dryrun:
 opt-bench:
 	DDL_BENCH_MODE=opt $(PY) bench.py
 
-# Optimizer-state/grad-comm sweep on whatever devices exist (the CPU
-# virtual mesh elsewhere): measured bytes/replica + leg times at small
-# scale, analytic v5e-32 pricing for the 8B/4B configs — the mirror of
-# tools/probe_ici.py for the optimizer tier.
+# Optimizer-state/grad-comm sweep on the CPU virtual mesh: bytes/replica
+# at small scale, analytic v5e-32 pricing for the 8B/4B configs — the
+# mirror of tools/probe_ici.py for the optimizer tier.
 opt-dryrun:
-	$(PY) tools/probe_opt.py
+	DDL_BENCH_PLATFORM=cpu $(PY) tools/probe_opt.py
 
 # Topology-aware vs naive producer→consumer placement A/B over the
 # simulated fabric (ddl_tpu/cluster/placement.py; Cloud Collectives
 # rank reordering) + the membership chaos counters.
 placement-bench:
-	DDL_BENCH_MODE=placement JAX_PLATFORMS=cpu $(PY) bench.py
+	DDL_BENCH_MODE=placement DDL_BENCH_PLATFORM=cpu $(PY) bench.py
 
 # Multi-tenant ingest-service A/B (K concurrent tenants over the shared
 # fair-share scheduler, autoscaled vs static pool; docs/SERVING.md) +
 # the tenant-burst/host-loss chaos leg.
 tenancy-bench:
-	DDL_BENCH_MODE=tenancy JAX_PLATFORMS=cpu $(PY) bench.py
+	DDL_BENCH_MODE=tenancy DDL_BENCH_PLATFORM=cpu $(PY) bench.py
 
 # Serve control-plane suite alone (admission/fair-share/autoscaler units,
 # concurrent-consumer fairness, the serve fault-site chaos rows).
@@ -112,13 +111,13 @@ opt-test:
 # Lossless byte identity + int8 loss parity asserted in the artifact;
 # winner is the headline.
 wire-bench:
-	DDL_BENCH_MODE=wire JAX_PLATFORMS=cpu $(PY) bench.py
+	DDL_BENCH_MODE=wire DDL_BENCH_PLATFORM=cpu $(PY) bench.py
 
 # Per-dtype/per-codec encode/decode bytes/s + compression ratios on
 # real shard data, break-even link speeds, and the analytic ICI wire
 # pricing — the mirror of probe_ici/probe_opt for the wire tier.
 wire-dryrun:
-	JAX_PLATFORMS=cpu $(PY) tools/probe_wire.py
+	DDL_BENCH_PLATFORM=cpu $(PY) tools/probe_wire.py
 
 # Wire-format suite alone (codec/quantizer units, trailer roundtrip,
 # slot/exchange/ICI wire paths, the wire chaos rows).
@@ -135,7 +134,7 @@ preempt-test:
 # stall A/B, notice→resumed recovery wall time, hard-kill lost-work
 # bound — byte-identical resume asserted in the artifact.
 preempt-bench:
-	DDL_BENCH_MODE=preempt JAX_PLATFORMS=cpu $(PY) bench.py
+	DDL_BENCH_MODE=preempt DDL_BENCH_PLATFORM=cpu $(PY) bench.py
 
 # Survivable-control-plane suite alone (supervisor journal replay,
 # the acked/fenced envelope seam, lease-expiry HA promotion incl. the
@@ -149,7 +148,7 @@ failover-test:
 # stream, zero watchdog failures, envelope drop/dup dedup counters and
 # scheduler-fairness continuity asserted in the artifact.
 failover-bench:
-	DDL_BENCH_MODE=failover JAX_PLATFORMS=cpu $(PY) bench.py
+	DDL_BENCH_MODE=failover DDL_BENCH_PLATFORM=cpu $(PY) bench.py
 
 # Multi-job ingest fabric unit + property tests (tests/test_fabric.py:
 # supervisor-resident admission, journal-replay failover, per-job
@@ -163,7 +162,7 @@ fabric-test:
 # preemption-drain SLOs, per-job cache accounting, and the supervisor-
 # kill leg's bit-identical admission order in the artifact.
 fabric-bench:
-	DDL_BENCH_MODE=fabric JAX_PLATFORMS=cpu $(PY) bench.py
+	DDL_BENCH_MODE=fabric DDL_BENCH_PLATFORM=cpu $(PY) bench.py
 
 # Self-tuning unit/e2e matrix (ddl_tpu/tune; docs/TUNING.md):
 # hysteresis, cooldown, never-worse revert, deadline-bounded
@@ -176,24 +175,24 @@ tune-test:
 # + KnobController live, interleaved A/B, never-slower gated by
 # bench_smoke.
 tune-bench:
-	DDL_BENCH_MODE=autotune JAX_PLATFORMS=cpu $(PY) bench.py
+	DDL_BENCH_MODE=autotune DDL_BENCH_PLATFORM=cpu $(PY) bench.py
 
 # Host-vs-device global-shuffle exchange A/B (ThreadExchangeShuffler
 # over the rendezvous boards vs the on-mesh DeviceExchangeShuffler;
 # docs/PERF_NOTES.md "Device-side global shuffle").  Byte identity of
 # the post-exchange pools asserted per rep; winner is the headline.
-# On a TPU pod the ring kernel runs real DMAs; elsewhere interpret
-# mode on the virtual mesh (the host path usually wins there — the
-# contract, not the speedup, is what CI gates on).
+# Here: the interpret-mode contract run on the CPU virtual mesh (the
+# host path usually wins there — the contract, not the speedup, is
+# what CI gates on); drop DDL_BENCH_PLATFORM on a multi-chip TPU host.
 shuffle-bench:
-	DDL_BENCH_MODE=shuffle JAX_PLATFORMS=cpu $(PY) bench.py
+	DDL_BENCH_MODE=shuffle DDL_BENCH_PLATFORM=cpu $(PY) bench.py
 
 # Analytic exchange pricing (device ICI bytes vs host boards raw/wire
 # per plan_exchange) across ring widths + a live byte-identity parity
 # run for both impls on the virtual mesh — the mirror of
 # probe_ici/probe_wire for the shuffle tier.
 shuffle-dryrun:
-	JAX_PLATFORMS=cpu $(PY) tools/probe_shuffle.py
+	DDL_BENCH_PLATFORM=cpu $(PY) tools/probe_shuffle.py
 
 # Device-exchange suite alone (seed parity across geometries, the DMA
 # -failure/peer-loss chaos rungs, resolution surface, end-to-end
@@ -211,4 +210,4 @@ obs-test:
 # overhead A/B (ceiling <= 2%, byte-identical), histogram percentiles
 # in the armed report, and the seeded-corruption flight-record leg.
 obs-bench:
-	DDL_BENCH_MODE=obs JAX_PLATFORMS=cpu $(PY) bench.py
+	DDL_BENCH_MODE=obs DDL_BENCH_PLATFORM=cpu $(PY) bench.py

@@ -1,0 +1,66 @@
+"""Process bring-up: which JAX platform, and where compiles are cached.
+
+The entry scripts (``chip_smoke.py``, ``bench.py``, ``tools/probe_*``,
+``examples/``, ``__graft_entry__.py``) call these two functions once,
+in the ONE process that will do the device work.  A chip belongs to one
+process at a time, so nothing here probes the backend from a child, and
+nothing falls back: a run that wants a TPU and finds none stops with
+the reason instead of publishing CPU numbers under device names.
+
+Producer workers never call this module (they stay off JAX).
+"""
+
+from __future__ import annotations
+
+import os
+from typing import Optional
+
+#: The checkout's own cache directory (git-ignored).  A FIXED path: the
+#: path is part of the cache key, so a directory that moves never hits.
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache",
+)
+
+
+def configure_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set the placement is the
+    environment's — JAX reads the variable itself and nothing is set in
+    code.  Otherwise the cache lives in ``<checkout>/.jax_cache``.
+    """
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    return CHECKOUT_CACHE_DIR
+
+
+def bring_up(request: Optional[str] = None) -> str:
+    """Initialise JAX in this process and return its platform.
+
+    ``request`` is what the caller was asked for BY NAME: ``"cpu"``
+    selects the CPU backend; ``None``/``""``/``"tpu"`` require a TPU and
+    raise ``SystemExit`` with the reason when JAX finds anything else
+    (exit code 1, nothing measured).  Also places the compile cache.
+    """
+    if request not in (None, "", "cpu", "tpu"):
+        raise ValueError(f"platform request must be cpu|tpu, got {request!r}")
+    import jax
+
+    if request == "cpu":
+        jax.config.update("jax_platforms", "cpu")
+    configure_compile_cache()
+    try:
+        platform = jax.devices()[0].platform
+    except RuntimeError as e:  # no backend could be initialised
+        raise SystemExit(f"JAX found no usable backend: {e}") from e
+    if request != "cpu" and platform != "tpu":
+        raise SystemExit(
+            f"this run needs a TPU and JAX found only {platform!r}; a CPU "
+            "run has to be asked for by name (DDL_BENCH_PLATFORM=cpu)"
+        )
+    return platform

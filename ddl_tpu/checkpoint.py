@@ -43,21 +43,24 @@ from ddl_tpu.parallel.train import TrainState
 MANIFEST_NAME = "ddl_manifest.json"
 
 
-def atomic_file_write(path: str, data: bytes, fsync: bool = True) -> None:
+def atomic_file_write(path: str, data: Any, fsync: bool = True) -> None:
     """THE checkpoint-byte write primitive: temp file in the target's
     own directory, then ``os.replace`` — readers see the old bytes or
     the new bytes, never a torn mix, and a crash mid-write leaves only
     a ``.tmp.<pid>`` orphan no reader matches.  ``fsync=True`` flushes
     to stable storage before the rename (durability, not just
     atomicity).  Every configured checkpoint write must route through
-    here (ddl-lint DDL022)."""
+    here (ddl-lint DDL022).  ``data`` is one bytes-like object, or a
+    list of them written back to back (a multi-gigabyte checkpoint is
+    streamed from its own buffers, never assembled first)."""
     path = os.path.abspath(path)
     os.makedirs(os.path.dirname(path), exist_ok=True)
     tmp = f"{path}.tmp.{os.getpid()}"
     with open(tmp, "wb") as f:  # ddl-lint: disable=DDL022
         # The helper itself is the one sanctioned bare write: the temp
         # name is unmatchable by any reader and replaced atomically.
-        f.write(data)
+        for piece in data if isinstance(data, list) else [data]:
+            f.write(piece)
         if fsync:
             f.flush()
             os.fsync(f.fileno())

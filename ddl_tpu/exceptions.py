@@ -268,3 +268,13 @@ class LoaderStateError(DDLError, RuntimeError):
     superseded ``windows()`` stream, batch iteration over abandoned
     staged windows).  Subclasses ``RuntimeError`` for backwards
     compatibility with callers that guarded on the builtin."""
+
+
+class KernelBuildError(RuntimeError):
+    """The TPU compiler refused a Pallas kernel (``ddl_tpu.ops``).
+
+    A broken program, not a degraded link: deliberately NOT a
+    :class:`DDLError` and not a ``JaxRuntimeError``, so neither the ICI
+    distributor's nor the device shuffle's runtime fault ladder — which
+    absorb those into a latched fallback — can mistake it for one.
+    """

@@ -356,7 +356,7 @@ def _routed_mlp(
             else None
         )
         if bax or sax or tax:
-            from ddl_tpu._compat import shard_map
+            from jax import shard_map
 
             token_axes = tuple(a for a in (bax, sax) if a)
             ff_specs = {

@@ -2,10 +2,9 @@
 
 The old knob was all-or-nothing: ``remat=True`` wrapped each layer in a
 bare ``jax.checkpoint``, recomputing EVERYTHING in the backward pass —
-including the attention kernel, the most expensive op in the layer.  On
-chip that bought HBM at a steep FLOPs price: the 1.39B bench config's
-MFU fell from 0.6255 (285M, no remat) to 0.5574 under full-layer remat
-(``BENCH_TPU_r05.json``, VERDICT r5 weak #3).
+including the attention kernel, the most expensive op in the layer.
+That buys HBM at a steep FLOPs price (what it costs on the current chip
+is not measured; the policies below exist to let a config pay less).
 
 Policies (``LlamaConfig.remat`` / ``MoeConfig.remat``; bools still
 accepted for back compat — ``True`` is ``"full"``, ``False`` is

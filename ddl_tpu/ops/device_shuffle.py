@@ -10,38 +10,39 @@ and lane B backward (``i -> pinv[i]``) — byte-identical to the host
 rendezvous exchange because both sides derive the permutation from
 ``exchange_permutation(n, seed, round)`` (ddl_tpu.shuffle).
 
-Kernel shape constraints (the ``ops/ici_fanout.py`` discipline):
+Kernel shape (the ``ops/ici_fanout.py`` discipline — written for
+devices that each run at their own pace):
 
-- **Permutation-shaped steps.**  Interpret mode (the CPU virtual-mesh
-  tier-1 path) discharges a remote DMA as a collective: every device in
-  the axis must execute every ``dma_start`` in lockstep, and each
-  step's target map must deliver exactly one copy per device.  An
-  exchange permutation is bijective (and a derangement), so both lane
-  steps are valid target maps by construction — no clamping or sink
-  chunks needed, unlike the fan-out ring.
-- **Scalar-prefetch routes.**  The permutation changes every round;
-  baking it into the kernel would recompile per round.  The routes
-  array ``[p, pinv]`` (2, n) int32 rides scalar prefetch instead
-  (``PrefetchScalarGridSpec(num_scalar_prefetch=1)``), so one compiled
+- **Routes are data.**  The permutation changes every round; baking it
+  into the kernel would recompile per round.  The routes array
+  ``[p, pinv]`` (2, n) int32 is an SMEM input instead, so one compiled
   program serves every round of a geometry and ``device_id`` is read
-  from SMEM per step.
-- **Double buffering.**  DMA semaphores are parity pairs
-  (``sem[t % 2]``): step ``t`` starts its send, then waits step
-  ``t-1``'s — lane B crosses the links while lane A's send drains
-  (the ``ici_fanout`` idiom; the waited descriptor's slice/target are
-  irrelevant, only its semaphore is consumed).
-- **Landing slots.**  Two concurrently-running collective kernels on a
-  chip must not share barrier semaphores, so the exchange reserves its
-  own per-slot Mosaic ``collective_id`` pair — distinct from the
-  fan-out's (11, 13)/(12, 14) — and callers riding a landing slot
-  alternate ``slot`` exactly like ``fanout_start``/``fanout_wait``.
-  The split surface is :func:`exchange_start` / :func:`exchange_wait`:
-  start dispatches the ring program device-side and returns
-  immediately; the wait is the consumer's first use of the value.
+  from SMEM per lane.
+- **Entry barrier.**  Lane A of device ``i`` lands in ``p[i]``'s output
+  buffer and lane B in ``pinv[i]``'s, and a buffer exists only once its
+  device has entered the kernel.  The two devices that write into ``i``
+  are ``pinv[i]`` and ``p[i]`` — exactly the two it writes into — so
+  every device signals both on the barrier semaphore
+  (``pltpu.get_barrier_semaphore``, keyed by the per-slot
+  ``collective_id``) and waits for two signals before its first send.
+  The wait consumes what was signalled, so the semaphore exits at zero.
+- **Write-once destinations.**  Each lane slice of every output is
+  written by exactly one DMA, on its own semaphore pair; both lanes are
+  in flight together and a device leaves only after both of its sends
+  drained and both of its lanes landed.
+- **Landing slots.**  Round r+1's program can be entered by a fast
+  device while a peer is still in round r's, so consecutive rounds
+  alternate ``slot`` (a per-slot ``collective_id``, distinct from the
+  fan-out's 11-14), exactly like ``fanout_start``/``fanout_wait``.  The
+  split surface is :func:`exchange_start` / :func:`exchange_wait`:
+  start dispatches the program device-side and returns immediately; the
+  wait is the consumer's first use of the value.
 
-Off-TPU the wrappers run ``interpret=True`` (how tier-1 proves byte
-identity against the host path on the CPU virtual mesh); on a pod the
-same kernels compile through Mosaic.
+Off-TPU the wrappers run the same kernel under Pallas' TPU interpret
+mode (simulated devices, remote DMAs, semaphores, barrier — how tier-1
+proves byte identity against the host path on the CPU virtual mesh); on
+a pod it compiles through Mosaic, ahead of time, so a compiler refusal
+is a :class:`~ddl_tpu.exceptions.KernelBuildError` at build.
 """
 
 from __future__ import annotations
@@ -53,97 +54,92 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 from jax import lax
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ddl_tpu._compat import shard_map
 from ddl_tpu.ops.ici_fanout import (
     AXIS,
     N_SLOTS,
     _check_slot,
     _ring_mesh,
+    compile_kernel,
+    interpret_arg,
     interpret_default,
+    kernel_view,
 )
 
 #: Mosaic collective ids for the exchange kernel, indexed by landing
-#: slot — must differ from every other collective kernel that can be in
-#: flight on the chip at the same time (the fan-out holds 11-14).
+#: slot — the id names the barrier semaphore the entry handshake runs
+#: on, and must differ from every other collective kernel a device can
+#: be ahead of or behind in (the fan-out holds 11-14).
 _EXCHANGE_COLLECTIVE_IDS = (15, 16)
 
-#: The two lane steps of one exchange round (grid size): step 0 moves
-#: lane A along ``p``, step 1 moves lane B along ``pinv``.
+#: The two lanes of one exchange round: lane 0 (A) moves along ``p``,
+#: lane 1 (B) along ``pinv``.
 _N_LANES = 2
+
+_LOGICAL = pltpu.DeviceIdType.LOGICAL
 
 
 def _exchange_kernel(routes_ref, in_ref, out_ref, send_sem, recv_sem, *,
                      half: int):
-    """One exchange round: two permutation-shaped remote-DMA steps.
-
-    ``routes_ref`` is the scalar-prefetched (2, n) int32 ``[p, pinv]``;
-    step ``t`` sends this device's rows ``[t*half, (t+1)*half)`` to
-    device ``routes[t, me]`` and receives the same lane slice from its
-    inverse — a full permutation per step, so interpret mode's
-    one-copy-per-device lockstep invariant holds by construction.
-    """
-    t = pl.program_id(0)
-    last_t = pl.num_programs(0) - 1
+    """One exchange round: this device's rows ``[t*half, (t+1)*half)``
+    go to device ``routes[t, me]`` and the same lane slice arrives from
+    its inverse, for both lanes at once."""
     me = lax.axis_index(AXIS)
+    barrier = pltpu.get_barrier_semaphore()
 
-    def _send_op(step):
-        # Slice + target always describe the CURRENT step's lane; the
-        # parity wait below only consumes step t-1's send semaphore, for
-        # which the descriptor's slice/target are irrelevant (the
-        # ici_fanout idiom).
+    def lane(t):
         return pltpu.make_async_remote_copy(
             src_ref=in_ref.at[pl.ds(t * half, half)],
             dst_ref=out_ref.at[pl.ds(t * half, half)],
-            send_sem=send_sem.at[step % 2],
-            recv_sem=recv_sem.at[step % 2],
+            send_sem=send_sem.at[t],
+            recv_sem=recv_sem.at[t],
             device_id=routes_ref[t, me],
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
+            device_id_type=_LOGICAL,
         )
 
-    op = _send_op(t)
-    op.start()
-    op.wait_recv()
-
-    # Double buffer: start step t's DMA before draining step t-1's —
-    # lane B is on the links while lane A's send completes.
-    @pl.when(t >= 1)
-    def _wait_prev():
-        _send_op(t - 1).wait_send()
-
-    @pl.when(t == last_t)
-    def _drain():
-        _send_op(t).wait_send()
+    # Entry barrier: my two targets are also my two writers (see the
+    # module docstring) — tell both I am in, wait for both.
+    for t in range(_N_LANES):
+        pltpu.semaphore_signal(
+            barrier, inc=1, device_id=routes_ref[t, me],
+            device_id_type=_LOGICAL,
+        )
+    pltpu.semaphore_wait(barrier, _N_LANES)
+    for t in range(_N_LANES):
+        lane(t).start()
+    for t in range(_N_LANES):
+        lane(t).wait_recv()
+    for t in range(_N_LANES):
+        lane(t).wait_send()
 
 
 @functools.lru_cache(maxsize=64)
 def _exchange_call(devices: Tuple[Any, ...], half: int, cols: int,
                    dtype_name: str, interpret: bool, slot: int = 0):
-    """Jitted shard_map'ed ring exchange over ``devices``: inputs are
-    the (2, n) int32 routes (replicated) and the global
-    (n * 2 * half, cols) P(x) lane blocks; output has the same global
-    shape with both lanes exchanged.  Cached per geometry — the routes
-    are DATA, so every round of a geometry reuses one program."""
+    """Compiled shard_map'ed exchange over ``devices``: inputs are the
+    (2, n) int32 routes (replicated) and the global (n * 2 * half, cols)
+    P(x) lane blocks; output has the same global shape with both lanes
+    exchanged.  Cached per geometry — the routes are DATA, so every
+    round of a geometry reuses one program."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    n_dev = len(devices)
     mesh = _ring_mesh(devices)
     dtype = np.dtype(dtype_name)
-    kern = functools.partial(_exchange_kernel, half=half)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(_N_LANES,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        scratch_shapes=[pltpu.SemaphoreType.DMA((2,))] * 2,
-    )
     call = pl.pallas_call(
-        kern,
+        functools.partial(_exchange_kernel, half=half),
         out_shape=jax.ShapeDtypeStruct((_N_LANES * half, cols), dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-        compiler_params=pltpu.TPUCompilerParams(
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.SemaphoreType.DMA((_N_LANES,))] * 2,
+        interpret=interpret_arg(interpret),
+        compiler_params=pltpu.CompilerParams(
             collective_id=_EXCHANGE_COLLECTIVE_IDS[slot]
         ),
     )
@@ -153,7 +149,13 @@ def _exchange_call(devices: Tuple[Any, ...], half: int, cols: int,
     )
     spec = NamedSharding(mesh, P(AXIS))
     rspec = NamedSharding(mesh, P(None, None))
-    return jax.jit(fn, in_shardings=(rspec, spec), out_shardings=spec)
+    return compile_kernel(
+        jax.jit(fn, in_shardings=(rspec, spec), out_shardings=spec),
+        jax.ShapeDtypeStruct((_N_LANES, n_dev), np.int32, sharding=rspec),
+        jax.ShapeDtypeStruct(
+            (n_dev * _N_LANES * half, cols), dtype, sharding=spec
+        ),
+    )
 
 
 @functools.lru_cache(maxsize=64)
@@ -193,7 +195,14 @@ def as_exchange_input(blocks: Sequence[np.ndarray],
     """Land per-instance lane blocks on their ring devices and assemble
     the SPMD global (n * 2 * half, cols) P(x) input — the H2D landing
     edge of the exchange (the host touches the rows exactly once; every
-    subsequent hop rides ICI)."""
+    subsequent hop rides ICI).
+
+    Mosaic only slices HBM along its tiling, so a block whose lanes are
+    off it (``ici_fanout.kernel_view``) is landed as its LANE VIEW —
+    each exchange lane's elements as ``(R, 128)`` rows padded to whole
+    tiles, lane A over lane B — and :func:`exchange_output_blocks`
+    restores the shape.  The packing is host-side numpy at an edge the
+    host touches anyway; tile-aligned blocks pass through untouched."""
     devices = tuple(devices)
     n_dev = len(devices)
     if len(blocks) != n_dev:
@@ -204,6 +213,17 @@ def as_exchange_input(blocks: Sequence[np.ndarray],
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     rows, cols = blocks[0].shape
+    half = rows // _N_LANES
+    krows, kcols = kernel_view(_N_LANES, half, cols, blocks[0].dtype)
+    if (krows, kcols) != (rows, cols):
+        blocks = [
+            np.pad(
+                b.reshape(_N_LANES, half * cols),
+                ((0, 0), (0, krows // _N_LANES * kcols - half * cols)),
+            ).reshape(krows, kcols)
+            for b in blocks
+        ]
+        rows, cols = krows, kcols
     shards = [jax.device_put(b, d) for b, d in zip(blocks, devices)]
     return jax.make_array_from_single_device_arrays(
         (n_dev * rows, cols),
@@ -212,18 +232,27 @@ def as_exchange_input(blocks: Sequence[np.ndarray],
     )
 
 
-def exchange_output_blocks(out: Any,
-                           devices: Sequence[Any]) -> List[np.ndarray]:
+def exchange_output_blocks(out: Any, devices: Sequence[Any],
+                           shape: Tuple[int, int]) -> List[np.ndarray]:
     """Fetch the exchanged lane blocks back to the host, one per ring
-    position — the D2H edge where the fabric hands rows back to each
-    producer's private pool (the exchange's only other host touch)."""
+    position and each of the posted block ``shape`` (undoing
+    :func:`as_exchange_input`'s lane view where it applied) — the D2H
+    edge where the fabric hands rows back to each producer's private
+    pool (the exchange's only other host touch)."""
     devices = tuple(devices)
     n_dev = len(devices)
     rows = out.shape[0] // n_dev
     by_start: Dict[int, Any] = {
         (s.index[0].start or 0): s.data for s in out.addressable_shards
     }
-    return [np.asarray(by_start[i * rows]) for i in range(n_dev)]
+    blocks = [np.asarray(by_start[i * rows]) for i in range(n_dev)]
+    if blocks[0].shape != tuple(shape):
+        half_elems = shape[0] // _N_LANES * shape[1]
+        blocks = [
+            b.reshape(_N_LANES, -1)[:, :half_elems].reshape(shape)
+            for b in blocks
+        ]
+    return blocks
 
 
 def exchange_ring(gin: Any, devices: Sequence[Any], routes: np.ndarray,

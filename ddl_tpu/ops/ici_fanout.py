@@ -1,57 +1,66 @@
 """Pallas ICI fan-out kernels: device-side window distribution.
 
-After six PRs every byte still entered the pod through one host's
-``device_put`` — the window crossed H2D once and was then scattered by
-XLA with no measurement or control of the ICI hop (ROADMAP item 1).
-These kernels make that hop explicit: one source device's committed
-window is replicated (ring broadcast) or sharded (ring scatter) across a
-1-axis device ring entirely over ICI with ``pltpu.make_async_remote_copy``
-DMAs, double-buffered so chunk N+1's DMA overlaps chunk N's wait.
+One source device's committed window is replicated (pipelined ring
+broadcast) or sharded (direct scatter) across a 1-axis device ring
+entirely over ICI with ``pltpu.make_async_remote_copy`` DMAs.  The
+kernels are written for the hardware — every device runs at its own
+pace — and the synchronisation argument is in the code, not in the
+test harness:
 
-Kernel shape constraints (why the code looks the way it does):
+- **Entry barrier.**  A remote DMA writes into the *destination's*
+  output buffer, which exists only once that device has entered the
+  kernel.  Every device that will be written to therefore signals its
+  writer on the kernel's barrier semaphore
+  (``pltpu.get_barrier_semaphore``, keyed by the per-slot
+  ``collective_id``) and every writer waits for those signals before
+  its first send.  Each signal is consumed by exactly one wait, so the
+  semaphore is back at zero when the kernel exits.
+- **Write-once destinations.**  No byte of any output buffer is written
+  twice and no buffer is reused inside a kernel: the broadcast forwards
+  chunk ``c`` only after ITS receive semaphore fired and nobody writes
+  chunk ``c`` of that device again; the scatter sends each block
+  straight from the source's window into its owner's output.  There is
+  no transit buffer to overwrite and no semaphore shared between two
+  in-flight transfers (one DMA semaphore per chunk / per block), so no
+  ordering between different DMAs is ever assumed.
+- **Exit.**  A device leaves only after every DMA it sent has drained
+  (``wait_send``) and every DMA aimed at it has landed (``wait_recv``).
 
-- **Permute-shaped steps.**  Interpret mode (the CPU virtual-mesh test
-  path) discharges a remote DMA as a *collective*: every device in the
-  axis must execute every ``dma_start`` in lockstep, and the target map
-  of each step must deliver exactly one copy to every device
-  (``jax/_src/pallas/mosaic/primitives.py`` gathers ``device_id`` with
-  ``lax.all_gather`` and ``argmax``-selects the sender).  Role-gated
-  sends (``pl.when(is_source)``) therefore deadlock under interpret —
-  both kernels instead run a full right-rotation every step, with the
-  chunk schedule clamped so devices ahead of / behind the pipeline send
-  repeats of valid edge chunks.
-- **Sink chunk.**  The rotation wraps: the ring tail sends to the
-  source every step.  Early steps that send would carry garbage into
-  the source's *live* window (a read-write race on real hardware), so
-  the tail redirects its wrap-around send into a dedicated sink chunk
-  past the payload — dead bytes on a link the broadcast cannot use
-  anyway.
-- **Double buffering.**  DMA semaphores are parity pairs (``sem[t % 2]``):
-  step ``t`` starts its send, *then* waits step ``t-1``'s send — one
-  send is always in flight while the previous one drains.  The scatter
-  kernel's transit buffer is a ``(2, block)`` VMEM ping-pong for the
-  same reason: the forward of step ``t`` reads the half the recv of
-  step ``t`` is not writing.
+**Landing slots (fused step).**  The fused compute/ingest step keeps
+TWO windows' fan-outs dispatched: window N+1's program is enqueued
+while the step computing window N runs.  Devices skew, so a fast
+device can enter window N+1's kernel while a neighbour is still in
+window N's; the two must not share a barrier semaphore.  Every wrapper
+takes a ``slot`` (< ``N_SLOTS``) selecting a per-slot ``collective_id``
+AND a per-slot set of cached landing buffers.  The split start/wait
+surface is :func:`fanout_start` / :func:`fanout_wait`: start IS the
+async dispatch of the slot's program and the wait is the consumer's
+first use of the returned value.
 
-- **Landing slots (fused step).**  The fused compute/ingest step keeps
-  TWO windows' fan-outs in flight: window N+1's ring is dispatched at
-  the entry of the step computing window N, and its DMA semaphores are
-  waited on only at the next step's first use of the data.  Two
-  concurrently-running collective kernels on a chip must not share
-  barrier semaphores, so every wrapper takes a ``slot`` (< ``N_SLOTS``)
-  selecting a *per-slot* Mosaic ``collective_id`` pair AND a per-slot
-  set of cached landing buffers — the device-side landing slots.  The
-  split start/wait surface is :func:`fanout_start` /
-  :func:`fanout_wait`: start IS the async dispatch of the slot's ring
-  program (the DMA ring is enqueued device-side and runs under the
-  in-flight step), and the wait is deferred to the consumer's first
-  use of the returned value (``sync=True`` forces a host
-  ``block_until_ready`` — the bring-up validation path only).
+**Tile alignment.**  Mosaic only slices an HBM array along its tiling
+(128 lanes by 8 sublanes of 32 bits; 16 sublanes at 16 bits, 32 at 8),
+and a training job's windows do not oblige: a dp-sharded token window
+hands each device ONE row.  Since a row block is just a contiguous run
+of elements, the wrappers move any block whose shape is off the tiling
+through its LANE VIEW — the same elements as ``(R, 128)`` rows, ``R``
+padded up to whole tiles — packed and unpacked by two small jitted
+programs around the kernel.  Blocks already on the tiling (the 64 MiB
+stream windows) go through as they are, with no extra copy.
 
-The wrappers fall back to ``interpret=True`` off-TPU, which is how the
-CPU suite validates byte identity against the host path (tier-1); on a
-real pod the same kernels compile through Mosaic (``collective_id`` is
-reserved per mode and slot).
+Off-TPU the wrappers run the same kernels under Pallas' TPU interpret
+mode (``pltpu.InterpretParams``), which simulates per-device progress,
+remote DMAs and semaphores — the barrier is executed there too, and the
+race detector can be turned on (tests/test_ici.py).  **Interpret mode
+has a size limit**: the interpreter hands each kernel operand to a
+Python callback, the CPU client copies operands past ~100 KiB on its
+own thread pool, and the ring's device threads — blocked in their
+callbacks on semaphores — already occupy that pool when the ring is as
+wide as the host has cores: the run deadlocks.  Keep interpreted
+windows under 64 KiB (tier-1 does, by a wide margin); real sizes are
+for the chip.  The compiled
+programs are built ahead of time (``.lower().compile()``) so that a
+kernel Mosaic refuses surfaces as :class:`KernelBuildError` at build,
+never as a "link fault" in a caller's fallback ladder.
 """
 
 from __future__ import annotations
@@ -63,20 +72,19 @@ from typing import Any, Optional, Sequence, Tuple
 import jax
 import numpy as np
 from jax import lax
-from jax import numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ddl_tpu._compat import shard_map
+from ddl_tpu.exceptions import KernelBuildError
 
-#: The fan-out ring's private mesh axis (always 1-axis: interpret-mode
-#: remote DMA only supports a single named dimension, and the
+#: The fan-out ring's private mesh axis (always 1-axis: the
 #: redistribution planner owns the mapping onto dp x fsdp x tp).
 AXIS = "x"
 
 #: Default chunk count for the broadcast pipeline.  More chunks deepen
-#: the pipeline (per-chunk latency hides behind the ring) but add
-#: (n_dev - 2) clamped edge sends of one chunk each; 4 is a reasonable
+#: the pipeline (a relay forwards chunk c while chunk c+1 is still
+#: arriving) at one DMA semaphore pair per chunk; 4 is a reasonable
 #: floor for the window sizes the loader moves (>= 8 MiB).
 DEFAULT_CHUNKS = 4
 
@@ -87,139 +95,121 @@ DEFAULT_CHUNKS = 4
 #: pinned landing-buffer set per geometry.
 N_SLOTS = 2
 
-#: Mosaic collective ids (must differ between concurrently-used
-#: collective kernels on a chip).  Indexed by landing slot: the fused
-#: step keeps two ring programs in flight, and two kernels sharing a
-#: ``collective_id`` would share barrier semaphores — the per-slot pair
-#: is what makes the overlap sound on real hardware.
+#: Mosaic collective ids, indexed by landing slot: the id names the
+#: barrier semaphore a kernel's entry handshake runs on, and two
+#: kernels that can be entered out of step by different devices (the
+#: two landing slots) must not share one.
 _BCAST_COLLECTIVE_IDS = (11, 13)
 _SCATTER_COLLECTIVE_IDS = (12, 14)
 
+_LOGICAL = pltpu.DeviceIdType.LOGICAL
+
 
 def _bcast_kernel(in_ref, out_ref, send_sem, recv_sem, copy_sem, *,
-                  src: int, n_dev: int, rows: int, n_chunks: int):
-    """Pipelined ring broadcast: source's ``in_ref`` (n_chunks * rows
-    payload rows) lands in every device's ``out_ref`` (payload + one
-    sink chunk).  Grid = (n_chunks + n_dev - 2,) steps; device at ring
-    position p forwards chunk ``clip(t - p)`` at step t."""
-    t = pl.program_id(0)
-    last_t = pl.num_programs(0) - 1
+                  src: int, n_dev: int, chunks: Tuple[Tuple[int, int], ...]):
+    """Pipelined ring broadcast: the source's ``in_ref`` lands in every
+    device's ``out_ref``.  Ring position 0 (the source) sends each chunk
+    to its right neighbour straight from the window; positions
+    1..n-2 forward chunk ``c`` as soon as it has landed; the tail only
+    receives.  ``chunks`` is the static ((row_start, n_rows), ...)
+    split."""
     me = lax.axis_index(AXIS)
     pos = lax.rem(me - src + n_dev, n_dev)
     right = lax.rem(me + 1, n_dev)
-    c_src = jnp.clip(t - pos, 0, n_chunks - 1)
-    # The ring tail's send wraps around to the source; redirect it into
-    # the sink chunk so the live window is never overwritten mid-stream.
-    c_dst = jnp.where(pos == n_dev - 1, n_chunks, c_src)
+    left = lax.rem(me + n_dev - 1, n_dev)
+    barrier = pltpu.get_barrier_semaphore()
 
-    # Source: stage chunk t of the window into its own out buffer BEFORE
-    # forwarding it (the send below reads out_ref).
-    @pl.when((pos == 0) & (t < n_chunks))
-    def _stage():
-        cp = pltpu.make_async_copy(
-            in_ref.at[pl.ds(t * rows, rows)],
-            out_ref.at[pl.ds(t * rows, rows)],
-            copy_sem.at[t % 2],
-        )
-        cp.start()
-        cp.wait()  # ddl-lint: disable=DDL012 - device-side DMA semaphore, not a host wait
-
-    def _send_op(step):
-        # One descriptor shape for start and the parity waits: the wait
-        # only consumes semaphore signals sized like one chunk, so the
-        # slice indices of the waited step are irrelevant.
+    def hop(c, from_ref):
+        start, size = chunks[c]
         return pltpu.make_async_remote_copy(
-            src_ref=out_ref.at[pl.ds(c_src * rows, rows)],
-            dst_ref=out_ref.at[pl.ds(c_dst * rows, rows)],
-            send_sem=send_sem.at[step % 2],
-            recv_sem=recv_sem.at[step % 2],
+            src_ref=from_ref.at[pl.ds(start, size)],
+            dst_ref=out_ref.at[pl.ds(start, size)],
+            send_sem=send_sem.at[c],
+            recv_sem=recv_sem.at[c],
             device_id=right,
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
+            device_id_type=_LOGICAL,
         )
 
-    op = _send_op(t)
-    op.start()
-    op.wait_recv()
+    # Entry barrier: my LEFT neighbour is the one device that writes
+    # into me — tell it my out_ref exists; before my own first send,
+    # wait for the same word from my right.
+    @pl.when(pos > 0)
+    def _announce():
+        pltpu.semaphore_signal(
+            barrier, inc=1, device_id=left, device_id_type=_LOGICAL
+        )
 
-    # Double buffer: only after launching step t's DMA do we drain step
-    # t-1's — chunk N+1 crosses the link while chunk N's wait runs.
-    @pl.when(t >= 1)
-    def _wait_prev():
-        _send_op(t - 1).wait_send()
+    @pl.when(pos < n_dev - 1)
+    def _await_right():
+        pltpu.semaphore_wait(barrier, 1)
 
-    @pl.when(t == last_t)
-    def _drain():
-        _send_op(t).wait_send()
-
-
-def _scatter_kernel(in_ref, out_ref, transit, send_sem, recv_sem,
-                    copy_sem, *, src: int, n_dev: int, rows: int):
-    """Pipelined ring scatter: row-block ``b`` of the source's window
-    lands on the device at ring position ``(b - src) % n_dev``.  Blocks
-    are injected farthest-destination-first, so every device's own block
-    arrives exactly at the last step (grid = (n_dev - 1,)).  Transit is
-    a double-buffered VMEM ping-pong; the source's transit half receives
-    the wrap-around garbage and is never read."""
-    s = pl.program_id(0)
-    last_s = pl.num_programs(0) - 1
-    me = lax.axis_index(AXIS)
-    pos = lax.rem(me - src + n_dev, n_dev)
-    right = lax.rem(me + 1, n_dev)
-    par = s % 2        # recv half this step
-    prev = (s + 1) % 2  # send half this step (== recv half of step s-1)
-
-    # Source stages the outgoing block (farthest destination first) into
-    # the send half; destination position p's block is row-block
-    # (src + p) % n_dev of the window.
     @pl.when(pos == 0)
-    def _stage():
-        blk = lax.rem(src + (n_dev - 1 - s), n_dev)
-        cp = pltpu.make_async_copy(
-            in_ref.at[pl.ds(blk * rows, rows)],
-            transit.at[prev],
-            copy_sem.at[par],
-        )
-        cp.start()
-        cp.wait()  # ddl-lint: disable=DDL012 - device-side DMA semaphore, not a host wait
+    def _source():
+        own = pltpu.make_async_copy(in_ref, out_ref, copy_sem.at[0])
+        own.start()
+        for c in range(len(chunks)):
+            hop(c, in_ref).start()
+        own.wait()  # ddl-lint: disable=DDL012 - device-side DMA semaphore, not a host wait
+        for c in range(len(chunks)):
+            hop(c, in_ref).wait_send()
 
-    def _send_op(step):
+    @pl.when((pos > 0) & (pos < n_dev - 1))
+    def _relay():
+        for c in range(len(chunks)):
+            hop(c, out_ref).wait_recv()
+            hop(c, out_ref).start()
+        for c in range(len(chunks)):
+            hop(c, out_ref).wait_send()
+
+    @pl.when(pos == n_dev - 1)
+    def _tail():
+        for c in range(len(chunks)):
+            hop(c, out_ref).wait_recv()
+
+
+def _scatter_kernel(in_ref, out_ref, send_sem, recv_sem, copy_sem, *,
+                    src: int, n_dev: int, rows: int):
+    """Direct scatter: row-block ``d`` of the source's window lands in
+    device ``d``'s ``out_ref``, one remote DMA per destination, all in
+    flight together (the source's egress is the bound either way, and
+    nothing is forwarded, so there is no transit buffer)."""
+    me = lax.axis_index(AXIS)
+    barrier = pltpu.get_barrier_semaphore()
+
+    def block_to(dst: int):
         return pltpu.make_async_remote_copy(
-            src_ref=transit.at[(step + 1) % 2],
-            dst_ref=transit.at[step % 2],
-            send_sem=send_sem.at[step % 2],
-            recv_sem=recv_sem.at[step % 2],
-            device_id=right,
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
+            src_ref=in_ref.at[pl.ds(dst * rows, rows)],
+            dst_ref=out_ref,
+            send_sem=send_sem.at[dst],
+            recv_sem=recv_sem.at[0],
+            device_id=dst,
+            device_id_type=_LOGICAL,
         )
 
-    op = _send_op(s)
-    op.start()
-    op.wait_recv()
+    others = [d for d in range(n_dev) if d != src]
 
-    # Every non-source device's own block arrives exactly at the last
-    # step: keep it.
-    @pl.when((pos > 0) & (s == last_s))
-    def _keep():
-        cp = pltpu.make_async_copy(transit.at[par], out_ref, copy_sem.at[prev])
-        cp.start()
-        cp.wait()  # ddl-lint: disable=DDL012 - device-side DMA semaphore, not a host wait
-
-    # The source's own block never travels the ring.
-    @pl.when((pos == 0) & (s == 0))
-    def _own():
-        cp = pltpu.make_async_copy(
-            in_ref.at[pl.ds(src * rows, rows)], out_ref, copy_sem.at[prev]
+    # Entry barrier: only the source writes remotely, so every other
+    # device tells the source its out_ref exists and the source waits
+    # for all of them before its first send.
+    @pl.when(me != src)
+    def _dest():
+        pltpu.semaphore_signal(
+            barrier, inc=1, device_id=src, device_id_type=_LOGICAL
         )
-        cp.start()
-        cp.wait()  # ddl-lint: disable=DDL012 - device-side DMA semaphore, not a host wait
+        block_to(others[0]).wait_recv()
 
-    @pl.when(s >= 1)
-    def _wait_prev():
-        _send_op(s - 1).wait_send()
-
-    @pl.when(s == last_s)
-    def _drain():
-        _send_op(s).wait_send()
+    @pl.when(me == src)
+    def _source():
+        pltpu.semaphore_wait(barrier, n_dev - 1)
+        own = pltpu.make_async_copy(
+            in_ref.at[pl.ds(src * rows, rows)], out_ref, copy_sem.at[0]
+        )
+        own.start()
+        for d in others:
+            block_to(d).start()
+        own.wait()  # ddl-lint: disable=DDL012 - device-side DMA semaphore, not a host wait
+        for d in others:
+            block_to(d).wait_send()
 
 
 def interpret_default(devices: Sequence[Any]) -> bool:
@@ -227,51 +217,89 @@ def interpret_default(devices: Sequence[Any]) -> bool:
     return any(getattr(d, "platform", "cpu") != "tpu" for d in devices)
 
 
+def interpret_arg(interpret: bool) -> Any:
+    """The ``pallas_call(interpret=...)`` value: Pallas' TPU interpret
+    mode (simulated per-device progress, remote DMAs, semaphores and
+    the barrier) off-chip, Mosaic on it."""
+    return pltpu.InterpretParams() if interpret else False
+
+
+def compile_kernel(jitted: Any, *args: Any) -> Any:
+    """Build a jitted kernel program ahead of time.  Whatever the
+    compiler refuses (Mosaic: a slice off the tiling, a fast-memory
+    overrun, a misused semaphore; XLA: a program that does not fit) is
+    a broken program, so it is re-typed as :class:`KernelBuildError` —
+    the runtime fault ladders catch ``JaxRuntimeError`` for genuine
+    link faults and must not see this one.  Python-level build errors
+    (tracing, lowering) propagate as they are."""
+    lowered = jitted.lower(*args)
+    try:
+        return lowered.compile()
+    except Exception as e:  # noqa: BLE001 - every failure of compile() is one
+        raise KernelBuildError(f"kernel failed to compile: {e}") from e
+
+
 # -- geometry helpers ---------------------------------------------------------
 
 
-def bcast_grid(n_dev: int, n_chunks: int) -> int:
-    """Broadcast pipeline depth: chunk c reaches ring position p at step
-    p + c - 1, so the tail's last chunk lands at step n_dev + n_chunks - 3."""
-    return n_chunks + n_dev - 2
+#: Lane width of the TPU's HBM/VMEM tiling.
+LANES = 128
 
 
-def wire_bytes(mode: str, nbytes: int, n_dev: int,
-               n_chunks: int = DEFAULT_CHUNKS,
-               rows: Optional[int] = None) -> int:
-    """Total bytes the fan-out moves over ICI links (including the
-    clamped edge repeats and the sink-chunk wrap sends) — the honest
-    numerator for link-utilization math.
-
-    Pass ``rows`` (the 2D view's leading dim) when known: the broadcast
-    pads rows up to a chunk multiple and every DMA moves whole padded
-    chunks, so the rowless byte-ceil estimate underprices the wire
-    whenever ``rows % n_chunks != 0``."""
-    if n_dev <= 1:
-        return 0
-    if mode == "replicate":
-        if rows:
-            # ceil(rows/n_chunks) whole rows per chunk-send.
-            chunk = -(-rows // n_chunks) * (nbytes // rows)
-        else:
-            chunk = -(-nbytes // n_chunks)
-        return n_dev * bcast_grid(n_dev, n_chunks) * chunk
-    if mode == "shard":
-        block = nbytes // n_dev
-        return n_dev * (n_dev - 1) * block
-    raise ValueError(f"mode must be replicate|shard, got {mode!r}")
+def sublanes(dtype: Any) -> int:
+    """Rows of one tile: 8 at 32 bits, 16 at 16, 32 at 8 — 4 KiB a
+    tile whatever the dtype."""
+    return max(8, 32 // np.dtype(dtype).itemsize)
 
 
-def payload_bytes(mode: str, nbytes: int, n_dev: int) -> int:
-    """Bytes usefully *delivered* by the fan-out (what the consumer
-    gains): n-1 windows for replicate, the off-source blocks for shard."""
+def tile_aligned(rows: int, cols: int, dtype: Any) -> bool:
+    """Can Mosaic slice whole ``rows``-row blocks out of a (k * rows,
+    cols) array of this dtype?"""
+    return cols % LANES == 0 and rows % sublanes(dtype) == 0
+
+
+def lane_rows(n_elems: int, dtype: Any) -> int:
+    """Rows ``R`` of the ``(R, 128)`` lane view that holds ``n_elems``
+    elements, padded up to whole tiles."""
+    sub = sublanes(dtype)
+    return -(-n_elems // (LANES * sub)) * sub
+
+
+def kernel_view(n_blocks: int, block_rows: int, cols: int,
+                dtype: Any) -> Tuple[int, int]:
+    """The (rows, cols) array a kernel moves for ``n_blocks`` blocks of
+    (block_rows, cols): the blocks themselves where Mosaic can slice
+    them, else their lane views stacked."""
+    if tile_aligned(block_rows, cols, dtype):
+        return n_blocks * block_rows, cols
+    return n_blocks * lane_rows(block_rows * cols, dtype), LANES
+
+
+def chunk_rows(rows: int, n_chunks: int,
+               align: int = 1) -> Tuple[Tuple[int, int], ...]:
+    """Static ((row_start, n_rows), ...) split of ``rows`` into at most
+    ``n_chunks`` contiguous chunks of a multiple of ``align`` rows (the
+    last one may be short)."""
+    n_chunks = max(1, min(n_chunks, rows))
+    size = -(-rows // n_chunks)
+    size = -(-size // align) * align
+    return tuple(
+        (start, min(size, rows - start)) for start in range(0, rows, size)
+    )
+
+
+def wire_bytes(mode: str, nbytes: int, n_dev: int) -> int:
+    """Bytes the fan-out's DMAs move over ICI — the numerator for
+    link-utilization math.  Neither kernel sends a byte that is not
+    delivered: the broadcast forwards the window once per non-tail
+    ring position, the scatter sends each off-source block once."""
+    if mode not in ("replicate", "shard"):
+        raise ValueError(f"mode must be replicate|shard, got {mode!r}")
     if n_dev <= 1:
         return 0
     if mode == "replicate":
         return (n_dev - 1) * nbytes
-    if mode == "shard":
-        return nbytes - nbytes // n_dev
-    raise ValueError(f"mode must be replicate|shard, got {mode!r}")
+    return nbytes - nbytes // n_dev
 
 
 # -- compiled-call cache ------------------------------------------------------
@@ -284,90 +312,122 @@ def _ring_mesh(devices: Tuple[Any, ...]):
     return Mesh(np.array(devices), (AXIS,))
 
 
-@functools.lru_cache(maxsize=64)
-def _bcast_call(devices: Tuple[Any, ...], rows: int, cols: int,
-                dtype_name: str, src: int, n_chunks: int, interpret: bool,
-                slot: int = 0):
-    """Jitted shard_map'ed broadcast over ``devices``: input global
-    (n * R_pad, cols) P(x) [only the source's block is real], output
-    global (n * (R_pad + rows_per_chunk), cols) P(x) [payload + sink]."""
-    import jax.numpy as jnp  # noqa: F401 - dtype resolution namespace
+def _ring_program(call: Any, devices: Tuple[Any, ...], in_rows: int,
+                  cols: int, dtype: Any) -> Any:
+    """shard_map + jit + ahead-of-time compile of one ring kernel whose
+    single input is the global (n * in_rows, cols) P(x) array."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    n_dev = len(devices)
     mesh = _ring_mesh(devices)
-    dtype = np.dtype(dtype_name)
-    chunk_rows = rows // n_chunks
-    kern = functools.partial(
-        _bcast_kernel, src=src, n_dev=n_dev, rows=chunk_rows,
-        n_chunks=n_chunks,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=0,
-        grid=(bcast_grid(n_dev, n_chunks),),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        scratch_shapes=[pltpu.SemaphoreType.DMA((2,))] * 3,
-    )
-    call = pl.pallas_call(
-        kern,
-        out_shape=jax.ShapeDtypeStruct((rows + chunk_rows, cols), dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-        compiler_params=pltpu.TPUCompilerParams(
-            collective_id=_BCAST_COLLECTIVE_IDS[slot]
-        ),
-    )
     fn = shard_map(
         call, mesh=mesh, in_specs=P(AXIS), out_specs=P(AXIS),
         check_vma=False,
     )
     spec = NamedSharding(mesh, P(AXIS))
-    return jax.jit(fn, in_shardings=spec, out_shardings=spec)
+    return compile_kernel(
+        jax.jit(fn, in_shardings=spec, out_shardings=spec),
+        jax.ShapeDtypeStruct(
+            (len(devices) * in_rows, cols), dtype, sharding=spec
+        ),
+    )
+
+
+@functools.lru_cache(maxsize=64)
+def _bcast_call(devices: Tuple[Any, ...], rows: int, cols: int,
+                dtype_name: str, src: int, n_chunks: int, interpret: bool,
+                slot: int = 0):
+    """Compiled broadcast over ``devices``: input global (n * rows,
+    cols) P(x) [only the source's block is real], output the same
+    global shape, every block the source's."""
+    dtype = np.dtype(dtype_name)
+    chunks = chunk_rows(rows, n_chunks, align=sublanes(dtype))
+    call = pl.pallas_call(
+        functools.partial(
+            _bcast_kernel, src=src, n_dev=len(devices), chunks=chunks
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows, cols), dtype),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.SemaphoreType.DMA((len(chunks),)),
+            pltpu.SemaphoreType.DMA((len(chunks),)),
+            pltpu.SemaphoreType.DMA((1,)),
+        ],
+        interpret=interpret_arg(interpret),
+        compiler_params=pltpu.CompilerParams(
+            collective_id=_BCAST_COLLECTIVE_IDS[slot]
+        ),
+    )
+    return _ring_program(call, devices, rows, cols, dtype)
 
 
 @functools.lru_cache(maxsize=64)
 def _scatter_call(devices: Tuple[Any, ...], rows: int, cols: int,
                   dtype_name: str, src: int, interpret: bool,
                   slot: int = 0):
-    """Jitted shard_map'ed scatter: input global (n * R, cols) P(x)
-    [source block real], output global (R, cols) P(x) — row-block i on
+    """Compiled scatter: input global (n * rows, cols) P(x) [source
+    block real], output global (rows, cols) P(x) — row-block i on
     device i."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
     n_dev = len(devices)
-    mesh = _ring_mesh(devices)
     dtype = np.dtype(dtype_name)
     block_rows = rows // n_dev
-    kern = functools.partial(
-        _scatter_kernel, src=src, n_dev=n_dev, rows=block_rows
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=0,
-        grid=(n_dev - 1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        scratch_shapes=[
-            pltpu.VMEM((2, block_rows, cols), jnp.dtype(dtype)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
     call = pl.pallas_call(
-        kern,
+        functools.partial(
+            _scatter_kernel, src=src, n_dev=n_dev, rows=block_rows
+        ),
         out_shape=jax.ShapeDtypeStruct((block_rows, cols), dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-        compiler_params=pltpu.TPUCompilerParams(
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.SemaphoreType.DMA((n_dev,)),
+            pltpu.SemaphoreType.DMA((1,)),
+            pltpu.SemaphoreType.DMA((1,)),
+        ],
+        interpret=interpret_arg(interpret),
+        compiler_params=pltpu.CompilerParams(
             collective_id=_SCATTER_COLLECTIVE_IDS[slot]
         ),
     )
+    return _ring_program(call, devices, rows, cols, dtype)
+
+
+@functools.lru_cache(maxsize=64)
+def _pack_call(device: Any, n_blocks: int, block_elems: int,
+               dtype_name: str):
+    """Source-local lane view: ``n_blocks`` blocks of ``block_elems``
+    contiguous elements each → ``(n_blocks * R, 128)``, every block
+    padded to whole tiles."""
+    from jax import numpy as jnp
+
+    lane_elems = lane_rows(block_elems, dtype_name) * LANES
+
+    def body(x):
+        x = x.reshape(n_blocks, block_elems)
+        x = jnp.pad(x, ((0, 0), (0, lane_elems - block_elems)))
+        return x.reshape(-1, LANES)
+
+    return jax.jit(
+        body, out_shardings=jax.sharding.SingleDeviceSharding(device)
+    )
+
+
+@functools.lru_cache(maxsize=64)
+def _unpack_call(devices: Tuple[Any, ...], rows: int, cols: int,
+                 dtype_name: str):
+    """Per-device inverse of :func:`_pack_call` for ONE block: global
+    (n * R, 128) P(x) → global (n * rows, cols) P(x)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    mesh = _ring_mesh(devices)
+    spec = NamedSharding(mesh, P(AXIS))
+
+    def body(x):
+        return x.reshape(-1)[: rows * cols].reshape(rows, cols)
+
     fn = shard_map(
-        call, mesh=mesh, in_specs=P(AXIS), out_specs=P(AXIS),
+        body, mesh=mesh, in_specs=P(AXIS), out_specs=P(AXIS),
         check_vma=False,
     )
-    spec = NamedSharding(mesh, P(AXIS))
     return jax.jit(fn, in_shardings=spec, out_shardings=spec)
 
 
@@ -422,6 +482,28 @@ def _check_slot(slot: int) -> int:
     return slot
 
 
+def _through_kernel(build: Any, block: Any, devices: Tuple[Any, ...],
+                    src: int, slot: int, n_blocks: int, block_rows: int,
+                    cols: int) -> Any:
+    """Run ``build(kernel_rows, kernel_cols, dtype_name)``'s program over
+    ``block`` = ``n_blocks`` blocks of (block_rows, cols): as it is where
+    the blocks sit on Mosaic's tiling, else packed into lane views
+    before and unpacked after."""
+    dtype_name = np.dtype(block.dtype).name
+    krows, kcols = kernel_view(n_blocks, block_rows, cols, dtype_name)
+    packed = (krows, kcols) != (n_blocks * block_rows, cols)
+    if packed:
+        block = _pack_call(
+            devices[src], n_blocks, block_rows * cols, dtype_name
+        )(block)
+    out = build(krows, kcols, dtype_name)(
+        _as_ring_input(block, devices, krows, kcols, src, slot)
+    )
+    if packed:
+        return _unpack_call(devices, block_rows, cols, dtype_name)(out)
+    return out
+
+
 def fanout_replicate(block: Any, devices: Sequence[Any], src: int = 0,
                      n_chunks: int = DEFAULT_CHUNKS,
                      interpret: Optional[bool] = None,
@@ -431,40 +513,31 @@ def fanout_replicate(block: Any, devices: Sequence[Any], src: int = 0,
     ``block`` must live on ``devices[src]``.  Returns a global
     ``(n * rows, cols)`` array sharded one block per device, every block
     byte-identical to the source (callers reinterpret the shards — see
-    :func:`replicated_view`).  Rows are padded up to a chunk multiple
-    internally and sliced back off.  ``slot`` selects the landing slot
-    (collective-id pair + cached landing buffers); callers keeping two
+    :func:`replicated_view`).  ``slot`` selects the landing slot
+    (collective id + cached landing buffers); callers keeping two
     fan-outs in flight must alternate slots.
     """
     devices = tuple(devices)
-    n_dev = len(devices)
     # Validate BEFORE the single-device passthrough: a bad slot must
     # fail on the 1-device dev box, not first on a real ring.
     slot = _check_slot(slot)
-    if n_dev == 1:
+    if len(devices) == 1:
         return block
     if interpret is None:
         interpret = interpret_default(devices)
     rows, cols = block.shape
-    n_chunks = max(1, min(n_chunks, rows))
-    pad = (-rows) % n_chunks
-    if pad:
-        block = jnp.pad(block, ((0, pad), (0, 0)))
-    rows_pad = rows + pad
-    gin = _as_ring_input(block, devices, rows_pad, cols, src, slot)
-    call = _bcast_call(
-        devices, rows_pad, cols, np.dtype(block.dtype).name, src,
-        n_chunks, interpret, slot,
+    return _through_kernel(
+        lambda krows, kcols, dtype_name: _bcast_call(
+            devices, krows, kcols, dtype_name, src, n_chunks, interpret, slot
+        ),
+        block, devices, src, slot, 1, rows, cols,
     )
-    out = call(gin)  # (n * (rows_pad + chunk), cols): payload + sink
-    return _strip_blocks(out, devices, rows_pad + rows_pad // n_chunks,
-                         rows)
 
 
 def fanout_shard(block: Any, devices: Sequence[Any], src: int = 0,
                  interpret: Optional[bool] = None, slot: int = 0) -> Any:
     """Scatter a (rows, cols) device block: row-block ``i`` lands on
-    ``devices[(src + ((i - src) % n)) % n]`` — i.e. block i on device i.
+    device ``i``.
 
     ``rows`` must divide evenly by the ring size (the planner guarantees
     this or falls back).  Returns a global (rows, cols) array sharded
@@ -484,12 +557,12 @@ def fanout_shard(block: Any, devices: Sequence[Any], src: int = 0,
             f"shard fan-out needs rows ({rows}) divisible by the ring "
             f"size ({n_dev})"
         )
-    gin = _as_ring_input(block, devices, rows, cols, src, slot)
-    call = _scatter_call(
-        devices, rows, cols, np.dtype(block.dtype).name, src, interpret,
-        slot,
+    return _through_kernel(
+        lambda krows, kcols, dtype_name: _scatter_call(
+            devices, krows, kcols, dtype_name, src, interpret, slot
+        ),
+        block, devices, src, slot, n_dev, rows // n_dev, cols,
     )
-    return call(gin)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -550,39 +623,6 @@ def fanout_wait(ticket: FanoutTicket, sync: bool = False) -> Any:
     if sync:
         jax.block_until_ready(ticket.value)
     return ticket.value
-
-
-def _strip_blocks(out: Any, devices: Tuple[Any, ...], block_rows: int,
-                  keep_rows: int) -> Any:
-    """Reassemble a (n * block_rows, cols) P(x) kernel output into the
-    same layout with each block truncated to ``keep_rows`` (drops chunk
-    padding + the sink chunk) — one cached jitted slice per geometry.
-    ``out`` already carries the ring's P(x) NamedSharding (the kernel's
-    declared out_shardings), so it feeds the slice directly."""
-    if block_rows == keep_rows:
-        return out
-    return _strip_call(
-        devices, block_rows, keep_rows, out.shape[1],
-        np.dtype(out.dtype).name,
-    )(out)
-
-
-@functools.lru_cache(maxsize=64)
-def _strip_call(devices: Tuple[Any, ...], block_rows: int, keep_rows: int,
-                cols: int, dtype_name: str):
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    mesh = _ring_mesh(devices)
-    spec = NamedSharding(mesh, P(AXIS))
-
-    def body(x):
-        return x[:keep_rows]
-
-    fn = shard_map(
-        body, mesh=mesh, in_specs=P(AXIS), out_specs=P(AXIS),
-        check_vma=False,
-    )
-    return jax.jit(fn, in_shardings=spec, out_shardings=spec)
 
 
 def replicated_view(out: Any, devices: Sequence[Any]) -> Any:

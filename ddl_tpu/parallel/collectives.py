@@ -45,7 +45,7 @@ def _build_sendrecv_step(
 ):
     """Jitted window-shuffle step for one permutation (cached per perm)."""
     import jax
-    from ddl_tpu._compat import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     mesh = mesh_key.mesh
@@ -77,7 +77,7 @@ def _build_all_to_all_step(mesh_key: Any, axis: str, num_exchange: int):
     """All-to-all strategy: every instance scatters its exchange block
     uniformly to all instances and gathers one sub-block from each."""
     import jax
-    from ddl_tpu._compat import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     mesh = mesh_key.mesh

@@ -24,9 +24,10 @@ programs (fan-out kernel + finish collective) and allocate nothing on
 the host.  Two fallback rungs to the ``xla`` path — the pre-existing
 ``device_put`` scatter: an UNPLANNABLE geometry (ragged batch,
 indivisible split) degrades that geometry only, while a DMA-leg failure
-(or the ``ici.fanout`` chaos site) latches the whole tier off — so the
-degradation ladder covers the new tier (``ici.fallbacks`` counts both
-rungs).
+at run time (or the ``ici.fanout`` chaos site) latches the whole tier
+off — so the degradation ladder covers the new tier (``ici.fallbacks``
+counts both rungs).  A kernel that fails to BUILD or COMPILE is neither:
+it is a broken program and propagates to the caller.
 
 Observability (all flowing into ``north_star_report`` / the bench
 ``ici`` block): ``ici.bytes`` (wire bytes the fan-out moved),
@@ -46,7 +47,7 @@ from typing import Any, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ddl_tpu import envspec
-from ddl_tpu.exceptions import ShutdownRequested
+from ddl_tpu.exceptions import InjectedFault
 from ddl_tpu.faults import fault_point
 from ddl_tpu.observability import Metrics, metrics as default_metrics
 
@@ -58,11 +59,11 @@ logger = logging.getLogger("ddl_tpu")
 #: ring's per-device landing block — shard_map needs an equal-shaped
 #: input block on EVERY ring device, so each non-source device carries
 #: one window-sized (cached, pinned) landing buffer through every leg —
-#: plus the kernel's output and the scatter's double-buffered VMEM
-#: transit.  3.0 is the worst case the shipped legs can construct: a
-#: single-chunk replicate (landing + payload output + sink chunk = 3
-#: windows); every multi-chunk or shard plan sits under it.
-DEFAULT_MEMORY_FACTOR = 3.0
+#: plus the kernel's output (the kernels keep no transit or sink
+#: buffer).  2.0 is the worst case the shipped legs can construct: a
+#: raw replicate (landing + the window-sized output); every shard or
+#: wire-encoded plan sits under it.
+DEFAULT_MEMORY_FACTOR = 2.0
 
 
 def fused_enabled(default: bool = True) -> bool:
@@ -206,15 +207,14 @@ def plan_distribution(
     dtype: Any,
     sharding: Any,
     max_memory_factor: Optional[float] = None,
-    n_chunks: Optional[int] = None,
     n_slots: int = 1,
     wire_dtype: str = "raw",
 ) -> DistributionPlan:
     """Plan the anchor→``sharding`` route for one window geometry.
 
     ``n_slots`` prices the fused two-slot protocol: with 2 landing
-    slots, window N+1's fan-out is live (its landing buffers, output
-    and transit) while window N's finish legs run, so every leg's peak
+    slots, window N+1's fan-out is live (its landing buffers and
+    output) while window N's finish legs run, so every leg's peak
     carries one extra in-flight fan-out's worth of bytes and the
     fan-out legs themselves are emitted ``asynchronous`` — start/wait
     pairs whose wait is the consuming step's first use (and which
@@ -240,7 +240,6 @@ def plan_distribution(
     rest_axes = tuple(
         a for a in mesh.axis_names if a not in split_axes
     )
-    n_chunks = n_chunks or ici_fanout.DEFAULT_CHUNKS
     n_slots = max(1, min(int(n_slots), ici_fanout.N_SLOTS))
     if max_memory_factor is None:
         max_memory_factor = DEFAULT_MEMORY_FACTOR * n_slots
@@ -255,28 +254,20 @@ def plan_distribution(
 
     if split_dim is None:
         ring = _ring_order(mesh, (), rest_axes)
-        # The kernel clamps the chunk count to the split-dim extent;
-        # mirror it so the plan prices what actually runs.
         rows = shape[0]
-        n_chunks = max(1, min(n_chunks, rows))
         enc = rows * _wire_cols(
             int(np.prod(shape)) // rows, dtype, wire_dtype
         )
-        wire = ici_fanout.wire_bytes(
-            "replicate", enc, n_dev, n_chunks, rows=rows
-        )
-        payload = ici_fanout.payload_bytes("replicate", nbytes, n_dev)
+        wire = ici_fanout.wire_bytes("replicate", enc, n_dev)
+        payload = ici_fanout.wire_bytes("replicate", nbytes, n_dev)
         # Per-device live: the window-sized SPMD landing block (cached —
         # every ring device needs an equal-shaped input block) + the
-        # kernel output (the full window, which IS the target, plus the
-        # sink chunk riding along during the kernel).  Chunk = whole
-        # padded rows, matching the kernel's row padding.  Every
+        # kernel output (the full window, which IS the target).  Every
         # ADDITIONAL in-flight landing slot pins one more landing +
         # output set for its whole dispatch span.  Wire plans size the
         # ring pieces at the ENCODED bytes and add the decoded output
         # (raw size) the landing-edge decode materialises.
-        chunk = -(-rows // n_chunks) * (enc // rows)
-        slot_live = 2 * enc + chunk
+        slot_live = 2 * enc
         peak = n_slots * slot_live + (nbytes if wire_dtype != "raw" else 0)
         legs = (
             RedistLeg("fanout.replicate", ("x",), wire, peak,
@@ -304,16 +295,15 @@ def plan_distribution(
             int(np.prod(shape)) // split, dtype, wire_dtype
         )
         wire = ici_fanout.wire_bytes("shard", enc, n_dev)
-        payload = ici_fanout.payload_bytes("shard", nbytes, n_dev)
+        payload = ici_fanout.wire_bytes("shard", nbytes, n_dev)
         block = enc // n_dev
         dst = nbytes // g
         # Scatter slot-live: the window-sized SPMD landing block (cached
-        # on every ring device) + the output block + the kernel's
-        # double-buffered VMEM transit (2 blocks) — all at the ENCODED
+        # on every ring device) + the output block — at the ENCODED
         # size for wire plans.  With the fused two-slot protocol the
         # NEXT window's fan-out is live through every leg of this
         # window's plan, so each leg carries one extra slot-live span.
-        slot_live = enc + 3 * block
+        slot_live = enc + block
         extra = (n_slots - 1) * slot_live
         legs: List[RedistLeg] = [
             RedistLeg("fanout.shard", ("x",), wire, slot_live + extra,
@@ -491,7 +481,7 @@ def _finish_shard_call(mesh_key: _MeshKey, shape: Tuple[int, ...],
     from jax import lax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ddl_tpu._compat import shard_map
+    from jax import shard_map
 
     mesh = mesh_key.mesh
     other_dims = tuple(
@@ -556,7 +546,10 @@ class IciDistributor:
     - **Tier-wide latch** — a failed DMA leg (or the ``ici.fanout``
       chaos site) sets ``faulted`` and every later window takes the XLA
       fallback — the chip keeps training while the bench/report shows
-      ``ici.fallbacks`` ticking.  The first window of each geometry is
+      ``ici.fallbacks`` ticking.  Only run-time faults latch: the
+      kernels are compiled ahead of time, and one that does not build
+      or compile raises to the caller (a broken program is not a
+      degraded link).  The first window of each geometry is
       synchronized (``block_until_ready``) inside the ladder's
       try/except, because on real TPUs dispatch is async and a bring-up
       DMA failure would otherwise surface at the CONSUMER's sync point,
@@ -644,7 +637,7 @@ class IciDistributor:
                 hit = plan_distribution(
                     key[0], key[1], self.sharding,
                     max_memory_factor=self.max_memory_factor,
-                    n_chunks=self.n_chunks, n_slots=self.n_slots,
+                    n_slots=self.n_slots,
                     wire_dtype=self.wire_dtype,
                 )
             except PlanError as e:
@@ -701,9 +694,14 @@ class IciDistributor:
     def distribute(self, block: Any) -> Any:
         """Move an anchor-resident window to the target sharding over
         ICI.  An unplannable geometry re-routes through the XLA path
-        (that geometry only); any fan-out execution failure (including
-        the ``ici.fanout`` chaos site) re-routes AND latches the
-        fallback for the rest of the distributor's life."""
+        (that geometry only); a fan-out that fails at RUN time — a
+        device-side error surfacing as ``JaxRuntimeError``, or the
+        ``ici.fanout`` chaos site — re-routes AND latches the fallback
+        for the rest of the distributor's life.  Anything else (a
+        kernel that does not build or compile, a shutdown) is not a
+        link fault and propagates."""
+        import jax
+
         if self.faulted:
             return self._xla_fallback(block)
         try:
@@ -712,9 +710,7 @@ class IciDistributor:
             return self._xla_fallback(block)
         try:
             return self._distribute_planned(block, plan)
-        except (ShutdownRequested, KeyboardInterrupt):
-            raise  # a shutdown is not a DMA failure — never latch on it
-        except Exception as e:  # noqa: BLE001 - ladder rung, re-routed
+        except (InjectedFault, jax.errors.JaxRuntimeError) as e:
             self._latch(f"{type(e).__name__}: {e}")
             return self._xla_fallback(block)
 

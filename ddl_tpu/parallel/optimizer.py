@@ -2,8 +2,8 @@
 
 The train step's optimizer state was fully replicated across the ``dp``
 axis — at adamw that is 2× the params in moments PER REPLICA, the single
-biggest HBM waste left in the training hot path (train_big at 1.39B:
-params+moments ≈ 8.4 GiB replicated per chip, BENCH_TPU_r05).  This
+biggest HBM waste left in the training hot path (the 1.39B bench model:
+params+moments in bf16 = 7.8 GiB replicated per chip).  This
 module is the cross-replica sharding of the weight update from
 *Automatic Cross-Replica Sharding of Weight Update in Data-Parallel
 Training* (arXiv:2004.13336), realised the GSPMD-native way:

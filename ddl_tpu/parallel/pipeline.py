@@ -420,7 +420,7 @@ def pipeline_apply(
         f"stacked params have {S} stages but mesh {axis}={mesh.shape[axis]}"
     )
 
-    from ddl_tpu._compat import shard_map
+    from jax import shard_map
     from ddl_tpu.observability import metrics as _default_metrics
 
     # Schedule observability (trace-time, once per compile): the

@@ -192,7 +192,7 @@ def sharded_local_attention(
     Axes that don't divide the corresponding dimension stay unsharded.
     ``segment_ids`` (B, T): packed-sequence masking, batch-sharded like q.
     """
-    from ddl_tpu._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ddl_tpu.ops import flash_attention
@@ -346,7 +346,7 @@ def ring_attention(
     ``axis`` or it has size 1.  ``segment_ids`` (B, T): packed-sequence
     masking; the key-side ids ride the ring with their K/V blocks.
     """
-    from ddl_tpu._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     if axis not in mesh.axis_names or mesh.shape[axis] == 1:

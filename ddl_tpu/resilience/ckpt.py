@@ -51,9 +51,10 @@ import logging
 import os
 import re
 import threading
+import time
+import zlib
 
 from ddl_tpu.concurrency import named_condition
-import time
 from typing import Any, List, Optional, Tuple
 
 import numpy as np
@@ -178,14 +179,17 @@ def _leaf_array(leaf: Any) -> np.ndarray:
     return np.asarray(jax.device_get(leaf))
 
 
-def serialize_generation(
+def generation_pieces(
     step: int,
     leaves: List[np.ndarray],
     loader_dict: Optional[dict],
-) -> np.ndarray:
-    """Build the stamped generation blob: magic | u32 header-len |
-    header JSON | leaf payload | 32-byte integrity trailer (crc over
-    everything before it, seq = step)."""
+) -> List[np.ndarray]:
+    """The stamped generation as the byte pieces it is written from, in
+    file order: magic | u32 header-len | header JSON, then each leaf's
+    own buffer (a VIEW — no copy of the state), then the 32-byte
+    integrity trailer (crc over everything before it, seq = step).
+    The writer streams these; for an HBM-filling model one assembled
+    blob would be one more host copy of the whole state."""
     header = json.dumps({
         "step": int(step),
         "loader": loader_dict,
@@ -194,28 +198,29 @@ def serialize_generation(
             for a in leaves
         ],
     }).encode()
-    payload_bytes = (
-        len(_MAGIC) + 4 + len(header) + sum(a.nbytes for a in leaves)
-    )
-    blob = np.empty(payload_bytes + integrity.HEADER_BYTES, dtype=np.uint8)
-    off = len(_MAGIC)
-    blob[:off] = np.frombuffer(_MAGIC, dtype=np.uint8)
-    blob[off : off + 4] = np.frombuffer(
-        np.uint32(len(header)).tobytes(), dtype=np.uint8
-    )
-    off += 4
-    blob[off : off + len(header)] = np.frombuffer(header, dtype=np.uint8)
-    off += len(header)
-    for a in leaves:
-        flat = np.ascontiguousarray(a).view(np.uint8).reshape(-1)
-        blob[off : off + flat.nbytes] = flat
-        off += flat.nbytes
-    crc = integrity.window_crc(blob[:payload_bytes])
+    head = _MAGIC + np.uint32(len(header)).tobytes() + header
+    pieces = [np.frombuffer(head, dtype=np.uint8)] + [
+        np.ascontiguousarray(a).view(np.uint8).reshape(-1) for a in leaves
+    ]
+    crc = 0
+    for piece in pieces:
+        crc = zlib.crc32(piece, crc)
+    trailer = np.zeros(integrity.HEADER_BYTES, dtype=np.uint8)
     integrity.write_header(
-        blob, payload_bytes, seq=int(step), producer_idx=_CKPT_PRODUCER,
-        crc=crc,
+        trailer, 0, seq=int(step), producer_idx=_CKPT_PRODUCER,
+        crc=crc & 0xFFFFFFFF,
     )
-    return blob
+    return pieces + [trailer]
+
+
+def serialize_generation(
+    step: int,
+    leaves: List[np.ndarray],
+    loader_dict: Optional[dict],
+) -> np.ndarray:
+    """:func:`generation_pieces` assembled into one blob (what a reader
+    of the file sees)."""
+    return np.concatenate(generation_pieces(step, leaves, loader_dict))
 
 
 def _parse_generation(path: str) -> Tuple[dict, np.ndarray]:
@@ -562,16 +567,21 @@ class AsyncCheckpointer:
         self, step: int, leaves: List[np.ndarray],
         loader_dict: Optional[dict],
     ) -> None:
-        blob = serialize_generation(step, leaves, loader_dict)
-        payload_bytes = blob.nbytes - integrity.HEADER_BYTES
-        # Chaos site: fires on the STAMPED blob just before the atomic
-        # write — CKPT_CORRUPTION flips committed bytes so read-time
-        # verification (and the quarantine/fallback ladder) is what the
-        # injection exercises.
-        fault_point("resilience.ckpt_write", view=blob[:payload_bytes])
+        pieces = generation_pieces(step, leaves, loader_dict)
+        # Chaos site: fires on the STAMPED generation just before the
+        # atomic write — CKPT_CORRUPTION flips committed bytes (of the
+        # largest leaf: the pieces are the staging buffers themselves)
+        # so read-time verification (and the quarantine/fallback
+        # ladder) is what the injection exercises.
+        fault_point(
+            "resilience.ckpt_write",
+            view=max(pieces[1:-1], key=lambda p: p.nbytes, default=None),
+        )
         path = os.path.join(self.directory, _gen_name(step))
-        atomic_file_write(path, blob.tobytes())
-        self.metrics.set_gauge("resilience.ckpt_bytes", float(blob.nbytes))
+        atomic_file_write(path, pieces)
+        self.metrics.set_gauge(
+            "resilience.ckpt_bytes", float(sum(p.nbytes for p in pieces))
+        )
         if loader_dict is not None:
             # Back-compat mirror: legacy tooling reads loader.json; the
             # EMBEDDED copy above is authoritative on restore (fenced

@@ -799,7 +799,9 @@ class DeviceExchangeError(DDLError):
 class _DeviceRound:
     """One (producer_idx, round) exchange round on the fabric board."""
 
-    __slots__ = ("n", "seed", "round_", "posts", "results", "error")
+    __slots__ = (
+        "n", "seed", "round_", "posts", "results", "error", "broken",
+    )
 
     def __init__(self, n: int, seed: int, round_: int) -> None:
         self.n = n
@@ -808,6 +810,16 @@ class _DeviceRound:
         self.posts: Dict[int, np.ndarray] = {}
         self.results: Optional[Dict[int, np.ndarray]] = None
         self.error: Optional[BaseException] = None
+        #: ``error`` is a broken program (the kernel did not build or
+        #: compile), not a failed leg: participants re-raise it as it
+        #: is instead of latching the host exchange.
+        self.broken = False
+
+    def raise_error(self) -> None:
+        assert self.error is not None
+        if self.broken:
+            raise self.error
+        raise DeviceExchangeError(str(self.error)) from self.error
 
 
 class DeviceExchangeFabric:
@@ -904,7 +916,7 @@ class DeviceExchangeFabric:
                 rnd = _DeviceRound(n, seed, round_)
                 self._rounds[key] = rnd
             if rnd.error is not None:
-                raise DeviceExchangeError(str(rnd.error)) from rnd.error
+                rnd.raise_error()
             if rnd.results is not None:
                 # Replayed take (respawned producer re-entering a
                 # completed round): idempotent per (key, instance).
@@ -946,7 +958,7 @@ class DeviceExchangeFabric:
                     )
                 self._cond.wait(timeout=min(0.1, remaining))
             if rnd.error is not None:
-                raise DeviceExchangeError(str(rnd.error)) from rnd.error
+                rnd.raise_error()
             return rnd.results[instance_idx]
 
     # -- internals -----------------------------------------------------------
@@ -975,9 +987,13 @@ class DeviceExchangeFabric:
 
     def _run_device_leg(self, rnd: _DeviceRound) -> None:
         """The arrival that completed the round runs the collective.
-        ANY failure here (unplannable geometry, a DMA error surfacing at
-        the sync point, a dtype the mesh cannot hold) is published to
-        every participant — they all latch the host fallback together."""
+        A leg that FAILS (unplannable geometry, a dtype the mesh cannot
+        hold, a device error surfacing at the sync point) is published
+        to every participant — they all latch the host fallback
+        together.  A kernel that does not BUILD or COMPILE is published
+        too, but as ``broken``: every participant re-raises it."""
+        import jax
+
         try:
             results = self._device_exchange(rnd)
         except (ShutdownRequested, KeyboardInterrupt):
@@ -985,10 +1001,15 @@ class DeviceExchangeFabric:
             # leg-stall timeout and latch the host fallback.
             raise
         except Exception as e:  # published, not swallowed
+            broken = not isinstance(
+                e, (DDLError, jax.errors.JaxRuntimeError)
+            )
             with self._cond:
                 if rnd.results is None and rnd.error is None:
-                    rnd.error = e
+                    rnd.error, rnd.broken = e, broken
                 self._cond.notify_all()
+            if broken:
+                raise
             return
         with self._cond:
             if rnd.error is None:
@@ -1032,7 +1053,7 @@ class DeviceExchangeFabric:
         # sync=True: an async DMA failure must surface HERE, inside the
         # fallback ladder, not at some later consumer's sync point.
         out = _dsh.exchange_wait(ticket, sync=True)
-        blocks_out = _dsh.exchange_output_blocks(out, devices)
+        blocks_out = _dsh.exchange_output_blocks(out, devices, shape)
         return {i: blocks_out[i] for i in range(n)}
 
 

@@ -240,10 +240,31 @@ class Trainer:
         if gen is not None and (
             legacy_step is None or gen[0] >= legacy_step
         ):
+            # The cold-start state exists here only to give the restore
+            # its structure and shardings: keep those and drop its
+            # buffers BEFORE the restore allocates — two full states do
+            # not fit beside each other for an HBM-filling model.
+            import jax
+
+            from ddl_tpu.parallel.train import TrainState
+
+            def abstract(tree: Any) -> Any:
+                return jax.tree.map(
+                    lambda x: jax.ShapeDtypeStruct(
+                        x.shape, x.dtype, sharding=x.sharding
+                    ) if isinstance(x, jax.Array) else x,
+                    tree,
+                )
+
+            like = TrainState(
+                params=abstract(state.params),
+                opt_state=abstract(state.opt_state),
+            )
+            del state
             # found=gen: the scan above already CRC'd every candidate —
             # restore must not re-read the blobs a second time.
             restored = restore_latest(
-                self.checkpoint_dir, like=state, metrics=self.metrics,
+                self.checkpoint_dir, like=like, metrics=self.metrics,
                 found=gen,
             )
             assert restored is not None  # gen verified just above

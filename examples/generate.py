@@ -26,9 +26,9 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from _common import pin_platform_from_env  # noqa: E402
+from _common import configure  # noqa: E402
 
-pin_platform_from_env()
+configure()
 
 VOCAB = 64
 PERIOD = 7

@@ -30,11 +30,11 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from _common import pin_platform_from_env  # noqa: E402
+from _common import configure  # noqa: E402
 
 # Pipeline stages need multiple devices; default the CPU sim to 8.
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-pin_platform_from_env()
+configure()
 
 from train_llama import (  # noqa: E402 - shared synthetic corpus
     SEQ_LEN,

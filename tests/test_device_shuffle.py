@@ -205,6 +205,48 @@ class TestDeviceChaos:
             assert snap.get("shuffle.degraded", 0) == 0
             assert sh.exchange_round == rounds
 
+    def test_kernel_build_error_propagates_to_every_participant(
+        self, monkeypatch
+    ):
+        """A kernel that does not build or compile is a broken program,
+        not a failed leg: every participant of the round raises it as
+        it is — nobody latches the host exchange, nobody waits out the
+        leg-stall clock."""
+        from ddl_tpu.ops import device_shuffle
+
+        def broken(*a, **k):
+            raise AttributeError("no attribute 'TPUCompilerParams'")
+
+        monkeypatch.setattr(device_shuffle, "_exchange_call", broken)
+        n = 3
+        fabric = DeviceExchangeFabric(impl="ring")
+        rdv = Rendezvous()
+        shufs = [
+            DeviceExchangeShuffler(
+                Topology(n_instances=n, instance_idx=i, n_producers=1),
+                1, 6, rendezvous=rdv, fabric=fabric, seed=SEED,
+            )
+            for i in range(n)
+        ]
+        arys = _pools(n, 10)
+        before = [a.copy() for a in arys]
+        raised = {}
+
+        def worker(i):
+            try:
+                shufs[i].global_shuffle(arys[i])
+            except AttributeError as e:
+                raised[i] = e
+
+        ts = [threading.Thread(target=worker, args=(i,)) for i in range(n)]
+        [t.start() for t in ts]
+        [t.join(30) for t in ts]
+        assert not any(t.is_alive() for t in ts), "participants hung"
+        assert sorted(raised) == list(range(n))
+        assert not any(sh._device_latched for sh in shufs)
+        for a, b in zip(arys, before):
+            np.testing.assert_array_equal(a, b)  # lanes unmutated
+
     def test_peer_loss_degrades_node_local_rung(self):
         """Persistent SHUFFLE_PEER_LOSS during device rounds (the host
         chaos test's missing-peer construction: a declared 2-instance

@@ -3,10 +3,12 @@ redistribution planner properties, distributor byte-identity vs the xla
 path, the loader seam, and the ``ici.fanout`` chaos row.
 
 Everything runs on the 8-device CPU virtual mesh (conftest.py): the
-fan-out kernels execute under ``interpret=True`` — the same kernel code
-Mosaic compiles on a real pod — which is how tier-1 proves the
-device-side distribution tier is byte-identical to the host
-(``device_put``-scattered) path before a chip ever sees it.
+fan-out kernels execute under Pallas' TPU interpret mode — the same
+kernel code Mosaic compiles on a real pod, with per-device progress,
+remote DMAs, semaphores and the entry barrier simulated — which is how
+tier-1 proves the device-side distribution tier is byte-identical to
+the host (``device_put``-scattered) path, and race-free, before a chip
+ever sees it (tests/test_tpu_compile.py covers the Mosaic compile).
 """
 
 import os
@@ -23,6 +25,7 @@ from ddl_tpu import (
     distributed_dataloader,
 )
 from ddl_tpu import faults
+from ddl_tpu.exceptions import KernelBuildError
 from ddl_tpu.faults import FaultKind, FaultPlan, FaultSpec
 from ddl_tpu.ingest import DeviceIngestor
 from ddl_tpu.observability import Metrics
@@ -85,10 +88,10 @@ class TestFanoutReplicate:
             np.testing.assert_array_equal(got[i * rows : (i + 1) * rows], x)
 
     def test_non_divisible_chunk_tail(self):
-        """rows % n_chunks != 0: the wrapper pads to a chunk multiple and
-        strips the tail — the delivered payload must be exact."""
+        """rows % n_chunks != 0: the last chunk is short — the delivered
+        payload must be exact."""
         devs = _ring(4)
-        rows, cols = 10, 4  # 10 % 4 == 2: padded to 12, 2 stripped
+        rows, cols = 10, 4  # chunks of 3, 3, 3, 1 rows
         x = np.arange(rows * cols, dtype=np.float32).reshape(rows, cols)
         blk = jax.device_put(x, devs[0])
         out = ici_fanout.fanout_replicate(blk, devs, n_chunks=4)
@@ -158,23 +161,20 @@ class TestFanoutShard:
         with pytest.raises(ValueError, match="divisible"):
             ici_fanout.fanout_shard(x, devs)
 
-    def test_semaphore_parity_over_long_pipelines(self):
-        """Grid length n_dev-1 = 7 on the full ring: every parity pair of
-        the double-buffered semaphores is exercised across odd AND even
-        steps — a pairing bug (waiting the in-flight half) deadlocks
-        interpret mode or corrupts a block, both caught here."""
+    def test_full_ring_scatter(self):
+        """All seven destinations of the full ring in flight at once,
+        each on its own send semaphore."""
         devs = _ring(8)
         rows, cols = 8, 6
         x = np.arange(rows * cols, dtype=np.float32).reshape(rows, cols)
         out = ici_fanout.fanout_shard(jax.device_put(x, devs[0]), devs)
         np.testing.assert_array_equal(np.asarray(out), x)
 
-    def test_bcast_pipeline_depth_covers_all_parities(self):
-        """Broadcast grid = n_chunks + n_dev - 2 (= 10 here): chunk
-        schedules clamp at both edges while the send/wait parity
-        alternates through the whole pipeline."""
+    def test_full_ring_broadcast_pipeline(self):
+        """Six relays between source and tail, four chunks: every relay
+        forwards chunk c only after its own receive of chunk c."""
         devs = _ring(8)
-        assert ici_fanout.bcast_grid(8, 4) == 10
+        assert ici_fanout.chunk_rows(8, 4) == ((0, 2), (2, 2), (4, 2), (6, 2))
         rows, cols = 8, 6
         x = np.random.default_rng(3).random((rows, cols)).astype(np.float32)
         out = ici_fanout.fanout_replicate(
@@ -185,43 +185,140 @@ class TestFanoutShard:
             np.testing.assert_array_equal(got[i * rows : (i + 1) * rows], x)
 
 
-class TestWireMath:
-    def test_replicate_wire_and_payload(self):
-        # 4 devices, 4 chunks of c bytes: grid = 6 steps, every device
-        # sends one chunk per step (full rotation) = 24 chunk-sends.
-        nbytes = 4 * 1024
-        assert ici_fanout.wire_bytes("replicate", nbytes, 4, 4) == (
-            4 * 6 * (nbytes // 4)
-        )
-        assert ici_fanout.payload_bytes("replicate", nbytes, 4) == 3 * nbytes
+class TestTiling:
+    """Blocks on Mosaic's HBM tiling go through as they are; anything
+    else travels as its (R, 128) lane view (every other geometry in
+    this file) — same bytes either way."""
 
-    def test_shard_wire_and_payload(self):
-        nbytes = 8 * 1024
-        # n*(n-1) block-sends of nbytes/n each.
-        assert ici_fanout.wire_bytes("shard", nbytes, 8) == 8 * 7 * (
-            nbytes // 8
+    @pytest.mark.parametrize("dtype,rows", [
+        (np.float32, 8), (np.int32, 16), (np.uint8, 32),
+    ])
+    def test_tile_aligned_blocks_skip_the_lane_view(self, dtype, rows):
+        devs = _ring(4)
+        cols = 2 * ici_fanout.LANES
+        assert ici_fanout.kernel_view(4, rows, cols, dtype) == (
+            4 * rows, cols
         )
-        assert ici_fanout.payload_bytes("shard", nbytes, 8) == (
+        x = np.random.default_rng(1).integers(
+            0, 200, (4 * rows, cols)
+        ).astype(dtype)
+        blk = jax.device_put(x, devs[0])
+        np.testing.assert_array_equal(
+            np.asarray(ici_fanout.fanout_shard(blk, devs)), x
+        )
+        np.testing.assert_array_equal(
+            np.asarray(ici_fanout.fanout_replicate(blk, devs)),
+            np.tile(x, (4, 1)),
+        )
+
+    def test_lane_view_geometry(self):
+        # One row of 4096 int32 tokens = 32 lanes-rows of 128, 4 tiles.
+        assert ici_fanout.kernel_view(4, 1, 4096, np.int32) == (128, 128)
+        # 3 x 3 float32 = 9 elements -> one padded (8, 128) tile.
+        assert ici_fanout.kernel_view(2, 3, 3, np.float32) == (16, 128)
+        # bf16 tiles are 16 sublanes deep, int8 tiles 32.
+        assert ici_fanout.lane_rows(1, "bfloat16") == 16
+        assert ici_fanout.lane_rows(1, np.uint8) == 32
+
+
+class TestKernelSynchronisation:
+    """Every remote-DMA kernel, both landing slots back to back, from a
+    non-zero source, with the interpreter's happens-before race
+    detector on.  Interpret mode executes a DMA only when something
+    waits on it — the adversarial schedule for a missing wait, which
+    shows up as wrong bytes (a relay forwarding a chunk it has not
+    received forwards the NaN fill).  The detector is a second net, not
+    the proof: it cannot see the hazard the entry barrier guards (the
+    simulator allocates every buffer up front); the four-chip run of
+    ``chip_smoke.py --chips 4`` is the evidence for that."""
+
+    @pytest.fixture
+    def races(self, monkeypatch):
+        from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+        from jax.experimental.pallas import tpu as pltpu
+
+        from ddl_tpu.ops import device_shuffle
+
+        def detecting(interpret):
+            assert interpret
+            return pltpu.InterpretParams(detect_races=True)
+
+        monkeypatch.setattr(ici_fanout, "interpret_arg", detecting)
+        monkeypatch.setattr(device_shuffle, "interpret_arg", detecting)
+        caches = (
+            ici_fanout._bcast_call, ici_fanout._scatter_call,
+            device_shuffle._exchange_call,
+        )
+        for c in caches:
+            c.cache_clear()
+        yield lambda: interpret_pallas_call.races.races_found
+        for c in caches:
+            c.cache_clear()
+        pltpu.reset_tpu_interpret_mode_state()
+
+    def test_fanout_kernels_race_free(self, races):
+        devs = _ring(4)
+        x = np.random.default_rng(0).random((16, 8)).astype(np.float32)
+        for slot in (0, 1, 0, 1):
+            blk = jax.device_put(x + slot, devs[1])
+            rep = ici_fanout.fanout_replicate(blk, devs, src=1, slot=slot)
+            np.testing.assert_array_equal(
+                np.asarray(rep), np.tile(x + slot, (4, 1))
+            )
+            sh = ici_fanout.fanout_shard(blk, devs, src=1, slot=slot)
+            np.testing.assert_array_equal(np.asarray(sh), x + slot)
+        assert not races()
+
+    def test_exchange_kernel_race_free(self, races):
+        from ddl_tpu.ops import device_shuffle
+        from ddl_tpu.shuffle import exchange_permutation
+
+        devs = _ring(4)
+        blocks = [
+            np.full((4, 8), i, np.float32) + np.arange(4)[:, None] / 10
+            for i in range(4)
+        ]
+        for rnd in range(4):
+            p = np.asarray(exchange_permutation(4, 7, rnd))
+            gin = device_shuffle.as_exchange_input(blocks, devs)
+            out = device_shuffle.exchange_wait(
+                device_shuffle.exchange_start(
+                    "ring", gin, devs, p, slot=rnd % 2
+                )
+            )
+            ref = device_shuffle.exchange_wait(
+                device_shuffle.exchange_start("xla", gin, devs, p)
+            )
+            np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+        assert not races()
+
+
+class TestWireMath:
+    def test_replicate_wire(self):
+        # Every non-tail ring position forwards the whole window once.
+        nbytes = 4 * 1024
+        assert ici_fanout.wire_bytes("replicate", nbytes, 4) == 3 * nbytes
+
+    def test_shard_wire(self):
+        # Each off-source block is sent once, straight to its owner.
+        nbytes = 8 * 1024
+        assert ici_fanout.wire_bytes("shard", nbytes, 8) == (
             nbytes - nbytes // 8
         )
 
-    def test_replicate_wire_prices_row_padding(self):
-        """Rows not divisible by n_chunks: the kernel pads to whole
-        chunk-rows and every DMA moves the padded chunk — rowless
-        byte-ceil would underprice the wire (5 rows → 8, 2-row chunks
-        of 2048 B vs ceil(nbytes/4) = 1280 B)."""
-        nbytes = 5 * 256 * 4
-        assert ici_fanout.wire_bytes(
-            "replicate", nbytes, 4, 4, rows=5
-        ) == 4 * 6 * (2 * 256 * 4)
-        # Rowless estimate stays as the documented fallback.
-        assert ici_fanout.wire_bytes("replicate", nbytes, 4, 4) == (
-            4 * 6 * (-(-nbytes // 4))
-        )
+    @pytest.mark.parametrize("rows,n_chunks,expect", [
+        (5, 4, ((0, 2), (2, 2), (4, 1))),  # short last chunk, 3 not 4
+        (2, 16, ((0, 1), (1, 1))),  # clamped to the row count
+        (12, 1, ((0, 12),)),
+    ])
+    def test_chunk_split_covers_rows_exactly(self, rows, n_chunks, expect):
+        """No padding: the static chunk split tiles the rows exactly, so
+        no DMA moves a byte that is not payload."""
+        assert ici_fanout.chunk_rows(rows, n_chunks) == expect
 
     def test_single_device_is_free(self):
         assert ici_fanout.wire_bytes("replicate", 1024, 1) == 0
-        assert ici_fanout.payload_bytes("shard", 1024, 1) == 0
+        assert ici_fanout.wire_bytes("shard", 1024, 1) == 0
 
     def test_unknown_mode_rejected(self):
         with pytest.raises(ValueError):
@@ -286,7 +383,7 @@ class TestPlanProperties:
         mesh = _mesh((("dp", 8),))
         sharding = NamedSharding(mesh, P("dp"))
         plan = plan_distribution((16, 16), np.float32, sharding)
-        # landing block + output + transit exceed one window
+        # landing block + output exceed one window
         assert plan.peak_factor > 1.0
         with pytest.raises(PlanError, match="memory bound"):
             plan_distribution(
@@ -394,6 +491,35 @@ class TestDistributorFallback:
             np.testing.assert_array_equal(np.asarray(out2), x + 1.0)
         assert m.counter("ici.fallbacks") == 1
         assert m.counter("ici.windows") == 0  # no window rode the tier
+
+    @pytest.mark.parametrize("err", [
+        AttributeError("module 'pltpu' has no attribute 'TPUCompilerParams'"),
+        ValueError("collective_id has to be unspecified"),
+        KernelBuildError("kernel failed to compile: Mosaic said no"),
+    ], ids=lambda e: type(e).__name__)
+    def test_kernel_build_error_propagates(self, monkeypatch, err):
+        """A kernel that does not build or compile is a broken program,
+        not a degraded link: it reaches the caller, nothing latches and
+        nothing is counted — while the SAME distributor still latches on
+        an injected DMA failure afterwards."""
+        def broken(*a, **k):
+            raise err
+
+        m = Metrics()
+        dist = IciDistributor(self._sharding(), metrics=m)
+        x = np.arange(16 * 4, dtype=np.float32).reshape(16, 4)
+        with monkeypatch.context() as mp:
+            mp.setattr(ici_fanout, "_scatter_call", broken)
+            with pytest.raises(type(err), match=str(err)[:20]):
+                dist.put(x, jax.device_put)
+        assert not dist.faulted
+        assert m.counter("ici.fallbacks") == 0
+        with faults.armed(FaultPlan(
+            [FaultSpec("ici.fanout", FaultKind.ICI_DMA_FAIL, at=1)]
+        )):
+            out = dist.put(x, jax.device_put)
+        assert dist.faulted and m.counter("ici.fallbacks") == 1
+        np.testing.assert_array_equal(np.asarray(out), x)
 
     def test_shutdown_propagates_without_latching(self):
         """``ShutdownRequested`` raised at the fault site is a shutdown,
@@ -691,7 +817,7 @@ class TestTwoSlotFused:
             leg.asynchronous for leg in p2.legs if "fanout" in leg.kind
         )
         # The single-slot bound rejects a fused REPLICATE plan's
-        # doubled peak (2 × (landing + output + chunk) > 3.0 windows).
+        # doubled peak (2 × (landing + output) > 2.0 windows).
         replicated = NamedSharding(_mesh((("dp", 8),)), P(None, None))
         with pytest.raises(PlanError, match="memory bound"):
             plan_distribution(
@@ -706,7 +832,7 @@ class TestTwoSlotFused:
         p1 = plan_distribution((16, 8), np.float32, sharding, n_slots=1)
         p2 = plan_distribution((16, 8), np.float32, sharding, n_slots=2)
         nbytes = 16 * 8 * 4
-        slot_live = nbytes + 3 * (nbytes // 8)
+        slot_live = nbytes + nbytes // 8  # landing block + output block
         for l1, l2 in zip(p1.legs, p2.legs):
             assert l2.peak_bytes == l1.peak_bytes + slot_live
         assert p2.peak_factor <= 2 * DEFAULT_MEMORY_FACTOR

@@ -22,7 +22,7 @@ import optax
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ddl_tpu._compat import shard_map
+from jax import shard_map
 from ddl_tpu.models import llama
 from ddl_tpu.parallel.collectives import (
     QUANT_BLOCK,

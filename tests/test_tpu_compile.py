@@ -1,0 +1,160 @@
+"""Ahead-of-time Mosaic compiles for a DESCRIBED ``v5e:2x2`` topology.
+
+Interpret mode proves bytes; only the TPU compiler can refuse a kernel
+(a slice off the tiling, too much fast memory, a misused semaphore).
+libtpu compiles for a chip that is described and not attached, so the
+main path's kernels are compiled here at their real sizes — the smoke
+geometry of ``chip_smoke.py`` — on every tier-1 run, at no chip time.
+Nothing executes: a passing compile is not a chip run.
+
+Skipped where the topology cannot be described (no libtpu).  The
+persistent compile cache is off around the compiles: an entry written
+for a described device cannot be read back without one, and the retry
+warns.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ddl_tpu.ops import device_shuffle, flash_attention, ici_fanout
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except (RuntimeError, ValueError, NotImplementedError, ImportError) as e:
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield tuple(topo.devices)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# The chip_smoke.py train-leg attention geometry: batch 4 x seq 2048,
+# 16 query / 8 kv heads x 128, bf16.
+B, T, H, HKV, D = 4, 2048, 16, 8, 128
+
+
+def _attn_args(device, packed):
+    one = SingleDeviceSharding(device)
+    q = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((B, T, HKV, D), jnp.bfloat16, sharding=one)
+    seg = jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=one)
+    return (q, kv, kv) + ((seg,) if packed else ())
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["plain", "packed"])
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+def test_flash_attention_compiles(v5e, grad, packed):
+    def attn(q, k, v, seg=None):
+        # interpret=False: the Mosaic kernel, whatever backend this
+        # process defaults to.
+        return flash_attention(
+            q, k, v, causal=True, kv_repeat=H // HKV, interpret=False,
+            segment_ids=seg,
+        )
+
+    fn = attn
+    if grad:
+        def fn(q, k, v, *seg):
+            return jax.grad(
+                lambda q, k, v: attn(q, k, v, *seg)
+                .astype(jnp.float32).sum(),
+                argnums=(0, 1, 2),
+            )(q, k, v)
+
+    text = jax.jit(fn).lower(*_attn_args(v5e[0], packed)).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+# The 64 MiB float32 stream window (65536 x 256) over the four chips.
+ROWS, COLS = 65536, 256
+
+
+def test_broadcast_kernel_compiles(v5e):
+    compiled = ici_fanout._bcast_call(
+        v5e, ROWS, COLS, "float32", 0, ici_fanout.DEFAULT_CHUNKS, False
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("slot", range(ici_fanout.N_SLOTS))
+def test_scatter_kernel_compiles(v5e, slot):
+    compiled = ici_fanout._scatter_call(
+        v5e, ROWS, COLS, "float32", 0, False, slot
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+    # No fast-memory transit: the only device memory beyond the SPMD
+    # input block is this device's output block.
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes == 0
+    assert mem.output_size_in_bytes == ROWS // len(v5e) * COLS * 4
+
+
+def test_exchange_kernel_compiles(v5e):
+    # 64 MiB per instance: two lanes of half the rows each.
+    compiled = device_shuffle._exchange_call(
+        v5e, ROWS // 2, COLS, "float32", False
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kernel,block,dtype", [
+    # A dp-sharded token window of the smoke's Trainer: ONE row of
+    # 2 steps x 2048 int32 tokens a device.
+    ("scatter", (1, 4096), "int32"),
+    ("scatter", (16, 520), "uint8"),  # an int8-wire window: rows + scales
+    ("bcast", (10, 256), "float32"),  # rows off the 8-sublane tile
+    ("exchange", (3, 3), "float32"),  # a toy shuffle pool's lane
+])
+def test_blocks_off_the_tiling_compile_through_the_lane_view(
+    v5e, kernel, block, dtype
+):
+    """Mosaic refuses to slice these shapes out of HBM; the wrappers
+    move them as (R, 128) lane views, which it takes."""
+    n = len(v5e)
+    assert not ici_fanout.tile_aligned(*block, dtype)
+    if kernel == "scatter":
+        rows, cols = ici_fanout.kernel_view(n, *block, dtype)
+        compiled = ici_fanout._scatter_call(
+            v5e, rows, cols, dtype, 0, False
+        )
+    elif kernel == "bcast":
+        rows, cols = ici_fanout.kernel_view(1, *block, dtype)
+        compiled = ici_fanout._bcast_call(
+            v5e, rows, cols, dtype, 0, ici_fanout.DEFAULT_CHUNKS, False
+        )
+    else:
+        rows, cols = ici_fanout.kernel_view(2, *block, dtype)
+        compiled = device_shuffle._exchange_call(
+            v5e, rows // 2, cols, dtype, False
+        )
+    assert cols == ici_fanout.LANES
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_compiler_refusal_is_a_build_error(v5e):
+    """What the TPU compiler refuses (here: 32 GiB on a 16 GB chip)
+    surfaces as KernelBuildError from the builder — the type the
+    runtime fault ladders do not absorb."""
+    from ddl_tpu.exceptions import KernelBuildError
+
+    too_big = jax.ShapeDtypeStruct(
+        (1 << 33,), jnp.float32, sharding=SingleDeviceSharding(v5e[0])
+    )
+    with pytest.raises(KernelBuildError, match="failed to compile"):
+        ici_fanout.compile_kernel(jax.jit(lambda x: x * 2), too_big)

@@ -831,9 +831,7 @@ class TestIciWireAccounting:
         )
         enc = 64 * (512 + 4 * 2)
         assert p.encoded_bytes == enc
-        assert p.wire_bytes == ici_fanout.wire_bytes(
-            "replicate", enc, 8, 4, rows=64
-        )
+        assert p.wire_bytes == ici_fanout.wire_bytes("replicate", enc, 8)
         assert p.wire_bytes < raw.wire_bytes
         assert p.payload_bytes == raw.payload_bytes  # logical delivery
         assert p.legs[0].wire_dtype == "int8"

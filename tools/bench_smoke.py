@@ -14,8 +14,7 @@ Four passes:
    headline is never slower than any sibling batch config the same run
    measured, `vs_baseline >= 1.0` on the CPU batch path (interleaved
    measurement in bench.py), `ingest.process_vs_thread >= 0.9` OR the
-   `ingest.core_attach` record proves core starvation, and a non-TPU
-   run embeds the `last_tpu_artifact` trail (+ `git_head`).
+   `ingest.core_attach` record proves core starvation.
 2. `DDL_BENCH_MODE=ici` — the device-side distribution A/B block must
    carry its contract keys (`bytes_per_s`, `bandwidth_utilization`,
    `vs_xla`, `byte_identical`, ...), the ICI-distributed window must be
@@ -114,8 +113,6 @@ MIN_PROCESS_VS_THREAD = 0.9
 #: alternation + per-batch sync); vs_baseline is measured interleaved
 #: in bench.py, retried here once against residual box noise.
 MIN_VS_BASELINE = 1.0
-#: last_tpu_artifact summary keys (present whenever the block is a dict).
-REQUIRED_ARTIFACT = ("path", "metric", "value", "unit")
 #: fit_stream contract (ISSUE 5 + 12): throughput + matched ceiling +
 #: overlap-health counters + schedule gauges + the fused A/B block.
 REQUIRED_FIT = (
@@ -485,22 +482,6 @@ def main() -> int:
             missing += [
                 f"ingest.{k}" for k in REQUIRED_INGEST if k not in ingest
             ]
-        # Trustworthy-headline contract: a non-TPU run must point at the
-        # newest committed chip artifact (None only if the repo has no
-        # committed TPU artifact at all).
-        if result.get("platform") != "tpu":
-            if "last_tpu_artifact" not in result:
-                missing.append("last_tpu_artifact")
-            else:
-                art = result["last_tpu_artifact"]
-                if isinstance(art, dict):
-                    missing += [
-                        f"last_tpu_artifact.{k}"
-                        for k in REQUIRED_ARTIFACT
-                        if k not in art
-                    ]
-                elif art is not None:
-                    missing.append("last_tpu_artifact (not a dict)")
         if "ingest_inline" not in result and "errors" not in result:
             missing.append("ingest_inline")
         if missing:

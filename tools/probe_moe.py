@@ -101,7 +101,8 @@ def run_one(platform: str, impl: str) -> None:
     tokens_per_step = batch * seq
     flops_per_step = _moe_flops_per_token(cfg, seq) * tokens_per_step
     kind = jax.local_devices()[0].device_kind
-    peak = bench._peak_flops(kind)
+    # A CPU run (asked for by name) has no device peak and no MFU.
+    peak = bench._peak_flops(kind) if platform == "tpu" else None
     mfu = flops_per_step / dt / peak if peak else None
     if not np.isfinite(final_loss):
         raise RuntimeError(f"non-finite loss {final_loss}")
@@ -194,7 +195,7 @@ def run_decode(platform: str, impl: str) -> None:
 def main() -> None:
     import bench
 
-    platform = bench.pin_platform()
+    platform = bench.bring_up()
     which = sys.argv[1] if len(sys.argv) > 1 else "both"
     impls = ("einsum", "ragged") if which == "both" else (which,)
     for impl in impls:

@@ -29,11 +29,7 @@ import numpy as np  # noqa: E402
 def main():
     import bench
 
-    platform = bench.pin_platform()  # killable probe + CPU pin
-    if platform != "tpu":
-        # zero1 needs a dp axis to shard over: simulate the 8-device
-        # mesh before the first backend touch.
-        bench._ensure_virtual_mesh(8)
+    platform = bench.bring_up(cpu_devices=8)
     import jax
     import optax
 

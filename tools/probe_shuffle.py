@@ -15,12 +15,12 @@ Prints, with no chips required (`make shuffle-dryrun`):
 
 The mirror of ``tools/probe_ici.py`` / ``probe_wire.py`` for the
 shuffle tier.  Throughput on the interpreted ring is NOT meaningful
-(Python emulation); for measured bytes/s run ``make shuffle-bench``,
-and for the chip A/B, ``tools/chip_checklist.sh`` step 11.
+(Python emulation); for measured bytes/s run ``DDL_BENCH_MODE=shuffle
+python bench.py`` on a multi-chip TPU host.
 
-Run anywhere:
+On the CPU virtual mesh:
 
-    python tools/probe_shuffle.py
+    DDL_BENCH_PLATFORM=cpu python tools/probe_shuffle.py
 """
 import json
 import os
@@ -152,9 +152,7 @@ def main():
     try:
         import bench
 
-        platform = bench.pin_platform()
-        if platform != "tpu":
-            bench._ensure_virtual_mesh(8)
+        platform = bench.bring_up(cpu_devices=8)
         import jax
 
         n_dev = len(jax.devices())

@@ -111,9 +111,7 @@ def main():
     try:
         import bench
 
-        platform = bench.pin_platform()
-        if platform != "tpu":
-            bench._ensure_virtual_mesh(8)
+        platform = bench.bring_up(cpu_devices=8)
         import jax
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
