@@ -43,6 +43,7 @@ from ddl_tpu.exceptions import (
 from ddl_tpu.obs import spans as obs_spans
 from ddl_tpu.obs.recorder import flight_dump
 from ddl_tpu.observability import Metrics, metrics as default_metrics
+from ddl_tpu.profiling import stage
 from ddl_tpu.transport.connection import NOTHING, ConsumerConnection
 from ddl_tpu.types import (
     ControlAck,
@@ -445,7 +446,6 @@ class DistributedDataLoader:
 
         import jax
 
-        from ddl_tpu.profiling import annotate
         from ddl_tpu.staging import StagedTransfer
 
         # Staged engine: the window is copied slot→pooled-staging-buffer
@@ -513,9 +513,7 @@ class DistributedDataLoader:
             self._apply_pending_pool()
             cursor = self._next_target(cursor, include=True)
             target = cursor
-            with annotate("ddl.window_acquire"), self.metrics.timed(
-                "consumer.wait"
-            ):
+            with stage("ddl.window_acquire", self.metrics) as st:
                 while True:
                     try:
                         slot = self._acquire_verified(
@@ -526,6 +524,7 @@ class DistributedDataLoader:
                         self._apply_pending_pool()
                         cursor = self._next_target(cursor, include=True)
                         target = cursor
+                st.key = (target + 1, self._last_acquired_seq)
             ring = self.connection.rings[target]
             # Window identity (the integrity trailer's (producer_idx,
             # seq)) — the key every downstream span of THIS window
@@ -643,9 +642,10 @@ class DistributedDataLoader:
                     jax.block_until_ready(dev)
                     return dev
 
-                dev = engine.complete_or_salvage(
-                    payload, inline_put, self.timeout_s
-                )
+                with stage("ddl.transfer_wait", self.metrics):
+                    dev = engine.complete_or_salvage(
+                        payload, inline_put, self.timeout_s
+                    )
             else:
                 dev = payload
             self.metrics.incr("ingest.bytes", float(dev.nbytes))
@@ -1160,7 +1160,7 @@ class DistributedDataLoader:
             if done or (target is not None and t != target):
                 remaining.append(entry)
                 continue
-            with self.metrics.timed("ingest.release_wait"):
+            with stage("ddl.release_wait", self.metrics):
                 jax.block_until_ready(dev)
             self.connection.rings[t].release(slot)
             if len(entry) > 3:
@@ -1217,7 +1217,7 @@ class DistributedDataLoader:
         SLO on a phantom window.
         """
         if self._admission is None:
-            return self._acquire_with_spans(target, ahead, timeout_s)
+            return self._acquire_observed(target, ahead, timeout_s)
         t_admit = time.monotonic()
         _span_t0 = obs_spans.t0()
         self._admission.admit(timeout_s)
@@ -1225,7 +1225,7 @@ class DistributedDataLoader:
         if timeout_s > 0:
             timeout_s = max(0.0, timeout_s - admit_wait)
         try:
-            slot = self._acquire_with_spans(target, ahead, timeout_s)
+            slot = self._acquire_observed(target, ahead, timeout_s)
         except BaseException:
             abort = getattr(self._admission, "note_aborted", None)
             if abort is not None:
@@ -1249,16 +1249,16 @@ class DistributedDataLoader:
         )
         return slot
 
-    def _acquire_with_spans(
+    def _acquire_observed(
         self, target: int, ahead: int, timeout_s: float
     ):
-        """The acquire choke point's observability shim: spans the
-        verified acquire, stashes the logical seq for downstream keying
-        (staging jobs, yields, releases), and feeds the bounded
-        ``consumer.window_latency`` histogram — head acquires only, so
-        the percentile measures "time to obtain the next committed
-        window" and non-blocking lookahead probes cannot dilute it."""
-        _span_t0 = obs_spans.t0()
+        """The acquire choke point's observability shim: stashes the
+        logical seq for downstream keying (the enclosing
+        ``ddl.window_acquire`` stage's span, staging jobs, yields,
+        releases), and feeds the bounded ``consumer.window_latency``
+        histogram — head acquires only, so the percentile measures
+        "time to obtain the next committed window" and non-blocking
+        lookahead probes cannot dilute it."""
         t0 = time.perf_counter() if ahead == 0 and timeout_s > 0 else 0.0
         slot = self._acquire_slot_verified(target, ahead, timeout_s)
         # The logical window number, by the same arithmetic the
@@ -1270,7 +1270,6 @@ class DistributedDataLoader:
             self.metrics.observe(
                 "consumer.window_latency", time.perf_counter() - t0
             )
-        obs_spans.record("consumer.acquire", target + 1, seq, _span_t0)
         return slot
 
     def _acquire_slot_verified(
@@ -1453,8 +1452,6 @@ class DistributedDataLoader:
         )
 
     def _acquire_current(self) -> None:
-        from ddl_tpu.profiling import annotate
-
         if self._release_backlog:
             # Batch-path acquire tracks no per-stream hold counter, so a
             # stream's deferred releases must land first — otherwise the
@@ -1473,9 +1470,7 @@ class DistributedDataLoader:
         # timeline next to the XLA ops (SURVEY §5.1 TPU-native tracing).
         self._apply_pending_pool()
         self._poll_obs()
-        with annotate("ddl.window_acquire"), self.metrics.timed(
-            "consumer.wait"
-        ):
+        with stage("ddl.window_acquire", self.metrics) as st:
             while True:
                 try:
                     slot = self._acquire_verified(
@@ -1489,6 +1484,7 @@ class DistributedDataLoader:
                     self._target = self._next_target(
                         self._target, include=True
                     )
+            st.key = (self._target + 1, self._last_acquired_seq)
         self._cur_slot = slot
         self._cur_array = self._slot_array(self._target, slot)
         self.metrics.incr("consumer.windows")

@@ -84,8 +84,8 @@ def _abort_sentinel() -> str:
 # ``_commit_window`` (per-window hot path) stays quiet.
 @for_all_methods(
     with_logging,
-    exclude=("_commit_window", "_stamp_and_commit", "_encode_and_commit",
-             "_slot_array", "_poll_control"),
+    exclude=("_commit_window", "_stage_next_fill", "_stamp_and_commit",
+             "_encode_and_commit", "_slot_array", "_poll_control"),
 )
 class DataPusher:
     """One producer worker: handshake, then fill windows until shutdown.
@@ -589,7 +589,7 @@ class DataPusher:
         self.ring.commit(slot, enc)
 
     def _commit_window(self) -> None:
-        """Publish the filled window and stage the next fill target."""
+        """Publish the filled window (``_stage_next_fill`` follows)."""
         if self.inplace_fill:
             # my_ary IS the slot: publish it, then point my_ary at the
             # next free slot for the coming refill.
@@ -604,6 +604,10 @@ class DataPusher:
             self._stamp_and_commit(slot)
         self.metrics.incr("producer.windows")
         self.metrics.incr("producer.bytes", self.window_nbytes)
+
+    def _stage_next_fill(self) -> None:
+        """Write-once pipeline: point ``my_ary`` at the next free slot
+        for the coming refill (blocks on ring backpressure)."""
         if self.inplace_fill:
             self._fill_slot = self.ring.acquire_fill()
             self.my_ary = self._slot_array(self._fill_slot)
@@ -807,11 +811,17 @@ class DataPusher:
                 self._commit_window()
                 # The commit span covers acquire_fill's free-slot wait
                 # too — producer-side backpressure is exactly what a
-                # trace of a slow consumer should show.
-                obs_spans.record(
-                    "producer.commit", self.producer_idx, self._iteration,
-                    _span_t0,
-                )
+                # trace of a slow consumer should show.  The window IS
+                # published by now: a shutdown landing in the wait for
+                # the next fill slot (the consumer drained its last
+                # window and left) must not lose its span.
+                try:
+                    self._stage_next_fill()
+                finally:
+                    obs_spans.record(
+                        "producer.commit", self.producer_idx,
+                        self._iteration, _span_t0,
+                    )
                 execute_callbacks(
                     self.callbacks, "on_shuffle_end", iteration=self._iteration
                 )

@@ -25,6 +25,7 @@ import numpy as np
 
 from ddl_tpu import envspec
 from ddl_tpu.observability import Metrics, metrics as default_metrics
+from ddl_tpu.profiling import stage
 from ddl_tpu.staging import StagedTransfer, staged_enabled
 
 
@@ -227,9 +228,7 @@ class DeviceIngestor:
         mid-transfer).  Staged mode stages into recycled pool buffers;
         inline mode allocates fresh.
         """
-        from ddl_tpu.profiling import annotate
-
-        with annotate("ddl.ingest_put"):
+        with stage("ddl.ingest_put"):
             if self.batch_staged:
                 pool = self.engine().pool
                 out = []
@@ -267,9 +266,7 @@ class DeviceIngestor:
         — tools/probe_ingest.py).  The device-side column slices are
         sub-microsecond XLA ops.
         """
-        from ddl_tpu.profiling import annotate
-
-        with annotate("ddl.ingest_put"):
+        with stage("ddl.ingest_put"):
             if self.batch_staged:
                 pool = self.engine().pool
                 buf = self._stage(batch)
@@ -338,9 +335,6 @@ class DeviceIngestor:
         measurement span (a dispatch-time count leads completion by the
         whole lookahead depth).
         """
-        from ddl_tpu.obs import spans as obs_spans
-        from ddl_tpu.profiling import annotate
-
         if self._target_platform() == "cpu":
             # The CPU PJRT client may *alias* a compatible host buffer
             # instead of copying — the returned array would then observe
@@ -351,12 +345,8 @@ class DeviceIngestor:
         # Dispatch span, keyed on the thread's current-window context
         # (set by the stream / staging executor) — the transfer itself
         # is async; completion shows up as the consumer.release mark.
-        _span_t0 = obs_spans.t0()
-        with annotate("ddl.ingest_put_window"):
+        with stage("ddl.ingest_put_window"):
             out = self._transfer(window)
-        obs_spans.record(
-            "ingest.transfer", *obs_spans.current_window(), _span_t0
-        )
         if not defer_metrics:
             self.metrics.incr("ingest.bytes", float(window.nbytes))
             self.metrics.incr("ingest.windows")

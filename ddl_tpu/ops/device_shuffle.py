@@ -68,6 +68,7 @@ from ddl_tpu.ops.ici_fanout import (
     interpret_default,
     kernel_view,
 )
+from ddl_tpu.ops.naming import named_pallas_call
 
 #: Mosaic collective ids for the exchange kernel, indexed by landing
 #: slot — the id names the barrier semaphore the entry handshake runs
@@ -129,7 +130,8 @@ def _exchange_call(devices: Tuple[Any, ...], half: int, cols: int,
     n_dev = len(devices)
     mesh = _ring_mesh(devices)
     dtype = np.dtype(dtype_name)
-    call = pl.pallas_call(
+    call = named_pallas_call(
+        "ddl_shuffle_exchange",
         functools.partial(_exchange_kernel, half=half),
         out_shape=jax.ShapeDtypeStruct((_N_LANES * half, cols), dtype),
         in_specs=[

@@ -46,6 +46,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ddl_tpu.ops.naming import named_pallas_call
+
 _NEG_INF = -1e30
 _LANES = 128  # TPU vector lane count: scratch accumulators are (bq, 128)
 
@@ -468,8 +470,8 @@ def _fwd_impl(q, k, v, offsets, causal, kv_repeat, block_q, block_k,
             pltpu.VMEM((block_q, D), jnp.float32),  # output accumulator
         ],
     )
-    out, lse = pl.pallas_call(
-        kernel,
+    out, lse = named_pallas_call(
+        "ddl_flash_fwd", kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
@@ -533,7 +535,8 @@ def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts):
         sq_spec, sk_spec = _seg_specs(block_q, block_k)
         dq_in_specs += [sq_spec, sk_spec]
         dq_inputs += [_prep_seg(seg_q, Tq), _prep_seg(seg_k, Tk)]
-    dq = pl.pallas_call(
+    dq = named_pallas_call(
+        "ddl_flash_bwd_dq",
         functools.partial(_dq_kernel_seg if packed else _dq_kernel,
                           **common),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -568,7 +571,8 @@ def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts):
         sq_spec_t, sk_spec_t = _seg_specs(block_q, block_k, transposed=True)
         dkv_in_specs += [sq_spec_t, sk_spec_t]
         dkv_inputs += [_prep_seg(seg_q, Tq), _prep_seg(seg_k, Tk)]
-    dk, dv = pl.pallas_call(
+    dk, dv = named_pallas_call(
+        "ddl_flash_bwd_dkv",
         functools.partial(_dkv_kernel_seg if packed else _dkv_kernel,
                           **common),
         grid_spec=pltpu.PrefetchScalarGridSpec(

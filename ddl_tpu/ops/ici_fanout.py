@@ -77,6 +77,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ddl_tpu.exceptions import KernelBuildError
+from ddl_tpu.ops.naming import named_pallas_call
 
 #: The fan-out ring's private mesh axis (always 1-axis: the
 #: redistribution planner owns the mapping onto dp x fsdp x tp).
@@ -341,7 +342,8 @@ def _bcast_call(devices: Tuple[Any, ...], rows: int, cols: int,
     global shape, every block the source's."""
     dtype = np.dtype(dtype_name)
     chunks = chunk_rows(rows, n_chunks, align=sublanes(dtype))
-    call = pl.pallas_call(
+    call = named_pallas_call(
+        "ddl_ici_bcast",
         functools.partial(
             _bcast_kernel, src=src, n_dev=len(devices), chunks=chunks
         ),
@@ -371,7 +373,8 @@ def _scatter_call(devices: Tuple[Any, ...], rows: int, cols: int,
     n_dev = len(devices)
     dtype = np.dtype(dtype_name)
     block_rows = rows // n_dev
-    call = pl.pallas_call(
+    call = named_pallas_call(
+        "ddl_ici_scatter",
         functools.partial(
             _scatter_kernel, src=src, n_dev=n_dev, rows=block_rows
         ),

@@ -50,6 +50,7 @@ from ddl_tpu import envspec
 from ddl_tpu.exceptions import InjectedFault
 from ddl_tpu.faults import fault_point
 from ddl_tpu.observability import Metrics, metrics as default_metrics
+from ddl_tpu.profiling import stage
 
 logger = logging.getLogger("ddl_tpu")
 
@@ -678,17 +679,7 @@ class IciDistributor:
             except PlanError:
                 pass  # counted+logged once in plan(); per-geometry xla
             else:
-                # Fan-out DISPATCH span, keyed on the thread's current
-                # window (ddl_tpu.obs; the ring kernels are async — the
-                # span is the host-side cost the fused step must hide).
-                from ddl_tpu.obs import spans as obs_spans
-
-                _span_t0 = obs_spans.t0()
-                out = self.distribute(device_put(arr, anchor))
-                obs_spans.record(
-                    "ici.fanout", *obs_spans.current_window(), _span_t0
-                )
-                return out
+                return self.distribute(device_put(arr, anchor))
         return device_put(arr, self.sharding)
 
     def distribute(self, block: Any) -> Any:
@@ -723,26 +714,31 @@ class IciDistributor:
         m = self.metrics
         dtype_name = np.dtype(block.dtype).name
         slot = self._slot
-        t0 = time.perf_counter()
+        # The fan-out DISPATCH stage (lane pack, wire encode, ring-kernel
+        # launch), keyed on the thread's current window: the ring
+        # kernels are async — the stage is the host-side cost the fused
+        # step must hide.
         if plan.mode == "replicate":
-            flat = _to2d_call(
-                plan.anchor, plan.shape, dtype_name, 0
-            )(block)
-            if plan.wire_dtype != "raw":
-                # Anchor-side device encode: the ring moves uint8 wire
-                # rows; the window is never a host fp32 temp between
-                # the encode and the send (DDL021 discipline).
-                flat = _encode2d_call(
-                    plan.anchor, plan.shape[0],
-                    int(np.prod(plan.shape)) // plan.shape[0],
-                    dtype_name, plan.wire_dtype,
-                )(flat)
-            ticket = ici_fanout.fanout_start(
-                "replicate", flat, plan.ring_devices, src=0, slot=slot,
-                n_chunks=self.n_chunks or ici_fanout.DEFAULT_CHUNKS,
-                interpret=self.interpret,
-            )
-            m.add_time("ici.fanout", time.perf_counter() - t0)
+            with stage("ddl.ici_fanout", m):
+                flat = _to2d_call(
+                    plan.anchor, plan.shape, dtype_name, 0
+                )(block)
+                if plan.wire_dtype != "raw":
+                    # Anchor-side device encode: the ring moves uint8
+                    # wire rows; the window is never a host fp32 temp
+                    # between the encode and the send (DDL021
+                    # discipline).
+                    flat = _encode2d_call(
+                        plan.anchor, plan.shape[0],
+                        int(np.prod(plan.shape)) // plan.shape[0],
+                        dtype_name, plan.wire_dtype,
+                    )(flat)
+                ticket = ici_fanout.fanout_start(
+                    "replicate", flat, plan.ring_devices, src=0,
+                    slot=slot,
+                    n_chunks=self.n_chunks or ici_fanout.DEFAULT_CHUNKS,
+                    interpret=self.interpret,
+                )
             t1 = time.perf_counter()
             rep = ici_fanout.replicated_view(
                 ici_fanout.fanout_wait(ticket), plan.ring_devices
@@ -758,20 +754,21 @@ class IciDistributor:
                 )(rep)
             m.add_time("ici.redistribute", time.perf_counter() - t1)
         else:
-            flat = _to2d_call(
-                plan.anchor, plan.shape, dtype_name, plan.split_dim
-            )(block)
-            if plan.wire_dtype != "raw":
-                flat = _encode2d_call(
-                    plan.anchor, plan.shape[plan.split_dim],
-                    int(np.prod(plan.shape)) // plan.shape[plan.split_dim],
-                    dtype_name, plan.wire_dtype,
-                )(flat)
-            ticket = ici_fanout.fanout_start(
-                "shard", flat, plan.ring_devices, src=0, slot=slot,
-                interpret=self.interpret,
-            )
-            m.add_time("ici.fanout", time.perf_counter() - t0)
+            with stage("ddl.ici_fanout", m):
+                flat = _to2d_call(
+                    plan.anchor, plan.shape, dtype_name, plan.split_dim
+                )(block)
+                if plan.wire_dtype != "raw":
+                    flat = _encode2d_call(
+                        plan.anchor, plan.shape[plan.split_dim],
+                        int(np.prod(plan.shape))
+                        // plan.shape[plan.split_dim],
+                        dtype_name, plan.wire_dtype,
+                    )(flat)
+                ticket = ici_fanout.fanout_start(
+                    "shard", flat, plan.ring_devices, src=0, slot=slot,
+                    interpret=self.interpret,
+                )
             t1 = time.perf_counter()
             result = _finish_shard_call(
                 self._mesh_key, plan.shape, dtype_name, plan.split_dim,

@@ -60,6 +60,7 @@ from ddl_tpu.exceptions import (
 from ddl_tpu.faults import fault_point
 from ddl_tpu.obs import spans as obs_spans
 from ddl_tpu.observability import Metrics, metrics as default_metrics
+from ddl_tpu.profiling import stage
 
 logger = logging.getLogger("ddl_tpu")
 
@@ -669,7 +670,6 @@ class TransferExecutor:
         key = job.span_key or (None, None)
 
         def copy_phase():
-            t0 = time.perf_counter()
             fault_point("staging.copy", view=_flat_u8(job.src))
             np.copyto(buf, job.src, casting="no")
             if job.expected_crc is not None:
@@ -685,38 +685,23 @@ class TransferExecutor:
                         f"staging copy crc32 0x{got:08x} != committed "
                         f"0x{job.expected_crc:08x} (torn slot read)"
                     )
-            self.metrics.add_time(
-                "ingest.stage_copy", time.perf_counter() - t0
-            )
-            obs_spans.record("staging.copy", key[0], key[1], t0)
-
-        def transfer_phase():
-            fault_point("staging.transfer")
-            # Identity context + profiler lane for the nested transfer
-            # (put_window / batch put / ICI fan-out) — this phase runs
-            # on whichever thread claimed the job, so the jax.profiler
-            # annotation here is what lines the staged H2D up with the
-            # SpanLog's staging.transfer lane by name.
-            from ddl_tpu.profiling import annotate
-
-            obs_spans.set_window(*key)
-            try:
-                with annotate("ddl.staging_transfer"):
-                    return job.transfer(buf)
-            finally:
-                obs_spans.clear_window()
 
         try:
             if job.alias_src:
-                handle._value = self._execute_alias(job)
+                # Alias path: the stage covers dispatch AND the
+                # completion wait (the slot is the live source).
+                with stage("ddl.staging_transfer", key=key):
+                    handle._value = self._execute_alias(job)
                 return
             buf = self.pool.acquire(job.src.shape, job.src.dtype)
-            self._retrying("copy", copy_phase)
+            with stage("ddl.staging_copy", self.metrics, key):
+                self._retrying("copy", copy_phase)
             handle.copy_done.set()  # source released: slot may free
             try:
-                _span_t0 = obs_spans.t0()
-                value, base = self._retrying("transfer", transfer_phase)
-                obs_spans.record("staging.transfer", key[0], key[1], _span_t0)
+                with stage("ddl.staging_transfer", key=key):
+                    value, base = self._retrying(
+                        "transfer", lambda: self._transfer_phase(job, buf)
+                    )
             except (ShutdownRequested, KeyboardInterrupt):
                 raise
             except Exception:
@@ -739,6 +724,20 @@ class TransferExecutor:
             handle.copy_done.set()
             handle.ready.set()
 
+    def _transfer_phase(self, job: _Job, src: np.ndarray) -> Any:
+        """One attempt at a job's H2D dispatch from ``src`` (the staging
+        buffer, or the ring slot on the alias path), inside the caller's
+        ``ddl.staging_transfer`` stage.  Runs on whichever thread
+        claimed the job; the window identity is published for the
+        nested emission sites (put_window / batch put / ICI fan-out)
+        that cannot see it."""
+        fault_point("staging.transfer")
+        obs_spans.set_window(*(job.span_key or (None, None)))
+        try:
+            return job.transfer(src)
+        finally:
+            obs_spans.clear_window()
+
     def _execute_alias(self, job: _Job) -> Any:
         """Run one zero-copy (shm-backed) job: transfer straight from the
         ring-slot view, no staging memcpy.
@@ -755,19 +754,6 @@ class TransferExecutor:
         consumer that needed the value NOW anyway), never adds a host
         memcpy, and its span lands in ``ingest.transfer``.
         """
-        key = job.span_key or (None, None)
-
-        def transfer_phase():
-            fault_point("staging.transfer")
-            from ddl_tpu.profiling import annotate
-
-            obs_spans.set_window(*key)
-            try:
-                with annotate("ddl.staging_transfer"):
-                    return job.transfer(job.src)
-            finally:
-                obs_spans.clear_window()
-
         def salvage_slot(buf: Optional[np.ndarray] = None) -> None:
             """Terminal transfer failure with the slot STILL HELD (this
             runs before ``_execute``'s ``finally`` fires ``copy_done``
@@ -783,7 +769,9 @@ class TransferExecutor:
 
         t0 = time.perf_counter()
         try:
-            value, base = self._retrying("transfer", transfer_phase)
+            value, base = self._retrying(
+                "transfer", lambda: self._transfer_phase(job, job.src)
+            )
         except (ShutdownRequested, KeyboardInterrupt):
             raise
         except Exception:
@@ -816,7 +804,6 @@ class TransferExecutor:
             return value
         _block_ready(base)
         self.metrics.add_time("ingest.transfer", time.perf_counter() - t0)
-        obs_spans.record("staging.transfer", key[0], key[1], t0)
         self.metrics.incr("staging.alias_windows")
         return value
 

@@ -32,6 +32,7 @@ from jax.sharding import PartitionSpec as P
 
 from ddl_tpu.datasetwrapper import ProducerFunctionSkeleton
 from ddl_tpu.observability import Metrics, metrics as default_metrics
+from ddl_tpu.profiling import stage
 
 logger = logging.getLogger("ddl_tpu")
 
@@ -593,8 +594,6 @@ class Trainer:
         spans whose scan finished mid-acquire are not counted).
         """
         from ddl_tpu import Marker
-        from ddl_tpu.obs import spans as obs_spans
-        from ddl_tpu.profiling import annotate
         from ddl_tpu.utils import value_ready
 
         m = self.metrics
@@ -606,10 +605,10 @@ class Trainer:
             # window is already in flight while the previous scan runs,
             # so this wait stays near zero; it flows into
             # north_star_report["window_wait_s"] and the bench JSON.
-            # The annotation puts the same wait on the jax.profiler
-            # timeline, named to line up with the SpanLog lanes.
+            # The stage puts the same wait on the jax.profiler timeline,
+            # where the device's idle gaps are attributed to it.
             t0 = time.perf_counter()
-            with m.timed("trainer.window_wait"), annotate("ddl.window_wait"):
+            with stage("ddl.window_wait", m):
                 win = next(stream, _done)
             # Ready-by-default polarity: an unprobeable future must
             # never inflate the overlap measurement.
@@ -623,27 +622,30 @@ class Trainer:
                 break
             if window_hook is not None:
                 win = window_hook(win)
-            _span_t0 = obs_spans.t0()
-            _wkey = loader.last_window_key() or (None, None)
-            state, losses = multi_for(win.shape[0])(
-                state, _window_cols(win, col_splits), per_step=True
-            )
-            # The epoch-loss reduction is dispatched HERE, right behind
-            # its own scan: backends that execute in dispatch order
-            # (the CPU client) would otherwise queue a read-time
-            # ``pending.mean()`` behind the NEXT scan, silently
-            # re-serializing the loop the fused step exists to overlap.
-            loss_mean = losses.mean()
-            loader.gate_release_on(losses)
             # Consume span = the scan DISPATCH (DDL020: the fused loop
             # never waits on the device, so dispatch is all there is).
-            obs_spans.record("trainer.consume", *_wkey, _span_t0)
+            with stage(
+                "ddl.step_dispatch", m,
+                loader.last_window_key() or (None, None),
+            ):
+                state, losses = multi_for(win.shape[0])(
+                    state, _window_cols(win, col_splits), per_step=True
+                )
+                # The epoch-loss reduction is dispatched HERE, right
+                # behind its own scan: backends that execute in dispatch
+                # order (the CPU client) would otherwise queue a
+                # read-time ``pending.mean()`` behind the NEXT scan,
+                # silently re-serializing the loop the fused step exists
+                # to overlap.
+                loss_mean = losses.mean()
+                loader.gate_release_on(losses)
             m.incr("trainer.fused_windows")
             if pending is not None:
                 # Deferred ONE window: blocks on the PREVIOUS scan's
                 # already-queued reduction, bounding in-flight depth at
                 # the landing-slot count.
-                epoch_losses.append(float(pending))
+                with stage("ddl.loss_readback", m):
+                    epoch_losses.append(float(pending))
             pending = loss_mean
             epoch += 1
             loader.mark(Marker.END_OF_EPOCH)
@@ -661,7 +663,8 @@ class Trainer:
                 break
         if pending is not None:
             # Stream drained; the final scan must be consumed.
-            epoch_losses.append(float(pending))
+            with stage("ddl.loss_readback", m):
+                epoch_losses.append(float(pending))
         return state
 
     def _sync_stream_loop(
@@ -681,15 +684,12 @@ class Trainer:
         import jax
 
         from ddl_tpu import Marker
-        from ddl_tpu.obs import spans as obs_spans
-        from ddl_tpu.profiling import annotate
 
+        m = self.metrics
         epoch = start_epoch
         _done = object()
         while True:
-            with self.metrics.timed("trainer.window_wait"), annotate(
-                "ddl.window_wait"
-            ):
+            with stage("ddl.window_wait", m):
                 win = next(stream, _done)
                 if win is not _done:
                     # "The window lands...": expose the whole transfer.
@@ -698,17 +698,17 @@ class Trainer:
                 break
             if window_hook is not None:
                 win = window_hook(win)
-            _span_t0 = obs_spans.t0()
-            _wkey = loader.last_window_key() or (None, None)
-            state, losses = multi_for(win.shape[0])(
-                state, _window_cols(win, col_splits), per_step=True
-            )
+            with stage(
+                "ddl.step_dispatch", m,
+                loader.last_window_key() or (None, None),
+            ):
+                state, losses = multi_for(win.shape[0])(
+                    state, _window_cols(win, col_splits), per_step=True
+                )
             # "...then compute runs to completion": immediate read-back
             # serializes the next acquire behind this scan.
-            epoch_losses.append(float(losses.mean()))
-            # Consume span covers dispatch + the blocking read-back —
-            # the synchronous discipline's whole per-window compute.
-            obs_spans.record("trainer.consume", *_wkey, _span_t0)
+            with stage("ddl.loss_readback", m):
+                epoch_losses.append(float(losses.mean()))
             epoch += 1
             loader.mark(Marker.END_OF_EPOCH)
             if (

@@ -520,16 +520,18 @@ class TestProfilingAndBandwidth:
         """profiling.trace captures a jax.profiler trace to the log dir."""
         import jax.numpy as jnp
 
-        from ddl_tpu.profiling import annotate, maybe_trace, trace
+        from ddl_tpu.observability import Metrics
+        from ddl_tpu.profiling import annotate, stage, trace
 
+        m = Metrics()
         with trace(str(tmp_path)):
-            with annotate("ddl.test_span"):
+            with annotate("ddl.test_span"), stage("ddl.loss_readback", m):
                 _ = float(jnp.sum(jnp.ones((8, 8))))
         produced = list((tmp_path).rglob("*"))
         assert any(p.is_file() for p in produced), produced
-        # maybe_trace with no dir is a no-op (no error, nothing written).
-        with maybe_trace(None):
-            pass
+        # The data plane's own emission point timed the same block
+        # (tests/test_stages.py reads the annotations back).
+        assert m.timer("trainer.loss_readback").count == 1
 
     def test_h2d_bandwidth_and_utilization(self):
         from ddl_tpu.ingest import measure_h2d_bandwidth, north_star_report

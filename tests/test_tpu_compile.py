@@ -7,6 +7,11 @@ main path's kernels are compiled here at their real sizes — the smoke
 geometry of ``chip_smoke.py`` — on every tier-1 run, at no chip time.
 Nothing executes: a passing compile is not a chip run.
 
+Each compile also shows the kernel under a NAME OF ITS OWN in the
+compiled HLO (``ddl_tpu.ops.naming``): the instruction name is what a
+profiler trace's ``XLA Ops`` events and the benchmark's
+``breakdown.device_ops`` carry.
+
 Skipped where the topology cannot be described (no libtpu).  The
 persistent compile cache is off around the compiles: an entry written
 for a described device cannot be read back without one, and the retry
@@ -14,6 +19,7 @@ warns.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
 
@@ -23,6 +29,20 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from ddl_tpu.ops import device_shuffle, flash_attention, ici_fanout
+from ddl_tpu.ops.naming import KERNEL_NAMES
+
+
+def kernel_names(compiled_text):
+    """The Mosaic custom calls' HLO instruction names, less XLA's
+    ``.<n>`` suffix — the op family ``benchmarks/lib/tracered.py`` shows."""
+    names = set()
+    for line in compiled_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            m = re.match(r"\s*(?:ROOT )?%?([\w\-]+?)(?:\.\d+)* = ", line)
+            assert m, line[:120]
+            names.add(m.group(1))
+    assert names, "no tpu_custom_call in the compiled program"
+    return names
 
 
 @pytest.fixture(scope="module")
@@ -78,7 +98,48 @@ def test_flash_attention_compiles(v5e, grad, packed):
             )(q, k, v)
 
     text = jax.jit(fn).lower(*_attn_args(v5e[0], packed)).compile().as_text()
-    assert "tpu_custom_call" in text
+    assert kernel_names(text) == (
+        {"ddl_flash_fwd", "ddl_flash_bwd_dq", "ddl_flash_bwd_dkv"}
+        if grad else {"ddl_flash_fwd"}
+    )
+
+
+def test_flash_names_survive_remat_and_shard_map(v5e):
+    """What used to rename the kernels: ``jax.checkpoint`` (``checkpoint``,
+    ``rematted_computation``), autodiff (``jvp__``, ``transpose_jvp___``)
+    and the dp mesh's ``shard_map`` — the benchmark's three cells."""
+    from jax import shard_map
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import numpy as np
+
+    mesh = Mesh(np.array(v5e), ("dp",))
+    spec = P("dp", None, None, None)
+
+    def attn(q, k, v):
+        return flash_attention(
+            q, k, v, causal=False, kv_repeat=H // HKV, interpret=False
+        )
+
+    def loss(q, k, v):
+        local = shard_map(
+            jax.checkpoint(
+                attn, policy=jax.checkpoint_policies.nothing_saveable
+            ),
+            mesh=mesh, in_specs=(spec,) * 3,
+            out_specs=spec, check_vma=False,
+        )
+        return local(q, k, v).astype(jnp.float32).sum()
+
+    sh = NamedSharding(mesh, spec)
+    q = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=sh)
+    kv = jax.ShapeDtypeStruct((B, T, HKV, D), jnp.bfloat16, sharding=sh)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv
+    ).compile().as_text()
+    assert kernel_names(text) == {
+        "ddl_flash_fwd", "ddl_flash_bwd_dq", "ddl_flash_bwd_dkv"
+    }
 
 
 # The 64 MiB float32 stream window (65536 x 256) over the four chips.
@@ -89,7 +150,7 @@ def test_broadcast_kernel_compiles(v5e):
     compiled = ici_fanout._bcast_call(
         v5e, ROWS, COLS, "float32", 0, ici_fanout.DEFAULT_CHUNKS, False
     )
-    assert "tpu_custom_call" in compiled.as_text()
+    assert kernel_names(compiled.as_text()) == {"ddl_ici_bcast"}
 
 
 @pytest.mark.parametrize("slot", range(ici_fanout.N_SLOTS))
@@ -97,7 +158,7 @@ def test_scatter_kernel_compiles(v5e, slot):
     compiled = ici_fanout._scatter_call(
         v5e, ROWS, COLS, "float32", 0, False, slot
     )
-    assert "tpu_custom_call" in compiled.as_text()
+    assert kernel_names(compiled.as_text()) == {"ddl_ici_scatter"}
     # No fast-memory transit: the only device memory beyond the SPMD
     # input block is this device's output block.
     mem = compiled.memory_analysis()
@@ -110,7 +171,7 @@ def test_exchange_kernel_compiles(v5e):
     compiled = device_shuffle._exchange_call(
         v5e, ROWS // 2, COLS, "float32", False
     )
-    assert "tpu_custom_call" in compiled.as_text()
+    assert kernel_names(compiled.as_text()) == {"ddl_shuffle_exchange"}
 
 
 @pytest.mark.parametrize("kernel,block,dtype", [
@@ -144,7 +205,7 @@ def test_blocks_off_the_tiling_compile_through_the_lane_view(
             v5e, rows // 2, cols, dtype, False
         )
     assert cols == ici_fanout.LANES
-    assert "tpu_custom_call" in compiled.as_text()
+    assert kernel_names(compiled.as_text()) <= set(KERNEL_NAMES)
 
 
 def test_compiler_refusal_is_a_build_error(v5e):
