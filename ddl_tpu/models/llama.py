@@ -56,6 +56,12 @@ class LlamaConfig:
     # "auto": Pallas flash attention on TPU, dense elsewhere; "flash"/"dense"
     # force one path.  Sequence-parallel meshes always use ring attention.
     attn_impl: str = "auto"
+    #: QK-norm as OLMoE has it (``model_type: olmoe``): RMSNorm with a
+    #: learned weight over the WHOLE projected query (``n_heads *
+    #: head_dim`` values) and key (``n_kv_heads * head_dim``), before the
+    #: head split and RoPE.  States the architecture; off, the params
+    #: tree and the program are exactly what they were without it.
+    qk_norm: bool = False
 
     def __post_init__(self) -> None:
         if self.attn_impl not in ("auto", "flash", "dense"):
@@ -130,6 +136,7 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
                 "w_gate": dense(next(keys), d, (d, cfg.d_ff)),
                 "w_up": dense(next(keys), d, (d, cfg.d_ff)),
                 "w_down": dense(next(keys), cfg.d_ff, (cfg.d_ff, d)),
+                **_qk_norm_params(cfg),
             }
         )
     return {
@@ -138,6 +145,24 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
         "final_norm": jnp.ones((d,), pdt),
         "lm_head": dense(next(keys), d, (d, cfg.vocab)),
     }
+
+
+def _qk_norm_params(cfg: Any) -> Params:
+    """A layer's two QK-norm weight vectors — present only where
+    ``cfg.qk_norm`` is on (shared by the llama and moe trees)."""
+    if not cfg.qk_norm:
+        return {}
+    hd, pdt = cfg.head_dim, cfg.param_dtype
+    return {
+        "q_norm": jnp.ones((cfg.n_heads * hd,), pdt),
+        "k_norm": jnp.ones((cfg.n_kv_heads * hd,), pdt),
+    }
+
+
+def _qk_norm_specs(cfg: Any) -> Params:
+    if not cfg.qk_norm:
+        return {}
+    return {"q_norm": P(None), "k_norm": P(None)}
 
 
 def param_shapes(cfg: LlamaConfig) -> Params:
@@ -163,6 +188,7 @@ def param_specs(cfg: LlamaConfig) -> Params:
         "w_gate": P("fsdp", "tp"),
         "w_up": P("fsdp", "tp"),
         "w_down": P("tp", "fsdp"),
+        **_qk_norm_specs(cfg),
     }
     return {
         "embed": P(None, "fsdp"),
@@ -285,14 +311,30 @@ def _attn_qkv(layer: Params, h: jax.Array, cfg: LlamaConfig,
               n_kv_heads: Optional[int] = None):
     """Project + rope one block's q/k/v (shared by train, decode and the
     tp-resident pipeline stage, which passes its LOCAL head counts —
-    column-sharded projections yield contiguous head blocks)."""
+    column-sharded projections yield contiguous head blocks).
+
+    With ``cfg.qk_norm`` the projected query and key are RMS-normalised
+    over ALL their values before the head split — which a tp-local head
+    block cannot do, so local head counts are refused there."""
     B, T = h.shape[:2]
     dt = h.dtype
     nh = cfg.n_heads if n_heads is None else n_heads
     nkv = cfg.n_kv_heads if n_kv_heads is None else n_kv_heads
-    q = (h @ layer["wq"].astype(dt)).reshape(B, T, nh, cfg.head_dim)
-    k = (h @ layer["wk"].astype(dt)).reshape(B, T, nkv, cfg.head_dim)
-    v = (h @ layer["wv"].astype(dt)).reshape(B, T, nkv, cfg.head_dim)
+    if cfg.qk_norm and (nh, nkv) != (cfg.n_heads, cfg.n_kv_heads):
+        raise NotImplementedError(
+            "qk_norm normalises over the whole projection; a "
+            "tp-resident stage holds only its own heads"
+        )
+
+    def project(w: str, heads: int, norm: Optional[str] = None) -> jax.Array:
+        y = h @ layer[w].astype(dt)
+        if norm:
+            y = _rms_norm(y, layer[norm], cfg.norm_eps)
+        return y.reshape(B, T, heads, cfg.head_dim)
+
+    q = project("wq", nh, "q_norm" if cfg.qk_norm else None)
+    k = project("wk", nkv, "k_norm" if cfg.qk_norm else None)
+    v = project("wv", nkv)
     return (
         _rope(q, positions, cfg.rope_theta),
         _rope(k, positions, cfg.rope_theta),

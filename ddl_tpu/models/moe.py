@@ -8,16 +8,24 @@ shapes, so XLA tiles every step onto the MXU and GSPMD inserts the ``ep``
 all-to-alls from sharding annotations alone — there is no hand-written
 collective and no data-dependent control flow.
 
-- Router: top-k (default 2) softmax gating, probabilities renormalised over
-  the chosen experts.
-- Dispatch: per-expert capacity ``C = ceil(topk·N/E·capacity_factor)``;
+- Router: top-k (default 2) float32 softmax gating; the chosen
+  probabilities are renormalised (``norm_topk_prob``, Mixtral) or kept
+  raw (OLMoE).
+- Dispatch, two implementations chosen by what the mesh shows
+  (``moe_impl="auto"``): sort-based DROPLESS routing over
+  ``jax.lax.ragged_dot`` wherever no ``ep`` axis shards the experts
+  (:func:`moe_mlp_ragged`), the capacity-bounded einsums only there.
+- Einsum dispatch: per-expert capacity ``C = ceil(topk·N/E·capacity_factor)``;
   slot positions come from a cumulative sum over a slot-major one-hot mask
   (earlier top-k slots get priority), overflow tokens are dropped (their
   combine weight is zero — the residual stream carries them unchanged).
 - Experts: stacked SwiGLU MLPs ``(E, D, F)``, sharded ``P("ep", "fsdp",
   "tp")`` so each device holds ``E/ep`` experts.
-- Load-balance aux loss: the Switch formulation
-  ``E · Σ_e fraction_dispatched(e) · mean_router_prob(e)``.
+- Router losses: the Switch load-balance term ``E · Σ_e
+  fraction_dispatched(e) · mean_router_prob(e)`` on slot 0 (default) or
+  over all top-k slots (``router_aux_all_slots``, the HF
+  ``load_balancing_loss_func`` count), and the router z-loss
+  ``mean(logsumexp(router logits)²)`` (``router_z_weight``).
 
 Attention/norms/RoPE reuse the llama building blocks and the shared
 attention dispatcher (ring attention over ``sp``, Pallas flash kernel on
@@ -64,23 +72,47 @@ class MoeConfig:
     #: here, and "selective" keeps the attention outputs saved.
     remat: Any = False
     attn_impl: str = "auto"
-    #: Expert-MLP dispatch implementation.  "einsum": the capacity-
-    #: bounded GShard dispatch/combine formulation — fully static, and
-    #: the layout GSPMD shards over the ``ep`` mesh axis.  "ragged":
-    #: sort-based dropless routing over ``jax.lax.ragged_dot`` — the
-    #: one-hot dispatch/combine einsums (which cost as many real FLOPs
-    #: as the experts themselves at single-chip scale) are replaced by
-    #: a sort + gather (measured 1.31x on chip at 889M params).
+    #: QK-norm over the whole projected query and key (OLMoE) — see
+    #: :attr:`LlamaConfig.qk_norm`; the attention block is llama's.
+    qk_norm: bool = False
+    #: Renormalise the chosen top-k probabilities to sum to 1 (Mixtral;
+    #: the default).  Off (OLMoE, ``norm_topk_prob: false``) the gate
+    #: weights are the raw softmax values and sum to less than 1.
+    norm_topk_prob: bool = True
+    #: The load-balance term counts every top-k slot's choice
+    #: (``f_e`` = choices of expert e / N, so ``Σ f_e = topk`` and the
+    #: term reads ``topk`` at perfect balance — HF's
+    #: ``load_balancing_loss_func``) instead of slot 0 alone (Switch).
+    router_aux_all_slots: bool = False
+    #: Weight of the router z-loss ``mean_tokens(logsumexp(logits)²)``,
+    #: averaged over layers like the load-balance term (0: not computed
+    #: into the loss).
+    router_z_weight: float = 0.0
+    #: Expert-MLP dispatch implementation.  "ragged": sort-based
+    #: dropless routing over ``jax.lax.ragged_dot`` — no capacity, no
+    #: dropped token, no (N, E, C) one-hots.  On a v5e XLA compiles each
+    #: ragged dot and both of its transposes to its own grouped-matmul
+    #: kernels (trace families ``ragged-dot-*``), no dense expansion.
     #: Token-sharded meshes (dp/sp) run the routing per shard under
     #: shard_map (dropless, so local == global routing exactly);
     #: tp/fsdp shard weights and compose too.  Only ``ep`` is rejected
     #: — ragged group boundaries are contiguous local row ranges and
-    #: cannot align with a sharded expert stack; use einsum for expert
-    #: parallelism.  Scale guidance (chip-measured): neither impl is a
-    #: single-chip answer at multi-B MoE scale — einsum's (N, E, C)
-    #: dispatch one-hots dominate (4% MFU at 1.7B) and ragged's N·topk
-    #: row duplication exhausts HBM; shard experts over ``ep`` there.
-    moe_impl: str = "einsum"
+    #: cannot align with a sharded expert stack.  "einsum": the
+    #: capacity-bounded GShard dispatch/combine formulation — fully
+    #: static, the layout GSPMD shards over the ``ep`` mesh axis, and
+    #: unusable at scale on one chip (at N = 16,384 tokens, 64 experts,
+    #: top-8 its (N, E, C) one-hots are 2.7e9 elements each).  "auto"
+    #: (default) reads the mesh: einsum where an ``ep`` axis of size > 1
+    #: shards the experts, ragged everywhere else.
+    #: What the chip said (my chip run, PR 26; TPU v5 lite, one chip,
+    #: OLMoE-1B-7B's widths at 2 of 16 layers, 16,384 tokens a step =
+    #: 131,072 routed rows, 2,048 a group on average and 2.7x that in
+    #: the fullest, bf16 + adamw, selective remat): the ragged path
+    #: trains at 39.9 k tokens/s, 30.9% MFU, with a peak of 10.9 of
+    #: 15.75 GiB — the N·topk row duplicate fits.  Its grouped matmuls
+    #: take 29% of the device time and run at 56% of the matmul peak;
+    #: the 131k-key sorts are 0.4%.
+    moe_impl: str = "auto"
 
     def __post_init__(self) -> None:
         from ddl_tpu.models import remat as _remat
@@ -107,6 +139,22 @@ class MoeConfig:
             n_kv_heads=8, d_ff=14336, n_experts=8, topk=2, max_seq=8192,
         )
 
+    @staticmethod
+    def olmoe_1b_7b() -> "MoeConfig":
+        """OLMoE-1B-7B (``allenai/OLMoE-1B-7B-0125-Instruct``,
+        arXiv:2409.02060) at full depth: 64 experts of width 1024, 8 per
+        token, raw (not renormalised) gates, full multi-head attention
+        with QK-norm, all-slot load-balance loss x 0.01 + z-loss x
+        0.001, bf16 storage.  The benchmark's configuration file builds
+        the same config (a test holds the two together)."""
+        return MoeConfig(
+            vocab=50304, d_model=2048, n_layers=16, n_heads=16,
+            n_kv_heads=16, d_ff=1024, n_experts=64, topk=8, max_seq=4096,
+            rope_theta=10000.0, norm_eps=1e-5, param_dtype=jnp.bfloat16,
+            qk_norm=True, norm_topk_prob=False, router_aux_weight=0.01,
+            router_aux_all_slots=True, router_z_weight=0.001,
+        )
+
 
 def init_params(cfg: MoeConfig, key: jax.Array) -> Params:
     # 8 dense draws per layer + embed + lm_head.
@@ -131,6 +179,7 @@ def init_params(cfg: MoeConfig, key: jax.Array) -> Params:
                 "w_gate": dense(next(keys), d, (E, d, F)),
                 "w_up": dense(next(keys), d, (E, d, F)),
                 "w_down": dense(next(keys), F, (E, F, d)),
+                **_llama._qk_norm_params(cfg),
             }
         )
     return {
@@ -164,6 +213,7 @@ def param_specs(cfg: MoeConfig) -> Params:
         "w_gate": P("ep", "fsdp", "tp"),
         "w_up": P("ep", "fsdp", "tp"),
         "w_down": P("ep", "tp", "fsdp"),
+        **_llama._qk_norm_specs(cfg),
     }
     return {
         "embed": P(None, "fsdp"),
@@ -175,41 +225,70 @@ def param_specs(cfg: MoeConfig) -> Params:
 
 def _router_topk(
     x: jax.Array, layer: Params, cfg: MoeConfig
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Shared router: softmax gate → top-k → renormalised gate weights.
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """Shared router: float32 softmax gate → top-k → gate weights
+    (renormalised over the chosen experts iff ``cfg.norm_topk_prob``).
 
     ONE implementation for both dispatch impls, so their 'identical
-    routing' equivalence holds by construction.  Returns
-    (probs (N, E) fp32, top_p (N, k) renormalised, top_e (N, k) ids).
+    routing' equivalence holds by construction.  The logits leave the
+    matmul in float32 (bf16 operands, float32 accumulation and result):
+    rounding them to bf16 first, as HF's module does, makes exact ties
+    between the last expert kept and the first one dropped common, and
+    a tie is broken by index, not by the router.  Returns
+    (probs (N, E) fp32, top_p (N, k), top_e (N, k) ids, z (N,) fp32
+    ``logsumexp(logits)``).
     """
-    logits = (x @ layer["w_router"].astype(x.dtype)).astype(jnp.float32)
+    logits = jnp.dot(
+        x, layer["w_router"].astype(x.dtype),
+        preferred_element_type=jnp.float32,
+    )
+    z = jax.nn.logsumexp(logits, axis=-1)
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_e = jax.lax.top_k(probs, cfg.topk)
-    top_p = top_p / jnp.maximum(jnp.sum(top_p, -1, keepdims=True), 1e-9)
-    return probs, top_p, top_e
+    if cfg.norm_topk_prob:
+        top_p = top_p / jnp.maximum(jnp.sum(top_p, -1, keepdims=True), 1e-9)
+    return probs, top_p, top_e, z
 
 
-def _switch_aux(probs: jax.Array, top_e: jax.Array, E: int) -> jax.Array:
-    """Switch load-balance loss on slot-0 dispatch decisions —
-    ``E · Σ_e fraction_dispatched(e) · mean_router_prob(e)``."""
-    frac_dispatched = jnp.mean(
-        jax.nn.one_hot(top_e[:, 0], E, dtype=jnp.float32), axis=0
+def _router_losses(
+    probs: jax.Array, top_e: jax.Array, z: jax.Array, cfg: MoeConfig
+) -> jax.Array:
+    """One layer's router losses, (2,) float32: the load-balance term
+    ``E · Σ_e fraction_dispatched(e) · mean_router_prob(e)`` — on the
+    slot-0 decisions (Switch), or over every slot's
+    (``cfg.router_aux_all_slots``) — and the z-loss ``mean(z²)``."""
+    E = cfg.n_experts
+    picks = top_e.reshape(-1) if cfg.router_aux_all_slots else top_e[:, 0]
+    frac_dispatched = (
+        jnp.sum(jax.nn.one_hot(picks, E, dtype=jnp.float32), axis=0)
+        / probs.shape[0]
     )
-    return E * jnp.sum(frac_dispatched * jnp.mean(probs, axis=0))
+    balance = E * jnp.sum(frac_dispatched * jnp.mean(probs, axis=0))
+    return jnp.stack([balance, jnp.mean(z * z)])
 
 
 def moe_mlp(
     x: jax.Array, layer: Params, cfg: MoeConfig
 ) -> Tuple[jax.Array, jax.Array]:
-    """Top-k routed SwiGLU experts over flat tokens x: (N, D).
+    """Top-k routed SwiGLU experts over flat tokens x: (N, D), by the
+    capacity-bounded einsum dispatch.
 
     Returns (out (N, D), aux load-balance loss scalar).
     """
+    out, losses, _ = _einsum_mlp(x, layer, cfg)
+    return out, losses[0]
+
+
+def _einsum_mlp(
+    x: jax.Array, layer: Params, cfg: MoeConfig
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """:func:`moe_mlp` with both router losses and the router's picks:
+    (out, (2,) losses, top_e (N, k))."""
     N, D = x.shape
     E, k, C = cfg.n_experts, cfg.topk, cfg.capacity(N)
     dt = x.dtype
 
-    probs, top_p, top_e = _router_topk(x, layer, cfg)
+    probs, top_p, top_e, z = _router_topk(x, layer, cfg)
 
     mask = jax.nn.one_hot(top_e, E, dtype=jnp.float32)  # (N, k, E)
     # Slot-major priority: all slot-0 picks queue before any slot-1 pick.
@@ -237,28 +316,42 @@ def moe_mlp(
         "ecf,efd->ecd", gate * up, layer["w_down"].astype(dt)
     )
     out = jnp.einsum("nec,ecd->nd", combine.astype(dt), expert_out)
-    return out, _switch_aux(probs, top_e, E)
+    return out, _router_losses(probs, top_e, z, cfg), top_e
 
 
-def _validate_impl_mesh(cfg: MoeConfig, mesh: Optional[Any]) -> None:
-    """The ragged impl's expert groups are contiguous row ranges of a
-    locally sorted copy list — they cannot align with an ``ep``-sharded
-    expert stack, so reject that combination up front instead of
-    letting GSPMD materialize a gathered stack silently.  Token-sharded
-    axes (``dp``/``sp``) ARE supported: :func:`_routed_mlp` shard_maps
-    the routing per shard.  tp/fsdp shard weights, not tokens — those
-    compose fine."""
-    if (
-        cfg.moe_impl == "ragged"
-        and mesh is not None
+def _ep_sharded(mesh: Optional[Any]) -> bool:
+    return (
+        mesh is not None
         and "ep" in getattr(mesh, "axis_names", ())
         and mesh.shape["ep"] > 1
-    ):
+    )
+
+
+def _resolve_impl(cfg: MoeConfig, mesh: Optional[Any]) -> MoeConfig:
+    """``cfg`` with ``moe_impl="auto"`` decided from what the mesh
+    shows: the einsum dispatch where an ``ep`` axis shards the expert
+    stacks (GSPMD derives the all-to-alls from its layout), dropless
+    ragged everywhere else.  A forced ``"ragged"`` on an ``ep`` mesh is
+    rejected up front instead of letting GSPMD materialise a gathered
+    stack silently: its expert groups are contiguous row ranges of a
+    locally sorted copy list and cannot align with a sharded stack.
+    Token-sharded axes (``dp``/``sp``) ARE supported:
+    :func:`_routed_mlp` shard_maps the routing per shard.  tp/fsdp
+    shard weights, not tokens — those compose fine."""
+    if cfg.moe_impl not in ("auto", "einsum", "ragged"):
+        raise ValueError(
+            f"unknown moe_impl {cfg.moe_impl!r} (want auto|einsum|ragged)"
+        )
+    if cfg.moe_impl == "auto":
+        impl = "einsum" if _ep_sharded(mesh) else "ragged"
+        return dataclasses.replace(cfg, moe_impl=impl)
+    if cfg.moe_impl == "ragged" and _ep_sharded(mesh):
         raise ValueError(
             "moe_impl='ragged' does not compose with an ep>1 mesh axis "
             "(expert groups are contiguous local row ranges); use the "
             "einsum impl for expert parallelism"
         )
+    return cfg
 
 
 def moe_mlp_ragged(
@@ -270,50 +363,66 @@ def moe_mlp_ragged(
     expert id, so each expert's rows form one contiguous group and the
     three expert matmuls run as ragged group-wise dots against the
     stacked ``(E, D, F)`` weights — no capacity, no drops, no N·E·C
-    one-hot einsums.  The router, normalised top-k gates, and Switch
-    aux loss are identical to :func:`moe_mlp`; outputs match it exactly
-    whenever capacity does not bind there (routing is per-token).
+    one-hot einsums.  The router, top-k gates and router losses are
+    identical to :func:`moe_mlp`; outputs match it exactly whenever
+    capacity does not bind there (routing is per-token).
+
+    Returns (out (N, D), aux load-balance loss scalar).
     """
+    out, losses, _ = _ragged_mlp(x, layer, cfg)
+    return out, losses[0]
+
+
+def _ragged_mlp(
+    x: jax.Array, layer: Params, cfg: MoeConfig
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """:func:`moe_mlp_ragged` with both router losses and the router's
+    picks: (out, (2,) losses, top_e (N, k)).  Its three phases carry
+    profiler scopes (``ddl.moe_route``, ``ddl.moe_experts``,
+    ``ddl.moe_combine``): they reach the device trace as each op's
+    ``tf_op`` name."""
     N, D = x.shape
     E, k = cfg.n_experts, cfg.topk
     dt = x.dtype
 
-    probs, top_p, top_e = _router_topk(x, layer, cfg)
+    with jax.named_scope("ddl.moe_route"):
+        probs, top_p, top_e, z = _router_topk(x, layer, cfg)
+        flat_e = top_e.reshape(-1)  # (N*k,) expert of copy i (token i//k)
+        order = jnp.argsort(flat_e)  # stable: ties keep token order
+        group_sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
 
-    flat_e = top_e.reshape(-1)  # (N*k,) expert of copy i (token i//k)
-    order = jnp.argsort(flat_e)  # stable: ties keep token order
-    xs = jnp.take(x, order // k, axis=0)  # (N*k, D) grouped by expert
-    group_sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
+    with jax.named_scope("ddl.moe_experts"):
+        xs = jnp.take(x, order // k, axis=0)  # (N*k, D) grouped by expert
+        gate = jax.nn.silu(
+            jax.lax.ragged_dot(xs, layer["w_gate"].astype(dt), group_sizes)
+        )
+        up = jax.lax.ragged_dot(xs, layer["w_up"].astype(dt), group_sizes)
+        rows = jax.lax.ragged_dot(
+            gate * up, layer["w_down"].astype(dt), group_sizes
+        )  # (N*k, D), still expert-sorted
 
-    gate = jax.nn.silu(
-        jax.lax.ragged_dot(xs, layer["w_gate"].astype(dt), group_sizes)
-    )
-    up = jax.lax.ragged_dot(xs, layer["w_up"].astype(dt), group_sizes)
-    rows = jax.lax.ragged_dot(
-        gate * up, layer["w_down"].astype(dt), group_sizes
-    )  # (N*k, D), still expert-sorted
-
-    inv = jnp.argsort(order)  # flat copy index -> its sorted row
-    per_slot = jnp.take(rows, inv, axis=0).reshape(N, k, D)
-    out = jnp.einsum("nk,nkd->nd", top_p.astype(dt), per_slot)
-    return out, _switch_aux(probs, top_e, E)
+    with jax.named_scope("ddl.moe_combine"):
+        inv = jnp.argsort(order)  # flat copy index -> its sorted row
+        per_slot = jnp.take(rows, inv, axis=0).reshape(N, k, D)
+        out = jnp.einsum("nk,nkd->nd", top_p.astype(dt), per_slot)
+    return out, _router_losses(probs, top_e, z, cfg), top_e
 
 
 def _moe_mlp_dispatch(
     x: jax.Array, layer: Params, cfg: MoeConfig
-) -> Tuple[jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """(out, (2,) router losses, top_e) by ``cfg.moe_impl`` — resolved
+    (:func:`_resolve_impl`) by every entry point before it gets here."""
     if cfg.moe_impl == "ragged":
-        return moe_mlp_ragged(x, layer, cfg)
-    if cfg.moe_impl != "einsum":
-        raise ValueError(
-            f"unknown moe_impl {cfg.moe_impl!r} (want einsum|ragged)"
-        )
-    return moe_mlp(x, layer, cfg)
+        return _ragged_mlp(x, layer, cfg)
+    if cfg.moe_impl == "einsum":
+        return _einsum_mlp(x, layer, cfg)
+    raise ValueError(f"unresolved moe_impl {cfg.moe_impl!r}")
 
 
 def _routed_mlp(
     h: jax.Array, layer: Params, cfg: MoeConfig, mesh: Optional[Any]
-) -> Tuple[jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The MoE MLP on the (B, T, D) residual stream, mesh-aware.
 
     Ragged impl on a token-sharded mesh (``dp``/``sp`` axes): routing is
@@ -328,13 +437,15 @@ def _routed_mlp(
     one ``psum`` over tp on the partial outputs) so tp devices divide
     the expert FLOPs rather than replicate them; tp that does not
     divide ``d_ff`` falls back to replicated expert compute.  ``ep``
-    stays rejected — :func:`_validate_impl_mesh`.  On an fsdp mesh the
+    stays rejected — :func:`_resolve_impl`.  On an fsdp mesh the
     shard_map boundary gathers a layer's expert stack per step, the
-    same traffic fsdp training pays at each use point.  The aux loss
-    becomes the shard-mean of per-shard Switch aux — the same
-    load-balance pressure at shard granularity, not numerically equal
-    to the global aux (it is not linear in token subsets;
-    ``forward_pp`` documents the same for microbatch groups).
+    same traffic fsdp training pays at each use point.  The router
+    losses become the shard-mean of the per-shard ones: for the z-loss
+    that is the global mean; for the load-balance term the same
+    pressure at shard granularity, not numerically equal to the global
+    one (it is not linear in token subsets; ``forward_pp`` documents
+    the same for microbatch groups).  Returns (out (B, T, D), (2,)
+    router losses, the router's picks (B, T, topk)).
     """
     B, T, D = h.shape
     if cfg.moe_impl == "ragged" and mesh is not None:
@@ -379,23 +490,25 @@ def _routed_mlp(
 
             def body(hs: jax.Array, lyr: Params):
                 b, t, _ = hs.shape
-                out, aux = moe_mlp_ragged(hs.reshape(b * t, -1), lyr, cfg)
+                out, aux, top_e = _ragged_mlp(
+                    hs.reshape(b * t, -1), lyr, cfg
+                )
                 if tax:
                     # Each tp shard computed its d_ff slice; the down
                     # projections are partial sums over the hidden dim.
                     out = jax.lax.psum(out, tax)
                 if token_axes:
                     aux = jax.lax.pmean(aux, token_axes)
-                return out.reshape(b, t, -1), aux
+                return out.reshape(b, t, -1), aux, top_e.reshape(b, t, -1)
 
             return shard_map(
                 body, mesh=mesh,
                 in_specs=(P(bax, sax, None), layer_specs),
-                out_specs=(P(bax, sax, None), P()),
+                out_specs=(P(bax, sax, None), P(), P(bax, sax, None)),
                 check_vma=False,
             )(h, mlp_layer)
-    out, aux = _moe_mlp_dispatch(h.reshape(B * T, -1), layer, cfg)
-    return out.reshape(B, T, -1), aux
+    out, aux, top_e = _moe_mlp_dispatch(h.reshape(B * T, -1), layer, cfg)
+    return out.reshape(B, T, -1), aux, top_e.reshape(B, T, -1)
 
 
 def _layer_apply(
@@ -405,18 +518,19 @@ def _layer_apply(
     positions: jax.Array,
     mesh: Optional[Any] = None,
     segment_ids: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, jax.Array]:
-    """One MoE block on the residual stream → (x, router aux) — the
-    single layer body shared by :func:`forward` and the pipelined
-    :func:`forward_pp`.  The attention sub-block is llama's
-    ``_attn_block`` (one implementation across families); only the MLP
-    differs — routed experts instead of SwiGLU."""
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One MoE block on the residual stream → (x, (2,) router losses,
+    the router's picks (B, T, topk)) — the single layer body shared by
+    :func:`forward` and the pipelined :func:`forward_pp`.  The attention
+    sub-block is llama's ``_attn_block`` (one implementation across
+    families); only the MLP differs — routed experts instead of
+    SwiGLU."""
     x = _llama._attn_block(
         layer, x, cfg, positions, mesh=mesh, segment_ids=segment_ids
     )
     h = _llama._rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-    moe_out, aux = _routed_mlp(h, layer, cfg, mesh)
-    return x + moe_out, aux
+    moe_out, aux, top_e = _routed_mlp(h, layer, cfg, mesh)
+    return x + moe_out, aux, top_e
 
 
 def forward(
@@ -426,15 +540,44 @@ def forward(
     mesh: Optional[Any] = None,
     segment_ids: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """(logits (B, T, vocab), mean router aux loss).
+    """(logits (B, T, vocab), mean router load-balance loss).
 
     ``segment_ids`` (B, T): packed-batch attention masking, as in
     ``models.llama.forward``."""
-    _validate_impl_mesh(cfg, mesh)
+    logits, losses, _ = _forward(params, tokens, cfg, mesh, segment_ids)
+    return logits, losses[0]
+
+
+def forward_with_choices(
+    params: Params,
+    tokens: jax.Array,
+    cfg: MoeConfig,
+    mesh: Optional[Any] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """(logits, the expert ids every layer's router picked (L, B, T,
+    topk)) of ONE forward pass — for holding the model against a
+    reference: with a top-k router a rounding flips some tokens' last
+    choice, so logits are comparable only where the sets agree."""
+    logits, _, picks = _forward(params, tokens, cfg, mesh, None)
+    return logits, picks
+
+
+def _forward(
+    params: Params,
+    tokens: jax.Array,
+    cfg: MoeConfig,
+    mesh: Optional[Any],
+    segment_ids: Optional[jax.Array],
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """:func:`forward` with both router losses, each a mean over the
+    layers, and every layer's picks: (logits, (2,) [load balance, z],
+    (L, B, T, topk)).  A caller that drops the picks pays nothing for
+    them: they are the ids the dispatch sorts by anyway."""
+    cfg = _resolve_impl(cfg, mesh)
     dt = cfg.dtype
     positions = jnp.arange(tokens.shape[1])
     x = params["embed"].astype(dt)[tokens]
-    aux_total = jnp.zeros((), jnp.float32)
+    losses = jnp.zeros((2,), jnp.float32)
 
     def layer_fn(x: jax.Array, layer: Params):
         return _layer_apply(
@@ -447,13 +590,20 @@ def forward(
     from ddl_tpu.models import remat as _remat
 
     layer_fn = _remat.wrap(layer_fn, cfg.remat)
+    picks = []
     for layer in params["layers"]:
-        x, aux = layer_fn(x, layer)
-        aux_total = aux_total + aux
+        x, layer_losses, top_e = layer_fn(x, layer)
+        losses = losses + layer_losses
+        picks.append(top_e)
 
     x = _llama._rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
-    return logits, aux_total / cfg.n_layers
+    return logits, losses / cfg.n_layers, jnp.stack(picks)
+
+
+def _router_penalty(cfg: MoeConfig, losses: jax.Array) -> jax.Array:
+    """What the router losses add to the train loss."""
+    return cfg.router_aux_weight * losses[0] + cfg.router_z_weight * losses[1]
 
 
 # -- pipeline parallelism ----------------------------------------------------
@@ -509,8 +659,8 @@ def forward_pp(
     schedule: str = "gpipe",
     n_chunks: "int | None" = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """(logits, mean router aux loss) with the MoE blocks pipelined over
-    ``axis`` (``schedule``: gpipe, or interleaved 1f1b with
+    """(logits, mean router load-balance loss) with the MoE blocks
+    pipelined over ``axis`` (``schedule``: gpipe, or interleaved 1f1b with
     ``stage_params(..., n_chunks=)`` weights).
 
     The router aux loss accumulates THROUGH the pipe: the activation
@@ -526,35 +676,53 @@ def forward_pp(
     group granularity, not numerically equal to the full-batch aux
     (it is not linear in token subsets).
     """
-    _validate_impl_mesh(cfg, mesh)
+    logits, losses = _forward_pp(
+        params, tokens, cfg, mesh, n_microbatches, axis, schedule, n_chunks
+    )
+    return logits, losses[0]
+
+
+def _forward_pp(
+    params: Params,
+    tokens: jax.Array,
+    cfg: MoeConfig,
+    mesh: Any,
+    n_microbatches: int,
+    axis: str,
+    schedule: str,
+    n_chunks: "int | None",
+) -> Tuple[jax.Array, jax.Array]:
+    """:func:`forward_pp` with both router losses: (logits, (2,))."""
     names = getattr(mesh, "axis_names", ())
-    if cfg.moe_impl == "ragged" and not (
-        axis in names and mesh.shape[axis] > 1
-    ):
-        # Without a real pp axis, pipeline_apply falls back to a
-        # sequential lax.map OUTSIDE shard_map (pipeline.py), where the
-        # layer body runs with mesh=None — a token-sharded dp/sp axis
-        # would then hit moe_mlp_ragged's global argsort under GSPMD
-        # and all-gather every token per layer.  (With pp>1 the
-        # pipeline's shard_map makes dp manual, so local routing is
-        # correct and fast — same argument as _routed_mlp.)
-        for ax in ("dp", "sp"):
-            if ax in names and mesh.shape[ax] > 1:
-                raise ValueError(
-                    f"moe_impl='ragged' with forward_pp needs a real "
-                    f"{axis}>1 mesh axis when {ax}>1 (the sequential "
-                    "fallback would gather token shards); use the "
-                    "einsum impl or a pipelined mesh"
-                )
+    # Without a real pp axis, pipeline_apply falls back to a sequential
+    # lax.map OUTSIDE shard_map (pipeline.py), where the layer body runs
+    # with mesh=None — a token-sharded dp/sp axis would then hit the
+    # ragged impl's global argsort under GSPMD and all-gather every
+    # token per layer.  (With pp>1 the pipeline's shard_map makes dp
+    # manual, so local routing is correct and fast — same argument as
+    # _routed_mlp.)
+    gathers_tokens = not (axis in names and mesh.shape[axis] > 1) and any(
+        ax in names and mesh.shape[ax] > 1 for ax in ("dp", "sp")
+    )
+    if cfg.moe_impl == "auto" and gathers_tokens:
+        cfg = dataclasses.replace(cfg, moe_impl="einsum")
+    cfg = _resolve_impl(cfg, mesh)
+    if cfg.moe_impl == "ragged" and gathers_tokens:
+        raise ValueError(
+            f"moe_impl='ragged' with forward_pp needs a real {axis}>1 "
+            "mesh axis when dp or sp > 1 (the sequential fallback would "
+            "gather token shards); use the einsum impl or a pipelined "
+            "mesh"
+        )
     B, T = tokens.shape
     dt = cfg.dtype
     positions = jnp.arange(T)
     x = params["embed"].astype(dt)[tokens]
 
     def one_layer(state, layer):
-        h, aux_rows = state
-        h, aux = _layer_apply(layer, h, cfg, positions, mesh=None)
-        return h, aux_rows + aux.astype(aux_rows.dtype)
+        h, loss_rows = state
+        h, losses, _ = _layer_apply(layer, h, cfg, positions, mesh=None)
+        return h, loss_rows + losses.astype(loss_rows.dtype)
 
     from ddl_tpu.models import remat as _remat
 
@@ -568,18 +736,18 @@ def forward_pp(
 
     from ddl_tpu.parallel.pipeline import pipeline_apply
 
-    x, aux_rows = pipeline_apply(
+    x, loss_rows = pipeline_apply(
         params["stages"],
-        (x, jnp.zeros((B,), jnp.float32)),
+        (x, jnp.zeros((B, 2), jnp.float32)),
         stage_fn, mesh, n_microbatches, axis=axis,
         schedule=schedule, n_chunks=n_chunks,
     )
     x = _llama._rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
-    # Every row of a microbatch carries that microbatch's summed aux;
-    # the row-mean is the microbatch-mean, normalized per layer as in
-    # the non-pp forward.
-    return logits, jnp.mean(aux_rows) / cfg.n_layers
+    # Every row of a microbatch carries that microbatch's summed router
+    # losses; the row-mean is the microbatch-mean, normalized per layer
+    # as in the non-pp forward.
+    return logits, jnp.mean(loss_rows, axis=0) / cfg.n_layers
 
 
 def next_token_loss_pp(
@@ -592,15 +760,15 @@ def next_token_loss_pp(
     schedule: str = "gpipe",
     n_chunks: "int | None" = None,
 ) -> jax.Array:
-    """Cross-entropy + weighted router aux over the pipelined forward."""
+    """Cross-entropy + weighted router losses over the pipelined
+    forward."""
     from ddl_tpu.models.losses import next_token_cross_entropy
 
-    logits, aux = forward_pp(
-        params, tokens, cfg, mesh, n_microbatches, axis=axis,
-        schedule=schedule, n_chunks=n_chunks,
+    logits, losses = _forward_pp(
+        params, tokens, cfg, mesh, n_microbatches, axis, schedule, n_chunks
     )
     ce = next_token_cross_entropy(logits, tokens)
-    return ce + cfg.router_aux_weight * aux
+    return ce + _router_penalty(cfg, losses)
 
 
 # -- inference: KV-cache decode + generate -----------------------------------
@@ -628,7 +796,8 @@ def forward_with_cache(
     The attention sub-block is the shared cache math
     (``llama._attn_with_cache``: compact GQA cache, causal-position
     mask); each decoded token then routes through the SAME top-k gate
-    and dispatch impl as training (``cfg.moe_impl``, via
+    and dispatch impl as training (``cfg.moe_impl``; ``auto`` is
+    dropless ragged here, there being no mesh — via
     ``_moe_mlp_dispatch`` on the flat (B*T, D) tokens).
 
     Impl semantics.  ``ragged``: dropless — decode matches the full
@@ -642,6 +811,7 @@ def forward_with_cache(
     Returns (logits, updated cache); router aux loss is a training
     quantity and is not computed here.
     """
+    cfg = _resolve_impl(cfg, None)
     B, T = tokens.shape
     dt = cfg.dtype
     positions = pos + jnp.arange(T)
@@ -656,7 +826,7 @@ def forward_with_cache(
             layer, x, cfg, k_all, v_all, li, pos, positions, cache_idx,
         )
         h = _llama._rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        moe_out, _aux = _moe_mlp_dispatch(h.reshape(B * T, -1), layer, cfg)
+        moe_out = _moe_mlp_dispatch(h.reshape(B * T, -1), layer, cfg)[0]
         x = x + moe_out.reshape(B, T, -1)
 
     x = _llama._rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -696,13 +866,14 @@ def next_token_loss(
     mesh: Optional[Any] = None,
     segment_ids: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """Cross-entropy + weighted router load-balance loss.
+    """Cross-entropy + the weighted router losses (load balance, and
+    the z-loss where ``cfg.router_z_weight`` is set).
 
     With ``segment_ids`` (packed batches), attention is segment-masked
     and cross-document boundary predictions drop from the CE, matching
     ``models.llama.next_token_loss``."""
     from ddl_tpu.models.losses import next_token_cross_entropy
 
-    logits, aux = forward(params, tokens, cfg, mesh, segment_ids=segment_ids)
+    logits, losses, _ = _forward(params, tokens, cfg, mesh, segment_ids)
     ce = next_token_cross_entropy(logits, tokens, segment_ids=segment_ids)
-    return ce + cfg.router_aux_weight * aux
+    return ce + _router_penalty(cfg, losses)

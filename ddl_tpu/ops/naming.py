@@ -10,6 +10,16 @@ first frame under it into its own (``transpose(jvp(<name>))``) — so the
 call is traced under a ``jax.named_scope`` of the same name as well; the
 instruction then reads ``<name>.<n>`` whatever wraps it
 (``tests/test_tpu_compile.py`` greps the compiled HLO).
+
+Not every kernel on the trace is the repo's.  XLA makes some itself and
+names them itself, stably: ``jax.lax.ragged_dot`` (the grouped matmuls
+of ``models/moe.py``'s dropless expert layer) and both of its
+transposes compile on a v5e to Mosaic custom calls
+``ragged-dot-none.<n>``, each with a small ``ragged-dot-metadata.<n>``
+beside it.  Those families need no name from here; the benchmark's
+``gmm_device_share`` / ``gmm_roofline_share`` read the ``ragged-dot-``
+prefix, and would read ``ddl_gmm*`` should a later PR bring a grouped
+matmul of the repo's own (add it to :data:`KERNEL_NAMES` then).
 """
 
 from __future__ import annotations

@@ -774,13 +774,14 @@ class TestRematPolicies:
         )(params)
         assert remat.ATTN_OUT_NAME in str(tagged)
 
-    def test_moe_selective_matches_no_remat(self):
+    @pytest.mark.parametrize("impl", ["einsum", "ragged"])
+    def test_moe_selective_matches_no_remat(self, impl):
         from ddl_tpu.models import moe
 
         base = dict(
             vocab=64, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,
             d_ff=64, n_experts=4, dtype=jnp.float32, attn_impl="dense",
-            capacity_factor=8.0,
+            capacity_factor=8.0, moe_impl=impl,
         )
         cfg = moe.MoeConfig(**base)
         cfg_r = moe.MoeConfig(**base, remat="selective")

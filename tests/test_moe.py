@@ -13,9 +13,12 @@ from ddl_tpu.parallel.train import make_train_step
 
 
 def _cfg(**kw):
+    # The capacity-bounded einsum dispatch unless a test names another:
+    # the config's default ("auto") is the dropless ragged one without an
+    # ep axis, and these tests are about capacity, drops and the ep mesh.
     base = dict(
         vocab=64, d_model=32, n_layers=1, n_heads=4, n_kv_heads=2,
-        d_ff=64, n_experts=4, dtype=jnp.float32,
+        d_ff=64, n_experts=4, dtype=jnp.float32, moe_impl="einsum",
     )
     base.update(kw)
     return moe.MoeConfig(**base)
@@ -223,9 +226,16 @@ class TestRaggedImpl:
             moe.forward(params, toks, cfg)
 
 
+#: The model-level contracts hold for the capacity-bounded dispatch (the
+#: one an ``ep`` mesh runs) and the dropless one (what ``auto`` picks
+#: everywhere else), each under its own name.
+BOTH_DISPATCHES = pytest.mark.parametrize("impl", ["einsum", "ragged"])
+
+
 class TestMoeModel:
-    def test_forward_finite_and_shapes(self, rng):
-        cfg = _cfg(n_layers=2)
+    @BOTH_DISPATCHES
+    def test_forward_finite_and_shapes(self, rng, impl):
+        cfg = _cfg(n_layers=2, moe_impl=impl)
         params = moe.init_params(cfg, jax.random.key(0))
         tokens = jnp.asarray(rng.integers(0, 64, (2, 16)), jnp.int32)
         logits, aux = moe.forward(params, tokens, cfg)
@@ -233,11 +243,12 @@ class TestMoeModel:
         assert np.isfinite(np.asarray(logits)).all()
         assert float(aux) > 0
 
-    def test_remat_matches_plain_forward_and_grad(self, rng):
+    @BOTH_DISPATCHES
+    def test_remat_matches_plain_forward_and_grad(self, rng, impl):
         """cfg.remat trades memory for FLOPs, not math: loss and grads
         must match the plain path through routing and dispatch."""
-        base = _cfg(n_layers=2)
-        rcfg = _cfg(n_layers=2, remat=True)
+        base = _cfg(n_layers=2, moe_impl=impl)
+        rcfg = _cfg(n_layers=2, remat=True, moe_impl=impl)
         params = moe.init_params(base, jax.random.key(0))
         tokens = jnp.asarray(rng.integers(0, 64, (2, 16)), jnp.int32)
 
@@ -267,7 +278,8 @@ class TestMoeModel:
         assert logits.dtype == jnp.float32
         assert np.isfinite(np.asarray(logits)).all()
 
-    def test_packed_segments_isolation(self, rng):
+    @BOTH_DISPATCHES
+    def test_packed_segments_isolation(self, rng, impl):
         """Packed MoE batches: rewriting document 0 must not change
         document 1's logits (segment masking reaches the MoE family).
 
@@ -276,7 +288,7 @@ class TestMoeModel:
         cross-token coupling of capacity-bounded MoE, not an attention
         leak — so the test raises capacity_factor above the drop point.
         """
-        cfg = _cfg(n_layers=2, capacity_factor=8.0)
+        cfg = _cfg(n_layers=2, capacity_factor=8.0, moe_impl=impl)
         params = moe.init_params(cfg, jax.random.key(0))
         t1 = jnp.asarray(rng.integers(1, 64, (1, 16)), jnp.int32)
         t2 = t1.at[0, :8].set(0)
@@ -292,11 +304,12 @@ class TestMoeModel:
         loss = moe.next_token_loss(params, t1, cfg, segment_ids=seg)
         assert np.isfinite(float(loss))
 
-    def test_cached_prefill_matches_forward(self, rng):
+    @BOTH_DISPATCHES
+    def test_cached_prefill_matches_forward(self, rng, impl):
         """forward_with_cache over a whole prompt == plain forward —
         EXACTLY, drops included: prefill routes the same token set with
         the same capacity as the training forward."""
-        cfg = _cfg(n_layers=2)
+        cfg = _cfg(n_layers=2, moe_impl=impl)
         params = moe.init_params(cfg, jax.random.key(0))
         tokens = jnp.asarray(rng.integers(0, cfg.vocab, (2, 12)), jnp.int32)
         full, _aux = moe.forward(params, tokens, cfg)
@@ -308,12 +321,13 @@ class TestMoeModel:
             np.asarray(full), np.asarray(cached), rtol=2e-5, atol=2e-5
         )
 
-    def test_stepwise_decode_matches_teacher_forcing(self, rng):
+    @BOTH_DISPATCHES
+    def test_stepwise_decode_matches_teacher_forcing(self, rng, impl):
         """One-token cached steps reproduce the full forward's logits at
         every position.  Ample capacity (see forward_with_cache's
         capacity-semantics note): routing is per-token, so with no drops
         in either path the KV-cache decode is exact."""
-        cfg = _cfg(n_layers=2, capacity_factor=8.0)
+        cfg = _cfg(n_layers=2, capacity_factor=8.0, moe_impl=impl)
         params = moe.init_params(cfg, jax.random.key(0))
         tokens = jnp.asarray(rng.integers(0, cfg.vocab, (1, 10)), jnp.int32)
         full, _aux = moe.forward(params, tokens, cfg)
@@ -327,11 +341,12 @@ class TestMoeModel:
                 rtol=2e-5, atol=2e-5, err_msg=f"position {t}",
             )
 
-    def test_greedy_generate(self, rng):
+    @BOTH_DISPATCHES
+    def test_greedy_generate(self, rng, impl):
         """Greedy MoE generation: deterministic, prompt-prefixed, first
         emitted token teacher-force-checked — llama's generate contract
         on the MoE family."""
-        cfg = _cfg(n_layers=2, capacity_factor=8.0)
+        cfg = _cfg(n_layers=2, capacity_factor=8.0, moe_impl=impl)
         params = moe.init_params(cfg, jax.random.key(0))
         prompt = jnp.asarray(rng.integers(0, cfg.vocab, (2, 5)), jnp.int32)
         out = moe.generate(params, prompt, cfg, max_new_tokens=4)

@@ -502,6 +502,7 @@ class TestMoePipeline:
             vocab=64, d_model=32, n_layers=4, n_heads=4, n_kv_heads=2,
             d_ff=64, n_experts=4, dtype=jnp.float32, attn_impl="dense",
             capacity_factor=8.0,  # unbound capacity -> exact logits
+            moe_impl="einsum",  # the dispatch these tests were written for
         )
         base.update(kw)
         return MoeConfig(**base)

@@ -142,6 +142,39 @@ def test_flash_names_survive_remat_and_shard_map(v5e):
     }
 
 
+@pytest.mark.parametrize("remat", ["none", "selective", "full", "dots"])
+def test_grouped_matmuls_are_xlas_own_kernels_under_a_stable_name(v5e, remat):
+    """``jax.lax.ragged_dot`` and both of its transposes compile to XLA's
+    own Mosaic kernels on a v5e, instructions ``ragged-dot-none.<n>``
+    (+ a small ``ragged-dot-metadata.<n>``): the op family the benchmark's
+    ``gmm_device_share`` / ``gmm_roofline_share`` read.  A layer and step
+    makes 9 such calls, 12 where the remat policy re-runs the forward:
+    the count ``benchmarks/lib/moe_flops.py`` multiplies FLOPs by."""
+    from benchmarks.lib import moe_flops
+    from ddl_tpu.models import moe
+
+    cfg = moe.MoeConfig(
+        vocab=512, d_model=256, n_layers=1, n_heads=2, n_kv_heads=2,
+        d_ff=256, n_experts=8, topk=2, max_seq=256,
+        param_dtype=jnp.bfloat16, attn_impl="dense", qk_norm=True,
+        norm_topk_prob=False, router_aux_all_slots=True,
+        router_z_weight=0.001, remat=remat,
+    )
+    one = SingleDeviceSharding(v5e[0])
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+        moe.param_shapes(cfg),
+    )
+    tokens = jax.ShapeDtypeStruct((2, 256), jnp.int32, sharding=one)
+    text = jax.jit(jax.value_and_grad(
+        lambda p, t: moe.next_token_loss(p, t, cfg)
+    )).lower(params, tokens).compile().as_text()
+    calls = re.findall(r"%(ragged-dot-[a-z]+)(?:\.\d+)* = \S+ custom-call\(", text)
+    assert calls.count("ragged-dot-none") == moe_flops.GMM_CALLS_PER_LAYER[remat]
+    assert set(calls) <= {"ragged-dot-none", "ragged-dot-metadata"}
+    assert "ragged-dot-none" in kernel_names(text)
+
+
 # The 64 MiB float32 stream window (65536 x 256) over the four chips.
 ROWS, COLS = 65536, 256
 
