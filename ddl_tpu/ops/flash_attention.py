@@ -33,6 +33,13 @@ The public wrappers pad ragged sequence lengths to the block size (padded
 keys are masked out, padded query rows sliced off) and fall back to
 ``interpret=True`` off-TPU, which is how the CPU test suite validates them
 bit-for-bit against the dense oracle.
+
+A sequence that fits ONE block needs none of this — no carry, no block
+skipping, no ``(B, H, T, D)`` relayout, no pad — and pays for all of it;
+``flash_attention()`` hands such calls (``flash_tile.fits``: shapes and
+arguments only) to the one-block kernels of ``ops/flash_tile.py``, which
+share nothing with the kernels here.  ``flash_attention_with_lse``, packed
+rows, GQA and every longer sequence stay here.
 """
 
 from __future__ import annotations
@@ -46,6 +53,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ddl_tpu.ops import flash_tile
 from ddl_tpu.ops.naming import named_pallas_call
 
 _NEG_INF = -1e30
@@ -710,6 +718,8 @@ def flash_attention(
     mask; unpacked calls are entirely unaffected.
     """
     block_q, block_k = _default_blocks(q.shape[1], block_q, block_k)
+    if flash_tile.fits(q, k, v, kv_repeat, block_q, block_k, segment_ids):
+        return flash_tile.tile_attention(q, k, v, causal, interpret)
     if segment_ids is not None:
         out, _ = _flash_core_seg(
             q, k, v, _offsets_arr(0, 0), segment_ids, segment_ids, causal,

@@ -33,6 +33,7 @@ from jax.experimental import pallas as pl
 #: benchmark's ``flash_device_share`` reads the ``ddl_flash_`` prefix.
 KERNEL_NAMES = (
     "ddl_flash_fwd", "ddl_flash_bwd_dq", "ddl_flash_bwd_dkv",
+    "ddl_flash_tile_fwd", "ddl_flash_tile_bwd",
     "ddl_ici_bcast", "ddl_ici_scatter", "ddl_shuffle_exchange",
 )
 

@@ -4,6 +4,9 @@ Runs in interpret mode on the CPU test backend (conftest); on a real TPU
 the same code path compiles via Mosaic.
 """
 
+import hashlib
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -264,3 +267,253 @@ class TestRingFlash:
         )
         assert float(np.abs(np.asarray(out2)).max()) == 0.0
         assert bool(np.all(np.asarray(lse2) < -1e29))
+
+
+# -- a sequence that fits one block: ops/flash_tile.py --------------------------
+
+#: (B, T, H, D): ViT-B/16's geometry, off every tile (196 rows, 64-deep
+#: heads: six 128-lane groups, two an iteration); a tile multiple (four
+#: heads in one group); a single token; an odd number of groups; an odd
+#: number of 64-lane heads (one static group); 128-lane heads (a group
+#: each).
+TILE_SHAPES = [(2, 196, 12, 64), (2, 128, 4, 32), (3, 1, 2, 32),
+               (1, 24, 6, 64), (1, 24, 3, 64), (1, 16, 2, 128)]
+
+
+def _kernels(fn, *args):
+    """The Pallas kernels' names in ``fn``'s program lowered for the TPU."""
+    text = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    return set(re.findall(r"ddl_flash_\w+", text))
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("shape", TILE_SHAPES, ids=lambda s: "x".join(map(str, s)))
+def test_tile_matches_dense(rng, shape, causal):
+    from ddl_tpu.ops import flash_tile
+
+    B, T, H, D = shape
+    q, k, v = _qkv(rng, B=B, T=T, H=H, D=D)
+    assert flash_tile.fits(q, k, v, 1, 512, 1024, None)
+    out = flash_attention(q, k, v, causal=causal)
+    ref = attention_reference(q, k, v, causal=causal)
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("shape", TILE_SHAPES, ids=lambda s: "x".join(map(str, s)))
+def test_tile_grads_match_dense(rng, shape, causal):
+    """The one backward kernel == autodiff through the dense oracle."""
+    B, T, H, D = shape
+    q, k, v = _qkv(rng, B=B, T=T, H=H, D=D)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v, causal=causal)))
+
+    gf = jax.grad(loss(flash_attention), argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(loss(attention_reference), argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(gf, gd, "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-5,
+            err_msg=f"d{name}",
+        )
+
+
+@pytest.mark.parametrize("rows", [1, 2, 3, 4, 6], ids=lambda b: f"batch{b}")
+def test_tile_one_batch_row_a_grid_step(rng, rows):
+    """Every batch size: the grid's first axis is the batch."""
+    q, k, v = _qkv(rng, B=rows, T=24, H=2, D=32)
+    out = flash_attention(q, k, v, causal=False)
+    ref = attention_reference(q, k, v, causal=False)
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+
+def test_tile_head_groups_when_all_heads_exceed_the_budget(rng, monkeypatch):
+    """Heads split over the grid's second axis in 128-lane groups."""
+    from ddl_tpu.ops import flash_tile
+
+    q, k, v = _qkv(rng, B=2, T=40, H=4, D=64)
+    monkeypatch.setattr(flash_tile, "_BLOCK_BUDGET", 14 * 40 * 4 * 64 * 2)
+    assert flash_tile._plan(40, 4, 64, q.dtype) == 2
+    fn = lambda q, k, v: jnp.sum(flash_attention(q, k, v, causal=False) ** 2)  # noqa: E731
+    rf = lambda q, k, v: jnp.sum(attention_reference(q, k, v, causal=False) ** 2)  # noqa: E731
+    for a, b in zip(jax.grad(fn, (0, 1, 2))(q, k, v),
+                    jax.grad(rf, (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-5, rtol=5e-5)
+    # 3 heads x 64 is no multiple of 128 lanes and all 3 are too many:
+    # no legal group, the blockwise kernels keep the call.
+    q3 = q[:, :, :3]
+    monkeypatch.setattr(flash_tile, "_BLOCK_BUDGET", 14 * 40 * 4 * 64)
+    assert not flash_tile.fits(q3, q3, q3, 1, 512, 1024, None)
+
+
+@pytest.mark.parametrize("what", ["out", "grads"])
+def test_tile_bf16_and_jit(rng, what):
+    q, k, v = _qkv(rng, B=2, T=196, H=2, D=64, dtype=jnp.bfloat16)
+
+    def run(attn):
+        if what == "out":
+            return (attn(q, k, v, causal=False),)
+        return jax.grad(
+            lambda q, k, v: jnp.sum(
+                attn(q, k, v, causal=False).astype(jnp.float32) ** 2
+            ), argnums=(0, 1, 2),
+        )(q, k, v)
+
+    got = jax.jit(lambda: run(flash_attention))()
+    want = run(attention_reference)
+    for a, b in zip(got, want):
+        assert a.dtype == jnp.bfloat16
+        np.testing.assert_allclose(
+            a.astype(np.float32), b.astype(np.float32), atol=3e-2, rtol=3e-2
+        )
+
+
+@pytest.mark.parametrize("what", ["out", "grads"])
+def test_tile_under_sharded_local_attention(rng, what):
+    """ViT's dp mesh: the one-block kernels inside the shard_map."""
+    from ddl_tpu.parallel.mesh import make_mesh
+    from ddl_tpu.parallel.ring_attention import sharded_local_attention
+
+    mesh = make_mesh({"dp": 8})
+    q, k, v = _qkv(rng, B=16, T=50, H=4, D=32)
+
+    def sharded(q, k, v):
+        return sharded_local_attention(q, k, v, mesh, causal=False,
+                                       use_flash=True)
+
+    def dense(q, k, v):
+        return attention_reference(q, k, v, causal=False)
+
+    if what == "out":
+        np.testing.assert_allclose(sharded(q, k, v), dense(q, k, v),
+                                   atol=2e-5, rtol=2e-5)
+        return
+    loss = lambda fn: lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))  # noqa: E731
+    for a, b in zip(jax.grad(loss(sharded), (0, 1, 2))(q, k, v),
+                    jax.grad(loss(dense), (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-5, rtol=5e-5)
+
+
+TILE = {"ddl_flash_tile_fwd", "ddl_flash_tile_bwd"}
+BLOCK = {"ddl_flash_fwd", "ddl_flash_bwd_dq", "ddl_flash_bwd_dkv"}
+
+
+def _grad_of(attn):
+    # The value too: the one-block backward needs nothing of the forward,
+    # and jit drops a forward kernel whose output nobody reads.
+    return jax.value_and_grad(
+        lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32)),
+        argnums=(0, 1, 2),
+    )
+
+
+@pytest.mark.parametrize("case,want", [
+    ("vit", TILE), ("vit_causal", TILE), ("t512_d128", TILE),
+    ("d16", BLOCK), ("d80", BLOCK), ("d96", BLOCK), ("d192", BLOCK),
+    ("float16", BLOCK), ("t4096", BLOCK), ("t513", BLOCK), ("segment_ids", BLOCK),
+    ("gqa", BLOCK), ("explicit_blocks", BLOCK), ("with_lse", BLOCK),
+    ("with_lse_offsets", BLOCK),
+])
+def test_which_kernels_a_call_lowers_to(case, want):
+    """The rule is shapes and arguments: the kernels' names in the program
+    lowered for the TPU, forward and backward."""
+    from ddl_tpu.ops import flash_attention_with_lse
+
+    def args(B, T, H, D, Hkv=None):
+        q = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((B, T, Hkv or H, D), jnp.bfloat16)
+        return q, kv, kv
+
+    kw = dict(interpret=False)
+    seg = jnp.zeros((2, 196), jnp.int32)
+    fn, shapes = {
+        "vit": (lambda q, k, v: flash_attention(q, k, v, causal=False, **kw),
+                args(128, 196, 12, 64)),
+        "vit_causal": (lambda q, k, v: flash_attention(q, k, v, **kw),
+                       args(2, 196, 12, 64)),
+        "t512_d128": (lambda q, k, v: flash_attention(q, k, v, **kw),
+                      args(1, 512, 32, 128)),
+        # Heads shallower than 32 lanes (a group would unroll 8), and heads
+        # that neither divide a 128-lane group nor fill whole ones (ViT-H/14's
+        # 80: no aligned lane slice): the blockwise kernels'.
+        "d16": (lambda q, k, v: flash_attention(q, k, v, causal=False, **kw),
+                args(8, 196, 16, 16)),
+        "d80": (lambda q, k, v: flash_attention(q, k, v, causal=False, **kw),
+                args(8, 257, 16, 80)),
+        "d96": (lambda q, k, v: flash_attention(q, k, v, causal=False, **kw),
+                args(8, 196, 16, 96)),
+        "d192": (lambda q, k, v: flash_attention(q, k, v, **kw),
+                 args(8, 128, 4, 192)),
+        # A dtype no chip run timed and no compile tried on the new path.
+        "float16": (lambda q, k, v: flash_attention(q, k, v, **kw),
+                    tuple(jax.ShapeDtypeStruct(x.shape, jnp.float16)
+                          for x in args(2, 196, 12, 64))),
+        "t4096": (lambda q, k, v: flash_attention(q, k, v, **kw),
+                  args(1, 4096, 2, 128)),
+        "t513": (lambda q, k, v: flash_attention(q, k, v, **kw),
+                 args(1, 513, 2, 128)),
+        "segment_ids": (
+            lambda q, k, v: flash_attention(q, k, v, segment_ids=seg, **kw),
+            args(2, 196, 12, 64)),
+        "gqa": (lambda q, k, v: flash_attention(q, k, v, kv_repeat=2, **kw),
+                args(2, 196, 12, 64, Hkv=6)),
+        "explicit_blocks": (
+            lambda q, k, v: flash_attention(q, k, v, block_q=128,
+                                            block_k=128, **kw),
+            args(2, 196, 12, 64)),
+        "with_lse": (
+            lambda q, k, v: flash_attention_with_lse(q, k, v, **kw)[0],
+            args(2, 196, 12, 64)),
+        "with_lse_offsets": (
+            lambda q, k, v: flash_attention_with_lse(
+                q, k, v, q_offset=196, k_offset=0, **kw)[0],
+            args(2, 196, 12, 64)),
+    }[case]
+    assert _kernels(_grad_of(fn), *shapes) == want
+
+
+#: sha256 of the traced train steps below on the parent commit (bf3b36b),
+#: source locations and function addresses taken out.  (The text LOWERED
+#: for the TPU will not do: Mosaic serialises each kernel with the file
+#: and line of every operation, so it changes with the checkout's path.)
+PARENT_JAX = "0.9.0"
+PARENT_STEP_SHA256 = {
+    "mistral":
+        "95d4a46c32ff0e5519e98aa4d263422da9d6c1d8a249806d4165733b7b0f6355",
+    "olmoe":
+        "2d60cdfcd6497abdb54858ed1103d886b520492276aebbbccc94f17b77e7e820",
+}
+
+
+@pytest.mark.parametrize("model", ["mistral", "olmoe"])
+def test_decoder_steps_trace_to_what_the_parent_traced(model, monkeypatch):
+    """T = 4096 bypasses the one-block path: the train step of a decoder
+    shaped like the benchmark's (GQA 2:1 for Mistral; full MHA + QK-norm
+    and routed experts for OLMoE; 128-deep heads, selective remat, flash
+    kernels and all) is the program the parent commit traced — the old
+    three kernels, equation for equation."""
+    from ddl_tpu.models import llama, moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    common = dict(
+        vocab=256, d_model=256, n_layers=1, n_heads=2, d_ff=128,
+        max_seq=4096, attn_impl="flash", remat="selective",
+        param_dtype=jnp.bfloat16,
+    )
+    if model == "mistral":
+        mod, cfg = llama, llama.LlamaConfig(n_kv_heads=1, **common)
+    else:
+        mod, cfg = moe, moe.MoeConfig(
+            n_kv_heads=2, n_experts=4, topk=2, qk_norm=True,
+            norm_topk_prob=False, **common)
+    params = jax.eval_shape(lambda: mod.init_params(cfg, jax.random.key(0)))
+    tokens = jax.ShapeDtypeStruct((1, 4096), jnp.int32)
+    text = str(jax.make_jaxpr(jax.value_and_grad(
+        lambda p, t: mod.next_token_loss(p, t, cfg)
+    ))(params, tokens))
+    assert set(re.findall(r"ddl_flash_\w+", text)) == BLOCK
+    if jax.__version__ == PARENT_JAX:  # the printed form is this JAX's
+        text = re.sub(r" at (0x[0-9a-f]+|\S+:\d+)", "", text)
+        assert hashlib.sha256(text.encode()).hexdigest() == PARENT_STEP_SHA256[model]

@@ -18,6 +18,7 @@ for a described device cannot be read back without one, and the retry
 warns.
 """
 
+import importlib
 import os
 import re
 
@@ -140,6 +141,121 @@ def test_flash_names_survive_remat_and_shard_map(v5e):
     assert kernel_names(text) == {
         "ddl_flash_fwd", "ddl_flash_bwd_dq", "ddl_flash_bwd_dkv"
     }
+
+
+# ViT-B/16's attention at the benchmark's batch: 128 rows x 196 patches,
+# 12 heads x 64, bf16, non-causal -- a sequence that fits one block
+# (``ops/flash_tile.py``).
+VB, VT, VH, VD = 128, 196, 12, 64
+TILE_KERNELS = {"ddl_flash_tile_fwd", "ddl_flash_tile_bwd"}
+BLOCK_KERNELS = {"ddl_flash_fwd", "ddl_flash_bwd_dq", "ddl_flash_bwd_dkv"}
+
+
+def _value_and_grads(attn):
+    # The value keeps the forward kernel in: the one-block backward reads
+    # nothing of it.
+    return jax.value_and_grad(
+        lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2),
+    )
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+def test_one_block_attention_compiles_at_vits_geometry(v5e, grad):
+    """64-lane head slices of a 196-row block, off every tile: Mosaic
+    takes both directions, under the names the trace reduction reads, and
+    the program needs less device memory than the blockwise kernels at
+    the same shape (no transposed, padded copies; no ``lse``)."""
+    # The module: ``ddl_tpu.ops.flash_attention`` the attribute is the function.
+    blockwise = importlib.import_module("ddl_tpu.ops.flash_attention")
+
+    def tile(q, k, v):
+        return flash_attention(q, k, v, causal=False, interpret=False)
+
+    def block(q, k, v):
+        return blockwise._flash_core(
+            q, k, v, blockwise._offsets_arr(0, 0), False, 1, 512, 1024, False
+        )[0]
+
+    x = jax.ShapeDtypeStruct(
+        (VB, VT, VH, VD), jnp.bfloat16, sharding=SingleDeviceSharding(v5e[0])
+    )
+    compiled = {
+        name: jax.jit(_value_and_grads(fn) if grad else fn)
+        .lower(x, x, x).compile()
+        for name, fn in (("tile", tile), ("block", block))
+    }
+    assert kernel_names(compiled["tile"].as_text()) == (
+        TILE_KERNELS if grad else {"ddl_flash_tile_fwd"}
+    )
+    assert kernel_names(compiled["block"].as_text()) == (
+        BLOCK_KERNELS if grad else {"ddl_flash_fwd"}
+    )
+    temp = {
+        name: c.memory_analysis().temp_size_in_bytes
+        for name, c in compiled.items()
+    }
+    assert temp["tile"] < temp["block"], temp
+
+
+def test_one_block_attention_compiles_under_vits_dp_mesh(v5e):
+    """``vit-b16.images-224-dp4``: 128 rows a chip inside
+    ``sharded_local_attention``'s shard_map, forward and backward."""
+    from unittest import mock
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import numpy as np
+
+    from ddl_tpu.parallel.ring_attention import sharded_local_attention
+
+    mesh = Mesh(np.array(v5e), ("dp",))
+    sh = NamedSharding(mesh, P("dp", None, None, None))
+    x = jax.ShapeDtypeStruct(
+        (VB * len(v5e), VT, VH, VD), jnp.bfloat16, sharding=sh
+    )
+    # The kernels themselves, not the interpreter this process's CPU
+    # backend would ask for.
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        text = jax.jit(_value_and_grads(
+            lambda q, k, v: sharded_local_attention(
+                q, k, v, mesh, causal=False, use_flash=True)
+        )).lower(x, x, x).compile().as_text()
+    assert kernel_names(text) == TILE_KERNELS
+    assert " all-gather(" not in text and " all-to-all(" not in text
+
+
+@pytest.mark.parametrize("shape,dtype,want", [
+    # What ``flash_tile.fits`` takes beyond ViT's geometry: three 64-lane
+    # heads (one static group off the 128 lanes), an odd number of groups,
+    # 128- and 256-lane heads at the longest T, float32, a single token.
+    ((2, 24, 3, 64), jnp.bfloat16, TILE_KERNELS),
+    ((2, 197, 20, 32), jnp.bfloat16, TILE_KERNELS),
+    ((2, 512, 16, 128), jnp.bfloat16, TILE_KERNELS),
+    ((2, 512, 4, 256), jnp.bfloat16, TILE_KERNELS),
+    ((2, 512, 16, 128), jnp.float32, TILE_KERNELS),
+    ((2, 196, 12, 64), jnp.float32, TILE_KERNELS),
+    ((2, 1, 2, 32), jnp.bfloat16, TILE_KERNELS),
+    # Heads that neither divide 128 lanes nor are a multiple of them
+    # (ViT-H/14's 80): Mosaic cannot prove their lane offsets aligned, so
+    # the rule leaves them to the blockwise kernels, as before.
+    ((8, 257, 16, 80), jnp.bfloat16, BLOCK_KERNELS),
+    ((8, 196, 16, 96), jnp.bfloat16, BLOCK_KERNELS),
+    ((8, 128, 4, 192), jnp.bfloat16, BLOCK_KERNELS),
+], ids=lambda x: "x".join(map(str, x)) if isinstance(x, tuple) else None)
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_short_sequences_compile_on_the_path_the_rule_gives(
+        v5e, shape, dtype, want, causal):
+    """Forward and backward of ``flash_attention`` at one-block shapes:
+    whichever kernels the rule picks, Mosaic takes them."""
+    x = jax.ShapeDtypeStruct(
+        shape, dtype, sharding=SingleDeviceSharding(v5e[0])
+    )
+    text = jax.jit(_value_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, causal=causal,
+                                        interpret=False)
+    )).lower(x, x, x).compile().as_text()
+    assert kernel_names(text) == want
 
 
 @pytest.mark.parametrize("remat", ["none", "selective", "full", "dots"])
