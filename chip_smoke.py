@@ -12,14 +12,14 @@ TPU it exits non-zero before any phase and prints no result.
 One chip:
 
 - **ingest leg** — two spawned PROCESS producers fill 64 MiB float32
-  windows (``bench.py``'s stream geometry) write-once into native shm
+  windows (65536 x 256) write-once into native shm
   ring slots; ``loader.windows()`` streams them into HBM; every window
   is CRC'd against a host-side regeneration from the seed; every
   fallback counter must read zero and both producers must exit 0
   without ever having initialised a JAX backend.
 - **train leg** — ``Trainer.fit(window_stream=True, mode="process")``,
-  fused default, on the repo's HBM-filling Llama (``bench.py``'s "big"
-  geometry at full depth and width: 1.39 B parameters, bf16, selective
+  fused default, on the repo's HBM-filling Llama (20 layers x 2048
+  wide, 16/8 heads, vocab 32768: 1.39 B parameters, bf16, selective
   remat, batch 4 x seq 2048, flash attention) fed token windows by
   seeded producers; then one checkpoint generation, and a fresh
   ``Trainer`` on the same directory that resumes and takes the next
@@ -61,13 +61,13 @@ class Sizes:
     """The run's geometry.  The defaults ARE the run; the only other
     instance is the CPU rehearsal's (tests/smoke_rehearsal.py)."""
 
-    # Stream windows: bench.py's stream geometry, 65536 x 256 float32.
+    # Stream windows: 65536 x 256 float32, 64 MiB.
     stream_rows: int = 65536
     stream_cols: int = 256
     stream_batch: int = 2048
     stream_windows: int = 8
     lookahead: int = 3
-    # The repo's HBM-filling Llama (bench.py _train_config "big").
+    # The repo's HBM-filling Llama: 1.39 B parameters at these sizes.
     vocab: int = 32768
     d_model: int = 2048
     n_layers: int = 20

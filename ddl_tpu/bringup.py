@@ -1,7 +1,8 @@
 """Process bring-up: which JAX platform, and where compiles are cached.
 
-The entry scripts (``chip_smoke.py``, ``bench.py``, ``tools/probe_*``,
-``examples/``, ``__graft_entry__.py``) call these two functions once,
+The entry scripts (``benchmarks/run.py``, ``chip_smoke.py``,
+``tools/probe_flash_tile.py``, ``examples/``, ``__graft_entry__.py``)
+call these two functions once,
 in the ONE process that will do the device work.  A chip belongs to one
 process at a time, so nothing here probes the backend from a child, and
 nothing falls back: a run that wants a TPU and finds none stops with
@@ -61,6 +62,6 @@ def bring_up(request: Optional[str] = None) -> str:
     if request != "cpu" and platform != "tpu":
         raise SystemExit(
             f"this run needs a TPU and JAX found only {platform!r}; a CPU "
-            "run has to be asked for by name (DDL_BENCH_PLATFORM=cpu)"
+            "run has to be asked for by name"
         )
     return platform

@@ -16,8 +16,9 @@ Guarantees:
 - **Never slower**: the naive (rank-order round-robin) assignment is
   always a candidate — when the greedy reorder does not beat it under
   the cost model, the naive assignment is returned with
-  ``reordered=False``.  The bench's measured ratio therefore has a
-  floor of ~1.0 by construction, and bench_smoke gates on it.
+  ``reordered=False`` (``tests/test_cluster.py``:
+  ``test_never_slower_fallback_on_uniform_fabric``).  "Slower" is the
+  cost model's: no placement has been measured on the chip.
 - **Deterministic**: ties break on sorted host ids, so every process
   planning from the same (view, costs) pair gets the same assignment —
   the same no-coordination property the membership layer has.
@@ -235,11 +236,10 @@ def placement_report(
     payload_bytes: int = 1 << 20,
     reps: int = 3,
 ) -> dict:
-    """The bench's ``placement`` block body: plan, measure both
-    assignments over ``transfer`` (default: the simulated fabric priced
-    by ``costs``), report modeled + measured rates and the ratio.  The
-    winner is never the slower measured assignment (the headline
-    invariant bench_smoke enforces)."""
+    """Plan, measure both assignments over ``transfer`` (default: the
+    simulated fabric priced by ``costs``), report modeled + measured
+    rates and the ratio.  The winner is never the slower measured
+    assignment."""
     plan = plan_placement(view, costs)
     naive = naive_placement(view)
     fabric = transfer or SimulatedFabric(costs)

@@ -51,9 +51,10 @@ Chaos coverage rides the ``cluster.supervise`` site inside
 :meth:`SupervisorHA.step`: ``SUPERVISOR_CRASH`` kills the leader
 mid-stream (lease lapses, standby promotes), ``NETWORK_PARTITION``
 suppresses lease renewal without killing the leader — the split-brain
-producer.  ``DDL_BENCH_MODE=failover`` A/Bs a mid-stream kill against
-an uninterrupted run (byte-identical streams, zero watchdog failures,
-fairness preserved); promotions and crashes are flight-recorded.
+producer.  ``tests/test_supervision.py`` holds a mid-stream kill to an
+uninterrupted run (``TestFailoverE2E``: byte-identical streams, zero
+watchdog failures; ``TestSchedulerFailover``: fairness preserved);
+promotions and crashes are flight-recorded.
 """
 
 from __future__ import annotations

@@ -66,7 +66,7 @@ class LoaderConfig:
     # env-only (DDL_TPU_CACHE_CODEC) with no config mirror — the stale
     # spawn-boundary drift ddl-verify VP003 now machine-checks.
     cache_codec: str = ""
-    # Wire format (ddl_tpu.wire; docs/PERF_NOTES.md "Wire format").
+    # Wire format (ddl_tpu.wire).
     # ``wire_dtype``: "" = no opinion (the per-reader capability
     # decides), "raw" = kill switch, "bf16"/"int8" = force the lossy
     # tier (A/B runs; licensed by the loss-parity gate).  ``wire_codec``:
@@ -77,8 +77,7 @@ class LoaderConfig:
     # (ddl_tpu.env._export_wire_knobs).
     wire_dtype: str = ""
     wire_codec: str = ""
-    # Device-tier global shuffle (ddl_tpu.ops.device_shuffle;
-    # docs/PERF_NOTES.md "Device-side global shuffle").
+    # Device-tier global shuffle (ddl_tpu.ops.device_shuffle).
     # ``device_shuffle``: "auto" = engage the device exchange when
     # plannable (THREAD topology, raw wire, in-process fabric),
     # "0"/"off"/"false" = host exchange only.  ``shuffle_impl``:

@@ -74,7 +74,7 @@ class ProducerFunctionSkeleton(abc.ABC):
     then fills in place by default but silently keeps the private-array
     fill when a cross-instance global shuffle needs ``my_ary`` to
     persist, or when ``DDL_TPU_INPLACE=0`` opts out.  Every built-in
-    reader advertises it (write-once producers, docs/PERF_NOTES.md).
+    reader advertises it (write-once producers).
     """
 
     inplace_fill: bool = False
@@ -86,8 +86,8 @@ class ProducerFunctionSkeleton(abc.ABC):
     #: blockwise-encoded wire payload instead (scales in the integrity
     #: trailer extension, decoded at the consumer edge) — valid only
     #: for float windows, and a LOSSY statement: set it on readers
-    #: whose data tolerates the quantization (the loss-parity gate is
-    #: the license — docs/PERF_NOTES.md "Wire format").  The
+    #: whose data tolerates the quantization (the loss-parity gate,
+    #: ``ddl_tpu.parallel.optimizer.loss_parity``, is the license).  The
     #: ``DDL_TPU_WIRE_DTYPE`` env overrides either way.
     wire_dtype: str = "raw"
 

@@ -67,7 +67,7 @@ class Knob:
     config_field: Optional[str] = None
     #: TrainConfig field this knob mirrors (DDL_TPU_TRAIN_<FIELD>).
     train_field: Optional[str] = None
-    #: Read outside ddl_tpu/ (bench/test harness knobs) or only through
+    #: Read outside ddl_tpu/ (the test harness's knob) or only through
     #: a computed name (the config families): VP003 skips its
     #: "registered but never read" hygiene check.
     external: bool = False
@@ -78,7 +78,7 @@ def _K(name: str, type: str, default: Any, doc: str, **kw: Any) -> Knob:
 
 
 #: Explicit entries for every knob read by name in ``ddl_tpu/`` (plus
-#: the documented harness knobs).  The config/train families are merged
+#: the documented test-harness knob).  The config/train families are merged
 #: in below from the dataclasses themselves, so a new config field can
 #: never ship unregistered.
 _EXPLICIT: List[Knob] = [
@@ -264,9 +264,9 @@ _EXPLICIT: List[Knob] = [
        "Flight-record dump directory (default /tmp/ddl_tpu_flight)."),
     _K("DDL_TPU_OBS_SHIP_EVERY", "int", 32,
        "Windows between periodic worker ObsReports (0 = disabled)."),
-    # -- harness knobs (read by bench/tests, documented here) -----------
+    # -- harness knob (read by tests/conftest.py, documented here) ------
     _K("DDL_TPU_ONCHIP", "bool", False,
-       "Enable @onchip tests / chip bench legs (needs a real TPU).",
+       "Enable @onchip tests (needs a real TPU).",
        external=True),
 ]
 

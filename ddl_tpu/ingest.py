@@ -116,9 +116,7 @@ class DeviceIngestor:
         CPU every buffer is alias-dropped after its first transfer: an
         ALL-MISS pool whose per-transfer pointer walk, sweep, and gauge
         bookkeeping are pure ceremony on top of the same fresh
-        allocation the inline path does plainly (measured on the 2-core
-        box: inline no-prefetch 83.2k vs staged 74.5k samples/s —
-        docs/PERF_NOTES.md "Write-once producers").  Accelerator puts
+        allocation the inline path does plainly.  Accelerator puts
         genuinely copy, the pool recycles, and the executor buys
         overlap.  ``staged=True`` passed explicitly forces the engine
         everywhere (tests, A/B measurement).
@@ -270,9 +268,8 @@ class DeviceIngestor:
 
         One copy + one transfer instead of one of each per column: narrow
         columns (a label column is ~KiB) otherwise pay the link's fixed
-        per-transfer cost for a few bytes (measured 0.15 ms per 8 KiB put
-        — tools/probe_ingest.py).  The device-side column slices are
-        sub-microsecond XLA ops.
+        per-transfer cost for a few bytes.  The device-side column slices
+        are sub-microsecond XLA ops.
         """
         with stage("ddl.ingest_put"):
             if self.batch_staged:
@@ -342,7 +339,7 @@ class DeviceIngestor:
         (``jax.block_until_ready``) — that is what
         ``DistributedDataLoader.windows`` does.  One large transfer per
         window beats per-batch/per-column puts wherever the link has fixed
-        per-transfer cost (tools/probe_ingest.py measures it).
+        per-transfer cost.
 
         ``defer_metrics=True`` skips the ``ingest.bytes``/``ingest.windows``
         accounting here so the caller can record it when the transfer

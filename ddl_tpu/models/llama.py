@@ -93,8 +93,8 @@ class LlamaConfig:
         residents (params + grads + adam moments) bust the per-chip HBM
         with the optimizer state replicated over dp and fit with ~6 GiB
         of activation headroom under ``optimizer_sharding="zero1"`` —
-        the accounting test (tests/test_optimizer.py) and
-        ``tools/probe_opt.py`` both price exactly this config."""
+        the accounting test (tests/test_optimizer.py) prices exactly
+        this config."""
         return LlamaConfig(
             vocab=32768, d_model=4096, n_layers=20, n_heads=32,
             n_kv_heads=8, d_ff=14336, max_seq=4096,
@@ -169,8 +169,8 @@ def param_shapes(cfg: LlamaConfig) -> Params:
     """Abstract (ShapeDtypeStruct) params pytree via ``eval_shape`` —
     the zero-FLOP input for optimizer HBM accounting
     (:func:`ddl_tpu.parallel.optimizer.hbm_accounting`, the
-    fits-only-with-zero1 test, ``tools/probe_opt.py``): a 4B-param
-    layout prices without materialising a single weight."""
+    fits-only-with-zero1 test): a 4B-param layout prices without
+    materialising a single weight."""
     return jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
 
 

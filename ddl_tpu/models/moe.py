@@ -192,9 +192,9 @@ def init_params(cfg: MoeConfig, key: jax.Array) -> Params:
 
 def param_shapes(cfg: MoeConfig) -> Params:
     """Abstract params pytree via ``eval_shape`` — the optimizer HBM
-    accounting input (``parallel.optimizer.hbm_accounting``,
-    ``tools/probe_opt.py``); a Mixtral-scale layout prices without
-    materialising the expert stacks."""
+    accounting input (``parallel.optimizer.hbm_accounting``); a
+    Mixtral-scale layout prices without materialising the expert
+    stacks."""
     return jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
 
 

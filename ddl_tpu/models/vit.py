@@ -94,8 +94,7 @@ def init_params(cfg: ViTConfig, key: jax.Array) -> Params:
 
 def param_shapes(cfg: ViTConfig) -> Params:
     """Abstract params pytree via ``eval_shape`` — the optimizer HBM
-    accounting input (``parallel.optimizer.hbm_accounting``,
-    ``tools/probe_opt.py``)."""
+    accounting input (``parallel.optimizer.hbm_accounting``)."""
     return jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
 
 

@@ -26,8 +26,9 @@ spots this layer closes):
 
 Reference: docs/OBSERVABILITY.md (name families, span model, bucket
 layout, aggregation topology, flight-record format, a Perfetto
-walkthrough).  Overhead is priced by ``DDL_BENCH_MODE=obs`` (armed vs
-disarmed, <= 2%, byte-identical — tools/bench_smoke.py enforces).
+walkthrough).  An armed stream is byte-identical to a disarmed one
+(``tests/test_obs.py::TestThreadE2E::test_arming_never_changes_bytes``);
+the armed side's overhead is not measured on the chip.
 """
 
 from __future__ import annotations

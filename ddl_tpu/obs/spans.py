@@ -15,9 +15,10 @@ Design constraints (the ``faults.armed()`` pattern, deliberately):
 
 - **Zero cost disarmed.**  Every emission site reads ONE module
   attribute and returns.  :func:`t0` returns 0.0 without touching the
-  clock when no log is armed; :func:`record` is a no-op.  The
-  ``DDL_BENCH_MODE=obs`` armed-vs-disarmed A/B prices the armed side
-  (<= 2% — tools/bench_smoke.py) and byte identity is asserted.
+  clock when no log is armed; :func:`record` is a no-op
+  (``tests/test_obs.py``: ``test_disarmed_is_a_noop``; arming never
+  changes the served bytes: ``test_arming_never_changes_bytes``).  What
+  the armed side costs is not measured on the chip.
 - **Bounded.**  The event buffer is a ``deque(maxlen=...)`` — a
   forgotten armed log on a week-long run drops oldest events instead
   of eating the host (ddl-lint DDL023 flags unbounded obs buffers).

@@ -13,8 +13,7 @@ The full well-known name-family reference (every ``consumer.*`` /
 lives in **docs/OBSERVABILITY.md** — kept out of this docstring so the
 table can be machine-checked: ``tests/test_obs.py`` asserts every
 documented name has at least one emitting site in the tree, so a new
-subsystem cannot document names it never emits.  The bench JSON
-contract in ``tools/bench_smoke.py`` pins the load-bearing ones.
+subsystem cannot document names it never emits.
 
 Beyond counters/gauges/timers, :meth:`Metrics.observe` records values
 into fixed log-spaced bounded histograms (:data:`HIST_BUCKETS_PER_DECADE`

@@ -579,9 +579,9 @@ def loss_parity(
     Compares two per-step loss sequences (same init, same batches) and
     returns ``{"parity": bool, "max_rel_drift": float, "rel_tol": ...}``
     — parity holds when every step's relative drift stays under
-    ``rel_tol``.  The bench ``opt`` block embeds this verbatim and
-    bench_smoke asserts ``parity`` is true; tests pin the fp32 zero1
-    path to max_rel_drift == 0.0 (bit-exact).
+    ``rel_tol``.  ``tests/test_optimizer.py::TestZero1Parity`` pins the
+    fp32 zero1 path to max_rel_drift == 0.0 (bit-exact) and holds the
+    int8 path inside this gate with non-zero drift.
     """
     ref = np.asarray(ref_losses, dtype=np.float64)
     test = np.asarray(test_losses, dtype=np.float64)

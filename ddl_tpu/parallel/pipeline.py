@@ -90,10 +90,10 @@ def bubble_fraction(
     n_chunks: "int | None" = None,
 ) -> float:
     """Analytic fill/drain idle fraction of a schedule — the ideal
-    against which measured pipeline efficiency is judged
-    (``tools/probe_pp.py`` measures the actual ratio; the ``lax.cond``
-    in the tick body makes bubble ticks cost a branch instead of a
-    layer, so measured should approach this floor from above).
+    against which measured pipeline efficiency is judged (the
+    ``lax.cond`` in the tick body makes bubble ticks cost a branch
+    instead of a layer, so measured should approach this floor from
+    above; the actual ratio is not measured on the chip).
 
     gpipe: ``(S-1)/(M+S-1)`` — of the ``S+M-1`` ticks each device
     sees, ``S-1`` are ramp.  1f1b (interleaved, ``v = n_chunks``): the

@@ -30,8 +30,7 @@ admission authority into the supervisor tier:
   snapshot; registry mutations snapshot the registry.  A promoted
   standby rebuilds via :meth:`IngestFabric.from_journal` and continues
   granting in an order bit-identical to what the dead leader would
-  have produced (the property ``tests/test_fabric.py`` pins and the
-  ``DDL_BENCH_MODE=fabric`` supervisor-kill leg measures).
+  have produced (the property ``tests/test_fabric.py`` pins).
 
 Transport: this PR ships the **loopback** channel — clients call the
 fabric in-process (same-host supervisor, or tests/bench), with the full

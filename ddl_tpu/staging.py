@@ -21,8 +21,8 @@ supposed to feed.  This module removes both:
   ``ready`` (the device value is available to pop).
 
 ``DDL_TPU_STAGED=0`` disables the whole engine — every consumer falls
-back to the previous inline copy path (the escape hatch for debugging
-and A/B measurement; ``bench.py`` reports both sides).
+back to the previous inline copy path (the escape hatch for
+debugging).
 
 Safety note: recycling a staging buffer is only sound when ``device_put``
 *copies* its host source.  The CPU PJRT client aliases a compatible host

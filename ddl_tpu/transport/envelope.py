@@ -23,8 +23,9 @@ Chaos coverage rides the ``transport.control_send`` fault site inside
 :meth:`ControlSender._wire`: ``CONTROL_MSG_DROP``/``NETWORK_PARTITION``
 lose the wire attempt (the send stays pending; backoff retry absorbs
 it), ``CONTROL_MSG_DUP`` sends the same envelope twice (the receiver's
-dedup absorbs it).  Both legs are asserted with counters by the
-``DDL_BENCH_MODE=failover`` chaos leg and ``tests/test_supervision.py``.
+dedup absorbs it).  Both legs are asserted with counters by
+``tests/test_supervision.py`` (``TestEnvelopeSeam``,
+``TestEnvelopeChaosE2E``).
 
 Threading: :class:`ControlSender` is intentionally lock-free —
 :class:`~ddl_tpu.transport.connection.ConsumerConnection` serializes

@@ -12,7 +12,7 @@ arXiv:2112.01075 prices redistribution legs before choosing them — this
 package does the same for the ingest plane's own knobs, automatically:
 
 - :class:`~ddl_tpu.tune.calibrate.Calibrator` — boot-time: runs the
-  probe_wire break-even table against *measured* link speeds (the
+  wire break-even table against *measured* link speeds (the
   pluggable ``probe_link_costs``) plus a distribution microbenchmark,
   and emits a :class:`~ddl_tpu.tune.calibrate.TunedConfig` overlay onto
   ``LoaderConfig``/envspec.  Every decision carries ``cost_source``
@@ -34,9 +34,9 @@ Audit trail: ``tune.decisions`` / ``tune.reverts`` /
 ``tune.cost_source.*`` counters surface in ``north_star_report`` as
 ``tune_decisions`` / ``tune_reverts`` / ``tune_cost_source``, and each
 decision lands in the flight-recorder ring (docs/TUNING.md walks a
-post-mortem).  ``DDL_BENCH_MODE=autotune`` is the proof: self-tuned vs
-shipped-defaults from a deliberately mis-matched cold start, gated
-never-slower by bench_smoke.
+post-mortem).  ``tests/test_tune.py`` holds the decisions, the revert
+rule and byte identity of a tuned stream; what tuning is worth is not
+measured on the chip (no benchmark cell runs it).
 """
 
 from ddl_tpu.tune.calibrate import (  # noqa: F401

@@ -1,8 +1,7 @@
 """Boot-time calibration: measured costs in, a tuned overlay out.
 
-The :class:`Calibrator` runs the same economics the operator-facing
-probes print — ``probe_wire``'s break-even table
-(:func:`ddl_tpu.wire.break_even_table`, one shared implementation) and
+The :class:`Calibrator` runs the wire break-even table
+(:func:`ddl_tpu.wire.break_even_table`, the one implementation) and
 ``probe_link_costs``'s pairwise bandwidth measurement (pluggable
 ``transfer``, exactly as the placement engine consumes it) — and turns
 them into a :class:`TunedConfig`: an overlay of ``LoaderConfig`` fields
@@ -159,7 +158,7 @@ class Calibrator:
     ``sample`` overrides the wire microbenchmark's input (e.g. a real
     shard slice); ``distribute_probe`` is a zero-arg callable returning
     ``{"ici": bytes_per_s, "xla": bytes_per_s}`` measured on the actual
-    mesh (``tools/probe_ici.py``-style) — absent, the distribution knob
+    mesh — absent, the distribution knob
     stays at its shipped default.
     """
 

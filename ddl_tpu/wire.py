@@ -114,7 +114,7 @@ def resolve_wire_codec(requested: Optional[str] = None) -> Optional[str]:
 def lossy_supported(dtype: Any) -> bool:
     """The lossy tier only makes sense on float windows: quantizing an
     int8 token stream would corrupt ids for zero wire win (use the
-    lossless codec tier there — docs/PERF_NOTES.md)."""
+    lossless codec tier there)."""
     return np.dtype(dtype).kind == "f"
 
 
@@ -524,9 +524,7 @@ def wire_report(metrics: Any) -> Dict[str, float]:
 # raw leg, and 1/enc + ratio/L + 1/dec on an encoded leg.  The encoded
 # leg wins exactly when L < (1 - ratio) / (1/enc + 1/dec) — a 4x ratio
 # is worthless behind a codec slower than the link.  One implementation,
-# shared by the probe_wire CLI and the boot-time Calibrator
-# (``ddl_tpu.tune``), so the operator-facing table and the controller's
-# decisions can never disagree.
+# the one the boot-time Calibrator (``ddl_tpu.tune``) decides from.
 
 
 def measure_wire_stats(
@@ -536,7 +534,7 @@ def measure_wire_stats(
     level: int = 1,
     deadline: Optional[float] = None,
 ) -> Dict[str, Dict[str, float]]:
-    """Microbenchmark each wire format on ``sample``, probe_wire-shaped.
+    """Microbenchmark each wire format on ``sample``.
 
     Returns ``{fmt: {"ratio", "encode_bytes_per_s", "decode_bytes_per_s"}}``
     (lossy entries add ``max_rel_drift``) — the stats dict
@@ -608,9 +606,9 @@ def break_even_table(
 
     ``stats`` maps format name → a dict carrying at least ``ratio``,
     ``encode_bytes_per_s``, ``decode_bytes_per_s`` (non-dict or
-    ratio-free entries are skipped, so a probe_wire shard entry passes
-    through unfiltered).  A format appears only when it can win at all
-    (``ratio < 1.0``); its value is the link speed below which paying
+    ratio-free entries are skipped, so a caller's own annotation such
+    as a shard label passes through unfiltered).  A format appears only
+    when it can win at all (``ratio < 1.0``); its value is the link speed below which paying
     the encode+decode CPU beats moving raw bytes.  When
     ``link_bytes_per_s`` is given, formats whose threshold the measured
     link already exceeds are dropped — what remains is exactly the set

@@ -5,9 +5,7 @@ this example shows the inference side of the rebuilt stack: a tiny
 llama is fitted on a repeating token pattern, then ``generate`` serves
 batched completions three ways — greedy, temperature sampling, and
 nucleus (top-p) sampling with a top-k cap — all through the in-place
-stacked KV cache (prefill in one cached forward, scanned decode steps;
-chip-measured 0.85 model-bandwidth utilization at B=8, bench.py
-``DDL_BENCH_MODE=decode``).
+stacked KV cache (prefill in one cached forward, scanned decode steps).
 
 Run:
 

@@ -123,9 +123,12 @@ def drain_cluster(
     collective=False,
     spill_dir=None,
     pace_s=0.0,
+    tenant=None,
 ):
     """Run the 2-mock-host THREAD pipeline under ``plan``; returns
-    (windows-by-shard, metrics, supervisor).  ``kill_host_after_epoch``
+    (windows-by-shard, metrics, supervisor).  ``tenant`` (a serve-tier
+    handle) is bound to the loader, so every window is admitted through
+    its scheduler.  ``kill_host_after_epoch``
     hard-kills mock host 1 at that epoch boundary; ``collective`` runs
     a jitted psum over the 8-device CPU mesh after every window and
     asserts it — "the collectives continue" through recovery.
@@ -146,6 +149,8 @@ def drain_cluster(
             n_epochs=n_epochs, output="numpy", timeout_s=60.0,
             metrics=m, cluster=elastic,
         )
+        if tenant is not None:
+            tenant.bind(loader)
         wd = Watchdog(
             env.workers, poll_interval_s=0.05, stall_budget_s=60.0,
             respawn=True, metrics=m, cluster=sup,

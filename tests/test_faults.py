@@ -468,24 +468,46 @@ class TestFaultMatrix:
             main()
         assert time.monotonic() - t0 < 30.0
 
-    def test_host_loss_repartitions_byte_identical(self):
+    @pytest.mark.parametrize("tenant_burst", [False, True])
+    def test_host_loss_repartitions_byte_identical(self, tenant_burst):
         """HOST_LOSS at cluster.heartbeat (ISSUE 10): the injected loss
         of a whole mock host drives the epoch-fenced view change — pool
         shrink, shard adoption — and the stream recovers byte-identical
         full-shard coverage (the runner lives in tests/test_cluster.py;
-        the matrix row wires it into the tier-1 chaos sweep)."""
+        the matrix row wires it into the tier-1 chaos sweep).  With
+        ``tenant_burst`` the loader is a serve-tier tenant and a
+        TENANT_BURST lands on its admissions in the same run: both
+        faults fire and the tenant's stream stays byte-correct."""
         from test_cluster import (
             assert_full_coverage_byte_identical,
             drain_cluster,
         )
 
-        plan = FaultPlan(
+        from ddl_tpu.serve import AdmissionController, TenantSpec
+
+        specs = [
             # at=8: past bootstrap sweeps, mid-stream (50 ms cadence).
-            [FaultSpec("cluster.heartbeat", FaultKind.HOST_LOSS,
-                       at=8, producer_idx=1)]
+            FaultSpec("cluster.heartbeat", FaultKind.HOST_LOSS,
+                      at=8, producer_idx=1)
+        ]
+        m, tenant = Metrics(), None
+        if tenant_burst:
+            tenant = AdmissionController(metrics=m).register(
+                TenantSpec("burst-me")
+            )
+            specs.append(
+                FaultSpec("serve.admit", FaultKind.TENANT_BURST,
+                          at=4, producer_idx=0, param=float(16 << 20))
+            )
+        plan = FaultPlan(specs)
+        seen, m, sup = drain_cluster(
+            plan=plan, n_epochs=24, pace_s=0.05, metrics=m, tenant=tenant,
         )
-        seen, m, sup = drain_cluster(plan=plan, n_epochs=24, pace_s=0.05)
-        assert plan.fired, "HOST_LOSS spec never fired"
+        fired = {kind for _, kind, *_ in plan.fired}
+        assert "host_loss" in fired, "HOST_LOSS spec never fired"
+        if tenant_burst:
+            assert "tenant_burst" in fired
+            assert m.counter("serve.tenant_bursts") == 1.0
         assert m.counter("cluster.host_losses") == 1.0
         assert m.counter("cluster.view_changes") == 1.0
         assert m.counter("watchdog.failures") == 0.0

@@ -637,7 +637,6 @@ class TestDocReflection:
         blobs = []
         for path in (REPO_ROOT / "ddl_tpu").rglob("*.py"):
             blobs.append(path.read_text())
-        blobs.append((REPO_ROOT / "bench.py").read_text())
         return "\n".join(blobs)
 
     def test_tables_were_parsed(self):

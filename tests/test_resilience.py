@@ -604,6 +604,20 @@ class TestDrainOnNotice:
             tmp_path, seed, res_b, crcs_b, drained_at
         )
 
+    def test_no_notice_loses_at_most_one_interval(self, tmp_path):
+        """The hard-kill bound: a run that ends with no drain resumes
+        from its newest durable generation, at most one checkpoint
+        interval back, and replays from there byte-identically."""
+        seed, died_at, every = 4321, 5, 2
+        res_a, crcs_a = self._uninterrupted(tmp_path, seed)
+        _run_fit(tmp_path, seed, died_at, every=every)  # no notice, no drain
+        res_c, crcs_c = _run_fit(tmp_path, seed, self.N, every=every)
+        resumed = res_c.resumed_from_epoch
+        assert 0 <= died_at - resumed <= every
+        assert crcs_c == crcs_a[resumed:]
+        assert res_c.losses == res_a.losses[resumed:]
+        assert res_c.state.step == res_a.state.step
+
     def test_sigterm_mid_fit_thread_mode(self, tmp_path):
         seed, drained_at = 77, 3
         m_b = Metrics()

@@ -364,7 +364,9 @@ class TestSpanRejection:
         timeout; a retention fabric with nslots=1 is rejected too (the
         one-slot restore could read the predecessor's torn in-flight
         fill)."""
-        from ddl_tpu import DataProducerOnInitReturn, ProducerFunctionSkeleton
+        from ddl_tpu import (
+            DataProducerOnInitReturn, ProducerFunctionSkeleton, integrity,
+        )
         from ddl_tpu.datapusher import DataPusher
         from ddl_tpu.exceptions import DoesNotMatchError
         from ddl_tpu.shuffle import ShmRendezvous, make_session
@@ -409,7 +411,11 @@ class TestSpanRejection:
             return DataPusher(
                 ProducerConnection(prod_end, 1, cross_process=False),
                 topo, 1, nslots=nslots, shuffler_factory=factory,
-                rejoin_ring=ThreadRing(nslots, 16 * 2 * 4),
+                # The predecessor's ring: slots carry the integrity
+                # header's headroom (integrity is on by default).
+                rejoin_ring=ThreadRing(
+                    nslots, 16 * 2 * 4 + integrity.HEADER_BYTES
+                ),
             )
 
         with pytest.raises(DoesNotMatchError, match="supports_elastic_replay"):

@@ -138,7 +138,7 @@ def island_costs(intra=8e9, cross=1e9):
 
 
 # ---------------------------------------------------------------------------
-# Units: break-even economics (the Calibrator/probe_wire shared core)
+# Units: break-even economics (the Calibrator's core)
 # ---------------------------------------------------------------------------
 
 
@@ -156,7 +156,7 @@ class TestBreakEven:
             "ratio": 1.2, "encode_bytes_per_s": 1e9,
             "decode_bytes_per_s": 1e9,
         }
-        stats["shard"] = "0/256x1024"  # probe_wire passthrough entry
+        stats["shard"] = "0/256x1024"  # a caller's annotation: passes
         be = wire.break_even_table(stats)
         assert set(be) == {"int8", "bf16"}
 
