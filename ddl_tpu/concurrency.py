@@ -77,6 +77,7 @@ LOCK_ORDER: Tuple[str, ...] = (
     # leaf utilities: reachable from under ANY of the above (fault
     # points fire inside ring waits; metrics/span appends happen under
     # data-plane locks), so they must order innermost.
+    "integrity.pool",
     "faults.plan",
     "obs.metrics",
     "obs.spans",

@@ -1190,14 +1190,26 @@ class DistributedDataLoader:
     def _verify_slot(
         self, target: int, slot: int, expect_seq: int
     ) -> Optional[str]:
-        """Drain-time header check; None when the window is intact."""
+        """Drain-time header check; None when the window is intact.
+
+        ``consumer.verify`` times every check and
+        ``consumer.verify_parallel_windows`` counts the windows large
+        enough for the span-parallel CRC fold — ``consumer.*``, not
+        ``integrity.*``: those counters read zero on a healthy run, and
+        the benchmark holds runs to that."""
         ring = self.connection.rings[target]
-        return integrity.verify_window(
+        payload_bytes = ring.slot_payload(slot)
+        t0 = time.perf_counter()
+        err = integrity.verify_window(
             ring.slot_view(slot),
-            ring.slot_payload(slot),
+            payload_bytes,
             expect_seq=expect_seq,
             expect_producer=target + 1,
         )
+        self.metrics.add_time("consumer.verify", time.perf_counter() - t0)
+        if integrity.fold_spans(payload_bytes) > 1:
+            self.metrics.incr("consumer.verify_parallel_windows")
+        return err
 
     def _acquire_verified(self, target: int, ahead: int, timeout_s: float):
         """Acquire the next committed slot on ``target`` and verify its
@@ -1553,6 +1565,10 @@ class DistributedDataLoader:
             # jobs error with ShutdownRequested instead of racing teardown,
             # and completed staging buffers flush back to their pool.
             self._ingestor.close()
+        # No window is verified past this point: stop the span-parallel
+        # CRC fold's threads (a later loader in this process starts its
+        # own at its first large window).
+        integrity.close_fold_pool()
         self.connection.shutdown_operation()
         # Final observability drain: PROCESS workers ship a last
         # cumulative ObsReport on their way out — give stragglers a

@@ -22,10 +22,16 @@ This module closes that gap:
   ``DistributedDataLoader._quarantine_and_replay`` and
   docs/ROBUSTNESS.md for the degradation ladder.
 
-CRC is ``zlib.crc32`` (C speed, ~fractions of a ms per MiB window —
-measured noise next to the slot memcpy it guards).  ``DDL_TPU_INTEGRITY=0``
-disables the whole layer: slots shrink back, commits and drains skip the
-checksum, and the loader serves exactly the PR 2 byte path.
+CRC is ``zlib.crc32``: ~0.27 ms per MB on one core of the chip's host
+(3.7 GB/s; PERF.md §6, PR 29).  That is noise for a KiB-sized token
+window and NOT for a 308 MB image window — on the alias path there is no
+slot memcpy to hide it behind, and one serial CRC was two thirds of the
+train loop's period.  So the drain-time check folds a large payload over
+contiguous spans on a few threads (:func:`crc32_spans`): the same
+polynomial over the same bytes, combined into the bit-identical 32-bit
+value the producer committed.  ``DDL_TPU_INTEGRITY=0`` disables the whole
+layer: slots shrink back, commits and drains skip the checksum, and the
+loader serves exactly the PR 2 byte path.
 
 Header layout (little-endian, 32 of 32 reserved bytes used)::
 
@@ -49,11 +55,19 @@ unchanged.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import os
+import queue
 import struct
+import threading
+import time
 import zlib
-from typing import Optional
+from concurrent.futures import Future
+from typing import List, Optional
 
 import numpy as np
+
+from ddl_tpu.concurrency import named_lock
 
 #: Trailer size reserved past the payload in every ring slot.
 HEADER_BYTES = 32
@@ -78,7 +92,7 @@ def window_crc(payload: np.ndarray) -> int:
 
 
 def wire_crc(slot_view: np.ndarray, payload_bytes: int,
-             scale_bytes: int) -> int:
+             scale_bytes: int, n_spans: int = 1) -> int:
     """The committed CRC of a (possibly wire-encoded) slot: the payload
     fold continued over the trailer-extension scales.
 
@@ -86,9 +100,12 @@ def wire_crc(slot_view: np.ndarray, payload_bytes: int,
     producer's encoded commit and :func:`verify_window`'s drain check
     call this one function, so the fold order / region layout cannot
     desynchronize between them.  ``scale_bytes == 0`` degrades to the
-    plain :func:`window_crc`.
+    plain :func:`window_crc`.  ``n_spans > 1`` (the drain check of a
+    large window, :func:`fold_spans`) computes the payload's CRC over
+    that many spans at once (:func:`crc32_spans`): the same value.
     """
-    crc = window_crc(slot_view[:payload_bytes])
+    payload = slot_view[:payload_bytes]
+    crc = window_crc(payload) if n_spans < 2 else crc32_spans(payload, n_spans)
     if scale_bytes:
         start = payload_bytes + HEADER_BYTES
         crc = zlib.crc32(
@@ -97,6 +114,159 @@ def wire_crc(slot_view: np.ndarray, payload_bytes: int,
             ),
             crc,
         ) & 0xFFFFFFFF
+    return crc
+
+
+# -- span-parallel fold of the drain-time CRC --------------------------------
+
+#: A span is worth a thread from about this many bytes, and more than
+#: ``MAX_SPANS`` of them buy little and not reliably.  Both placed by
+#: ``tools/probe_crc_fold.py`` on the chip's host (PERF.md §6, PR 29):
+#: 8 MB folds in 2.2 ms over 1 span, 1.4 over 2 and 0.9 over 4, and
+#: spans under 2 MB stop paying for their hand-over; 308 MB folds in
+#: 83 ms over 1 span, 11.5 over 8 and 8-11 over 12-16 on 13 cores.
+SPAN_MIN_BYTES = 4 << 20
+MAX_SPANS = 8
+
+#: Bound on the wait for one span's CRC (tens of ms of C code): a fold
+#: that cannot finish raises into the caller instead of parking it.
+_FOLD_TIMEOUT_S = 60.0
+
+_CRC_POLY = 0xEDB88320  # CRC-32, reflected: bit 31 is x^0
+
+
+def _usable_cores() -> int:
+    return len(os.sched_getaffinity(0))
+
+
+def fold_spans(payload_bytes: int) -> int:
+    """How many spans the drain-time CRC of ``payload_bytes`` folds
+    over — from what the code observes, no knob: spans of at least
+    ``SPAN_MIN_BYTES``, at most ``MAX_SPANS``, at most half the cores
+    this process may run on.  Below 2 the check is one ``zlib.crc32``
+    call on the caller's thread and no pool exists."""
+    return max(
+        1,
+        min(payload_bytes // SPAN_MIN_BYTES, MAX_SPANS, _usable_cores() // 2),
+    )
+
+
+def _mulmod(a: int, b: int) -> int:
+    """``a(x) * b(x) mod P`` over GF(2), reflected bit order."""
+    out = 0
+    bit = 1 << 31
+    while a:
+        if a & bit:
+            out ^= b
+            a ^= bit
+        bit >>= 1
+        b = (b >> 1) ^ _CRC_POLY if b & 1 else b >> 1
+    return out
+
+
+@functools.lru_cache(maxsize=32)
+def _append_zeros_operator(nbytes: int) -> int:
+    """The GF(2) operator "append ``nbytes`` zero bytes" on a CRC-32
+    register, as the polynomial ``x^(8 * nbytes) mod P`` — computed
+    once per span length (windows have one size, so a fold needs two:
+    the full span's and the last span's)."""
+    op = 1 << 31  # x^0
+    power = 1 << 23  # x^8: one zero byte
+    while nbytes:
+        if nbytes & 1:
+            op = _mulmod(power, op)
+        power = _mulmod(power, power)
+        nbytes >>= 1
+    return op
+
+
+def crc32_combine(crc_a: int, crc_b: int, len_b: int) -> int:
+    """``zlib.crc32(a + b)`` from ``zlib.crc32(a)``, ``zlib.crc32(b)``
+    and ``len(b)`` — zlib's ``crc32_combine``, which the stdlib does
+    not expose."""
+    return _mulmod(_append_zeros_operator(len_b), crc_a) ^ crc_b
+
+
+class _SpanPool:
+    """The few daemon threads that CRC spans for :func:`crc32_spans`.
+
+    Started by the first fold that wants them, in the process that
+    drains (a producer commits serially and never gets here), grown to
+    the widest fold seen, stopped by :meth:`close` and started again by
+    the next fold.  One lock covers "start threads + hand over the
+    spans", so a ``close`` racing a fold either sees its spans queued
+    (the threads finish them before they exit) or comes first (the
+    fold starts fresh threads)."""
+
+    def __init__(self) -> None:
+        self._lock = named_lock("integrity.pool")
+        self._jobs: "queue.SimpleQueue" = queue.SimpleQueue()
+        self._threads: List[threading.Thread] = []
+
+    def submit(self, spans: List[np.ndarray]) -> List["Future[int]"]:
+        futures: List["Future[int]"] = [Future() for _ in spans]
+        with self._lock:
+            while len(self._threads) < len(spans):
+                t = threading.Thread(
+                    target=self._run, args=(self._jobs,),
+                    name=f"ddl-verify-{len(self._threads)}", daemon=True,
+                )
+                t.start()
+                self._threads.append(t)
+            for job in zip(futures, spans):
+                self._jobs.put(job)
+        return futures
+
+    @staticmethod
+    def _run(jobs: "queue.SimpleQueue") -> None:
+        while True:
+            # A daemon parked on its own queue: close() posts one None
+            # a thread, and nothing joins it without a timeout.
+            job = jobs.get()  # ddl-lint: disable=DDL012
+            if job is None:
+                return
+            future, span = job
+            try:
+                future.set_result(zlib.crc32(span))
+            except Exception as e:  # ddl-lint: disable=DDL007
+                # Not swallowed: the waiting fold's result() raises it.
+                future.set_exception(e)
+
+    def close(self, timeout_s: float = 5.0) -> None:
+        with self._lock:
+            # The stopping threads keep their queue, so the Nones reach
+            # them and not the threads a later fold starts.
+            threads, self._threads = self._threads, []
+            jobs, self._jobs = self._jobs, queue.SimpleQueue()
+        for _ in threads:
+            jobs.put(None)
+        deadline = time.monotonic() + timeout_s
+        for t in threads:
+            t.join(max(0.0, deadline - time.monotonic()))
+
+
+_POOL = _SpanPool()
+
+
+def close_fold_pool() -> None:
+    """Stop the fold's threads (``loader.shutdown()``).  A later fold,
+    from another loader in this process, starts its own."""
+    _POOL.close()
+
+
+def crc32_spans(payload: np.ndarray, n_spans: int) -> int:
+    """``zlib.crc32`` of ``payload``, bit for bit, computed over
+    ``n_spans`` contiguous spans at once (``zlib.crc32`` drops the GIL)
+    and combined.  The caller's thread takes the first span itself."""
+    flat = np.ascontiguousarray(payload).reshape(-1).view(np.uint8)
+    span = max(1, -(-flat.nbytes // n_spans))
+    rest = [flat[o : o + span] for o in range(span, flat.nbytes, span)]
+    futures = _POOL.submit(rest)
+    crc = zlib.crc32(flat[:span])
+    for part, future in zip(rest, futures):
+        crc = crc32_combine(
+            crc, future.result(timeout=_FOLD_TIMEOUT_S), part.nbytes
+        )
     return crc
 
 
@@ -209,7 +379,7 @@ def verify_window(
     Ordered cheap-to-expensive: magic (a producer that never stamped a
     header — torn commit or version skew), identity and sequencing (a
     dropped/duplicated/foreign window), then the payload CRC (flipped
-    bytes).
+    bytes) — every byte of it, folded over :func:`fold_spans` spans.
     """
     hdr = read_header(slot_view, payload_bytes)
     if not hdr.valid_magic:
@@ -225,7 +395,9 @@ def verify_window(
     # wire-encoded) payload, then the trailer-extension scales — so
     # corruption detection survives the dtype change (a flipped int8
     # wire byte or scale byte mismatches exactly like a raw one).
-    got = wire_crc(slot_view, payload_bytes, hdr.scale_bytes)
+    got = wire_crc(
+        slot_view, payload_bytes, hdr.scale_bytes, fold_spans(payload_bytes)
+    )
     if got != hdr.crc:
         return (
             f"payload crc32 0x{got:08x} != committed 0x{hdr.crc:08x} "

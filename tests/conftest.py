@@ -46,6 +46,30 @@ def rng():
     return np.random.default_rng(0)
 
 
+@pytest.fixture(params=["serial", "parallel"])
+def crc_fold(request, monkeypatch):
+    """Run a corruption test twice: as it stands (its KiB windows take
+    the drain-time verify's one serial ``zlib.crc32``), and with the
+    span floor lowered so the span-parallel fold finds the corrupt
+    window, whatever machine runs the test.  Returns a check of a
+    loader's ``Metrics``: every drain verify took that path."""
+    from ddl_tpu import integrity
+
+    parallel = request.param == "parallel"
+    if parallel:
+        monkeypatch.setattr(integrity, "SPAN_MIN_BYTES", 32)
+        monkeypatch.setattr(integrity, "_usable_cores", lambda: 16)
+
+    def check(m):
+        verifies = m.timer("consumer.verify").count
+        assert verifies > 0
+        assert m.counter("consumer.verify_parallel_windows") == (
+            verifies if parallel else 0
+        )
+
+    return check
+
+
 @pytest.fixture(scope="session")
 def eight_devices():
     assert len(jax.devices()) == 8, (

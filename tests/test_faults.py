@@ -109,10 +109,12 @@ def drain_numpy(plan, n_epochs=6, metrics=None, stall_budget_s=60.0,
     return windows, wd, m
 
 
-def drain_windows_jax(plan, n_epochs=5, metrics=None):
+def drain_windows_jax(plan, n_epochs=5, metrics=None, pace_s=0.0):
     """Run the staged ``windows()`` stream (engine forced on) under
     ``plan``; return served window arrays, the metrics, and the loader's
-    engine-faulted flag."""
+    engine-faulted flag.  ``pace_s`` holds each window that long, so
+    the producer runs ahead and the stream's lookahead finds a
+    committed window to probe."""
     m = metrics or Metrics()
 
     @distributed_dataloader(n_producers=1, mode="thread")
@@ -125,6 +127,7 @@ def drain_windows_jax(plan, n_epochs=5, metrics=None):
         windows = []
         for win in loader.windows():
             windows.append(np.asarray(win).reshape(SHAPE).copy())
+            time.sleep(pace_s)
             loader.mark(Marker.END_OF_EPOCH)
         engine = loader._ingestor._engine
         return windows, bool(engine is not None and engine.faulted)
@@ -187,11 +190,12 @@ class TestFaultMatrix:
         assert list(wd.respawns) == [1]
         assert list(wd.failures) == []
 
-    def test_ring_corruption_quarantined_and_replayed(self):
+    def test_ring_corruption_quarantined_and_replayed(self, crc_fold):
         """Flipped slot bytes after commit: drain-time CRC verification
         quarantines the window and the producer replays it — the served
         stream is byte-identical, with the corruption visible in
-        metrics, not in data."""
+        metrics, not in data.  Found by the serial CRC and by the
+        span-parallel fold alike (``crc_fold``)."""
         plan = FaultPlan(
             [FaultSpec("producer.commit", FaultKind.RING_CORRUPTION,
                        at=2, param=4)]
@@ -201,6 +205,38 @@ class TestFaultMatrix:
         assert m.counter("integrity.corrupt_windows") == 1
         assert m.counter("integrity.replays") == 1
         assert list(wd.failures) == []
+        crc_fold(m)
+
+    def test_corrupt_lookahead_window_waits_for_the_head(
+        self, crc_fold, monkeypatch
+    ):
+        """A corrupt window met while the ``windows()`` stream deepens
+        its lookahead is not quarantined out of FIFO order
+        (``_CorruptAhead``): it is counted once and replayed when a
+        blocking acquire reaches it at the head, before anything of it
+        is submitted downstream."""
+        from ddl_tpu import dataloader
+
+        ahead_errors = []
+        real = dataloader._CorruptAhead
+
+        class Spy(real):
+            def __init__(self, *a):
+                ahead_errors.append(a)
+                super().__init__(*a)
+
+        monkeypatch.setattr(dataloader, "_CorruptAhead", Spy)
+        plan = FaultPlan(
+            [FaultSpec("producer.commit", FaultKind.RING_CORRUPTION,
+                       at=3, param=4)]
+        )
+        windows, faulted, m = drain_windows_jax(plan, n_epochs=6, pace_s=0.05)
+        assert_byte_identical(windows, 6)
+        assert ahead_errors, "the lookahead never met the corrupt window"
+        assert m.counter("integrity.corrupt_windows") == 1
+        assert m.counter("integrity.replays") == 1
+        assert not faulted
+        crc_fold(m)
 
     def test_persistent_corruption_escalates_to_integrity_error(self):
         """Corruption that survives every replay exhausts the budget and
@@ -254,7 +290,7 @@ class TestFaultMatrix:
         assert m.counter("integrity.corrupt_windows") == 0
         assert plan.fired and plan.fired[0][0] == "pusher.inplace_fill"
 
-    def test_inplace_torn_commit_quarantined_and_replayed(self):
+    def test_inplace_torn_commit_quarantined_and_replayed(self, crc_fold):
         """A torn COMMITTED slot on the write-once path (bytes flipped
         after the trailer stamp — what a real shared-memory scribble
         looks like): the drain-time CRC quarantines it, and the replay
@@ -272,6 +308,7 @@ class TestFaultMatrix:
         assert m.counter("integrity.corrupt_windows") == 1
         assert m.counter("integrity.replays") == 1
         assert list(wd.failures) == []
+        crc_fold(m)
 
     def test_staging_copy_fault_retried(self):
         """A transient staging-copy failure is retried with backoff; the

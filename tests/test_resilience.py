@@ -206,11 +206,14 @@ class TestAsyncCheckpointer:
         assert restore_latest(str(tmp_path), like=_state(), metrics=m) is None
         assert m.counter("resilience.ckpt_cold_starts") == 0
 
-    def test_ckpt_corruption_chaos_site(self, tmp_path):
+    def test_ckpt_corruption_chaos_site(self, tmp_path, crc_fold):
         """CKPT_CORRUPTION at resilience.ckpt_write flips bytes AFTER
         the CRC stamp: the written generation verifies false on read,
         quarantines, and the previous verified generation restores —
-        the production ladder is what the injection exercises."""
+        the production ladder is what the injection exercises.  A
+        generation is verified by ``integrity.verify_window``, so a
+        large one folds its CRC over spans (``crc_fold``) like a large
+        window."""
         plan = FaultPlan([
             FaultSpec("resilience.ckpt_write", FaultKind.CKPT_CORRUPTION,
                       at=2, param=16),

@@ -416,23 +416,47 @@ class TestWireChaos:
         prod.wire_dtype = wd
         return prod
 
-    def test_wire_corruption_quarantine_and_replay(self):
+    @pytest.mark.parametrize("late_producer", [None, 1, 2])
+    def test_wire_corruption_quarantine_and_replay(
+        self, crc_fold, late_producer
+    ):
         """WIRE_CORRUPTION flips bytes in the ENCODED slot payload after
         the CRC was stamped: drain-time integrity (which verifies the
         quantized bytes) must quarantine, replay through the existing
-        ladder, and deliver a stream identical to an uninjected run."""
+        ladder, and deliver a stream identical to an uninjected run —
+        whether the serial CRC or the span-parallel fold (``crc_fold``)
+        finds it.
+
+        The spec names ONE producer's second encode.  The consumer
+        drains two windows of each producer while each encodes four
+        (they fill ahead), and an unpinned ``at=3`` counted hits across
+        both producer threads: under load one producer ran ahead, the
+        third hit was its third window, which nobody drains, and the
+        injection fired on bytes that were never verified.
+        ``late_producer`` holds one producer's first fill back half a
+        second, which is that schedule made on purpose."""
         clean, _ = _stream_loader(self._producer())
-        plan = FaultPlan([
+        specs = [
             FaultSpec(
-                "wire.encode", FaultKind.WIRE_CORRUPTION, at=3, param=8
+                "wire.encode", FaultKind.WIRE_CORRUPTION, at=2, param=8,
+                producer_idx=1,
             )
-        ])
+        ]
+        if late_producer is not None:
+            specs.append(FaultSpec(
+                "producer.fill", FaultKind.PRODUCER_SLOWDOWN, at=1,
+                param=0.5, producer_idx=late_producer,
+            ))
+        plan = FaultPlan(specs)
         with faults.armed(plan):
             got, m = _stream_loader(self._producer())
-        assert plan.fired, "injection never fired"
+        assert any(
+            f[1] == "wire_corruption" for f in plan.fired
+        ), "injection never fired"
         assert m.counter("integrity.corrupt_windows") >= 1
         assert m.counter("integrity.replays") >= 1
         assert np.array_equal(clean, got)
+        crc_fold(m)
 
     def test_decode_fail_bounded_retry(self):
         """DECODE_FAIL at the consumer edge's wire.decode: one failure
