@@ -1,5 +1,5 @@
 """Model zoo for the consumer-side training loops the loader feeds."""
 
-from ddl_tpu.models import llama, moe, pointnet, vit
+from ddl_tpu.models import afmoe, llama, moe, pointnet, vit
 
-__all__ = ["llama", "moe", "pointnet", "vit"]
+__all__ = ["afmoe", "llama", "moe", "pointnet", "vit"]

@@ -1,0 +1,422 @@
+"""AFMoE decoder LM (``model_type: afmoe`` — Arcee's Trinity family):
+layers of different kinds over one stack.
+
+Every layer is an attention kind followed by an MLP kind, both static
+data of the config (``layer_types``, ``n_dense_layers``):
+
+- attention: ``sliding_attention`` (causal inside a ``sliding_window``,
+  RoPE on q and k) or ``full_attention`` (causal over the whole row, NO
+  position encoding).  Both: grouped-query heads of an explicit
+  ``head_dim`` (32 x 128 over a hidden size of 2048 in Trinity-Mini),
+  RMSNorm with one learned ``head_dim`` weight applied per head to q and
+  k, and an output gate — ``(softmax(scores) v * sigmoid(h Wg)) Wo``.
+- MLP: a dense SwiGLU (the first ``n_dense_layers`` layers) or routed
+  experts plus a shared expert — ``s = sigmoid(h Wr)`` in float32,
+  ``sel = top_k(s + expert_bias)`` (the bias enters the selection only),
+  ``w = s[sel] / (sum(s[sel]) + 1e-20) * route_scale``, ``Shared(h) +
+  sum_k w_k Expert_sel_k(h)``.
+- the block: sandwich norms, ``x + post_norm(f(pre_norm(x)))`` for both
+  halves; the embedding is scaled by ``sqrt(d_model)`` (``mup_enabled``).
+
+What is llama's is llama's: ``_rope``, ``_rms_norm``, ``_swiglu``,
+``_dense_init``, ``remat.tag_attn_out`` and the attention dispatcher
+(``window=`` for the sliding layers).  The routed experts are
+``moe.ragged_experts``, the dropless core OLMoE runs, handed this
+family's scoring and the RANGE OF EXPERTS HELD HERE
+(``held_experts=(first, count)`` of the router's ``n_experts``): one
+chip's share of a layer whose experts are divided over chips.  The
+share computes what its own experts add for the tokens routed to them;
+what the others would have added is left out, and nothing stands in for
+them or for their exchange; nor does a share train its router
+(:func:`_moe_tokens` says why).  With the whole range it is the uncut layer.
+
+Serving is not here: a sliding layer's cache is a ring of
+``sliding_window`` entries and nothing measures one, so
+:func:`forward_with_cache` and :func:`generate` raise by name.
+``expert_bias`` stays where initialisation put it (zeros): the published
+recipe moves it outside the gradient (``load_balance_coeff``), and that
+update does not exist here yet.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from ddl_tpu.models import llama as _llama
+from ddl_tpu.models import moe as _moe
+from ddl_tpu.models import remat as _remat
+
+Params = Dict[str, Any]
+
+SLIDING, FULL = "sliding_attention", "full_attention"
+
+
+@dataclasses.dataclass(frozen=True)
+class AfmoeConfig:
+    vocab: int = 256
+    d_model: int = 64
+    n_heads: int = 4
+    n_kv_heads: int = 2
+    #: Stated, not derived: Trinity-Mini's 32 heads x 128 are twice its
+    #: hidden size.
+    head_dim: int = 32
+    d_ff: int = 192  # the dense layers' SwiGLU width
+    d_expert: int = 32  # each routed expert's, and the shared expert's
+    n_experts: int = 8  # the router's width, whatever is held here
+    topk: int = 2
+    n_shared_experts: int = 1
+    #: One attention kind a layer; its length is the depth.
+    layer_types: Tuple[str, ...] = (SLIDING, FULL)
+    #: The leading layers whose MLP is dense; the rest route.
+    n_dense_layers: int = 1
+    sliding_window: int = 16
+    route_norm: bool = True
+    route_scale: float = 1.0
+    mup_enabled: bool = True
+    #: ``(first, count)`` of the ``n_experts`` whose weights live here;
+    #: ``None`` is all of them.
+    held_experts: Optional[Tuple[int, int]] = None
+    max_seq: int = 512
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    #: Remat policy, as :attr:`LlamaConfig.remat`.
+    remat: Any = False
+    attn_impl: str = "auto"
+
+    def __post_init__(self) -> None:
+        _remat.resolve(self.remat)  # fail on junk at config build time
+        bad = set(self.layer_types) - {SLIDING, FULL}
+        if bad or not self.layer_types:
+            raise ValueError(f"layer_types must be of {SLIDING!r}/{FULL!r}: {bad}")
+        if not 0 <= self.n_dense_layers <= len(self.layer_types):
+            raise ValueError("n_dense_layers outside the stack")
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError("n_kv_heads must divide n_heads")
+        first, count = self.held
+        if not (0 <= first and count >= 1 and first + count <= self.n_experts):
+            raise ValueError(
+                f"held_experts={self.held_experts} is not a range of the "
+                f"router's {self.n_experts}"
+            )
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first, count) of the experts held here."""
+        return self.held_experts or (0, self.n_experts)
+
+    def is_dense(self, layer: int) -> bool:
+        return layer < self.n_dense_layers
+
+    @staticmethod
+    def trinity_mini() -> "AfmoeConfig":
+        """Trinity-Mini (``arcee-ai/Trinity-Mini``, 26B total / 3B active)
+        at full depth with every expert held: 32 layers, three sliding
+        (window 2048) then one full, two leading dense layers (SwiGLU
+        6144) then 128 routed experts x 1024, 8 per token, plus one
+        shared; sigmoid scores, normalised, x 2.826; vocabulary 200,192
+        untied; bf16 storage.  The benchmark's configuration file builds
+        the same config at its published depth, experts and vocabulary
+        (a test holds the two together)."""
+        return AfmoeConfig(
+            vocab=200192, d_model=2048, n_heads=32, n_kv_heads=4,
+            head_dim=128, d_ff=6144, d_expert=1024, n_experts=128, topk=8,
+            n_shared_experts=1, layer_types=(SLIDING, SLIDING, SLIDING, FULL) * 8,
+            n_dense_layers=2, sliding_window=2048, route_norm=True,
+            route_scale=2.826, mup_enabled=True, max_seq=8192,
+            rope_theta=10000.0, norm_eps=1e-5, param_dtype=jnp.bfloat16,
+        )
+
+
+def init_params(cfg: AfmoeConfig, key: jax.Array) -> Params:
+    """Seeded normal / sqrt(fan_in) matrices, norm weights 1,
+    ``expert_bias`` 0 (float32 whatever the storage dtype: it is compared
+    with float32 scores)."""
+    keys = iter(jax.random.split(key, 2 + cfg.n_layers * 12))
+    pdt = cfg.param_dtype
+
+    def dense(fan_in, shape):
+        return _llama._dense_init(next(keys), fan_in, shape, pdt)
+
+    def swiglu(d_in, width, lead=()):
+        return {
+            "w_gate": dense(d_in, lead + (d_in, width)),
+            "w_up": dense(d_in, lead + (d_in, width)),
+            "w_down": dense(width, lead + (width, d_in)),
+        }
+
+    d, hd = cfg.d_model, cfg.head_dim
+    q_out, kv_out = cfg.n_heads * hd, cfg.n_kv_heads * hd
+    layers = []
+    for li in range(cfg.n_layers):
+        layer = {
+            "input_norm": jnp.ones((d,), pdt),
+            "post_attn_norm": jnp.ones((d,), pdt),
+            "pre_mlp_norm": jnp.ones((d,), pdt),
+            "post_mlp_norm": jnp.ones((d,), pdt),
+            "wq": dense(d, (d, q_out)),
+            "wk": dense(d, (d, kv_out)),
+            "wv": dense(d, (d, kv_out)),
+            "wg": dense(d, (d, q_out)),
+            "wo": dense(q_out, (q_out, d)),
+            "q_norm": jnp.ones((hd,), pdt),
+            "k_norm": jnp.ones((hd,), pdt),
+        }
+        if cfg.is_dense(li):
+            layer.update(swiglu(d, cfg.d_ff))
+        else:
+            layer.update(
+                w_router=dense(d, (d, cfg.n_experts)),
+                expert_bias=jnp.zeros((cfg.n_experts,), jnp.float32),
+                shared=swiglu(d, cfg.d_expert * cfg.n_shared_experts),
+                experts=swiglu(d, cfg.d_expert, lead=(cfg.held[1],)),
+            )
+        layers.append(layer)
+    return {
+        "embed": dense(d, (cfg.vocab, d)),
+        "layers": layers,
+        "final_norm": jnp.ones((d,), pdt),
+        "lm_head": dense(d, (d, cfg.vocab)),
+    }
+
+
+def param_specs(cfg: AfmoeConfig) -> Params:
+    """PartitionSpecs mirroring :func:`init_params` (the Megatron fsdp x
+    tp layout; the held experts' leading axis is this chip's own and is
+    not sharded)."""
+    col, row = P("fsdp", "tp"), P("tp", "fsdp")
+    swiglu = {"w_gate": col, "w_up": col, "w_down": row}
+    layers = []
+    for li in range(cfg.n_layers):
+        layer = {
+            "input_norm": P(None), "post_attn_norm": P(None),
+            "pre_mlp_norm": P(None), "post_mlp_norm": P(None),
+            "wq": col, "wk": col, "wv": col, "wg": col, "wo": row,
+            "q_norm": P(None), "k_norm": P(None),
+        }
+        if cfg.is_dense(li):
+            layer.update(swiglu)
+        else:
+            layer.update(
+                w_router=P(None, None), expert_bias=P(None),
+                shared=dict(swiglu),
+                experts={
+                    "w_gate": P(None, "fsdp", "tp"),
+                    "w_up": P(None, "fsdp", "tp"),
+                    "w_down": P(None, "tp", "fsdp"),
+                },
+            )
+        layers.append(layer)
+    return {
+        "embed": P(None, "fsdp"),
+        "layers": layers,
+        "final_norm": P(None),
+        "lm_head": P("fsdp", "tp"),
+    }
+
+
+def _attn_block(
+    layer: Params,
+    x: jax.Array,
+    cfg: AfmoeConfig,
+    positions: jax.Array,
+    sliding: bool,
+    mesh: Optional[Any],
+) -> jax.Array:
+    """Gated attention with sandwich norms on the residual stream."""
+    from ddl_tpu.parallel.ring_attention import attention
+
+    B, T = x.shape[:2]
+    dt = x.dtype
+    eps = cfg.norm_eps
+    h = _llama._rms_norm(x, layer["input_norm"], eps)
+
+    def heads(w: str, n: int) -> jax.Array:
+        return (h @ layer[w].astype(dt)).reshape(B, T, n, cfg.head_dim)
+
+    # One head_dim-long weight, applied to every head.
+    q = _llama._rms_norm(heads("wq", cfg.n_heads), layer["q_norm"], eps)
+    k = _llama._rms_norm(heads("wk", cfg.n_kv_heads), layer["k_norm"], eps)
+    v = heads("wv", cfg.n_kv_heads)
+    if sliding:  # a full layer carries no position encoding
+        q = _llama._rope(q, positions, cfg.rope_theta)
+        k = _llama._rope(k, positions, cfg.rope_theta)
+    attn = attention(
+        q, k, v, mesh=mesh, impl=cfg.attn_impl, causal=True,
+        kv_repeat=cfg.n_heads // cfg.n_kv_heads,
+        window=cfg.sliding_window if sliding else None,
+    )
+    attn = _remat.tag_attn_out(attn)  # saveable under remat="selective"
+    with jax.named_scope("ddl.attn_gate"):
+        gate = jax.nn.sigmoid(h @ layer["wg"].astype(dt))
+        gated = attn.reshape(B, T, -1) * gate
+    out = gated @ layer["wo"].astype(dt)
+    return x + _llama._rms_norm(out, layer["post_attn_norm"], eps)
+
+
+def _route(h: jax.Array, layer: Params, cfg: AfmoeConfig):
+    """The router on flat tokens ``h`` (N, D): (weights (N, k) float32,
+    expert ids (N, k)).  Scores leave their matmul in float32 (bf16
+    operands), as ``moe._router_topk``'s do."""
+    logits = jnp.dot(
+        h, layer["w_router"].astype(h.dtype),
+        preferred_element_type=jnp.float32,
+    )
+    scores = jax.nn.sigmoid(logits)
+    bias = jax.lax.stop_gradient(layer["expert_bias"])  # selection only
+    _, top_e = jax.lax.top_k(scores + bias, cfg.topk)
+    top_w = jnp.take_along_axis(scores, top_e, axis=-1)
+    if cfg.route_norm:
+        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+    return top_w * cfg.route_scale, top_e
+
+
+def _moe_tokens(h: jax.Array, layer: Params, cfg: AfmoeConfig):
+    """Shared expert + the held routed experts on flat tokens (N, D):
+    (out (N, D), the router's picks (N, k))."""
+    with jax.named_scope("ddl.moe_route"):
+        top_w, top_e = _route(h, layer, cfg)
+    held = None if cfg.held == (0, cfg.n_experts) else cfg.held
+    if held is not None:
+        # A share cannot train its router: the absent experts add exactly
+        # nothing here, so this chip's part of the router's gradient says
+        # "send the tokens to them" - and the router obeys (on the chip,
+        # PR 30: the held sixteen's 12.5% of the choices is 0.1% after 26
+        # adamw steps, PERF.md section 6).  In the deployment the other
+        # chips' parts balance it.  So a share routes with its router where
+        # it stands, as it selects with expert_bias where it stands.
+        top_w = jax.lax.stop_gradient(top_w)
+    routed = _moe.ragged_experts(h, layer["experts"], top_w, top_e, held=held)
+    with jax.named_scope("ddl.moe_shared"):
+        shared = _llama._swiglu(layer["shared"], h)
+    return shared + routed, top_e
+
+
+def _moe_mlp(h: jax.Array, layer: Params, cfg: AfmoeConfig,
+             mesh: Optional[Any]):
+    """:func:`_moe_tokens` on the (B, T, D) stream.  On a ``dp`` mesh
+    each shard routes its own rows under ``shard_map``: routing is per
+    token and dropless, so local is global (``moe._routed_mlp``'s
+    argument); the weights cross replicated.  (An ``sp`` mesh never gets
+    here with a sliding layer in the stack: ``attention()`` refuses a
+    window over the ring by name.)"""
+    B, T, D = h.shape
+    names = getattr(mesh, "axis_names", ())
+    if not ("dp" in names and mesh.shape["dp"] > 1):
+        out, top_e = _moe_tokens(h.reshape(B * T, D), layer, cfg)
+        return out.reshape(B, T, D), top_e.reshape(B, T, -1)
+    from jax import shard_map
+
+    read = {k: layer[k] for k in ("w_router", "expert_bias", "shared", "experts")}
+
+    def body(hs: jax.Array, lyr: Params):
+        b, t, _ = hs.shape
+        out, top_e = _moe_tokens(hs.reshape(b * t, D), lyr, cfg)
+        return out.reshape(b, t, D), top_e.reshape(b, t, -1)
+
+    tokens = P("dp", None, None)
+    return shard_map(
+        body, mesh=mesh,
+        in_specs=(tokens, jax.tree.map(lambda _: P(), read)),
+        out_specs=(tokens, tokens), check_vma=False,
+    )(h, read)
+
+
+def _layer_apply(
+    layer: Params,
+    x: jax.Array,
+    cfg: AfmoeConfig,
+    positions: jax.Array,
+    sliding: bool,
+    dense: bool,
+    mesh: Optional[Any],
+):
+    """One block of the stated kinds → (x, the router's picks (B, T,
+    topk), or ``None`` from a dense layer)."""
+    x = _attn_block(layer, x, cfg, positions, sliding, mesh)
+    h = _llama._rms_norm(x, layer["pre_mlp_norm"], cfg.norm_eps)
+    if dense:
+        out, top_e = _llama._swiglu(layer, h), None
+    else:
+        out, top_e = _moe_mlp(h, layer, cfg, mesh)
+    out = _llama._rms_norm(out, layer["post_mlp_norm"], cfg.norm_eps)
+    return x + out, top_e
+
+
+def forward_with_choices(
+    params: Params,
+    tokens: jax.Array,
+    cfg: AfmoeConfig,
+    mesh: Optional[Any] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """(logits (B, T, vocab) float32, the expert ids every expert layer's
+    router picked (L_expert, B, T, topk) — out of all ``n_experts``, held
+    here or not)."""
+    dt = cfg.dtype
+    positions = jnp.arange(tokens.shape[1])
+    x = params["embed"].astype(dt)[tokens]
+    if cfg.mup_enabled:
+        x = x * jnp.asarray(math.sqrt(cfg.d_model), dt)
+    picks = []
+    for li, (layer, kind) in enumerate(zip(params["layers"], cfg.layer_types)):
+
+        def layer_fn(x, layer, sliding=kind == SLIDING, dense=cfg.is_dense(li)):
+            return _layer_apply(layer, x, cfg, positions, sliding, dense, mesh)
+
+        x, top_e = _remat.wrap(layer_fn, cfg.remat)(x, layer)
+        if top_e is not None:
+            picks.append(top_e)
+    x = _llama._rms_norm(x, params["final_norm"], cfg.norm_eps)
+    logits = (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
+    return logits, jnp.stack(picks) if picks else jnp.zeros(
+        (0,) + tokens.shape + (cfg.topk,), jnp.int32
+    )
+
+
+def forward(
+    params: Params,
+    tokens: jax.Array,
+    cfg: AfmoeConfig,
+    mesh: Optional[Any] = None,
+) -> jax.Array:
+    """Next-token logits, (B, T, vocab) float32."""
+    return forward_with_choices(params, tokens, cfg, mesh)[0]
+
+
+def next_token_loss(
+    params: Params,
+    tokens: jax.Array,
+    cfg: AfmoeConfig,
+    mesh: Optional[Any] = None,
+) -> jax.Array:
+    """Mean next-token cross-entropy.  No auxiliary router loss: the
+    published recipe balances by moving ``expert_bias``, not by a term of
+    the loss."""
+    from ddl_tpu.models.losses import next_token_cross_entropy
+
+    return next_token_cross_entropy(forward(params, tokens, cfg, mesh), tokens)
+
+
+def forward_with_cache(*args: Any, **kwargs: Any):
+    raise NotImplementedError(
+        "afmoe.forward_with_cache: a sliding_attention layer's KV cache is "
+        "a ring of sliding_window entries, which does not exist yet"
+    )
+
+
+def generate(*args: Any, **kwargs: Any):
+    raise NotImplementedError(
+        "afmoe.generate: serving needs the windowed KV cache "
+        "(see forward_with_cache)"
+    )

@@ -1,24 +1,31 @@
-"""Mixture-of-Experts decoder LM with expert parallelism.
+"""Mixture-of-Experts decoder LM, and the routed-expert core its
+siblings share.
 
 The reference had no models and no expert parallelism (SURVEY §2.3 lists EP
-as absent); ddl_tpu makes it a first-class mesh axis.  The design is the
-TPU-idiomatic GShard/Switch formulation rather than gather/scatter token
-routing: capacity-bounded dispatch/combine einsums with fully static
-shapes, so XLA tiles every step onto the MXU and GSPMD inserts the ``ep``
-all-to-alls from sharding annotations alone — there is no hand-written
-collective and no data-dependent control flow.
+as absent).  Two dispatches live here, chosen by what the mesh shows
+(``moe_impl="auto"``):
 
-- Router: top-k (default 2) float32 softmax gating; the chosen
-  probabilities are renormalised (``norm_topk_prob``, Mixtral) or kept
-  raw (OLMoE).
-- Dispatch, two implementations chosen by what the mesh shows
-  (``moe_impl="auto"``): sort-based DROPLESS routing over
-  ``jax.lax.ragged_dot`` wherever no ``ep`` axis shards the experts
-  (:func:`moe_mlp_ragged`), the capacity-bounded einsums only there.
-- Einsum dispatch: per-expert capacity ``C = ceil(topk·N/E·capacity_factor)``;
-  slot positions come from a cumulative sum over a slot-major one-hot mask
-  (earlier top-k slots get priority), overflow tokens are dropped (their
-  combine weight is zero — the residual stream carries them unchanged).
+- **Dropless ragged** (:func:`ragged_experts`, wherever no ``ep`` axis
+  shards the experts): every (token, slot) choice becomes a row, rows are
+  stably sorted by expert, and the three expert matmuls run as
+  ``jax.lax.ragged_dot`` group-wise dots against the stacked weights — no
+  capacity, no dropped token, no one-hots.  The core takes the router's
+  scoring AS GIVEN — (weights, expert ids) per token and slot — so each
+  family brings its own: float32 softmax top-k, renormalised
+  (``norm_topk_prob``, Mixtral) or raw (OLMoE), here
+  (:func:`_router_topk`); sigmoid + selection bias + normalise + scale in
+  ``models/afmoe.py``.  And it takes the RANGE OF EXPERTS IT HOLDS: told
+  ``held=(first, count)`` of a wider router, it routes over all of them,
+  computes the rows whose choice falls on a held expert and leaves the
+  rest out — one chip's share of an expert-parallel layer, without the
+  exchange and with nothing standing in for it.
+- **Capacity-bounded einsums** (:func:`moe_mlp`, only where an ``ep`` mesh
+  axis shards the expert stacks): the GShard/Switch formulation — fully
+  static dispatch/combine one-hots, per-expert capacity ``C =
+  ceil(topk·N/E·capacity_factor)``, overflow tokens dropped (their combine
+  weight is zero, the residual stream carries them) — whose ``ep``
+  all-to-alls GSPMD derives from the sharding annotations.  An explicit
+  all-to-all for a dropless ``ep`` does not exist yet (ROADMAP).
 - Experts: stacked SwiGLU MLPs ``(E, D, F)``, sharded ``P("ep", "fsdp",
   "tp")`` so each device holds ``E/ep`` experts.
 - Router losses: the Switch load-balance term ``E · Σ_e
@@ -377,35 +384,91 @@ def _ragged_mlp(
     x: jax.Array, layer: Params, cfg: MoeConfig
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """:func:`moe_mlp_ragged` with both router losses and the router's
-    picks: (out, (2,) losses, top_e (N, k)).  Its three phases carry
-    profiler scopes (``ddl.moe_route``, ``ddl.moe_experts``,
-    ``ddl.moe_combine``): they reach the device trace as each op's
-    ``tf_op`` name."""
-    N, D = x.shape
-    E, k = cfg.n_experts, cfg.topk
-    dt = x.dtype
-
+    picks: (out, (2,) losses, top_e (N, k)) — this family's softmax
+    router in front of :func:`ragged_experts`."""
     with jax.named_scope("ddl.moe_route"):
         probs, top_p, top_e, z = _router_topk(x, layer, cfg)
+    out = ragged_experts(x, layer, top_p, top_e)
+    return out, _router_losses(probs, top_e, z, cfg), top_e
+
+
+def ragged_experts(
+    x: jax.Array,
+    experts: Params,
+    top_w: jax.Array,
+    top_e: jax.Array,
+    held: Optional[Tuple[int, int]] = None,
+) -> jax.Array:
+    """The dropless routed-expert core: ``sum_k top_w[n, k] *
+    expert_{top_e[n, k]}(x[n])`` over flat tokens ``x`` (N, D), for the
+    experts held here.
+
+    ``experts`` holds the stacked SwiGLU weights ``w_gate``/``w_up`` (G,
+    D, F) and ``w_down`` (G, F, D); ``top_w``/``top_e`` (N, k) are the
+    router's weights and expert ids, whatever scoring produced them.
+    ``held=None``: the stack is every expert the router can name.
+    ``held=(first, count)``: the stack is experts ``first .. first +
+    count - 1`` of a wider router (G = count) — the part of the layer's
+    result that those experts give is returned, the other choices add
+    nothing (no capacity, no dropped held row, no stand-in for the chips
+    that hold the rest).
+
+    Each choice is a row; rows are stably sorted by expert so that each
+    expert's rows are one contiguous group and the three matmuls run as
+    ``jax.lax.ragged_dot``.  Shapes are static: N·k rows whatever the
+    router chose.  With a held range the unheld choices sort behind the
+    last group and belong to none.  What the chip then does with them
+    (``tools/probe_ragged_rows.py``, my chip run, PR 30, TPU v5 lite;
+    PERF.md section 6): the gather and the elementwise passes run over
+    all N·k rows; XLA's grouped-matmul kernels visit the grouped rows'
+    tiles only (0.57 ms for an eighth of 131,072 rows against 4.10 ms for
+    all) and leave the other rows of their result UNWRITTEN — stale
+    values, NaN after a NaN fill, in the transposes too — so both ends of
+    the expert pass are ``where``-masked (never multiplied by zero), in
+    the forward and, by transposition, in the backward pass.
+
+    Its three phases carry profiler scopes (``ddl.moe_route``,
+    ``ddl.moe_experts``, ``ddl.moe_combine``): they reach the device
+    trace as each op's ``tf_op`` name."""
+    N, D = x.shape
+    k = top_e.shape[1]
+    dt = x.dtype
+    n_groups = experts["w_gate"].shape[0]
+
+    with jax.named_scope("ddl.moe_route"):
         flat_e = top_e.reshape(-1)  # (N*k,) expert of copy i (token i//k)
+        if held is not None:
+            first, count = held
+            assert count == n_groups, (held, n_groups)
+            is_held = (flat_e >= first) & (flat_e < first + count)
+            # Unheld choices get the id past the last group: they sort
+            # behind every group and are counted in none.
+            flat_e = jnp.where(is_held, flat_e - first, count)
         order = jnp.argsort(flat_e)  # stable: ties keep token order
-        group_sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
+        group_sizes = jnp.bincount(
+            flat_e, length=n_groups + (held is not None)
+        ).astype(jnp.int32)[:n_groups]
 
     with jax.named_scope("ddl.moe_experts"):
         xs = jnp.take(x, order // k, axis=0)  # (N*k, D) grouped by expert
+        if held is not None:
+            in_a_group = (jnp.arange(N * k) < jnp.sum(group_sizes))[:, None]
+            xs = jnp.where(in_a_group, xs, 0)
         gate = jax.nn.silu(
-            jax.lax.ragged_dot(xs, layer["w_gate"].astype(dt), group_sizes)
+            jax.lax.ragged_dot(xs, experts["w_gate"].astype(dt), group_sizes)
         )
-        up = jax.lax.ragged_dot(xs, layer["w_up"].astype(dt), group_sizes)
+        up = jax.lax.ragged_dot(xs, experts["w_up"].astype(dt), group_sizes)
         rows = jax.lax.ragged_dot(
-            gate * up, layer["w_down"].astype(dt), group_sizes
+            gate * up, experts["w_down"].astype(dt), group_sizes
         )  # (N*k, D), still expert-sorted
 
     with jax.named_scope("ddl.moe_combine"):
         inv = jnp.argsort(order)  # flat copy index -> its sorted row
         per_slot = jnp.take(rows, inv, axis=0).reshape(N, k, D)
-        out = jnp.einsum("nk,nkd->nd", top_p.astype(dt), per_slot)
-    return out, _router_losses(probs, top_e, z, cfg), top_e
+        if held is not None:
+            per_slot = jnp.where(is_held.reshape(N, k, 1), per_slot, 0)
+        out = jnp.einsum("nk,nkd->nd", top_w.astype(dt), per_slot)
+    return out
 
 
 def _moe_mlp_dispatch(

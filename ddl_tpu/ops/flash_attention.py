@@ -71,27 +71,43 @@ def _positions(offs_ref, i, j, block_q, block_k):
     return offs_ref[0] + q_loc, offs_ref[1] + k_loc, q_loc, k_loc
 
 
-def _live(offs_ref, i, j, block_q, block_k, causal):
+def _live(offs_ref, i, j, block_q, block_k, causal, window=None):
     """False only when block (i, j) lies strictly above the global causal
-    diagonal (then every entry is masked and the matmuls can be skipped)."""
+    diagonal (then every entry is masked and the matmuls can be skipped)
+    — or, with a ``window``, wholly below the band: its newest key is
+    already out of the oldest query's window."""
     if not causal:
         return j >= 0  # traced True
-    return (
+    live = (
         offs_ref[1] + j * block_k
         <= offs_ref[0] + i * block_q + block_q - 1
     )
+    if window is None:
+        return live
+    return live & (
+        offs_ref[1] + (j + 1) * block_k - 1
+        > offs_ref[0] + i * block_q - window
+    )
 
 
-def _crosses_diag(offs_ref, i, j, block_q, block_k, causal):
+def _crosses_diag(offs_ref, i, j, block_q, block_k, causal, window=None):
     """True when block (i, j) straddles the global causal diagonal (some
-    entries masked, some not).  Interior blocks — fully below the diagonal
-    — skip mask construction entirely: the two (bq, bk) position grids,
-    compares, and selects are the kernel's dominant VPU cost after exp."""
+    entries masked, some not) — or, with a ``window``, the band's lower
+    edge: its oldest key is out of the newest query's window.  Interior
+    blocks — fully below the diagonal, fully inside the band — skip mask
+    construction entirely: the two (bq, bk) position grids, compares, and
+    selects are the kernel's dominant VPU cost after exp."""
     if not causal:
         return j < 0  # traced False
-    return (
+    crosses = (
         offs_ref[1] + (j + 1) * block_k - 1
         > offs_ref[0] + i * block_q
+    )
+    if window is None:
+        return crosses
+    return crosses | (
+        offs_ref[0] + (i + 1) * block_q - 1 - (offs_ref[1] + j * block_k)
+        >= window
     )
 
 
@@ -107,7 +123,7 @@ def _seg_invalid(seg):
 def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_ref, l_ref, acc_ref, *, scale: float, causal: bool,
                 block_q: int, block_k: int, kv_len: int, precision,
-                seg=None):
+                seg=None, window=None):
     i = pl.program_id(2)  # Q block
     j = pl.program_id(3)  # KV block (innermost, sequential)
 
@@ -151,9 +167,9 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     # conditionally — a value-level lax.cond computed both sides):
     # interior blocks fully below the diagonal with no padded keys skip
     # mask construction entirely, the dominant VPU cost after exp.
-    live = _live(offs_ref, i, j, block_q, block_k, causal)
+    live = _live(offs_ref, i, j, block_q, block_k, causal, window)
     needs_mask = (
-        _crosses_diag(offs_ref, i, j, block_q, block_k, causal)
+        _crosses_diag(offs_ref, i, j, block_q, block_k, causal, window)
         | ((j + 1) * block_k > kv_len)
     )
     if seg is not None:
@@ -167,6 +183,8 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         invalid = k_loc >= kv_len  # padded keys
         if causal:
             invalid |= k_pos > q_pos
+        if window is not None:
+            invalid |= q_pos - k_pos >= window
         if seg is not None:
             invalid |= _seg_invalid(seg)
         _update(jnp.where(invalid, _NEG_INF, s))
@@ -201,15 +219,15 @@ def _block_scores(q_ref, k_ref, scale, precision):
 
 def _bwd_p_dispatch(offs_ref, q_ref, k_ref, lse_ref, i, j, accum, *,
                     scale, causal, block_q, block_k, seq_len, kv_len,
-                    precision, seg=None):
+                    precision, seg=None, window=None):
     """Backward-pass block dispatch shared by the dQ and dK/dV kernels:
     dead blocks skipped, boundary blocks recompute p with full masking,
     interior blocks use the bare ``exp(s - lse)`` fast path (statement-
     level ``pl.when`` — real branches, unlike a value-level cond which
     Mosaic computes on both sides)."""
-    live = _live(offs_ref, i, j, block_q, block_k, causal)
+    live = _live(offs_ref, i, j, block_q, block_k, causal, window)
     needs_mask = _needs_mask_bwd(
-        offs_ref, i, j, block_q, block_k, causal, seq_len, kv_len
+        offs_ref, i, j, block_q, block_k, causal, seq_len, kv_len, window
     )
     if seg is not None:
         needs_mask = needs_mask | (j >= 0)  # packed: every block masks
@@ -222,7 +240,7 @@ def _bwd_p_dispatch(offs_ref, q_ref, k_ref, lse_ref, i, j, accum, *,
         accum(_p_masked(
             offs_ref, scores(), lse_ref[0, 0][:, 0], i, j, causal=causal,
             block_q=block_q, block_k=block_k, seq_len=seq_len,
-            kv_len=kv_len, seg=seg,
+            kv_len=kv_len, seg=seg, window=window,
         ))
 
     @pl.when(live & jnp.logical_not(needs_mask))
@@ -231,13 +249,15 @@ def _bwd_p_dispatch(offs_ref, q_ref, k_ref, lse_ref, i, j, accum, *,
 
 
 def _p_masked(offs_ref, s, lse, i, j, *, causal, block_q, block_k,
-              seq_len, kv_len, seg=None):
+              seq_len, kv_len, seg=None, window=None):
     """p = exp(s - lse) with mask/padding/empty-row handling (the slow,
     boundary-block path — interior blocks use the bare exp)."""
     q_pos, k_pos, q_loc, k_loc = _positions(offs_ref, i, j, block_q, block_k)
     invalid = (k_loc >= kv_len) | (q_loc >= seq_len)
     if causal:
         invalid |= k_pos > q_pos
+    if window is not None:
+        invalid |= q_pos - k_pos >= window
     if seg is not None:
         invalid |= _seg_invalid(seg)
     empty = lse <= _NEG_INF / 2  # (bq,)
@@ -246,13 +266,13 @@ def _p_masked(offs_ref, s, lse, i, j, *, causal, block_q, block_k,
 
 
 def _needs_mask_bwd(offs_ref, i, j, block_q, block_k, causal, seq_len,
-                    kv_len):
-    """True unless block (i, j) is interior: fully below the diagonal with
-    no padded keys/queries.  Interior blocks cannot contain masked entries
+                    kv_len, window=None):
+    """True unless block (i, j) is interior: fully below the diagonal
+    (and inside the band, with a ``window``) with no padded keys/queries.  Interior blocks cannot contain masked entries
     or globally-empty rows (the block itself supplies valid keys), so
     ``exp(s - lse)`` is exact there and mask construction is skipped."""
     return (
-        _crosses_diag(offs_ref, i, j, block_q, block_k, causal)
+        _crosses_diag(offs_ref, i, j, block_q, block_k, causal, window)
         | ((j + 1) * block_k > kv_len)
         | ((i + 1) * block_q > seq_len)
     )
@@ -261,7 +281,7 @@ def _needs_mask_bwd(offs_ref, i, j, block_q, block_k, causal, seq_len,
 def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                dlse_ref, dq_ref, dq_acc, *, scale: float, causal: bool,
                block_q: int, block_k: int, seq_len: int, kv_len: int,
-               precision, seg=None):
+               precision, seg=None, window=None):
     i = pl.program_id(2)  # Q block
     j = pl.program_id(3)  # KV block (innermost, sequential)
 
@@ -286,7 +306,7 @@ def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     _bwd_p_dispatch(
         offs_ref, q_ref, k_ref, lse_ref, i, j, _accum, scale=scale,
         causal=causal, block_q=block_q, block_k=block_k, seq_len=seq_len,
-        kv_len=kv_len, precision=precision, seg=seg,
+        kv_len=kv_len, precision=precision, seg=seg, window=window,
     )
 
     @pl.when(j == pl.num_programs(3) - 1)
@@ -297,7 +317,7 @@ def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dlse_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
                 causal: bool, block_q: int, block_k: int, seq_len: int,
-                kv_len: int, precision, seg=None):
+                kv_len: int, precision, seg=None, window=None):
     j = pl.program_id(2)  # KV block
     i = pl.program_id(3)  # Q block (innermost, sequential)
 
@@ -327,7 +347,7 @@ def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     _bwd_p_dispatch(
         offs_ref, q_ref, k_ref, lse_ref, i, j, _accum, scale=scale,
         causal=causal, block_q=block_q, block_k=block_k, seq_len=seq_len,
-        kv_len=kv_len, precision=precision, seg=seg,
+        kv_len=kv_len, precision=precision, seg=seg, window=window,
     )
 
     @pl.when(i == pl.num_programs(3) - 1)
@@ -434,7 +454,7 @@ def _seg_specs(block_q, block_k, transposed: bool = False):
 
 
 def _fwd_impl(q, k, v, offsets, causal, kv_repeat, block_q, block_k,
-              interpret, seg_q=None, seg_k=None):
+              interpret, seg_q=None, seg_k=None, window=None):
     assert q.shape[2] == k.shape[2] * kv_repeat, (q.shape, k.shape, kv_repeat)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -446,7 +466,7 @@ def _fwd_impl(q, k, v, offsets, causal, kv_repeat, block_q, block_k,
     packed = seg_q is not None
     common = dict(
         scale=1.0 / (D**0.5), causal=causal, block_q=block_q,
-        block_k=block_k, kv_len=Tkv, precision=precision,
+        block_k=block_k, kv_len=Tkv, precision=precision, window=window,
     )
     kernel = functools.partial(
         _fwd_kernel_seg if packed else _fwd_kernel, **common
@@ -479,7 +499,7 @@ def _fwd_impl(q, k, v, offsets, causal, kv_repeat, block_q, block_k,
         ],
     )
     out, lse = named_pallas_call(
-        "ddl_flash_fwd", kernel,
+        "ddl_flash_fwd" if window is None else "ddl_flash_swa_fwd", kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
@@ -495,7 +515,8 @@ def _fwd_impl(q, k, v, offsets, causal, kv_repeat, block_q, block_k,
     )
 
 
-def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts):
+def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts,
+              window=None):
     do, dlse = cts
     # Resolved block sizes / interpret flag ride in the residuals so both
     # passes use identical values (the nondiff args are pre-resolution).
@@ -525,6 +546,7 @@ def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts):
     common = dict(
         scale=1.0 / (D**0.5), causal=causal, block_q=block_q,
         block_k=block_k, seq_len=T, kv_len=Tkv, precision=precision,
+        window=window,
     )
     q_spec = pl.BlockSpec(
         (1, 1, block_q, D), lambda b, h, i, j, *_refs: (b, h, i, 0)
@@ -544,7 +566,7 @@ def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts):
         dq_in_specs += [sq_spec, sk_spec]
         dq_inputs += [_prep_seg(seg_q, Tq), _prep_seg(seg_k, Tk)]
     dq = named_pallas_call(
-        "ddl_flash_bwd_dq",
+        "ddl_flash_bwd_dq" if window is None else "ddl_flash_swa_bwd_dq",
         functools.partial(_dq_kernel_seg if packed else _dq_kernel,
                           **common),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -580,7 +602,7 @@ def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts):
         dkv_in_specs += [sq_spec_t, sk_spec_t]
         dkv_inputs += [_prep_seg(seg_q, Tq), _prep_seg(seg_k, Tk)]
     dk, dv = named_pallas_call(
-        "ddl_flash_bwd_dkv",
+        "ddl_flash_bwd_dkv" if window is None else "ddl_flash_swa_bwd_dkv",
         functools.partial(_dkv_kernel_seg if packed else _dkv_kernel,
                           **common),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -677,6 +699,40 @@ def _bwd_impl_seg(causal, kv_repeat, block_q, block_k, interpret, res, cts):
 _flash_core_seg.defvjp(_vjp_fwd_seg, _bwd_impl_seg)
 
 
+# Sliding-window core: the same kernels with the band's lower edge
+# (``window`` static: key j is visible to query i iff 0 <= i - j < window).
+# A custom_vjp of its own, like the packed one, so that the causal-full
+# core's signature, jaxpr and lowered program stay what they were.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_core_win(q, k, v, offsets, kv_repeat, block_q, block_k,
+                    interpret, window):
+    out, lse, _ = _fwd_impl(
+        q, k, v, offsets, True, kv_repeat, block_q, block_k, interpret,
+        window=window,
+    )
+    return out, lse
+
+
+def _vjp_fwd_win(q, k, v, offsets, kv_repeat, block_q, block_k, interpret,
+                 window):
+    out, lse, (out_padded, lse_padded, ipret, bq, bk) = _fwd_impl(
+        q, k, v, offsets, True, kv_repeat, block_q, block_k, interpret,
+        window=window,
+    )
+    return (out, lse), (
+        q, k, v, offsets, out_padded, lse_padded, ipret, bq, bk, None, None
+    )
+
+
+def _bwd_impl_win(kv_repeat, block_q, block_k, interpret, window, res, cts):
+    return _bwd_impl(
+        True, kv_repeat, block_q, block_k, interpret, res, cts, window=window
+    )
+
+
+_flash_core_win.defvjp(_vjp_fwd_win, _bwd_impl_win)
+
+
 def _default_blocks(T: int, block_q, block_k):
     """v5e-tuned defaults, sequence-length adaptive (measured fwd+bwd at
     B=4, H=16, D=128: bq=512 wins at T<=2k, bq=1024 wins at 4k/8k by
@@ -701,6 +757,7 @@ def flash_attention(
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     segment_ids: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Flash attention over (B, T, H, D) queries.
 
@@ -716,10 +773,38 @@ def flash_attention(
     multiple documents into one row.  Packed blocks always take the
     masked path, so packing trades the interior-block fast path for the
     mask; unpacked calls are entirely unaffected.
+
+    ``window`` (static int, causal only): sliding-window attention — key
+    ``j`` is visible to query ``i`` iff ``0 <= i - j < window``.  Blocks
+    wholly older than the band skip their matmuls as blocks above the
+    diagonal do (they still cost their grid step and their DMAs), blocks
+    wholly inside it keep the unmasked fast path, and the kernels are
+    named ``ddl_flash_swa_*`` on the trace.  A window that covers the
+    sequence is plain causal attention and runs as such; ``None`` leaves
+    every program exactly what it was before windows existed.
     """
     block_q, block_k = _default_blocks(q.shape[1], block_q, block_k)
-    if flash_tile.fits(q, k, v, kv_repeat, block_q, block_k, segment_ids):
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(
+                f"window={window!r} needs causal attention and window >= 1"
+            )
+        if window >= q.shape[1]:
+            window = None  # the band holds every causal pair
+    if flash_tile.fits(q, k, v, kv_repeat, block_q, block_k, segment_ids,
+                       window=window):
         return flash_tile.tile_attention(q, k, v, causal, interpret)
+    if window is not None:
+        if segment_ids is not None:
+            raise NotImplementedError(
+                "flash_attention: packed rows (segment_ids) inside a "
+                "sliding window have no kernel"
+            )
+        out, _ = _flash_core_win(
+            q, k, v, _offsets_arr(0, 0), kv_repeat, block_q, block_k,
+            interpret, window,
+        )
+        return out
     if segment_ids is not None:
         out, _ = _flash_core_seg(
             q, k, v, _offsets_arr(0, 0), segment_ids, segment_ids, causal,

@@ -33,6 +33,8 @@ from jax.experimental import pallas as pl
 #: benchmark's ``flash_device_share`` reads the ``ddl_flash_`` prefix.
 KERNEL_NAMES = (
     "ddl_flash_fwd", "ddl_flash_bwd_dq", "ddl_flash_bwd_dkv",
+    # the same kernels with a sliding window's band (``window=``)
+    "ddl_flash_swa_fwd", "ddl_flash_swa_bwd_dq", "ddl_flash_swa_bwd_dkv",
     "ddl_flash_tile_fwd", "ddl_flash_tile_bwd",
     "ddl_ici_bcast", "ddl_ici_scatter", "ddl_shuffle_exchange",
 )

@@ -182,6 +182,7 @@ def sharded_local_attention(
     dp_axis: str = "dp",
     tp_axis: str = "tp",
     segment_ids: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Batch/head-sharded attention for meshes WITHOUT a sequence axis.
 
@@ -191,18 +192,14 @@ def sharded_local_attention(
     Pallas kernel is an opaque custom call and XLA would gather its operands.
     Axes that don't divide the corresponding dimension stay unsharded.
     ``segment_ids`` (B, T): packed-sequence masking, batch-sharded like q.
+    ``window``: sliding-window attention, local to every shard.
     """
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from ddl_tpu.ops import flash_attention
-
     def impl(q, k, v, seg):
-        if use_flash:
-            return flash_attention(q, k, v, causal=causal,
-                                   kv_repeat=kv_repeat, segment_ids=seg)
-        return attention_reference(q, k, v, causal=causal,
-                                   kv_repeat=kv_repeat, segment_ids=seg)
+        return _local_attention(q, k, v, use_flash, causal, kv_repeat, seg,
+                                window)
 
     B, _, H, _ = q.shape
     Hkv = k.shape[2]
@@ -250,6 +247,19 @@ def sharded_local_attention(
     )(q, k, v, segment_ids)
 
 
+def _local_attention(q, k, v, use_flash, causal, kv_repeat, segment_ids,
+                     window):
+    """One device's whole attention: the Pallas flash kernels or the dense
+    oracle."""
+    if use_flash:
+        from ddl_tpu.ops import flash_attention
+
+        return flash_attention(q, k, v, causal=causal, kv_repeat=kv_repeat,
+                               segment_ids=segment_ids, window=window)
+    return attention_reference(q, k, v, causal=causal, kv_repeat=kv_repeat,
+                               segment_ids=segment_ids, window=window)
+
+
 def attention(
     q: jax.Array,
     k: jax.Array,
@@ -262,6 +272,7 @@ def attention(
     dp_axis: str = "dp",
     tp_axis: str = "tp",
     segment_ids: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """The single attention dispatcher — one source of truth for impl/mesh
     routing (models call this, not the individual strategies):
@@ -273,6 +284,11 @@ def attention(
       Pallas flash kernel on TPU backends and dense XLA elsewhere.
     - ``segment_ids`` (B, T): packed-sequence masking on every strategy
       (on the ring path the key-side ids rotate with their K/V blocks).
+    - ``window`` (static int, causal only): sliding-window attention — key
+      ``j`` is visible to query ``i`` iff ``0 <= i - j < window`` — on the
+      local strategies.  The ``sp`` ring refuses it by name: a band needs
+      only the neighbouring shards' keys, and a ring that rotates every
+      block past every device to mask most of them is not that.
     """
     if impl not in ("auto", "flash", "dense"):
         raise ValueError(
@@ -282,6 +298,11 @@ def attention(
         impl == "auto" and jax.default_backend() == "tpu"
     )
     if mesh is not None and axis in mesh.axis_names and mesh.shape[axis] > 1:
+        if window is not None:
+            raise NotImplementedError(
+                f"attention(window={window}): ring attention over the "
+                f"{axis!r} axis has no sliding window"
+            )
         return ring_attention(
             q, k, v, mesh, causal=causal, axis=axis, dp_axis=dp_axis,
             kv_repeat=kv_repeat, use_flash=use_flash,
@@ -291,24 +312,21 @@ def attention(
         return sharded_local_attention(
             q, k, v, mesh, causal=causal, kv_repeat=kv_repeat,
             use_flash=use_flash, dp_axis=dp_axis, tp_axis=tp_axis,
-            segment_ids=segment_ids,
+            segment_ids=segment_ids, window=window,
         )
-    if use_flash:
-        from ddl_tpu.ops import flash_attention
-
-        return flash_attention(q, k, v, causal=causal, kv_repeat=kv_repeat,
-                               segment_ids=segment_ids)
-    return attention_reference(q, k, v, causal=causal, kv_repeat=kv_repeat,
-                               segment_ids=segment_ids)
+    return _local_attention(q, k, v, use_flash, causal, kv_repeat,
+                            segment_ids, window)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "kv_repeat"))
+@functools.partial(jax.jit, static_argnames=("causal", "kv_repeat", "window"))
 def attention_reference(q, k, v, causal: bool = True, kv_repeat: int = 1,
-                        segment_ids=None):
+                        segment_ids=None, window=None):
     """Single-device full attention — the correctness oracle for tests.
 
     ``segment_ids`` (B, T): packed-sequence masking, tokens attend only
     within their own segment (matching ``ops.flash_attention``).
+    ``window``: key ``j`` is visible to query ``i`` iff ``i - j < window``
+    (on top of causality).
     """
     if kv_repeat > 1:
         k = jnp.repeat(k, kv_repeat, axis=2)
@@ -318,6 +336,11 @@ def attention_reference(q, k, v, causal: bool = True, kv_repeat: int = 1,
     if causal:
         mask = jnp.arange(T)[None, :] > jnp.arange(T)[:, None]
         s = jnp.where(mask[None, None], _NEG_INF, s)
+    if window is not None:
+        if not causal:
+            raise ValueError("a sliding window needs causal attention")
+        old = jnp.arange(T)[:, None] - jnp.arange(T)[None, :] >= window
+        s = jnp.where(old[None, None], _NEG_INF, s)
     if segment_ids is not None:
         seg = jnp.asarray(segment_ids)
         segmask = seg[:, :, None] != seg[:, None, :]  # (B, Tq, Tk)

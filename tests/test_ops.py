@@ -269,6 +269,65 @@ class TestRingFlash:
         assert bool(np.all(np.asarray(lse2) < -1e29))
 
 
+# -- a sliding window in the blockwise kernels -----------------------------------
+
+
+@pytest.mark.parametrize("gqa", [1, 2], ids=["mha", "gqa"])
+@pytest.mark.parametrize("T,window", [
+    (512, 200),  # the band's edge cuts inside a 128-block
+    (512, 256),  # ... lies on a block edge
+    (512, 1000),  # ... is wider than the row: plain causal
+    (400, 150),  # the row is off the block size
+    (384, 1),  # every query sees itself alone
+], ids=lambda v: str(v))
+def test_windowed_flash_matches_dense_forward_and_gradients(rng, T, window, gqa):
+    q, k, v = _qkv(rng, B=1, T=T, H=2, Hkv=2 // gqa, D=32)
+    do = jnp.asarray(rng.standard_normal(q.shape), jnp.float32)
+    kw = dict(kv_repeat=gqa, window=window)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, block_q=128, block_k=128, **kw)
+
+    def dense(q, k, v):
+        return attention_reference(q, k, v, **kw)
+
+    np.testing.assert_allclose(flash(q, k, v), dense(q, k, v), atol=2e-5, rtol=2e-5)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * do), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * do), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=5e-5, rtol=5e-5)
+
+
+def test_the_window_is_a_band_and_not_the_whole_causal_triangle(rng):
+    q, k, v = _qkv(rng, B=1, T=256, H=2, D=32)
+    banded = attention_reference(q, k, v, window=64)
+    assert float(jnp.max(jnp.abs(banded - attention_reference(q, k, v)))) > 0.05
+    # A key older than the window does not reach the query.
+    k2 = k.at[:, 0].add(100.0)
+    v2 = v.at[:, 0].add(100.0)
+    np.testing.assert_array_equal(
+        np.asarray(flash_attention(q, k2, v2, window=64, block_q=64, block_k=64))[:, 64:],
+        np.asarray(flash_attention(q, k, v, window=64, block_q=64, block_k=64))[:, 64:],
+    )
+
+
+def test_a_window_needs_causal_attention_and_refuses_packed_rows(rng):
+    q, k, v = _qkv(rng, B=1, T=256, H=2, D=32)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, causal=False, window=64)
+    with pytest.raises(NotImplementedError, match="packed rows"):
+        flash_attention(q, k, v, window=64, segment_ids=jnp.zeros((1, 256), jnp.int32))
+
+
+def test_the_one_block_kernels_refuse_a_window_narrower_than_the_row(rng):
+    from ddl_tpu.ops import flash_tile
+
+    q, k, v = _qkv(rng, B=2, T=196, H=12, D=64)
+    assert flash_tile.fits(q, k, v, 1, 512, 1024, None)
+    assert flash_tile.fits(q, k, v, 1, 512, 1024, None, window=196)
+    assert not flash_tile.fits(q, k, v, 1, 512, 1024, None, window=195)
+
+
 # -- a sequence that fits one block: ops/flash_tile.py --------------------------
 
 #: (B, T, H, D): ViT-B/16's geometry, off every tile (196 rows, 64-deep
@@ -398,6 +457,7 @@ def test_tile_under_sharded_local_attention(rng, what):
 
 TILE = {"ddl_flash_tile_fwd", "ddl_flash_tile_bwd"}
 BLOCK = {"ddl_flash_fwd", "ddl_flash_bwd_dq", "ddl_flash_bwd_dkv"}
+SWA = {"ddl_flash_swa_fwd", "ddl_flash_swa_bwd_dq", "ddl_flash_swa_bwd_dkv"}
 
 
 def _grad_of(attn):
@@ -414,7 +474,8 @@ def _grad_of(attn):
     ("d16", BLOCK), ("d80", BLOCK), ("d96", BLOCK), ("d192", BLOCK),
     ("float16", BLOCK), ("t4096", BLOCK), ("t513", BLOCK), ("segment_ids", BLOCK),
     ("gqa", BLOCK), ("explicit_blocks", BLOCK), ("with_lse", BLOCK),
-    ("with_lse_offsets", BLOCK),
+    ("with_lse_offsets", BLOCK), ("window", SWA), ("window_one_block", SWA),
+    ("window_covers_t4096", BLOCK), ("window_covers_one_block", TILE),
 ])
 def test_which_kernels_a_call_lowers_to(case, want):
     """The rule is shapes and arguments: the kernels' names in the program
@@ -469,6 +530,22 @@ def test_which_kernels_a_call_lowers_to(case, want):
         "with_lse_offsets": (
             lambda q, k, v: flash_attention_with_lse(
                 q, k, v, q_offset=196, k_offset=0, **kw)[0],
+            args(2, 196, 12, 64)),
+        # A sliding window narrower than the row: kernels of their own
+        # names, in the one-block geometry too (the tile kernels have no
+        # band); one that covers the row is plain causal attention.
+        "window": (
+            lambda q, k, v: flash_attention(q, k, v, window=2048,
+                                            kv_repeat=2, **kw),
+            args(1, 8192, 2, 128, Hkv=1)),
+        "window_one_block": (
+            lambda q, k, v: flash_attention(q, k, v, window=64, **kw),
+            args(2, 196, 12, 64)),
+        "window_covers_t4096": (
+            lambda q, k, v: flash_attention(q, k, v, window=4096, **kw),
+            args(1, 4096, 2, 128)),
+        "window_covers_one_block": (
+            lambda q, k, v: flash_attention(q, k, v, window=196, **kw),
             args(2, 196, 12, 64)),
     }[case]
     assert _kernels(_grad_of(fn), *shapes) == want

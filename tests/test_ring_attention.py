@@ -149,3 +149,20 @@ class TestMaskedRowNumerics:
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-4
         )
+
+
+def test_the_sp_ring_refuses_a_sliding_window_by_name():
+    """A window on the ring is not the ring with a mask: the dispatcher
+    says so rather than ignoring the argument; off the ring it is taken."""
+    from ddl_tpu.parallel.ring_attention import attention
+
+    q, k, v = _qkv(jax.random.key(4))
+    mesh = make_mesh({"sp": 4}, jax.devices()[:4])
+    with pytest.raises(NotImplementedError, match="ring attention.*sliding window"):
+        attention(q, k, v, mesh=mesh, impl="dense", window=8)
+    want = attention_reference(q, k, v, window=8)
+    for on in (None, make_mesh({"dp": 2}, jax.devices()[:2])):
+        np.testing.assert_allclose(
+            np.asarray(attention(q, k, v, mesh=on, impl="dense", window=8)),
+            np.asarray(want), rtol=2e-5, atol=2e-5,
+        )

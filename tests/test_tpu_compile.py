@@ -105,6 +105,29 @@ def test_flash_attention_compiles(v5e, grad, packed):
     )
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+def test_windowed_flash_attention_compiles_at_trinity_minis_geometry(v5e, grad):
+    """2 x 8192 tokens, 32 query / 4 kv heads x 128, window 2048, bf16:
+    the band's kernels under names of their own, beside the causal-full
+    ones of the model's full_attention layer."""
+    one = SingleDeviceSharding(v5e[0])
+    q = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((2, 8192, 4, 128), jnp.bfloat16, sharding=one)
+
+    def both(q, k, v):
+        banded = flash_attention(q, k, v, kv_repeat=8, interpret=False, window=2048)
+        full = flash_attention(q, k, v, kv_repeat=8, interpret=False)
+        return (banded + full).astype(jnp.float32).sum()
+
+    fn = jax.grad(both, argnums=(0, 1, 2)) if grad else both
+    text = jax.jit(fn).lower(q, kv, kv).compile().as_text()
+    want = {"ddl_flash_fwd", "ddl_flash_swa_fwd"}
+    if grad:
+        want |= {"ddl_flash_bwd_dq", "ddl_flash_bwd_dkv",
+                 "ddl_flash_swa_bwd_dq", "ddl_flash_swa_bwd_dkv"}
+    assert kernel_names(text) == want and want <= set(KERNEL_NAMES)
+
+
 def test_flash_names_survive_remat_and_shard_map(v5e):
     """What used to rename the kernels: ``jax.checkpoint`` (``checkpoint``,
     ``rematted_computation``), autodiff (``jvp__``, ``transpose_jvp___``)
