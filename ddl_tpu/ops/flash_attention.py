@@ -28,6 +28,9 @@ VMEM):
   masked future costs DMAs but no FLOPs.
 - K/V stay compact under grouped-query attention — the head index map
   divides by ``kv_repeat``.
+- A sliding ``window`` (offsets (0, 0) only) is the same three kernels on
+  grids whose innermost axis covers the band's blocks alone
+  (``band_grid``), under the names ``ddl_flash_swa_*``.
 
 The public wrappers pad ragged sequence lengths to the block size (padded
 keys are masked out, padded query rows sliced off) and fall back to
@@ -45,7 +48,7 @@ rows, GQA and every longer sequence stay here.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -111,6 +114,85 @@ def _crosses_diag(offs_ref, i, j, block_q, block_k, causal, window=None):
     )
 
 
+class BandGrid(NamedTuple):
+    """Static shape of the windowed kernels' grids for one row (see
+    :func:`band_grid`)."""
+
+    nqb: int  # query blocks a row
+    nkb: int  # key blocks a row
+    nk: int  # inner steps of fwd / dq: key blocks a query block
+    nq: int  # inner steps of dkv: query blocks a key block
+    live: int  # blocks a head that hold a visible pair
+
+    @property
+    def steps(self) -> int:
+        """Executed grid steps a head, forward (dq alike)."""
+        return self.nqb * self.nk
+
+    @property
+    def steps_dkv(self) -> int:
+        return self.nkb * self.nq
+
+
+def band_grid(T: int, window: int, block_q: int, block_k: int) -> BandGrid:
+    """The band's extent in blocks for a row of ``T`` tokens at offsets
+    (0, 0) — all static, so the windowed kernels' inner grid axis runs
+    over the band's blocks and not over the whole row.
+
+    Query block ``i`` sees keys ``i*block_q - window + 1 ..
+    (i+1)*block_q - 1`` and key block ``j`` is seen by queries
+    ``j*block_k .. (j+1)*block_k + window - 2``; ``nk`` / ``nq`` are the
+    most blocks either span covers over the row, every block inside a span
+    holds a visible pair, and ``live`` counts them.  At Trinity-Mini's
+    8192 / 2048 / 1024 / 1024: 8 x 3 = 24 steps a head, 21 live (64 steps
+    before the grid followed the band)."""
+    nqb, nkb = -(-T // block_q), -(-T // block_k)
+    k_spans = [
+        (max(0, (i * block_q - window + 1) // block_k),
+         min(nkb - 1, ((i + 1) * block_q - 1) // block_k))
+        for i in range(nqb)
+    ]
+    q_spans = [
+        ((j * block_k) // block_q,
+         min(nqb - 1, ((j + 1) * block_k + window - 2) // block_q))
+        for j in range(nkb)
+    ]
+    return BandGrid(
+        nqb, nkb,
+        nk=max(hi - lo + 1 for lo, hi in k_spans),
+        nq=max(hi - lo + 1 for lo, hi in q_spans),
+        live=sum(hi - lo + 1 for lo, hi in k_spans),
+    )
+
+
+def _band_k_block(i, jj, block_q, block_k, band: BandGrid):
+    """Key block of inner step ``jj`` of query block ``i`` (fwd, dq): the
+    ``nk`` blocks that end on the diagonal's.  Below 0 at the row's head:
+    a dead step, whose index map clamps to block 0 — the neighbour's, so
+    nothing is fetched for it."""
+    top = jnp.minimum(((i + 1) * block_q - 1) // block_k, band.nkb - 1)
+    return top - (band.nk - 1) + jj
+
+
+def _band_q_block(j, ii, block_q, block_k):
+    """Query block of inner step ``ii`` of key block ``j`` (dkv): the
+    ``nq`` blocks from the diagonal's on.  Past ``nqb - 1`` at the row's
+    tail: dead, clamped to the last block."""
+    return (j * block_k) // block_q + ii
+
+
+def _k_block_of(block_q, block_k, band: BandGrid):
+    """(query block, inner step) -> key block to fetch, for index maps."""
+    return lambda i, jj: jnp.maximum(
+        _band_k_block(i, jj, block_q, block_k, band), 0)
+
+
+def _q_block_of(block_q, block_k, band: BandGrid):
+    """(key block, inner step) -> query block to fetch, for index maps."""
+    return lambda j, ii: jnp.minimum(
+        _band_q_block(j, ii, block_q, block_k), band.nqb - 1)
+
+
 def _seg_invalid(seg):
     """(bq, bk) True where query and key belong to different packed
     segments.  ``seg`` is the (seg_q_ref, seg_k_ref) pair of (1,1,b,1)
@@ -123,11 +205,14 @@ def _seg_invalid(seg):
 def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_ref, l_ref, acc_ref, *, scale: float, causal: bool,
                 block_q: int, block_k: int, kv_len: int, precision,
-                seg=None, window=None):
+                seg=None, window=None, band=None):
     i = pl.program_id(2)  # Q block
-    j = pl.program_id(3)  # KV block (innermost, sequential)
+    jj = pl.program_id(3)  # inner step (sequential): the KV block ...
+    j = jj  # ... itself, or with a band its place among the band's blocks
+    if band is not None:
+        j = _band_k_block(i, jj, block_q, block_k, band)
 
-    @pl.when(j == 0)
+    @pl.when(jj == 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
@@ -168,6 +253,8 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     # interior blocks fully below the diagonal with no padded keys skip
     # mask construction entirely, the dominant VPU cost after exp.
     live = _live(offs_ref, i, j, block_q, block_k, causal, window)
+    if band is not None:
+        live = live & (j >= 0)  # a step before the row's head
     needs_mask = (
         _crosses_diag(offs_ref, i, j, block_q, block_k, causal, window)
         | ((j + 1) * block_k > kv_len)
@@ -193,7 +280,7 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     def _attend_fast():
         _update(_scores())
 
-    @pl.when(j == pl.num_programs(3) - 1)
+    @pl.when(jj == pl.num_programs(3) - 1)
     def _finish():
         m = jnp.max(m_ref[:], axis=-1)
         l = jnp.max(l_ref[:], axis=-1)
@@ -219,13 +306,16 @@ def _block_scores(q_ref, k_ref, scale, precision):
 
 def _bwd_p_dispatch(offs_ref, q_ref, k_ref, lse_ref, i, j, accum, *,
                     scale, causal, block_q, block_k, seq_len, kv_len,
-                    precision, seg=None, window=None):
+                    precision, seg=None, window=None, in_row=None):
     """Backward-pass block dispatch shared by the dQ and dK/dV kernels:
     dead blocks skipped, boundary blocks recompute p with full masking,
     interior blocks use the bare ``exp(s - lse)`` fast path (statement-
     level ``pl.when`` — real branches, unlike a value-level cond which
-    Mosaic computes on both sides)."""
+    Mosaic computes on both sides).  ``in_row`` (windowed grids): False
+    on a step whose block lies before the row's head or past its tail."""
     live = _live(offs_ref, i, j, block_q, block_k, causal, window)
+    if in_row is not None:
+        live = live & in_row
     needs_mask = _needs_mask_bwd(
         offs_ref, i, j, block_q, block_k, causal, seq_len, kv_len, window
     )
@@ -281,11 +371,15 @@ def _needs_mask_bwd(offs_ref, i, j, block_q, block_k, causal, seq_len,
 def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                dlse_ref, dq_ref, dq_acc, *, scale: float, causal: bool,
                block_q: int, block_k: int, seq_len: int, kv_len: int,
-               precision, seg=None, window=None):
+               precision, seg=None, window=None, band=None):
     i = pl.program_id(2)  # Q block
-    j = pl.program_id(3)  # KV block (innermost, sequential)
+    jj = pl.program_id(3)  # inner step (sequential): the KV block, or ...
+    j, in_row = jj, None
+    if band is not None:  # ... its place among the band's blocks
+        j = _band_k_block(i, jj, block_q, block_k, band)
+        in_row = j >= 0
 
-    @pl.when(j == 0)
+    @pl.when(jj == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
@@ -307,9 +401,10 @@ def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         offs_ref, q_ref, k_ref, lse_ref, i, j, _accum, scale=scale,
         causal=causal, block_q=block_q, block_k=block_k, seq_len=seq_len,
         kv_len=kv_len, precision=precision, seg=seg, window=window,
+        in_row=in_row,
     )
 
-    @pl.when(j == pl.num_programs(3) - 1)
+    @pl.when(jj == pl.num_programs(3) - 1)
     def _finish():
         dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
 
@@ -317,11 +412,15 @@ def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dlse_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
                 causal: bool, block_q: int, block_k: int, seq_len: int,
-                kv_len: int, precision, seg=None, window=None):
+                kv_len: int, precision, seg=None, window=None, band=None):
     j = pl.program_id(2)  # KV block
-    i = pl.program_id(3)  # Q block (innermost, sequential)
+    ii = pl.program_id(3)  # inner step (sequential): the Q block, or ...
+    i, in_row = ii, None
+    if band is not None:  # ... its place among the band's blocks
+        i = _band_q_block(j, ii, block_q, block_k)
+        in_row = i < band.nqb
 
-    @pl.when(i == 0)
+    @pl.when(ii == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -348,9 +447,10 @@ def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         offs_ref, q_ref, k_ref, lse_ref, i, j, _accum, scale=scale,
         causal=causal, block_q=block_q, block_k=block_k, seq_len=seq_len,
         kv_len=kv_len, precision=precision, seg=seg, window=window,
+        in_row=in_row,
     )
 
-    @pl.when(i == pl.num_programs(3) - 1)
+    @pl.when(ii == pl.num_programs(3) - 1)
     def _finish():
         dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
@@ -468,12 +568,17 @@ def _fwd_impl(q, k, v, offsets, causal, kv_repeat, block_q, block_k,
         scale=1.0 / (D**0.5), causal=causal, block_q=block_q,
         block_k=block_k, kv_len=Tkv, precision=precision, window=window,
     )
+    inner, k_block = Tk // block_k, lambda i, j: j
+    if window is not None:
+        band = common["band"] = band_grid(T, window, block_q, block_k)
+        inner, k_block = band.nk, _k_block_of(block_q, block_k, band)
     kernel = functools.partial(
         _fwd_kernel_seg if packed else _fwd_kernel, **common
     )
     kv_spec = pl.BlockSpec(
         (1, 1, block_k, D),
-        lambda b, h, i, j, *_refs, rep=kv_repeat: (b, h // rep, j, 0),
+        lambda b, h, i, j, *_refs, rep=kv_repeat: (
+            b, h // rep, k_block(i, j), 0),
     )
     q_spec = pl.BlockSpec(
         (1, 1, block_q, D), lambda b, h, i, j, *_refs: (b, h, i, 0)
@@ -489,7 +594,7 @@ def _fwd_impl(q, k, v, offsets, causal, kv_repeat, block_q, block_k,
         inputs += [_prep_seg(seg_q, Tq), _prep_seg(seg_k, Tk)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, H, Tq // block_q, Tk // block_k),
+        grid=(B, H, Tq // block_q, inner),
         in_specs=in_specs,
         out_specs=[q_spec, row_spec],
         scratch_shapes=[
@@ -548,12 +653,21 @@ def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts,
         block_k=block_k, seq_len=T, kv_len=Tkv, precision=precision,
         window=window,
     )
+    # Inner grid sizes and the blocks their steps fetch: the whole row, or
+    # with a window the band's blocks only (``band_grid``).
+    inner_k, k_block = Tk // block_k, lambda i, j: j
+    inner_q, q_block = Tq // block_q, lambda j, i: i
+    if window is not None:
+        band = common["band"] = band_grid(T, window, block_q, block_k)
+        inner_k, k_block = band.nk, _k_block_of(block_q, block_k, band)
+        inner_q, q_block = band.nq, _q_block_of(block_q, block_k, band)
     q_spec = pl.BlockSpec(
         (1, 1, block_q, D), lambda b, h, i, j, *_refs: (b, h, i, 0)
     )
     kv_spec = pl.BlockSpec(
         (1, 1, block_k, D),
-        lambda b, h, i, j, *_refs, rep=kv_repeat: (b, h // rep, j, 0),
+        lambda b, h, i, j, *_refs, rep=kv_repeat: (
+            b, h // rep, k_block(i, j), 0),
     )
     row_spec = pl.BlockSpec(
         (1, 1, block_q, 1), lambda b, h, i, j, *_refs: (b, h, i, 0)
@@ -571,7 +685,7 @@ def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts,
                           **common),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, H, Tq // block_q, Tk // block_k),
+            grid=(B, H, Tq // block_q, inner_k),
             in_specs=dq_in_specs,
             out_specs=q_spec,
             scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
@@ -582,14 +696,16 @@ def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts,
 
     # dK/dV: grid transposed so the Q axis is innermost (sequential).
     q_spec_t = pl.BlockSpec(
-        (1, 1, block_q, D), lambda b, h, j, i, *_refs: (b, h, i, 0)
+        (1, 1, block_q, D),
+        lambda b, h, j, i, *_refs: (b, h, q_block(j, i), 0),
     )
     kv_spec_t = pl.BlockSpec(
         (1, 1, block_k, D),
         lambda b, h, j, i, *_refs, rep=kv_repeat: (b, h // rep, j, 0),
     )
     row_spec_t = pl.BlockSpec(
-        (1, 1, block_q, 1), lambda b, h, j, i, *_refs: (b, h, i, 0)
+        (1, 1, block_q, 1),
+        lambda b, h, j, i, *_refs: (b, h, q_block(j, i), 0),
     )
     out_kv_t = pl.BlockSpec(
         (1, 1, block_k, D), lambda b, h, j, i, *_refs: (b, h, j, 0)
@@ -607,7 +723,7 @@ def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts,
                           **common),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, H, Tk // block_k, Tq // block_q),
+            grid=(B, H, Tk // block_k, inner_q),
             in_specs=dkv_in_specs,
             out_specs=[out_kv_t, out_kv_t],
             scratch_shapes=[
@@ -700,7 +816,9 @@ _flash_core_seg.defvjp(_vjp_fwd_seg, _bwd_impl_seg)
 
 
 # Sliding-window core: the same kernels with the band's lower edge
-# (``window`` static: key j is visible to query i iff 0 <= i - j < window).
+# (``window`` static: key j is visible to query i iff 0 <= i - j < window)
+# on grids whose inner axis runs over the band's blocks only
+# (``band_grid``; offsets are (0, 0) here, so the band's extent is static).
 # A custom_vjp of its own, like the packed one, so that the causal-full
 # core's signature, jaxpr and lowered program stay what they were.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
@@ -733,17 +851,33 @@ def _bwd_impl_win(kv_repeat, block_q, block_k, interpret, window, res, cts):
 _flash_core_win.defvjp(_vjp_fwd_win, _bwd_impl_win)
 
 
-def _default_blocks(T: int, block_q, block_k):
+_FINE_BAND = 2048  # widest window whose band runs in 512 x 512 blocks
+
+
+def _default_blocks(T: int, block_q, block_k, window=None):
     """v5e-tuned defaults, sequence-length adaptive (measured fwd+bwd at
     B=4, H=16, D=128: bq=512 wins at T<=2k, bq=1024 wins at 4k/8k by
     ~10%).  Both directions compile within v5e's VMEM budget — the
     backward reuses the forward's resolved blocks.  On smaller-VMEM
     generations pass smaller blocks explicitly if Mosaic reports VMEM
-    exhaustion."""
+    exhaustion.
+
+    With a ``window`` (narrower than the row) of at most 2048 both
+    blocks are 512: a band executes whole blocks, so at window 2048 the
+    1024-blocks' 3 key blocks a query block hold 67% useful pairs and the
+    512-blocks' 5 hold 80%, which outweighs what a smaller block loses.
+    Measured on the banded grids at 2 x 8192 x 32/4 heads x 128, bf16, ms
+    a sliding layer under selective remat (2 fwd + dq + dkv;
+    ``tools/probe_flash_band.py``, PERF.md §6, PR 31): window 2048 —
+    1024 x 1024 29.83, **512 x 512 27.71**, 512 x 1024 30.95, 1024 x 512
+    32.61, 256 x 512 33.86, 256 x 1024 36.06, 512 x 256 41.58; window
+    1024 — 23.01, **18.95**, 256 x 256 32.55; window 4096 — **40.54**
+    against 41.55, so wider windows keep the row's defaults."""
+    fine = window is not None and window <= _FINE_BAND
     if block_q is None:
-        block_q = 512 if T <= 2048 else 1024
+        block_q = 512 if T <= 2048 or fine else 1024
     if block_k is None:
-        block_k = 1024
+        block_k = 512 if fine else 1024
     return block_q, block_k
 
 
@@ -774,23 +908,34 @@ def flash_attention(
     masked path, so packing trades the interior-block fast path for the
     mask; unpacked calls are entirely unaffected.
 
-    ``window`` (static int, causal only): sliding-window attention — key
-    ``j`` is visible to query ``i`` iff ``0 <= i - j < window``.  Blocks
-    wholly older than the band skip their matmuls as blocks above the
-    diagonal do (they still cost their grid step and their DMAs), blocks
-    wholly inside it keep the unmasked fast path, and the kernels are
-    named ``ddl_flash_swa_*`` on the trace.  A window that covers the
-    sequence is plain causal attention and runs as such; ``None`` leaves
-    every program exactly what it was before windows existed.
+    ``window`` (static int, causal only, k/v of q's length): sliding-window
+    attention — key ``j`` is visible to query ``i`` iff
+    ``0 <= i - j < window``.  The kernels' inner grid axis runs over the
+    most blocks the band covers, not over the row (``band_grid``: 5 key
+    blocks a query block where the row has 16, at T = 8192, window 2048
+    and the windowed default of 512 x 512), so the blocks outside it cost
+    neither a grid step nor a DMA.  A step that falls before the row's
+    head or past its tail skips its matmuls and refetches nothing, one
+    just below a narrower stretch of the band skips its matmuls as blocks
+    above the diagonal do, blocks wholly inside the band keep the unmasked
+    fast path, and the kernels are named ``ddl_flash_swa_*`` on the
+    trace.  A window that covers the sequence is plain causal attention
+    and runs as such; ``None`` leaves every program exactly what it was
+    before windows existed.
     """
-    block_q, block_k = _default_blocks(q.shape[1], block_q, block_k)
     if window is not None:
         if not causal or window < 1:
             raise ValueError(
                 f"window={window!r} needs causal attention and window >= 1"
             )
+        if k.shape[1] != q.shape[1]:
+            raise ValueError(
+                f"window={window!r} is self-attention: {k.shape[1]} keys "
+                f"for {q.shape[1]} queries"
+            )
         if window >= q.shape[1]:
             window = None  # the band holds every causal pair
+    block_q, block_k = _default_blocks(q.shape[1], block_q, block_k, window)
     if flash_tile.fits(q, k, v, kv_repeat, block_q, block_k, segment_ids,
                        window=window):
         return flash_tile.tile_attention(q, k, v, causal, interpret)

@@ -272,21 +272,30 @@ class TestRingFlash:
 # -- a sliding window in the blockwise kernels -----------------------------------
 
 
-@pytest.mark.parametrize("gqa", [1, 2], ids=["mha", "gqa"])
-@pytest.mark.parametrize("T,window", [
-    (512, 200),  # the band's edge cuts inside a 128-block
-    (512, 256),  # ... lies on a block edge
-    (512, 1000),  # ... is wider than the row: plain causal
-    (400, 150),  # the row is off the block size
-    (384, 1),  # every query sees itself alone
+@pytest.mark.parametrize("gqa", [1, 2, 8], ids=["mha", "gqa", "gqa8"])
+@pytest.mark.parametrize("T,window,block_q,block_k", [
+    (512, 200, 128, 128),  # the band's edge cuts inside a 128-block
+    (512, 256, 128, 128),  # ... lies on a block edge: two blocks exactly
+    (512, 128, 128, 128),  # ... one block exactly
+    (512, 50, 128, 128),  # ... is narrower than one block
+    (512, 1000, 128, 128),  # ... is wider than the row: plain causal
+    (400, 150, 128, 128),  # the row is off the block size
+    (384, 1, 128, 128),  # every query sees itself alone
+    (512, 200, 64, 128),  # query blocks narrower than key blocks
+    (512, 200, 128, 64),  # ... and wider
+    (400, 150, 64, 128),  # off the block size: 448 queries, 512 keys
+    (400, 150, 128, 64),  # ... 512 queries, 448 keys
+    (330, 129, 128, 64),  # the window one past a block edge, 46 pad rows
 ], ids=lambda v: str(v))
-def test_windowed_flash_matches_dense_forward_and_gradients(rng, T, window, gqa):
-    q, k, v = _qkv(rng, B=1, T=T, H=2, Hkv=2 // gqa, D=32)
+def test_windowed_flash_matches_dense_forward_and_gradients(
+        rng, T, window, block_q, block_k, gqa):
+    H = max(2, gqa)
+    q, k, v = _qkv(rng, B=1, T=T, H=H, Hkv=H // gqa, D=32)
     do = jnp.asarray(rng.standard_normal(q.shape), jnp.float32)
     kw = dict(kv_repeat=gqa, window=window)
 
     def flash(q, k, v):
-        return flash_attention(q, k, v, block_q=128, block_k=128, **kw)
+        return flash_attention(q, k, v, block_q=block_q, block_k=block_k, **kw)
 
     def dense(q, k, v):
         return attention_reference(q, k, v, **kw)
@@ -296,6 +305,110 @@ def test_windowed_flash_matches_dense_forward_and_gradients(rng, T, window, gqa)
     want = jax.grad(lambda *a: jnp.sum(dense(*a) * do), argnums=(0, 1, 2))(q, k, v)
     for g, w in zip(got, want):
         np.testing.assert_allclose(g, w, atol=5e-5, rtol=5e-5)
+
+
+def _blocks_with_a_visible_pair(T, window, block_q, block_k):
+    """(query blocks, key blocks) bool: brute force over the padded mask."""
+    nqb, nkb = -(-T // block_q), -(-T // block_k)
+    i, j = np.arange(T)[:, None], np.arange(T)[None, :]
+    mask = np.zeros((nqb * block_q, nkb * block_k), bool)
+    mask[:T, :T] = (j <= i) & (i - j < window)
+    return mask.reshape(nqb, block_q, nkb, block_k).any(axis=(1, 3))
+
+
+@pytest.mark.parametrize("T,window,block_q,block_k,steps,steps_dkv,live", [
+    (8192, 2048, 1024, 1024, 24, 24, 21),  # Trinity-Mini's, at the old
+    # blocks: 64 steps a head before the grid followed the band
+    (8192, 2048, 512, 512, 80, 80, 70),  # ... at the windowed default
+    (8192, 2048, 512, 1024, 48, 48, 42),
+    (8192, 2048, 1024, 512, 48, 48, 42),
+    (8192, 4096, 1024, 1024, 40, 40, 30),
+    (4096, 2048, 512, 512, 40, 40, 30),
+    (512, 200, 128, 128, 12, 12, 9),
+    (512, 256, 128, 128, 12, 12, 9),  # one key past two blocks: three
+    (512, 128, 128, 128, 8, 8, 7),
+    (512, 50, 128, 128, 8, 8, 7),
+    (384, 1, 128, 128, 3, 3, 3),
+    (400, 150, 64, 128, 21, 20, 14),
+    (400, 150, 128, 64, 20, 21, 15),
+    (330, 129, 128, 64, 12, 12, 10),
+    (512, 511, 128, 128, 16, 16, 10),  # all but one pair: the whole triangle
+], ids=lambda v: str(v))
+def test_the_band_grid_is_a_brute_force_count_of_blocks(
+        T, window, block_q, block_k, steps, steps_dkv, live):
+    """The static counter: executed steps a head and the live ones, and
+    every live block visited exactly once by both grids' index maps."""
+    from ddl_tpu.ops.flash_attention import (
+        _band_k_block, _band_q_block, band_grid,
+    )
+
+    want = _blocks_with_a_visible_pair(T, window, block_q, block_k)
+    g = band_grid(T, window, block_q, block_k)
+    assert (g.nqb, g.nkb) == want.shape
+    assert g.live == want.sum() == live
+    assert g.nk == want.sum(axis=1).max() and g.nq == want.sum(axis=0).max()
+    assert (g.steps, g.steps_dkv) == (steps, steps_dkv)
+    assert max(steps, steps_dkv) <= g.nqb * g.nkb
+    for i in range(g.nqb):  # fwd, dq: key blocks of query block i
+        js = [int(_band_k_block(i, jj, block_q, block_k, g))
+              for jj in range(g.nk)]
+        assert js == list(range(js[0], js[0] + g.nk)) and js[-1] < g.nkb
+        assert set(np.flatnonzero(want[i])) <= {j for j in js if j >= 0}
+    for j in range(g.nkb):  # dkv: query blocks of key block j
+        qs = [_band_q_block(j, ii, block_q, block_k) for ii in range(g.nq)]
+        assert set(np.flatnonzero(want[:, j])) <= {i for i in qs if i < g.nqb}
+
+
+@pytest.mark.parametrize("block_q,block_k", [(128, 128), (64, 128), (128, 64)],
+                         ids=lambda v: str(v))
+@pytest.mark.parametrize("pick", [0, 3, 4])  # the head, inside, the tail
+def test_blocks_outside_the_band_are_never_attended(rng, pick, block_q, block_k):
+    """NaN in every key/value block that holds no pair visible to query
+    block ``pick`` leaves that block's output and dq as they were, and NaN
+    in every query block that sees nothing of key block ``pick`` leaves its
+    dk and dv as they were: whatever a clamped or a dead step fetches, it
+    attends nothing."""
+    T, window = 640, 200
+    q, k, v = _qkv(rng, B=1, T=T, H=1, D=32)
+    do = jnp.asarray(rng.standard_normal(q.shape), jnp.float32)
+    live = _blocks_with_a_visible_pair(T, window, block_q, block_k)
+
+    def run(q, k, v, do):
+        out, vjp = jax.vjp(
+            lambda *a: flash_attention(*a, window=window, block_q=block_q,
+                                       block_k=block_k), q, k, v)
+        return (out, *vjp(do))
+
+    def poison(x, block, keep):
+        dead = np.repeat(~keep, block)[:T]
+        assert dead.any()
+        return jnp.where(dead[None, :, None, None], jnp.nan, x)
+
+    clean = run(q, k, v, do)
+    rows = slice(pick * block_q, (pick + 1) * block_q)
+    got = run(q, poison(k, block_k, live[pick]), poison(v, block_k, live[pick]), do)
+    for a, b in zip(got[:2], clean[:2]):  # the output, dq
+        np.testing.assert_array_equal(np.asarray(a)[:, rows], np.asarray(b)[:, rows])
+    cols = slice(pick * block_k, (pick + 1) * block_k)
+    got = run(poison(q, block_q, live[:, pick]), k, v,
+              poison(do, block_q, live[:, pick]))
+    for a, b in zip(got[2:], clean[2:]):  # dk, dv
+        np.testing.assert_array_equal(np.asarray(a)[:, cols], np.asarray(b)[:, cols])
+
+
+@pytest.mark.parametrize("T,window,given,want", [
+    (8192, 2048, (None, None), (512, 512)),  # Trinity-Mini's sliding layers
+    (8192, 1024, (None, None), (512, 512)),
+    (8192, 4096, (None, None), (1024, 1024)),  # a wide band: the row's
+    (8192, None, (None, None), (1024, 1024)),  # no window: as before
+    (2048, None, (None, None), (512, 1024)),
+    (8192, 2048, (1024, None), (1024, 512)),  # what is given is kept
+    (8192, 2048, (256, 128), (256, 128)),
+], ids=lambda v: str(v))
+def test_the_windowed_default_blocks(T, window, given, want):
+    from ddl_tpu.ops.flash_attention import _default_blocks
+
+    assert _default_blocks(T, *given, window) == want
 
 
 def test_the_window_is_a_band_and_not_the_whole_causal_triangle(rng):
@@ -317,6 +430,8 @@ def test_a_window_needs_causal_attention_and_refuses_packed_rows(rng):
         flash_attention(q, k, v, causal=False, window=64)
     with pytest.raises(NotImplementedError, match="packed rows"):
         flash_attention(q, k, v, window=64, segment_ids=jnp.zeros((1, 256), jnp.int32))
+    with pytest.raises(ValueError, match="self-attention"):  # the band is static
+        flash_attention(q, k[:, :128], v[:, :128], window=64)
 
 
 def test_the_one_block_kernels_refuse_a_window_narrower_than_the_row(rng):

@@ -46,6 +46,27 @@ def kernel_names(compiled_text):
     return names
 
 
+def mosaic_grids(lowered_text):
+    """{grid: count} over the Mosaic kernels of a lowered program: each
+    ``tpu_custom_call`` carries its kernel as MLIR bytecode, whose
+    ``iteration_bounds`` is the grid the chip runs."""
+    import base64
+    import collections
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    grids = collections.Counter()
+    for body in re.findall(r'\\22body\\22: \\22([^\\]*)\\22', lowered_text):
+        ctx = mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True  # Mosaic's, versioned
+        with ctx:
+            module = str(ir.Module.parse(base64.b64decode(body)))
+        bounds, = re.findall(r"iteration_bounds = array<i64: ([\d, ]+)>", module)
+        grids[tuple(int(n) for n in bounds.split(","))] += 1
+    return dict(grids)
+
+
 @pytest.fixture(scope="module")
 def v5e():
     from jax.experimental import topologies
@@ -120,12 +141,23 @@ def test_windowed_flash_attention_compiles_at_trinity_minis_geometry(v5e, grad):
         return (banded + full).astype(jnp.float32).sum()
 
     fn = jax.grad(both, argnums=(0, 1, 2)) if grad else both
-    text = jax.jit(fn).lower(q, kv, kv).compile().as_text()
+    lowered = jax.jit(fn).lower(q, kv, kv)
+    text = lowered.compile().as_text()
     want = {"ddl_flash_fwd", "ddl_flash_swa_fwd"}
     if grad:
         want |= {"ddl_flash_bwd_dq", "ddl_flash_bwd_dkv",
                  "ddl_flash_swa_bwd_dq", "ddl_flash_swa_bwd_dkv"}
     assert kernel_names(text) == want and want <= set(KERNEL_NAMES)
+    # The banded kernels' inner grid axis runs over the band's blocks
+    # (5 of 16 at the windowed default of 512 x 512), the causal-full
+    # ones' over the whole row; a kernel a direction each.
+    band = importlib.import_module(
+        "ddl_tpu.ops.flash_attention").band_grid(8192, 2048, 512, 512)
+    assert (band.nqb, band.nk, band.nq, band.steps, band.live) == (16, 5, 5, 80, 70)
+    n = 3 if grad else 1
+    assert mosaic_grids(lowered.as_text()) == {
+        (2, 32, 16, 5): n, (2, 32, 8, 8): n,
+    }
 
 
 def test_flash_names_survive_remat_and_shard_map(v5e):
