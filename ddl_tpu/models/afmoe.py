@@ -28,7 +28,7 @@ chip's share of a layer whose experts are divided over chips.  The
 share computes what its own experts add for the tokens routed to them;
 what the others would have added is left out, and nothing stands in for
 them or for their exchange; nor does a share train its router
-(:func:`_moe_tokens` says why).  With the whole range it is the uncut layer.
+(``moe.sigmoid_expert_tokens`` says why).  With the whole range it is the uncut layer.
 
 Serving is not here: a sliding layer's cache is a ring of
 ``sliding_window`` entries and nothing measures one, so
@@ -265,72 +265,11 @@ def _attn_block(
     return x + _llama._rms_norm(out, layer["post_attn_norm"], eps)
 
 
-def _route(h: jax.Array, layer: Params, cfg: AfmoeConfig):
-    """The router on flat tokens ``h`` (N, D): (weights (N, k) float32,
-    expert ids (N, k)).  Scores leave their matmul in float32 (bf16
-    operands), as ``moe._router_topk``'s do."""
-    logits = jnp.dot(
-        h, layer["w_router"].astype(h.dtype),
-        preferred_element_type=jnp.float32,
-    )
-    scores = jax.nn.sigmoid(logits)
-    bias = jax.lax.stop_gradient(layer["expert_bias"])  # selection only
-    _, top_e = jax.lax.top_k(scores + bias, cfg.topk)
-    top_w = jnp.take_along_axis(scores, top_e, axis=-1)
-    if cfg.route_norm:
-        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
-    return top_w * cfg.route_scale, top_e
-
-
-def _moe_tokens(h: jax.Array, layer: Params, cfg: AfmoeConfig):
-    """Shared expert + the held routed experts on flat tokens (N, D):
-    (out (N, D), the router's picks (N, k))."""
-    with jax.named_scope("ddl.moe_route"):
-        top_w, top_e = _route(h, layer, cfg)
-    held = None if cfg.held == (0, cfg.n_experts) else cfg.held
-    if held is not None:
-        # A share cannot train its router: the absent experts add exactly
-        # nothing here, so this chip's part of the router's gradient says
-        # "send the tokens to them" - and the router obeys (on the chip,
-        # PR 30: the held sixteen's 12.5% of the choices is 0.1% after 26
-        # adamw steps, PERF.md section 6).  In the deployment the other
-        # chips' parts balance it.  So a share routes with its router where
-        # it stands, as it selects with expert_bias where it stands.
-        top_w = jax.lax.stop_gradient(top_w)
-    routed = _moe.ragged_experts(h, layer["experts"], top_w, top_e, held=held)
-    with jax.named_scope("ddl.moe_shared"):
-        shared = _llama._swiglu(layer["shared"], h)
-    return shared + routed, top_e
-
-
-def _moe_mlp(h: jax.Array, layer: Params, cfg: AfmoeConfig,
-             mesh: Optional[Any]):
-    """:func:`_moe_tokens` on the (B, T, D) stream.  On a ``dp`` mesh
-    each shard routes its own rows under ``shard_map``: routing is per
-    token and dropless, so local is global (``moe._routed_mlp``'s
-    argument); the weights cross replicated.  (An ``sp`` mesh never gets
-    here with a sliding layer in the stack: ``attention()`` refuses a
-    window over the ring by name.)"""
-    B, T, D = h.shape
-    names = getattr(mesh, "axis_names", ())
-    if not ("dp" in names and mesh.shape["dp"] > 1):
-        out, top_e = _moe_tokens(h.reshape(B * T, D), layer, cfg)
-        return out.reshape(B, T, D), top_e.reshape(B, T, -1)
-    from jax import shard_map
-
-    read = {k: layer[k] for k in ("w_router", "expert_bias", "shared", "experts")}
-
-    def body(hs: jax.Array, lyr: Params):
-        b, t, _ = hs.shape
-        out, top_e = _moe_tokens(hs.reshape(b * t, D), lyr, cfg)
-        return out.reshape(b, t, D), top_e.reshape(b, t, -1)
-
-    tokens = P("dp", None, None)
-    return shard_map(
-        body, mesh=mesh,
-        in_specs=(tokens, jax.tree.map(lambda _: P(), read)),
-        out_specs=(tokens, tokens), check_vma=False,
-    )(h, read)
+# The routed + shared expert layer is ``moe.sigmoid_expert_mlp``, the one
+# routine this family and ``models/deepseek_v3.py`` run (a share's router is
+# not trained: ``moe.sigmoid_expert_tokens`` says why).
+_moe_tokens = _moe.sigmoid_expert_tokens
+_moe_mlp = _moe.sigmoid_expert_mlp
 
 
 def _layer_apply(

@@ -1,0 +1,345 @@
+"""DeepSeek-V3-shaped decoder LM (``model_type: deepseek_v3`` — here at
+Kakao's Kanana-2-30B-A3B sizes): latent attention in front of
+sigmoid-routed experts.
+
+Every layer, with ``h = RMSNorm(x)`` and pre-norm residuals
+(``x + Attn(norm(x))``, ``x + MLP(norm(x))``):
+
+- latent attention (MLA), no query low-rank step (``q_lora_rank: null``):
+  ``q = h Wq``, per head ``[q_nope (qk_nope_dim) | q_rope (qk_rope_dim)]``;
+  ``[c | k_r] = h Wkv_a`` (``kv_lora_rank`` + ``qk_rope_dim``),
+  ``c = RMSNorm(c)``, ``[k_nope | v] = c Wkv_b`` per head; ``k_r`` is ONE
+  rotary key a position, shared by all heads.  RoPE on ``q_rope`` and
+  ``k_r`` only, on adjacent pairs ``(x[2i], x[2i+1])``
+  (``rope_interleave``): the pairs are de-interleaved and then rotated in
+  ``llama._rope``'s half-split form — q and k are permuted alike, so the
+  scores are the published ones on the published weight layout.
+  ``s = (q_nope . k_nope + q_rope . k_r) / sqrt(qk_nope_dim + qk_rope_dim)``,
+  causal softmax, ``o = softmax(s) v`` (``v_head_dim`` a head),
+  ``concat(o) Wo``.  The two products reach the attention dispatcher as
+  they are (``attention(q_rope=, k_rope=)``): on a TPU the
+  ``ddl_flash_mla_*`` kernels, which read the shared key through their
+  index map, so neither a 192-wide q/k nor the H-fold ``k_r`` is written.
+- MLP: a dense SwiGLU (the first ``n_dense_layers`` layers,
+  ``first_k_dense_replace``) or ``moe.sigmoid_expert_mlp`` — the routine
+  ``models/afmoe.py`` runs too: ``sc = sigmoid(h Wr)`` in float32, ``sel =
+  top_k(sc + expert_bias)`` (the bias in the selection only; ``n_group``
+  1, so no group limit), ``w = sc[sel] / (sum + 1e-20) * route_scale``,
+  ``sum_k w_k Expert_sel_k(h) + Shared(h)``, the shared experts one ungated
+  SwiGLU of ``n_shared_experts * d_expert``.
+
+What is llama's is llama's (``_rms_norm``, ``_rope``, ``_swiglu``,
+``_dense_init``, ``remat.tag_attn_out``); the experts are
+``moe.ragged_experts`` with the RANGE OF EXPERTS HELD HERE
+(``held_experts=(first, count)`` of the router's ``n_experts``): one
+chip's share of a layer divided over chips by experts, as in
+``models/afmoe.py`` and for its reasons — nothing stands in for the absent
+experts, a share does not train its router, and ``expert_bias`` stays at
+its initial zeros (``noaux_tc`` moves it outside the gradient; that
+update does not exist here).
+
+Serving is not here: a latent cache holds ``c`` and ``k_r`` (576 numbers a
+position, not 32 x 320) and decodes in the absorbed form (``Wkv_b`` folded
+into the query and the output); neither exists, so
+:func:`forward_with_cache` and :func:`generate` raise by name.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from ddl_tpu.models import llama as _llama
+from ddl_tpu.models import moe as _moe
+from ddl_tpu.models import remat as _remat
+
+Params = Dict[str, Any]
+
+
+@dataclasses.dataclass(frozen=True)
+class DeepseekV3Config:
+    vocab: int = 256
+    d_model: int = 64
+    n_layers: int = 3
+    n_heads: int = 4
+    #: The score's two widths and the value's, stated, not derived: 128 +
+    #: 64 and 128 in Kanana-2, over a hidden size of 2048 / 32 heads.
+    qk_nope_dim: int = 16
+    qk_rope_dim: int = 8
+    v_head_dim: int = 16
+    kv_lora_rank: int = 32
+    d_ff: int = 192  # the dense layers' SwiGLU width
+    d_expert: int = 32  # each routed expert's; the shared experts' unit
+    n_experts: int = 8  # the router's width, whatever is held here
+    topk: int = 2
+    n_shared_experts: int = 2
+    #: The leading layers whose MLP is dense; the rest route.
+    n_dense_layers: int = 1
+    route_norm: bool = True
+    route_scale: float = 1.0
+    #: ``(first, count)`` of the ``n_experts`` whose weights live here;
+    #: ``None`` is all of them.
+    held_experts: Optional[Tuple[int, int]] = None
+    max_seq: int = 512
+    rope_theta: float = 1e6
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    #: Remat policy, as :attr:`LlamaConfig.remat`.
+    remat: Any = False
+    attn_impl: str = "auto"
+
+    def __post_init__(self) -> None:
+        _remat.resolve(self.remat)  # fail on junk at config build time
+        if not 0 <= self.n_dense_layers <= self.n_layers or self.n_layers < 1:
+            raise ValueError("n_dense_layers outside the stack")
+        if self.qk_rope_dim % 2:
+            raise ValueError("qk_rope_dim is rotated in pairs: an even width")
+        first, count = self.held
+        if not (0 <= first and count >= 1 and first + count <= self.n_experts):
+            raise ValueError(
+                f"held_experts={self.held_experts} is not a range of the "
+                f"router's {self.n_experts}"
+            )
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first, count) of the experts held here."""
+        return self.held_experts or (0, self.n_experts)
+
+    def is_dense(self, layer: int) -> bool:
+        return layer < self.n_dense_layers
+
+    @staticmethod
+    def kanana_2_30b_a3b() -> "DeepseekV3Config":
+        """Kanana-2-30B-A3B (``kakaocorp/kanana-2-30b-a3b-instruct-2601``,
+        30B total / 3B active) at full depth with every expert held: 48
+        layers, 32 heads of 128 + 64 score and 128 value width over a
+        512-wide latent, one dense layer (SwiGLU 6144) then 128 routed
+        experts x 768, 6 per token, plus 2 shared; sigmoid scores,
+        normalised, x 2.448; vocabulary 128,256 untied; bf16 storage.  The
+        benchmark's configuration file builds the same config at its
+        published depth, experts and vocabulary (a test holds the two
+        together)."""
+        return DeepseekV3Config(
+            vocab=128256, d_model=2048, n_layers=48, n_heads=32,
+            qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128, kv_lora_rank=512,
+            d_ff=6144, d_expert=768, n_experts=128, topk=6,
+            n_shared_experts=2, n_dense_layers=1, route_norm=True,
+            route_scale=2.448, max_seq=32768, rope_theta=1e6, norm_eps=1e-6,
+            param_dtype=jnp.bfloat16,
+        )
+
+
+def init_params(cfg: DeepseekV3Config, key: jax.Array) -> Params:
+    """Seeded normal / sqrt(fan_in) matrices, norm weights 1,
+    ``expert_bias`` 0 (float32 whatever the storage dtype: it is compared
+    with float32 scores)."""
+    keys = iter(jax.random.split(key, 2 + cfg.n_layers * 11))
+    pdt = cfg.param_dtype
+
+    def dense(fan_in, shape):
+        return _llama._dense_init(next(keys), fan_in, shape, pdt)
+
+    def swiglu(d_in, width, lead=()):
+        return {
+            "w_gate": dense(d_in, lead + (d_in, width)),
+            "w_up": dense(d_in, lead + (d_in, width)),
+            "w_down": dense(width, lead + (width, d_in)),
+        }
+
+    d, H, rank = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    layers = []
+    for li in range(cfg.n_layers):
+        layer = {
+            "attn_norm": jnp.ones((d,), pdt),
+            "mlp_norm": jnp.ones((d,), pdt),
+            "wq": dense(d, (d, H * (cfg.qk_nope_dim + cfg.qk_rope_dim))),
+            "wkv_a": dense(d, (d, rank + cfg.qk_rope_dim)),
+            "kv_a_norm": jnp.ones((rank,), pdt),
+            "wkv_b": dense(rank, (rank, H * (cfg.qk_nope_dim + cfg.v_head_dim))),
+            "wo": dense(H * cfg.v_head_dim, (H * cfg.v_head_dim, d)),
+        }
+        if cfg.is_dense(li):
+            layer.update(swiglu(d, cfg.d_ff))
+        else:
+            layer.update(
+                w_router=dense(d, (d, cfg.n_experts)),
+                expert_bias=jnp.zeros((cfg.n_experts,), jnp.float32),
+                shared=swiglu(d, cfg.d_expert * cfg.n_shared_experts),
+                experts=swiglu(d, cfg.d_expert, lead=(cfg.held[1],)),
+            )
+        layers.append(layer)
+    return {
+        "embed": dense(d, (cfg.vocab, d)),
+        "layers": layers,
+        "final_norm": jnp.ones((d,), pdt),
+        "lm_head": dense(d, (d, cfg.vocab)),
+    }
+
+
+def param_specs(cfg: DeepseekV3Config) -> Params:
+    """PartitionSpecs mirroring :func:`init_params` (the Megatron fsdp x
+    tp layout: heads over ``tp`` in ``wq``, ``wkv_b`` and ``wo``; the
+    latent projection, shared by all heads, is not head-sharded; the held
+    experts' leading axis is this chip's own)."""
+    col, row = P("fsdp", "tp"), P("tp", "fsdp")
+    swiglu = {"w_gate": col, "w_up": col, "w_down": row}
+    layers = []
+    for li in range(cfg.n_layers):
+        layer = {
+            "attn_norm": P(None), "mlp_norm": P(None), "wq": col,
+            "wkv_a": P("fsdp", None), "kv_a_norm": P(None), "wkv_b": col,
+            "wo": row,
+        }
+        if cfg.is_dense(li):
+            layer.update(swiglu)
+        else:
+            layer.update(
+                w_router=P(None, None), expert_bias=P(None),
+                shared=dict(swiglu),
+                experts={
+                    "w_gate": P(None, "fsdp", "tp"),
+                    "w_up": P(None, "fsdp", "tp"),
+                    "w_down": P(None, "tp", "fsdp"),
+                },
+            )
+        layers.append(layer)
+    return {
+        "embed": P(None, "fsdp"),
+        "layers": layers,
+        "final_norm": P(None),
+        "lm_head": P("fsdp", "tp"),
+    }
+
+
+def _rope_pairs(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """RoPE on adjacent pairs ``(x[2i], x[2i+1])`` of the last axis
+    (``rope_interleave``); x: (B, T, H, R).  The pairs are de-interleaved —
+    evens first, then odds — and rotated in the half-split form; the result
+    stays de-interleaved, in q and in k alike, so their product is the
+    interleaved form's."""
+    x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    return _llama._rope(x, positions, theta)
+
+
+def _attn_block(
+    layer: Params,
+    x: jax.Array,
+    cfg: DeepseekV3Config,
+    positions: jax.Array,
+    mesh: Optional[Any],
+) -> jax.Array:
+    """Latent attention with a pre-norm residual."""
+    from ddl_tpu.parallel.ring_attention import attention
+
+    B, T = x.shape[:2]
+    dt = x.dtype
+    H, nope, rank = cfg.n_heads, cfg.qk_nope_dim, cfg.kv_lora_rank
+    h = _llama._rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+    with jax.named_scope("ddl.mla_q"):
+        q = (h @ layer["wq"].astype(dt)).reshape(B, T, H, -1)
+        q_nope = q[..., :nope]
+        q_rope = _rope_pairs(q[..., nope:], positions, cfg.rope_theta)
+    with jax.named_scope("ddl.mla_kv_up"):
+        kv_a = h @ layer["wkv_a"].astype(dt)  # (B, T, rank + rope)
+        c = _llama._rms_norm(kv_a[..., :rank], layer["kv_a_norm"], cfg.norm_eps)
+        kv = (c @ layer["wkv_b"].astype(dt)).reshape(B, T, H, -1)
+        k_nope, v = kv[..., :nope], kv[..., nope:]
+        # One rotary key a position, for all heads.
+        k_rope = _rope_pairs(kv_a[..., None, rank:], positions, cfg.rope_theta)
+    attn = attention(
+        q_nope, k_nope, v, mesh=mesh, impl=cfg.attn_impl, causal=True,
+        q_rope=q_rope, k_rope=k_rope,
+    )
+    attn = _remat.tag_attn_out(attn)  # saveable under remat="selective"
+    return x + attn.reshape(B, T, -1) @ layer["wo"].astype(dt)
+
+
+def _layer_apply(
+    layer: Params,
+    x: jax.Array,
+    cfg: DeepseekV3Config,
+    positions: jax.Array,
+    dense: bool,
+    mesh: Optional[Any],
+):
+    """One block → (x, the router's picks (B, T, topk), or ``None`` from a
+    dense layer)."""
+    x = _attn_block(layer, x, cfg, positions, mesh)
+    h = _llama._rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+    if dense:
+        return x + _llama._swiglu(layer, h), None
+    out, top_e = _moe.sigmoid_expert_mlp(h, layer, cfg, mesh)
+    return x + out, top_e
+
+
+def forward_with_choices(
+    params: Params,
+    tokens: jax.Array,
+    cfg: DeepseekV3Config,
+    mesh: Optional[Any] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """(logits (B, T, vocab) float32, the expert ids every expert layer's
+    router picked (L_expert, B, T, topk) — out of all ``n_experts``, held
+    here or not)."""
+    dt = cfg.dtype
+    positions = jnp.arange(tokens.shape[1])
+    x = params["embed"].astype(dt)[tokens]
+    picks = []
+    for li, layer in enumerate(params["layers"]):
+
+        def layer_fn(x, layer, dense=cfg.is_dense(li)):
+            return _layer_apply(layer, x, cfg, positions, dense, mesh)
+
+        x, top_e = _remat.wrap(layer_fn, cfg.remat)(x, layer)
+        if top_e is not None:
+            picks.append(top_e)
+    x = _llama._rms_norm(x, params["final_norm"], cfg.norm_eps)
+    logits = (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
+    return logits, jnp.stack(picks) if picks else jnp.zeros(
+        (0,) + tokens.shape + (cfg.topk,), jnp.int32
+    )
+
+
+def forward(
+    params: Params,
+    tokens: jax.Array,
+    cfg: DeepseekV3Config,
+    mesh: Optional[Any] = None,
+) -> jax.Array:
+    """Next-token logits, (B, T, vocab) float32."""
+    return forward_with_choices(params, tokens, cfg, mesh)[0]
+
+
+def next_token_loss(
+    params: Params,
+    tokens: jax.Array,
+    cfg: DeepseekV3Config,
+    mesh: Optional[Any] = None,
+) -> jax.Array:
+    """Mean next-token cross-entropy.  No auxiliary router loss
+    (``topk_method: noaux_tc`` balances by moving ``expert_bias``, not by
+    a term of the loss)."""
+    from ddl_tpu.models.losses import next_token_cross_entropy
+
+    return next_token_cross_entropy(forward(params, tokens, cfg, mesh), tokens)
+
+
+def forward_with_cache(*args: Any, **kwargs: Any):
+    raise NotImplementedError(
+        "deepseek_v3.forward_with_cache: a latent KV cache (the normalised "
+        "latent and the shared rotary key a position) and the absorbed "
+        "decode form do not exist yet"
+    )
+
+
+def generate(*args: Any, **kwargs: Any):
+    raise NotImplementedError(
+        "deepseek_v3.generate: serving needs the latent KV cache "
+        "(see forward_with_cache)"
+    )

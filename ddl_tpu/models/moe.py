@@ -13,8 +13,9 @@ as absent).  Two dispatches live here, chosen by what the mesh shows
   scoring AS GIVEN — (weights, expert ids) per token and slot — so each
   family brings its own: float32 softmax top-k, renormalised
   (``norm_topk_prob``, Mixtral) or raw (OLMoE), here
-  (:func:`_router_topk`); sigmoid + selection bias + normalise + scale in
-  ``models/afmoe.py``.  And it takes the RANGE OF EXPERTS IT HOLDS: told
+  (:func:`_router_topk`); sigmoid + selection bias + normalise + scale
+  with an ungated shared expert (:func:`sigmoid_expert_mlp`, the one
+  routine ``models/afmoe.py`` and ``models/deepseek_v3.py`` both run).  And it takes the RANGE OF EXPERTS IT HOLDS: told
   ``held=(first, count)`` of a wider router, it routes over all of them,
   computes the rows whose choice falls on a held expert and leaves the
   rest out — one chip's share of an expert-parallel layer, without the
@@ -469,6 +470,80 @@ def ragged_experts(
             per_slot = jnp.where(is_held.reshape(N, k, 1), per_slot, 0)
         out = jnp.einsum("nk,nkd->nd", top_w.astype(dt), per_slot)
     return out
+
+
+# -- sigmoid-routed experts with a shared expert (AFMoE, DeepSeek-V3) ----------
+#
+# One routine for every family that scores by sigmoid, selects under a bias,
+# normalises, scales, and adds an ungated shared expert.  ``cfg`` is the
+# family's own config; read here: ``topk``, ``route_norm``, ``route_scale``,
+# ``n_experts`` (the router's width) and ``held`` ((first, count) of them).
+
+
+def sigmoid_route(h: jax.Array, layer: Params, cfg: Any):
+    """The router on flat tokens ``h`` (N, D): (weights (N, k) float32,
+    expert ids (N, k)).  Scores leave their matmul in float32 (bf16
+    operands), as :func:`_router_topk`'s do."""
+    logits = jnp.dot(
+        h, layer["w_router"].astype(h.dtype),
+        preferred_element_type=jnp.float32,
+    )
+    scores = jax.nn.sigmoid(logits)
+    bias = jax.lax.stop_gradient(layer["expert_bias"])  # selection only
+    _, top_e = jax.lax.top_k(scores + bias, cfg.topk)
+    top_w = jnp.take_along_axis(scores, top_e, axis=-1)
+    if cfg.route_norm:
+        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+    return top_w * cfg.route_scale, top_e
+
+
+def sigmoid_expert_tokens(h: jax.Array, layer: Params, cfg: Any):
+    """Shared expert + the held routed experts on flat tokens (N, D):
+    (out (N, D), the router's picks (N, k))."""
+    with jax.named_scope("ddl.moe_route"):
+        top_w, top_e = sigmoid_route(h, layer, cfg)
+    held = None if cfg.held == (0, cfg.n_experts) else cfg.held
+    if held is not None:
+        # A share cannot train its router: the absent experts add exactly
+        # nothing here, so this chip's part of the router's gradient says
+        # "send the tokens to them" - and the router obeys (on the chip,
+        # PR 30: the held sixteen's 12.5% of the choices is 0.1% after 26
+        # adamw steps, PERF.md section 6).  In the deployment the other
+        # chips' parts balance it.  So a share routes with its router where
+        # it stands, as it selects with expert_bias where it stands.
+        top_w = jax.lax.stop_gradient(top_w)
+    routed = ragged_experts(h, layer["experts"], top_w, top_e, held=held)
+    with jax.named_scope("ddl.moe_shared"):
+        shared = _llama._swiglu(layer["shared"], h)
+    return shared + routed, top_e
+
+
+def sigmoid_expert_mlp(h: jax.Array, layer: Params, cfg: Any,
+                       mesh: Optional[Any]):
+    """:func:`sigmoid_expert_tokens` on the (B, T, D) stream.  On a ``dp``
+    mesh each shard routes its own rows under ``shard_map``: routing is per
+    token and dropless, so local is global (:func:`_routed_mlp`'s
+    argument); the weights cross replicated."""
+    B, T, D = h.shape
+    names = getattr(mesh, "axis_names", ())
+    if not ("dp" in names and mesh.shape["dp"] > 1):
+        out, top_e = sigmoid_expert_tokens(h.reshape(B * T, D), layer, cfg)
+        return out.reshape(B, T, D), top_e.reshape(B, T, -1)
+    from jax import shard_map
+
+    read = {k: layer[k] for k in ("w_router", "expert_bias", "shared", "experts")}
+
+    def body(hs: jax.Array, lyr: Params):
+        b, t, _ = hs.shape
+        out, top_e = sigmoid_expert_tokens(hs.reshape(b * t, D), lyr, cfg)
+        return out.reshape(b, t, D), top_e.reshape(b, t, -1)
+
+    tokens = P("dp", None, None)
+    return shard_map(
+        body, mesh=mesh,
+        in_specs=(tokens, jax.tree.map(lambda _: P(), read)),
+        out_specs=(tokens, tokens), check_vma=False,
+    )(h, read)
 
 
 def _moe_mlp_dispatch(

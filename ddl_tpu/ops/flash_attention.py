@@ -31,6 +31,14 @@ VMEM):
 - A sliding ``window`` (offsets (0, 0) only) is the same three kernels on
   grids whose innermost axis covers the band's blocks alone
   (``band_grid``), under the names ``ddl_flash_swa_*``.
+- Latent attention (``q_rope`` / ``k_rope``): the score is the sum of two
+  products, ``q . k`` over the heads' own width and ``q_rope . k_rope``
+  over a rotary width whose key is ONE a position, shared by all heads
+  (its index map sends every head to it, as ``kv_repeat`` sends a group);
+  the scale is ``1/sqrt`` of the two widths together and the value keeps
+  the heads' own.  The same three kernels with two more operands, under
+  the names ``ddl_flash_mla_*``; ``dk_rope`` is summed over the heads
+  outside the kernel, as a GQA group's ``dk`` is.
 
 The public wrappers pad ragged sequence lengths to the block size (padded
 keys are masked out, padded query rows sliced off) and fall back to
@@ -205,7 +213,7 @@ def _seg_invalid(seg):
 def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_ref, l_ref, acc_ref, *, scale: float, causal: bool,
                 block_q: int, block_k: int, kv_len: int, precision,
-                seg=None, window=None, band=None):
+                seg=None, window=None, band=None, rope=None):
     i = pl.program_id(2)  # Q block
     jj = pl.program_id(3)  # inner step (sequential): the KV block ...
     j = jj  # ... itself, or with a band its place among the band's blocks
@@ -218,8 +226,8 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def _scores():
-        return _block_scores(q_ref, k_ref, scale, precision)  # (bq, bk) f32
+    def _scores():  # (bq, bk) f32
+        return _block_scores(q_ref, k_ref, scale, precision, rope)
 
     def _update(s):
         """Online-softmax accumulate of one score block into m/l/acc."""
@@ -294,19 +302,28 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         o_ref[0, 0] = (acc_ref[:] / l_safe[:, None]).astype(o_ref.dtype)
 
 
-def _block_scores(q_ref, k_ref, scale, precision):
+def _block_scores(q_ref, k_ref, scale, precision, rope=None):
     """Scaled q·kᵀ of the current blocks, fp32 accumulation with operands
     in the input dtype (bf16 runs the MXU at full rate; fp32 would quarter
-    it) — shared by the forward and both backward kernels."""
-    return jax.lax.dot_general(
+    it) — shared by the forward and both backward kernels.  ``rope``: the
+    latent form's (q_rope_ref, k_rope_ref, ...), whose product joins the
+    score before the scale."""
+    s = jax.lax.dot_general(
         q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32, precision=precision,
-    ) * scale
+    )
+    if rope is not None:
+        s = s + jax.lax.dot_general(
+            rope[0][0, 0], rope[1][0, 0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision,
+        )
+    return s * scale
 
 
 def _bwd_p_dispatch(offs_ref, q_ref, k_ref, lse_ref, i, j, accum, *,
                     scale, causal, block_q, block_k, seq_len, kv_len,
-                    precision, seg=None, window=None, in_row=None):
+                    precision, seg=None, window=None, in_row=None,
+                    rope=None):
     """Backward-pass block dispatch shared by the dQ and dK/dV kernels:
     dead blocks skipped, boundary blocks recompute p with full masking,
     interior blocks use the bare ``exp(s - lse)`` fast path (statement-
@@ -323,7 +340,7 @@ def _bwd_p_dispatch(offs_ref, q_ref, k_ref, lse_ref, i, j, accum, *,
         needs_mask = needs_mask | (j >= 0)  # packed: every block masks
 
     def scores():
-        return _block_scores(q_ref, k_ref, scale, precision)
+        return _block_scores(q_ref, k_ref, scale, precision, rope)
 
     @pl.when(live & needs_mask)
     def _accum_masked():
@@ -371,7 +388,7 @@ def _needs_mask_bwd(offs_ref, i, j, block_q, block_k, causal, seq_len,
 def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                dlse_ref, dq_ref, dq_acc, *, scale: float, causal: bool,
                block_q: int, block_k: int, seq_len: int, kv_len: int,
-               precision, seg=None, window=None, band=None):
+               precision, seg=None, window=None, band=None, rope=None):
     i = pl.program_id(2)  # Q block
     jj = pl.program_id(3)  # inner step (sequential): the KV block, or ...
     j, in_row = jj, None
@@ -382,6 +399,8 @@ def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(jj == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
+        if rope is not None:  # (q_rope, k_rope, dq_rope, its accumulator)
+            rope[3][:] = jnp.zeros_like(rope[3])
 
     def _accum(p):
         k = k_ref[0, 0]
@@ -396,23 +415,31 @@ def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision,
         )
+        if rope is not None:
+            rope[3][:] += jax.lax.dot_general(
+                ds.astype(k.dtype), rope[1][0, 0], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=precision,
+            )
 
     _bwd_p_dispatch(
         offs_ref, q_ref, k_ref, lse_ref, i, j, _accum, scale=scale,
         causal=causal, block_q=block_q, block_k=block_k, seq_len=seq_len,
         kv_len=kv_len, precision=precision, seg=seg, window=window,
-        in_row=in_row,
+        in_row=in_row, rope=rope,
     )
 
     @pl.when(jj == pl.num_programs(3) - 1)
     def _finish():
         dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
+        if rope is not None:
+            rope[2][0, 0] = rope[3][:].astype(rope[2].dtype)
 
 
 def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dlse_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
                 causal: bool, block_q: int, block_k: int, seq_len: int,
-                kv_len: int, precision, seg=None, window=None, band=None):
+                kv_len: int, precision, seg=None, window=None, band=None,
+                rope=None):
     j = pl.program_id(2)  # KV block
     ii = pl.program_id(3)  # inner step (sequential): the Q block, or ...
     i, in_row = ii, None
@@ -424,6 +451,8 @@ def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+        if rope is not None:  # (q_rope, k_rope, dk_rope, its accumulator)
+            rope[3][:] = jnp.zeros_like(rope[3])
 
     def _accum(p):
         q = q_ref[0, 0]
@@ -442,18 +471,25 @@ def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision,
         )
+        if rope is not None:
+            rope[3][:] += jax.lax.dot_general(
+                ds.astype(q.dtype), rope[0][0, 0], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=precision,
+            )
 
     _bwd_p_dispatch(
         offs_ref, q_ref, k_ref, lse_ref, i, j, _accum, scale=scale,
         causal=causal, block_q=block_q, block_k=block_k, seq_len=seq_len,
         kv_len=kv_len, precision=precision, seg=seg, window=window,
-        in_row=in_row,
+        in_row=in_row, rope=rope,
     )
 
     @pl.when(ii == pl.num_programs(3) - 1)
     def _finish():
         dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+        if rope is not None:
+            rope[2][0, 0] = rope[3][:].astype(rope[2].dtype)
 
 
 # Packed-segment kernel adapters: same bodies, two extra int32 input refs
@@ -480,6 +516,44 @@ def _dkv_kernel_seg(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dlse_ref, dk_ref, dv_ref, dk_acc, dv_acc,
                 seg=(sq_ref, sk_ref), **kw)
+
+
+# Latent-attention kernel adapters, in the packed ones' manner: the same
+# bodies with the rotary operands (and their gradient's output and
+# accumulator) spliced in by position.
+
+
+def _fwd_kernel_mla(offs_ref, q_ref, k_ref, v_ref, qr_ref, kr_ref, o_ref,
+                    lse_ref, m_ref, l_ref, acc_ref, **kw):
+    _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref,
+                l_ref, acc_ref, rope=(qr_ref, kr_ref), **kw)
+
+
+def _dq_kernel_mla(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                   delta_ref, dlse_ref, qr_ref, kr_ref, dq_ref, dqr_ref,
+                   dq_acc, dqr_acc, **kw):
+    _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+               dlse_ref, dq_ref, dq_acc,
+               rope=(qr_ref, kr_ref, dqr_ref, dqr_acc), **kw)
+
+
+def _dkv_kernel_mla(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                    delta_ref, dlse_ref, qr_ref, kr_ref, dk_ref, dv_ref,
+                    dkr_ref, dk_acc, dv_acc, dkr_acc, **kw):
+    _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dlse_ref, dk_ref, dv_ref, dk_acc, dv_acc,
+                rope=(qr_ref, kr_ref, dkr_ref, dkr_acc), **kw)
+
+
+def _prep_rope(rope, Tq, Tk):
+    """The rotary operands in the kernels' layout, padded as ``_prep``
+    padded q and k: (B, H, Tq, R) and the shared key's (B, 1, Tk, R)."""
+    qr, kr = (jnp.moveaxis(x, 2, 1) for x in rope)
+    if Tq != qr.shape[2]:
+        qr = jnp.pad(qr, ((0, 0), (0, 0), (0, Tq - qr.shape[2]), (0, 0)))
+    if Tk != kr.shape[2]:
+        kr = jnp.pad(kr, ((0, 0), (0, 0), (0, Tk - kr.shape[2]), (0, 0)))
+    return qr, kr
 
 
 def _prep(q, k, v, block_q, block_k):
@@ -553,8 +627,42 @@ def _seg_specs(block_q, block_k, transposed: bool = False):
     return sq, sk
 
 
+def _mla_scale(q, rope) -> float:
+    """``1/sqrt`` of the score's whole width: the heads' own plus the
+    rotary one."""
+    return 1.0 / ((q.shape[-1] + rope[0].shape[-1]) ** 0.5)
+
+
+#: Scoped VMEM the latent kernels may use.  Their two rotary blocks, the
+#: rotary gradient's block and its accumulator come on top of what the
+#: one-product kernels hold, and at 1024 x 1024 blocks that passes
+#: Mosaic's default 16 MiB by 1.3 (dq; AOT for a described v5e, PR 32).
+_MLA_VMEM_LIMIT = 32 * 1024 * 1024
+
+
+def _mla_call_args(rope) -> dict:
+    """What a latent kernel's ``pallas_call`` takes besides the others'
+    arguments; nothing without the rotary operands."""
+    if rope is None:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=_MLA_VMEM_LIMIT)}
+
+
+def _rope_specs(rope, block_q, block_k, k_block):
+    """Block specs of (q_rope (B, H, Tq, R), k_rope (B, 1, Tk, R)) on the
+    forward / dq grid: every head reads the one shared key."""
+    R = rope[0].shape[-1]
+    return [
+        pl.BlockSpec((1, 1, block_q, R),
+                     lambda b, h, i, j, *_refs: (b, h, i, 0)),
+        pl.BlockSpec((1, 1, block_k, R),
+                     lambda b, h, i, j, *_refs: (b, 0, k_block(i, j), 0)),
+    ]
+
+
 def _fwd_impl(q, k, v, offsets, causal, kv_repeat, block_q, block_k,
-              interpret, seg_q=None, seg_k=None, window=None):
+              interpret, seg_q=None, seg_k=None, window=None, rope=None):
     assert q.shape[2] == k.shape[2] * kv_repeat, (q.shape, k.shape, kv_repeat)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -572,9 +680,13 @@ def _fwd_impl(q, k, v, offsets, causal, kv_repeat, block_q, block_k,
     if window is not None:
         band = common["band"] = band_grid(T, window, block_q, block_k)
         inner, k_block = band.nk, _k_block_of(block_q, block_k, band)
-    kernel = functools.partial(
-        _fwd_kernel_seg if packed else _fwd_kernel, **common
-    )
+    kernel, name = _fwd_kernel_seg if packed else _fwd_kernel, "ddl_flash_fwd"
+    if window is not None:
+        name = "ddl_flash_swa_fwd"
+    if rope is not None:
+        kernel, name = _fwd_kernel_mla, "ddl_flash_mla_fwd"
+        common["scale"] = _mla_scale(q, rope)
+    kernel = functools.partial(kernel, **common)
     kv_spec = pl.BlockSpec(
         (1, 1, block_k, D),
         lambda b, h, i, j, *_refs, rep=kv_repeat: (
@@ -592,6 +704,9 @@ def _fwd_impl(q, k, v, offsets, causal, kv_repeat, block_q, block_k,
         sq_spec, sk_spec = _seg_specs(block_q, block_k)
         in_specs += [sq_spec, sk_spec]
         inputs += [_prep_seg(seg_q, Tq), _prep_seg(seg_k, Tk)]
+    if rope is not None:
+        in_specs += _rope_specs(rope, block_q, block_k, k_block)
+        inputs += _prep_rope(rope, Tq, Tk)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, H, Tq // block_q, inner),
@@ -604,13 +719,13 @@ def _fwd_impl(q, k, v, offsets, causal, kv_repeat, block_q, block_k,
         ],
     )
     out, lse = named_pallas_call(
-        "ddl_flash_fwd" if window is None else "ddl_flash_swa_fwd", kernel,
+        name, kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
             jax.ShapeDtypeStruct((B, H, Tq, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, **_mla_call_args(rope),
     )(offsets, *inputs)
     o = out[:, :, :T] if Tq != T else out
     return (
@@ -621,7 +736,7 @@ def _fwd_impl(q, k, v, offsets, causal, kv_repeat, block_q, block_k,
 
 
 def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts,
-              window=None):
+              window=None, rope=None):
     do, dlse = cts
     # Resolved block sizes / interpret flag ride in the residuals so both
     # passes use identical values (the nondiff args are pre-resolution).
@@ -661,6 +776,17 @@ def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts,
         band = common["band"] = band_grid(T, window, block_q, block_k)
         inner_k, k_block = band.nk, _k_block_of(block_q, block_k, band)
         inner_q, q_block = band.nq, _q_block_of(block_q, block_k, band)
+    dq_kernel = _dq_kernel_seg if packed else _dq_kernel
+    dkv_kernel = _dkv_kernel_seg if packed else _dkv_kernel
+    names = ("ddl_flash_bwd_dq", "ddl_flash_bwd_dkv")
+    if window is not None:
+        names = ("ddl_flash_swa_bwd_dq", "ddl_flash_swa_bwd_dkv")
+    if rope is not None:
+        dq_kernel, dkv_kernel = _dq_kernel_mla, _dkv_kernel_mla
+        names = ("ddl_flash_mla_bwd_dq", "ddl_flash_mla_bwd_dkv")
+        common["scale"] = _mla_scale(q, rope)
+        R = rope[0].shape[-1]
+        rope_t = _prep_rope(rope, Tq, Tk)
     q_spec = pl.BlockSpec(
         (1, 1, block_q, D), lambda b, h, i, j, *_refs: (b, h, i, 0)
     )
@@ -679,19 +805,26 @@ def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts,
         sq_spec, sk_spec = _seg_specs(block_q, block_k)
         dq_in_specs += [sq_spec, sk_spec]
         dq_inputs += [_prep_seg(seg_q, Tq), _prep_seg(seg_k, Tk)]
+    dq_out_specs, dq_scratch = q_spec, [pltpu.VMEM((block_q, D), jnp.float32)]
+    dq_shape = jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype)
+    if rope is not None:
+        qr_spec, kr_spec = _rope_specs(rope, block_q, block_k, k_block)
+        dq_in_specs += [qr_spec, kr_spec]
+        dq_inputs += rope_t
+        dq_out_specs = [q_spec, qr_spec]
+        dq_scratch.append(pltpu.VMEM((block_q, R), jnp.float32))
+        dq_shape = [dq_shape, jax.ShapeDtypeStruct((B, H, Tq, R), q.dtype)]
     dq = named_pallas_call(
-        "ddl_flash_bwd_dq" if window is None else "ddl_flash_swa_bwd_dq",
-        functools.partial(_dq_kernel_seg if packed else _dq_kernel,
-                          **common),
+        names[0], functools.partial(dq_kernel, **common),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, H, Tq // block_q, inner_k),
             in_specs=dq_in_specs,
-            out_specs=q_spec,
-            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+            out_specs=dq_out_specs,
+            scratch_shapes=dq_scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
-        interpret=interpret,
+        out_shape=dq_shape,
+        interpret=interpret, **_mla_call_args(rope),
     )(offsets, *dq_inputs)
 
     # dK/dV: grid transposed so the Q axis is innermost (sequential).
@@ -717,27 +850,47 @@ def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts,
         sq_spec_t, sk_spec_t = _seg_specs(block_q, block_k, transposed=True)
         dkv_in_specs += [sq_spec_t, sk_spec_t]
         dkv_inputs += [_prep_seg(seg_q, Tq), _prep_seg(seg_k, Tk)]
-    dk, dv = named_pallas_call(
-        "ddl_flash_bwd_dkv" if window is None else "ddl_flash_swa_bwd_dkv",
-        functools.partial(_dkv_kernel_seg if packed else _dkv_kernel,
-                          **common),
+    dkv_out_specs = [out_kv_t, out_kv_t]
+    dkv_scratch = [
+        pltpu.VMEM((block_k, D), jnp.float32),
+        pltpu.VMEM((block_k, D), jnp.float32),
+    ]
+    dkv_shapes = [
+        jax.ShapeDtypeStruct((B, H, Tk, D), k.dtype),
+        jax.ShapeDtypeStruct((B, H, Tk, D), v.dtype),
+    ]
+    if rope is not None:
+        dkv_in_specs += [
+            pl.BlockSpec((1, 1, block_q, R),
+                         lambda b, h, j, i, *_refs: (b, h, q_block(j, i), 0)),
+            pl.BlockSpec((1, 1, block_k, R),
+                         lambda b, h, j, i, *_refs: (b, 0, j, 0)),
+        ]
+        dkv_inputs += rope_t
+        # Every head's part of the shared key's gradient, float32: the sum
+        # over the heads is taken outside, once, before it is rounded.
+        dkv_out_specs.append(pl.BlockSpec(
+            (1, 1, block_k, R), lambda b, h, j, i, *_refs: (b, h, j, 0)))
+        dkv_scratch.append(pltpu.VMEM((block_k, R), jnp.float32))
+        dkv_shapes.append(jax.ShapeDtypeStruct((B, H, Tk, R), jnp.float32))
+    dk, dv, *dkr = named_pallas_call(
+        names[1], functools.partial(dkv_kernel, **common),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, H, Tk // block_k, inner_q),
             in_specs=dkv_in_specs,
-            out_specs=[out_kv_t, out_kv_t],
-            scratch_shapes=[
-                pltpu.VMEM((block_k, D), jnp.float32),
-                pltpu.VMEM((block_k, D), jnp.float32),
-            ],
+            out_specs=dkv_out_specs,
+            scratch_shapes=dkv_scratch,
         ),
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, Tk, D), k.dtype),
-            jax.ShapeDtypeStruct((B, H, Tk, D), v.dtype),
-        ],
-        interpret=interpret,
+        out_shape=dkv_shapes,
+        interpret=interpret, **_mla_call_args(rope),
     )(offsets, *dkv_inputs)
 
+    if rope is not None:
+        dq, dqr = dq
+        dqr = jnp.moveaxis(dqr[:, :, :T], 1, 2)
+        dkr = jnp.sum(dkr[0][:, :, :Tkv], axis=1, keepdims=True)
+        dkr = jnp.moveaxis(dkr, 1, 2).astype(rope[1].dtype)
     if Tq != T:
         dq = dq[:, :, :T]
     if Tk != Tkv:
@@ -751,6 +904,8 @@ def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts,
     dk = jnp.moveaxis(dk, 1, 2)
     dv = jnp.moveaxis(dv, 1, 2)
     d_offsets = np.zeros((2,), jax.dtypes.float0)  # int arg: zero cotangent
+    if rope is not None:  # in the latent core's argument order
+        return dq, dk.astype(k.dtype), dv.astype(v.dtype), dqr, dkr, d_offsets
     return dq, dk.astype(k.dtype), dv.astype(v.dtype), d_offsets
 
 
@@ -851,6 +1006,40 @@ def _bwd_impl_win(kv_repeat, block_q, block_k, interpret, window, res, cts):
 _flash_core_win.defvjp(_vjp_fwd_win, _bwd_impl_win)
 
 
+# Latent-attention core (causal, offsets (0, 0), one key/value head a query
+# head): the same kernels with the rotary product in the score, under the
+# names ``ddl_flash_mla_*``.  A custom_vjp of its own for the reason the
+# windowed core has one.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _flash_core_mla(q, k, v, q_rope, k_rope, offsets, block_q, block_k,
+                    interpret):
+    out, lse, _ = _fwd_impl(
+        q, k, v, offsets, True, 1, block_q, block_k, interpret,
+        rope=(q_rope, k_rope),
+    )
+    return out, lse
+
+
+def _vjp_fwd_mla(q, k, v, q_rope, k_rope, offsets, block_q, block_k,
+                 interpret):
+    out, lse, (out_padded, lse_padded, ipret, bq, bk) = _fwd_impl(
+        q, k, v, offsets, True, 1, block_q, block_k, interpret,
+        rope=(q_rope, k_rope),
+    )
+    return (out, lse), (
+        (q, k, v, offsets, out_padded, lse_padded, ipret, bq, bk, None, None),
+        (q_rope, k_rope),
+    )
+
+
+def _bwd_impl_mla(block_q, block_k, interpret, res, cts):
+    res, rope = res
+    return _bwd_impl(True, 1, block_q, block_k, interpret, res, cts, rope=rope)
+
+
+_flash_core_mla.defvjp(_vjp_fwd_mla, _bwd_impl_mla)
+
+
 _FINE_BAND = 2048  # widest window whose band runs in 512 x 512 blocks
 
 
@@ -892,6 +1081,8 @@ def flash_attention(
     interpret: Optional[bool] = None,
     segment_ids: Optional[jax.Array] = None,
     window: Optional[int] = None,
+    q_rope: Optional[jax.Array] = None,
+    k_rope: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Flash attention over (B, T, H, D) queries.
 
@@ -922,7 +1113,40 @@ def flash_attention(
     trace.  A window that covers the sequence is plain causal attention
     and runs as such; ``None`` leaves every program exactly what it was
     before windows existed.
+
+    ``q_rope`` (B, T, H, R) with ``k_rope`` (B, T, 1, R): latent attention
+    (causal self-attention, one k/v head a query head) — the score is
+    ``(q . k + q_rope . k_rope) / sqrt(D + R)`` with ONE rotary key a
+    position for all heads, which the kernels read through their index
+    map, so its H-fold broadcast is never written; v and the output keep
+    width D.  The kernels are named ``ddl_flash_mla_*`` on the trace.
+    Absent, every program is what it was before.
     """
+    if (q_rope is None) != (k_rope is None):
+        raise ValueError("q_rope and k_rope come together")
+    if q_rope is not None:
+        B, T, H, _ = q.shape
+        R = q_rope.shape[-1]
+        if (q_rope.shape != (B, T, H, R) or k_rope.shape != (B, T, 1, R)
+                or k.shape != q.shape or v.shape != q.shape):
+            raise ValueError(
+                f"latent attention takes q, k, v {q.shape}, q_rope "
+                f"(B, T, H, R) and the shared k_rope (B, T, 1, R); got "
+                f"{k.shape}, {v.shape}, {q_rope.shape}, {k_rope.shape}"
+            )
+        if (not causal or kv_repeat != 1 or window is not None
+                or segment_ids is not None):
+            raise NotImplementedError(
+                "flash_attention: the latent form (q_rope/k_rope) is causal "
+                "self-attention with a key/value head a query head; grouped "
+                "heads, a sliding window and packed rows have no kernel"
+            )
+        block_q, block_k = _default_blocks(T, block_q, block_k)
+        out, _ = _flash_core_mla(
+            q, k, v, q_rope, k_rope, _offsets_arr(0, 0), block_q, block_k,
+            interpret,
+        )
+        return out
     if window is not None:
         if not causal or window < 1:
             raise ValueError(

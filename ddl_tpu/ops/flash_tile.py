@@ -81,16 +81,18 @@ def _plan(T: int, H: int, D: int, dtype) -> Optional[int]:
 
 
 def fits(q, k, v, kv_repeat, block_q, block_k, segment_ids,
-         window=None) -> bool:
+         window=None, q_rope=None) -> bool:
     """True where the one-block kernels take the call: the blockwise
     grid's last two axes would both be 1, every row is one segment, k/v
     have q's heads and length, and the sequence is short enough for the
     scores of a head to sit in VMEM whole.  Causal or not: both maskings
-    are built in; a sliding ``window`` narrower than the sequence is not.
+    are built in; a sliding ``window`` narrower than the sequence is not,
+    nor is latent attention's second product (``q_rope``).
     bf16 or float32: the dtypes timed and compiled."""
     _, T, H, D = q.shape
     return (
         segment_ids is None
+        and q_rope is None
         and (window is None or window >= T)
         and kv_repeat == 1
         and q.dtype in (jnp.bfloat16, jnp.float32)

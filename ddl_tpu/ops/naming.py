@@ -35,6 +35,8 @@ KERNEL_NAMES = (
     "ddl_flash_fwd", "ddl_flash_bwd_dq", "ddl_flash_bwd_dkv",
     # the same kernels with a sliding window's band (``window=``)
     "ddl_flash_swa_fwd", "ddl_flash_swa_bwd_dq", "ddl_flash_swa_bwd_dkv",
+    # ... and with latent attention's rotary product (``q_rope=``)
+    "ddl_flash_mla_fwd", "ddl_flash_mla_bwd_dq", "ddl_flash_mla_bwd_dkv",
     "ddl_flash_tile_fwd", "ddl_flash_tile_bwd",
     "ddl_ici_bcast", "ddl_ici_scatter", "ddl_shuffle_exchange",
 )

@@ -183,6 +183,8 @@ def sharded_local_attention(
     tp_axis: str = "tp",
     segment_ids: Optional[jax.Array] = None,
     window: Optional[int] = None,
+    q_rope: Optional[jax.Array] = None,
+    k_rope: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Batch/head-sharded attention for meshes WITHOUT a sequence axis.
 
@@ -193,13 +195,15 @@ def sharded_local_attention(
     Axes that don't divide the corresponding dimension stay unsharded.
     ``segment_ids`` (B, T): packed-sequence masking, batch-sharded like q.
     ``window``: sliding-window attention, local to every shard.
+    ``q_rope``/``k_rope``: latent attention; the shared rotary key crosses
+    whole to every head shard.
     """
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    def impl(q, k, v, seg):
+    def impl(q, k, v, seg, q_rope=None, k_rope=None):
         return _local_attention(q, k, v, use_flash, causal, kv_repeat, seg,
-                                window)
+                                window, q_rope, k_rope)
 
     B, _, H, _ = q.shape
     Hkv = k.shape[2]
@@ -233,9 +237,15 @@ def sharded_local_attention(
             logger.debug(
                 "sharded_local_attention: single-device mesh, local attention"
             )
-        return impl(q, k, v, segment_ids)
+        return impl(q, k, v, segment_ids, q_rope, k_rope)
     spec = P(bax, None, hax, None)
     seg_spec = P(bax, None)
+    if q_rope is not None:  # (never with segment_ids: no packed latent form)
+        return shard_map(
+            lambda q, k, v, qr, kr: impl(q, k, v, None, qr, kr), mesh=mesh,
+            in_specs=(spec, spec, spec, spec, P(bax, None, None, None)),
+            out_specs=spec, check_vma=False,
+        )(q, k, v, q_rope, k_rope)
     if segment_ids is None:
         return shard_map(
             lambda q, k, v: impl(q, k, v, None), mesh=mesh,
@@ -248,16 +258,18 @@ def sharded_local_attention(
 
 
 def _local_attention(q, k, v, use_flash, causal, kv_repeat, segment_ids,
-                     window):
+                     window, q_rope=None, k_rope=None):
     """One device's whole attention: the Pallas flash kernels or the dense
     oracle."""
     if use_flash:
         from ddl_tpu.ops import flash_attention
 
         return flash_attention(q, k, v, causal=causal, kv_repeat=kv_repeat,
-                               segment_ids=segment_ids, window=window)
+                               segment_ids=segment_ids, window=window,
+                               q_rope=q_rope, k_rope=k_rope)
     return attention_reference(q, k, v, causal=causal, kv_repeat=kv_repeat,
-                               segment_ids=segment_ids, window=window)
+                               segment_ids=segment_ids, window=window,
+                               q_rope=q_rope, k_rope=k_rope)
 
 
 def attention(
@@ -273,6 +285,8 @@ def attention(
     tp_axis: str = "tp",
     segment_ids: Optional[jax.Array] = None,
     window: Optional[int] = None,
+    q_rope: Optional[jax.Array] = None,
+    k_rope: Optional[jax.Array] = None,
 ) -> jax.Array:
     """The single attention dispatcher — one source of truth for impl/mesh
     routing (models call this, not the individual strategies):
@@ -289,6 +303,12 @@ def attention(
       local strategies.  The ``sp`` ring refuses it by name: a band needs
       only the neighbouring shards' keys, and a ring that rotates every
       block past every device to mask most of them is not that.
+    - ``q_rope`` (B, T, H, R) / ``k_rope`` (B, T, 1, R): latent attention —
+      scores ``(q . k + q_rope . k_rope) / sqrt(D + R)``, the rotary key
+      one a position for all heads, v and the output of q's width — on
+      the local strategies (causal, ``kv_repeat`` 1).  The ``sp`` ring
+      refuses it by name: the shared key would have to ride the ring
+      beside k and v, and no ring step takes it.
     """
     if impl not in ("auto", "flash", "dense"):
         raise ValueError(
@@ -298,6 +318,11 @@ def attention(
         impl == "auto" and jax.default_backend() == "tpu"
     )
     if mesh is not None and axis in mesh.axis_names and mesh.shape[axis] > 1:
+        if q_rope is not None:
+            raise NotImplementedError(
+                f"attention(q_rope=, k_rope=): ring attention over the "
+                f"{axis!r} axis has no latent form"
+            )
         if window is not None:
             raise NotImplementedError(
                 f"attention(window={window}): ring attention over the "
@@ -312,27 +337,38 @@ def attention(
         return sharded_local_attention(
             q, k, v, mesh, causal=causal, kv_repeat=kv_repeat,
             use_flash=use_flash, dp_axis=dp_axis, tp_axis=tp_axis,
-            segment_ids=segment_ids, window=window,
+            segment_ids=segment_ids, window=window, q_rope=q_rope,
+            k_rope=k_rope,
         )
     return _local_attention(q, k, v, use_flash, causal, kv_repeat,
-                            segment_ids, window)
+                            segment_ids, window, q_rope, k_rope)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "kv_repeat", "window"))
 def attention_reference(q, k, v, causal: bool = True, kv_repeat: int = 1,
-                        segment_ids=None, window=None):
+                        segment_ids=None, window=None, q_rope=None,
+                        k_rope=None):
     """Single-device full attention — the correctness oracle for tests.
 
     ``segment_ids`` (B, T): packed-sequence masking, tokens attend only
     within their own segment (matching ``ops.flash_attention``).
     ``window``: key ``j`` is visible to query ``i`` iff ``i - j < window``
     (on top of causality).
+    ``q_rope`` (B, T, H, R) / ``k_rope`` (B, T, 1, R): latent attention's
+    rotary product, one key for all heads, added to the scores under the
+    scale ``1/sqrt(D + R)``.
     """
     if kv_repeat > 1:
         k = jnp.repeat(k, kv_repeat, axis=2)
         v = jnp.repeat(v, kv_repeat, axis=2)
     B, T, H, D = q.shape
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / (D**0.5)
+    if q_rope is None:
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / (D**0.5)
+    else:
+        s = (
+            jnp.einsum("bqhd,bkhd->bhqk", q, k)
+            + jnp.einsum("bqhr,bkr->bhqk", q_rope, k_rope[:, :, 0])
+        ) / ((D + q_rope.shape[-1]) ** 0.5)
     if causal:
         mask = jnp.arange(T)[None, :] > jnp.arange(T)[:, None]
         s = jnp.where(mask[None, None], _NEG_INF, s)

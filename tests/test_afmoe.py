@@ -177,13 +177,13 @@ def test_leaving_out_part_of_the_mathematics_fails(tokens, left_out, monkeypatch
         c = ref_config(cfg, route_norm=True)
     elif left_out == "bias_in_the_weights":
         monkeypatch.setattr(jax.lax, "stop_gradient", lambda x: x)
-        real = afmoe._route
+        real = moe.sigmoid_route  # the routine afmoe shares (models/moe.py)
 
         def biased(h, layer, cfg):
             top_w, top_e = real(h, layer, cfg)
             return top_w + 0.1 * layer["expert_bias"][top_e], top_e
 
-        monkeypatch.setattr(afmoe, "_route", biased)
+        monkeypatch.setattr(moe, "sigmoid_route", biased)
     elif left_out == "no_shared_expert":
         monkeypatch.setattr(
             llama, "_swiglu",

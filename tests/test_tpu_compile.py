@@ -160,6 +160,36 @@ def test_windowed_flash_attention_compiles_at_trinity_minis_geometry(v5e, grad):
     }
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+def test_latent_flash_attention_compiles_at_kanana_2s_geometry(v5e, grad):
+    """2 x 8192 tokens, 32 heads, 128-wide q/k/v + a 64-wide rotary
+    product with one shared key, bf16: the latent kernels under names of
+    their own, at the row's 1024 x 1024 blocks - which need the scoped VMEM
+    limit the latent path asks for (dq passes Mosaic's default 16 MiB by
+    1.3) - on the causal grid; the rotary key's gradient comes back as ONE
+    key a position."""
+    one = SingleDeviceSharding(v5e[0])
+    shape = lambda h, d: jax.ShapeDtypeStruct(  # noqa: E731
+        (2, 8192, h, d), jnp.bfloat16, sharding=one)
+    args = (shape(32, 128),) * 3 + (shape(32, 64), shape(1, 64))
+
+    def latent(q, k, v, q_rope, k_rope):
+        return flash_attention(
+            q, k, v, interpret=False, q_rope=q_rope, k_rope=k_rope
+        ).astype(jnp.float32).sum()
+
+    fn = jax.grad(latent, argnums=(0, 1, 2, 3, 4)) if grad else latent
+    lowered = jax.jit(fn).lower(*args)
+    compiled = lowered.compile()
+    want = {"ddl_flash_mla_fwd"}
+    if grad:
+        want |= {"ddl_flash_mla_bwd_dq", "ddl_flash_mla_bwd_dkv"}
+        assert [o.shape for o in jax.tree.leaves(compiled.out_info)] == [
+            a.shape for a in args]
+    assert kernel_names(compiled.as_text()) == want and want <= set(KERNEL_NAMES)
+    assert mosaic_grids(lowered.as_text()) == {(2, 32, 8, 8): 3 if grad else 1}
+
+
 def test_flash_names_survive_remat_and_shard_map(v5e):
     """What used to rename the kernels: ``jax.checkpoint`` (``checkpoint``,
     ``rematted_computation``), autodiff (``jvp__``, ``transpose_jvp___``)
