@@ -290,7 +290,10 @@ _CONFIG_FIELD_DOCS: Dict[str, str] = {
 }
 
 _TRAIN_FIELD_DOCS: Dict[str, str] = {
-    "remat": "Rematerialisation policy: none/full/selective/dots.",
+    "remat":
+        "Rematerialisation policy: none/full/selective/dots (selective "
+        "keeps the attention output and the flash kernels' logsumexp: "
+        "the backward re-runs no attention kernel; full and dots do).",
     "schedule": "Pipeline schedule: gpipe | 1f1b.",
     "pp_chunks": "Stage chunks per device for 1f1b (0 = default).",
     "n_microbatches": "Microbatches per pipeline step.",

@@ -19,7 +19,7 @@ data of the config (``layer_types``, ``n_dense_layers``):
   halves; the embedding is scaled by ``sqrt(d_model)`` (``mup_enabled``).
 
 What is llama's is llama's: ``_rope``, ``_rms_norm``, ``_swiglu``,
-``_dense_init``, ``remat.tag_attn_out`` and the attention dispatcher
+``_dense_init`` and the attention dispatcher
 (``window=`` for the sliding layers).  The routed experts are
 ``moe.ragged_experts``, the dropless core OLMoE runs, handed this
 family's scoring and the RANGE OF EXPERTS HELD HERE
@@ -257,7 +257,6 @@ def _attn_block(
         kv_repeat=cfg.n_heads // cfg.n_kv_heads,
         window=cfg.sliding_window if sliding else None,
     )
-    attn = _remat.tag_attn_out(attn)  # saveable under remat="selective"
     with jax.named_scope("ddl.attn_gate"):
         gate = jax.nn.sigmoid(h @ layer["wg"].astype(dt))
         gated = attn.reshape(B, T, -1) * gate

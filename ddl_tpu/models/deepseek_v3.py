@@ -29,7 +29,7 @@ Every layer, with ``h = RMSNorm(x)`` and pre-norm residuals
   SwiGLU of ``n_shared_experts * d_expert``.
 
 What is llama's is llama's (``_rms_norm``, ``_rope``, ``_swiglu``,
-``_dense_init``, ``remat.tag_attn_out``); the experts are
+``_dense_init``); the experts are
 ``moe.ragged_experts`` with the RANGE OF EXPERTS HELD HERE
 (``held_experts=(first, count)`` of the router's ``n_experts``): one
 chip's share of a layer divided over chips by experts, as in
@@ -256,7 +256,6 @@ def _attn_block(
         q_nope, k_nope, v, mesh=mesh, impl=cfg.attn_impl, causal=True,
         q_rope=q_rope, k_rope=k_rope,
     )
-    attn = _remat.tag_attn_out(attn)  # saveable under remat="selective"
     return x + attn.reshape(B, T, -1) @ layer["wo"].astype(dt)
 
 

@@ -48,9 +48,10 @@ class LlamaConfig:
     #: each layer's residual-stream input, recompute everything — the
     #: classic FLOPs-for-HBM trade that lets long-sequence/big-model
     #: configs fit a single chip) | ``"selective"`` (additionally save
-    #: the attention outputs so the backward never re-runs the attention
-    #: kernel — buys back most of full-remat's MFU loss) | ``"dots"``
-    #: (save all non-batched matmul outputs).  Bools accepted for back
+    #: the attention outputs, and the flash kernels' logsumexp beside
+    #: them, so the backward never re-runs the attention kernel — buys
+    #: back most of full-remat's MFU loss) | ``"dots"`` (save all
+    #: non-batched matmul outputs; re-runs it).  Bools accepted for back
     #: compat: ``True`` == ``"full"``, ``False`` == ``"none"``.
     remat: Any = False
     # "auto": Pallas flash attention on TPU, dense elsewhere; "flash"/"dense"
@@ -281,11 +282,6 @@ def _attn_block(
         q, k, v, mesh=mesh, impl=cfg.attn_impl, causal=True,
         kv_repeat=rep, segment_ids=segment_ids,
     )
-    # Saveable under remat="selective" (identity otherwise): the
-    # backward pass then never re-runs the attention kernel.
-    from ddl_tpu.models import remat as _remat
-
-    attn = _remat.tag_attn_out(attn)
     return x + attn.reshape(B, T, -1) @ layer["wo"].astype(dt)
 
 
@@ -734,9 +730,6 @@ def _layer_apply_tp_local(
         q, k, v, mesh=None, impl=cfg.attn_impl, causal=True,
         kv_repeat=lh // lkv,
     )
-    from ddl_tpu.models import remat as _remat
-
-    attn = _remat.tag_attn_out(attn)  # saveable under remat="selective"
     # Row-sharded wo: each device's head block contributes a PARTIAL
     # output projection; the psum completes the sum over heads.
     x = x + lax.psum(

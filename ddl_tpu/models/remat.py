@@ -16,29 +16,46 @@ accepted for back compat — ``True`` is ``"full"``, ``False`` is
   ``policy=nothing_saveable`` is ``jax.checkpoint``'s default spelled
   explicitly, so the models' lint guard — every ``jax.checkpoint``
   names a policy — holds by construction).
-- ``"selective"`` — save each layer's ATTENTION OUTPUT (the tensors
-  tagged :data:`ATTN_OUT_NAME` by the shared attention blocks) and
+- ``"selective"`` — save each layer's ATTENTION OUTPUT (what the
+  attention dispatcher returns, tagged :data:`ATTN_OUT_NAME`) and
   recompute the cheap rest: norms, qkv/rope projections, and the FFN.
-  The backward pass then never re-runs the attention kernel — the
-  standard Megatron-style selective trade that buys back most of the
-  full-remat MFU loss at a fraction of full activation memory.
+  The blockwise flash kernels tag the two values their backward
+  kernels read inside their own ``custom_vjp`` rules — that same output
+  and the logsumexp, compact ``(B, H, T)`` float32 — so the backward
+  pass never re-runs the attention kernel: the standard Megatron-style
+  selective trade that buys back most of the full-remat MFU loss at a
+  fraction of full activation memory.  Saved a layer beside its input:
+  ``2·B·T·H·D`` bytes of bf16 output and ``4·B·H·T`` of logsumexp, 1/64
+  of it at D = 128 (at 2 x 8192 rows, 32 heads x 128: 128 MiB + 2 MiB).
+  The one-block kernels (``ops/flash_tile.py``, T <= 512) keep no
+  logsumexp; their output is saved and their forward re-run.  The
+  ``sp`` ring calls the same blockwise rules once a ring step, so it
+  keeps each step's partial output and logsumexp beside the combined
+  output (1 + ``sp`` local outputs a layer, where it kept the one and
+  re-ran the ring's forward kernels): measured by no cell.
 - ``"dots"`` — ``jax.checkpoint_policies.dots_with_no_batch_dims_
   saveable``: save every non-batched matmul output (all weight
   projections), recompute only elementwise ops and attention — the
-  memory-heavier, FLOPs-lighter point between none and selective.
+  memory-heavier point between none and selective, which RE-RUNS the
+  attention kernel in the backward pass (names are not its criterion).
 
 One wrap site per model family (:func:`wrap` around the layer body),
-one tag site per attention block (:func:`tag_attn_out`) — the policy
-semantics cannot drift between llama, moe, and the pipelined forwards.
+one tag function (:func:`tag_attn_out`), called by the one attention
+dispatcher (``parallel.ring_attention.attention``) and by the flash
+kernels' rules, never by a model — so a value is tagged once (a second
+tag on the same output would save it twice) and the policy semantics
+cannot drift between llama, moe, afmoe, deepseek_v3 and the pipelined
+forwards.
 """
 
 from __future__ import annotations
 
 from typing import Any, Callable
 
-#: Checkpoint name carried by every attention block's output tensor
-#: (``checkpoint_name`` is an identity outside a policy-bearing
-#: ``jax.checkpoint``, so tagging is unconditional and free).
+#: Checkpoint name carried by every attention output and by the
+#: blockwise flash kernels' logsumexp (``checkpoint_name`` is an
+#: identity outside a policy-bearing ``jax.checkpoint``, so tagging is
+#: unconditional and free).
 ATTN_OUT_NAME = "ddl_attn_out"
 
 #: Every accepted policy name, in cheapest-memory-first order.
@@ -62,8 +79,9 @@ def resolve(remat: Any) -> str:
 
 
 def tag_attn_out(x: Any) -> Any:
-    """Mark an attention block's output as saveable under the
-    ``"selective"`` policy (identity everywhere else)."""
+    """Mark a value — an attention output, or a flash kernel's
+    logsumexp — as saved under the ``"selective"`` policy (identity
+    everywhere else)."""
     from jax.ad_checkpoint import checkpoint_name
 
     return checkpoint_name(x, ATTN_OUT_NAME)

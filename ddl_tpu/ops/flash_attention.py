@@ -21,6 +21,14 @@ VMEM):
   ``ds += p * dlse`` (since d lse_i / d s_ik = p_ik).  Under GQA the
   per-Q-head dK/dV are summed over each query-head group outside the
   kernel.
+- What a forward call keeps for its backward (the ``custom_vjp``
+  residuals) is its output as the caller gets it, ``(B, T, H, D)``, and
+  the compact logsumexp ``(B, H, T)`` float32, both tagged with the name
+  ``remat="selective"`` saves (``_saved``): a rematerialised backward
+  then reads them and runs no forward kernel.  ``delta`` is taken in the
+  caller's layout and the kernels' ``(B, H, Tq, 1)`` row operands are
+  rebuilt from the compact values, so no lane-padded row array and no
+  second copy of the output outlives the forward pass.
 - Global-position offsets ride in as scalar-prefetch arguments (they are
   traced values inside a ring ``lax.scan``), so causal masking uses global
   token positions and blocks strictly above the (global) diagonal skip
@@ -728,11 +736,34 @@ def _fwd_impl(q, k, v, offsets, causal, kv_repeat, block_q, block_k,
         interpret=interpret, **_mla_call_args(rope),
     )(offsets, *inputs)
     o = out[:, :, :T] if Tq != T else out
-    return (
-        jnp.moveaxis(o, 1, 2),
-        lse[:, :, :T, 0],
-        (out, lse, interpret, block_q, block_k),
-    )
+    return jnp.moveaxis(o, 1, 2), lse[:, :, :T, 0], (interpret, block_q, block_k)
+
+
+def _tag(x):
+    """``x`` under the name ``remat="selective"`` saves (lazily: the
+    models import this module)."""
+    from ddl_tpu.models.remat import tag_attn_out
+
+    return tag_attn_out(x)
+
+
+def _saved(out, lse):
+    """The two values of a forward call that its backward reads, under the
+    name ``remat="selective"`` saves: the output as the caller gets it
+    (B, T, H, D) and the compact logsumexp (B, H, T) float32 — 4 bytes a
+    (row, head), where the kernels' own (B, H, Tq, 1) operand is padded
+    to 128 lanes in HBM and the rows to the block.  With both kept, a
+    rematerialised backward has nothing left to run the forward kernel
+    for."""
+    return _tag(out), _tag(lse)
+
+
+def _row_operand(x, Tq):
+    """(B, H, T) float32 -> the kernels' (B, H, Tq, 1) row operand."""
+    pad = Tq - x.shape[2]
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, pad)))
+    return x[..., None]
 
 
 def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts,
@@ -740,7 +771,7 @@ def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts,
     do, dlse = cts
     # Resolved block sizes / interpret flag ride in the residuals so both
     # passes use identical values (the nondiff args are pre-resolution).
-    (q, k, v, offsets, out_padded, lse, interpret, block_q, block_k,
+    (q, k, v, offsets, out, lse, interpret, block_q, block_k,
      seg_q, seg_k) = res
     B, T, H, D = q.shape
     Tkv, Hkv = k.shape[1], k.shape[2]
@@ -752,16 +783,15 @@ def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts,
     dot = jnp.moveaxis(do, 2, 1)
     if Tq != T:
         dot = jnp.pad(dot, ((0, 0), (0, 0), (0, Tq - T), (0, 0)))
-    # delta_i = rowsum(dO_i * O_i), the softmax-jacobian diagonal term.
-    delta = jnp.sum(
-        dot.astype(jnp.float32) * out_padded.astype(jnp.float32), axis=-1,
-        keepdims=True,
-    )  # (B, H, Tq, 1)
+    # delta_i = rowsum(dO_i * O_i), the softmax-jacobian diagonal term,
+    # taken in the caller's layout (the saved output's): only the
+    # (B, T, H) sums are transposed.
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    delta = _row_operand(jnp.moveaxis(delta, 2, 1), Tq)
+    lse = _row_operand(lse, Tq)  # pad rows: their p is masked to 0
     # lse cotangent from the caller (zero for plain flash_attention; the
     # ring combine's weights make it nonzero there).
-    dl = dlse.astype(jnp.float32)[..., None]  # (B, H, T, 1)
-    if Tq != T:
-        dl = jnp.pad(dl, ((0, 0), (0, 0), (0, Tq - T), (0, 0)))
+    dl = _row_operand(dlse.astype(jnp.float32), Tq)
 
     common = dict(
         scale=1.0 / (D**0.5), causal=causal, block_q=block_q,
@@ -920,12 +950,11 @@ def _flash_core(q, k, v, offsets, causal, kv_repeat, block_q, block_k,
 
 def _vjp_fwd(q, k, v, offsets, causal, kv_repeat, block_q, block_k,
              interpret):
-    out, lse, (out_padded, lse_padded, ipret, bq, bk) = _fwd_impl(
+    out, lse, resolved = _fwd_impl(
         q, k, v, offsets, causal, kv_repeat, block_q, block_k, interpret
     )
-    return (out, lse), (
-        q, k, v, offsets, out_padded, lse_padded, ipret, bq, bk, None, None
-    )
+    out, lse = _saved(out, lse)
+    return (out, lse), (q, k, v, offsets, out, lse, *resolved, None, None)
 
 
 _flash_core.defvjp(_vjp_fwd, _bwd_impl)
@@ -947,14 +976,12 @@ def _flash_core_seg(q, k, v, offsets, seg_q, seg_k, causal, kv_repeat,
 
 def _vjp_fwd_seg(q, k, v, offsets, seg_q, seg_k, causal, kv_repeat,
                  block_q, block_k, interpret):
-    out, lse, (out_padded, lse_padded, ipret, bq, bk) = _fwd_impl(
+    out, lse, resolved = _fwd_impl(
         q, k, v, offsets, causal, kv_repeat, block_q, block_k, interpret,
         seg_q=seg_q, seg_k=seg_k,
     )
-    return (out, lse), (
-        q, k, v, offsets, out_padded, lse_padded, ipret, bq, bk,
-        seg_q, seg_k,
-    )
+    out, lse = _saved(out, lse)
+    return (out, lse), (q, k, v, offsets, out, lse, *resolved, seg_q, seg_k)
 
 
 def _bwd_impl_seg(causal, kv_repeat, block_q, block_k, interpret, res, cts):
@@ -988,13 +1015,12 @@ def _flash_core_win(q, k, v, offsets, kv_repeat, block_q, block_k,
 
 def _vjp_fwd_win(q, k, v, offsets, kv_repeat, block_q, block_k, interpret,
                  window):
-    out, lse, (out_padded, lse_padded, ipret, bq, bk) = _fwd_impl(
+    out, lse, resolved = _fwd_impl(
         q, k, v, offsets, True, kv_repeat, block_q, block_k, interpret,
         window=window,
     )
-    return (out, lse), (
-        q, k, v, offsets, out_padded, lse_padded, ipret, bq, bk, None, None
-    )
+    out, lse = _saved(out, lse)
+    return (out, lse), (q, k, v, offsets, out, lse, *resolved, None, None)
 
 
 def _bwd_impl_win(kv_repeat, block_q, block_k, interpret, window, res, cts):
@@ -1022,12 +1048,13 @@ def _flash_core_mla(q, k, v, q_rope, k_rope, offsets, block_q, block_k,
 
 def _vjp_fwd_mla(q, k, v, q_rope, k_rope, offsets, block_q, block_k,
                  interpret):
-    out, lse, (out_padded, lse_padded, ipret, bq, bk) = _fwd_impl(
+    out, lse, resolved = _fwd_impl(
         q, k, v, offsets, True, 1, block_q, block_k, interpret,
         rope=(q_rope, k_rope),
     )
+    out, lse = _saved(out, lse)
     return (out, lse), (
-        (q, k, v, offsets, out_padded, lse_padded, ipret, bq, bk, None, None),
+        (q, k, v, offsets, out, lse, *resolved, None, None),
         (q_rope, k_rope),
     )
 
@@ -1056,12 +1083,14 @@ def _default_blocks(T: int, block_q, block_k, window=None):
     1024-blocks' 3 key blocks a query block hold 67% useful pairs and the
     512-blocks' 5 hold 80%, which outweighs what a smaller block loses.
     Measured on the banded grids at 2 x 8192 x 32/4 heads x 128, bf16, ms
-    a sliding layer under selective remat (2 fwd + dq + dkv;
-    ``tools/probe_flash_band.py``, PERF.md §6, PR 31): window 2048 —
-    1024 x 1024 29.83, **512 x 512 27.71**, 512 x 1024 30.95, 1024 x 512
-    32.61, 256 x 512 33.86, 256 x 1024 36.06, 512 x 256 41.58; window
-    1024 — 23.01, **18.95**, 256 x 256 32.55; window 4096 — **40.54**
-    against 41.55, so wider windows keep the row's defaults."""
+    a sliding layer under selective remat (fwd + dq + dkv, each once
+    since the forward's residuals are saved; per-kernel readings of
+    ``tools/probe_flash_band.py``, PERF.md §6, PR 31, summed anew by
+    PR 33 — no order changed from the sums with the forward twice):
+    window 2048 — 1024 x 1024 23.71, **512 x 512 22.27**, 512 x 1024
+    24.64, 1024 x 512 26.27, 256 x 512 27.53, 256 x 1024 29.18, 512 x 256
+    33.96; window 1024 — 18.37, **15.25**, 256 x 256 26.14; window 4096 —
+    **32.16** against 33.38, so wider windows keep the row's defaults."""
     fine = window is not None and window <= _FINE_BAND
     if block_q is None:
         block_q = 512 if T <= 2048 or fine else 1024
@@ -1162,7 +1191,9 @@ def flash_attention(
     block_q, block_k = _default_blocks(q.shape[1], block_q, block_k, window)
     if flash_tile.fits(q, k, v, kv_repeat, block_q, block_k, segment_ids,
                        window=window):
-        return flash_tile.tile_attention(q, k, v, causal, interpret)
+        # The one-block kernels keep no named residuals: under a policy
+        # their output is saved and their forward re-run.
+        return _tag(flash_tile.tile_attention(q, k, v, causal, interpret))
     if window is not None:
         if segment_ids is not None:
             raise NotImplementedError(

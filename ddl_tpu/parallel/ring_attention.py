@@ -309,7 +309,13 @@ def attention(
       the local strategies (causal, ``kv_repeat`` 1).  The ``sp`` ring
       refuses it by name: the shared key would have to ride the ring
       beside k and v, and no ring step takes it.
+
+    The output is tagged ``models.remat.ATTN_OUT_NAME`` on every route,
+    here or by the flash kernels' own rules, so a layer under
+    ``remat="selective"`` keeps it whatever attention it ran.
     """
+    from ddl_tpu.models.remat import tag_attn_out
+
     if impl not in ("auto", "flash", "dense"):
         raise ValueError(
             f"impl must be 'auto', 'flash', or 'dense', got {impl!r}"
@@ -328,20 +334,25 @@ def attention(
                 f"attention(window={window}): ring attention over the "
                 f"{axis!r} axis has no sliding window"
             )
-        return ring_attention(
+        return tag_attn_out(ring_attention(
             q, k, v, mesh, causal=causal, axis=axis, dp_axis=dp_axis,
             kv_repeat=kv_repeat, use_flash=use_flash,
             segment_ids=segment_ids,
-        )
+        ))
     if mesh is not None:
-        return sharded_local_attention(
+        out = sharded_local_attention(
             q, k, v, mesh, causal=causal, kv_repeat=kv_repeat,
             use_flash=use_flash, dp_axis=dp_axis, tp_axis=tp_axis,
             segment_ids=segment_ids, window=window, q_rope=q_rope,
             k_rope=k_rope,
         )
-    return _local_attention(q, k, v, use_flash, causal, kv_repeat,
-                            segment_ids, window, q_rope, k_rope)
+    else:
+        out = _local_attention(q, k, v, use_flash, causal, kv_repeat,
+                               segment_ids, window, q_rope, k_rope)
+    # ``flash_attention`` tags what it returns itself, beside the
+    # logsumexp its backward reads; a second tag here would save the
+    # output twice.
+    return out if use_flash else tag_attn_out(out)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "kv_repeat", "window"))

@@ -773,6 +773,85 @@ class TestRematPolicies:
             lambda p: llama.forward(p, tokens, cfg)
         )(params)
         assert remat.ATTN_OUT_NAME in str(tagged)
+        # ... and with the flash kernels what is saved is what their
+        # backward reads: the train step lowered for the TPU holds one
+        # forward kernel a layer under "selective", two under "full".
+        assert self._flash_kernels("selective") == {
+            "ddl_flash_fwd": 2, "ddl_flash_bwd_dq": 2, "ddl_flash_bwd_dkv": 2}
+        assert self._flash_kernels("full")["ddl_flash_fwd"] == 4
+
+    def _flash_kernels(self, policy):
+        import collections
+        import re
+        from unittest import mock
+
+        cfg = self._cfg(
+            remat=policy, attn_impl="flash", d_model=256, n_heads=2,
+            n_kv_heads=1, max_seq=1024, dtype=jnp.bfloat16,
+        )
+        params = jax.eval_shape(lambda: llama.init_params(cfg, jax.random.key(0)))
+        with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+            text = jax.jit(jax.value_and_grad(
+                lambda p, t: llama.next_token_loss(p, t, cfg)
+            )).trace(params, jax.ShapeDtypeStruct((1, 1024), jnp.int32)).lower(
+                lowering_platforms=("tpu",)).as_text()
+        return dict(collections.Counter(
+            re.findall(r'kernel_name = "(ddl_flash_\w+)"', text)))
+
+    @staticmethod
+    def _flash_stack(family, T, policy):
+        from ddl_tpu.models import afmoe, deepseek_v3, moe
+
+        common = dict(vocab=64, max_seq=T, dtype=jnp.float32,
+                      attn_impl="flash", remat=policy)
+        if family == "llama":
+            return llama, llama.LlamaConfig(
+                d_model=32, n_layers=2, n_heads=2, n_kv_heads=1, d_ff=64,
+                **common)
+        if family == "moe":
+            return moe, moe.MoeConfig(
+                d_model=32, n_layers=2, n_heads=2, n_kv_heads=1, d_ff=64,
+                n_experts=4, topk=2, qk_norm=True, **common)
+        if family == "afmoe":
+            return afmoe, afmoe.AfmoeConfig(
+                d_model=32, n_heads=2, n_kv_heads=1, head_dim=16, d_ff=64,
+                d_expert=16, n_experts=8, topk=2,
+                layer_types=(afmoe.SLIDING, afmoe.FULL), n_dense_layers=1,
+                sliding_window=8, route_scale=2.0, **common)
+        return deepseek_v3, deepseek_v3.DeepseekV3Config(
+            d_model=32, n_layers=2, n_heads=2, qk_nope_dim=16, qk_rope_dim=8,
+            v_head_dim=16, kv_lora_rank=16, d_ff=64, d_expert=16, n_experts=8,
+            topk=2, n_shared_experts=1, n_dense_layers=1, route_scale=2.0,
+            **common)
+
+    @pytest.mark.parametrize("T", [16, 21], ids=["on_the_block", "off_the_block"])
+    @pytest.mark.parametrize("family", ["llama", "moe", "afmoe", "deepseek_v3"])
+    def test_selective_is_bit_equal_to_no_remat_through_the_flash_kernels(
+            self, family, T):
+        """What "selective" saves of a blockwise call — the output and the
+        compact logsumexp — is what a second forward call would compute
+        again: loss and every gradient leaf equal the no-remat step's to
+        the last bit, with the interpreted kernels, at a T that fills its
+        (8-row) tile and one whose rows are padded.  Op by op
+        (``disable_jit``): under ``jit`` XLA fuses the recomputed norms
+        and projections another way and the last bit moves with it, with
+        or without kernels."""
+        tokens = jnp.asarray(
+            np.random.default_rng(0).integers(0, 64, (1, T)), jnp.int32)
+        got, key = {}, jax.random.key(0)  # the same weights under both
+        for policy in ("none", "selective"):
+            mod, cfg = self._flash_stack(family, T, policy)
+            params = mod.init_params(cfg, key)
+            with jax.disable_jit():
+                got[policy] = jax.value_and_grad(
+                    lambda p: mod.next_token_loss(p, tokens, cfg))(params)
+        (loss_n, grads_n), (loss_s, grads_s) = got["none"], got["selective"]
+        assert float(loss_n) == float(loss_s)
+        for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(grads_n),
+                                jax.tree.leaves(grads_s)):
+            assert float(jnp.max(jnp.abs(a))) > 0 or "bias" in str(path), path
+            np.testing.assert_array_equal(
+                np.asarray(a), np.asarray(b), jax.tree_util.keystr(path))
 
     @pytest.mark.parametrize("impl", ["einsum", "ragged"])
     def test_moe_selective_matches_no_remat(self, impl):

@@ -454,10 +454,19 @@ TILE_SHAPES = [(2, 196, 12, 64), (2, 128, 4, 32), (3, 1, 2, 32),
                (1, 24, 6, 64), (1, 24, 3, 64), (1, 16, 2, 128)]
 
 
+def _kernel_calls(fn, *args):
+    """How often ``fn``'s program lowered for the TPU calls each Pallas
+    kernel, by name."""
+    import collections
+
+    text = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    return dict(collections.Counter(
+        re.findall(r'kernel_name = "(ddl_flash_\w+)"', text)))
+
+
 def _kernels(fn, *args):
     """The Pallas kernels' names in ``fn``'s program lowered for the TPU."""
-    text = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
-    return set(re.findall(r"ddl_flash_\w+", text))
+    return set(_kernel_calls(fn, *args))
 
 
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
@@ -666,16 +675,20 @@ def test_which_kernels_a_call_lowers_to(case, want):
     assert _kernels(_grad_of(fn), *shapes) == want
 
 
-#: sha256 of the traced train steps below on the parent commit (bf3b36b),
-#: source locations and function addresses taken out.  (The text LOWERED
-#: for the TPU will not do: Mosaic serialises each kernel with the file
-#: and line of every operation, so it changes with the checkout's path.)
+#: sha256 of the traced train steps below, source locations and function
+#: addresses taken out, on PR 33's tree (the child of 116395f): that PR
+#: changed these programs on purpose — under selective remat the blockwise
+#: cores' residuals are saved, so the rematerialised backward holds no
+#: second forward kernel — and re-recorded what bf3b36b had pinned.  (The
+#: text LOWERED for the TPU will not do: Mosaic serialises each kernel with
+#: the file and line of every operation, so it changes with the checkout's
+#: path.)
 PARENT_JAX = "0.9.0"
 PARENT_STEP_SHA256 = {
     "mistral":
-        "95d4a46c32ff0e5519e98aa4d263422da9d6c1d8a249806d4165733b7b0f6355",
+        "428749c663ca7ab763f822afa339dac426ca3badca2afa6015c76fe3988bbdcd",
     "olmoe":
-        "2d60cdfcd6497abdb54858ed1103d886b520492276aebbbccc94f17b77e7e820",
+        "4306a592046e7ba287a97c3298e62e77c69b1f1285607cfaa35f086c313cca81",
 }
 
 
@@ -684,8 +697,9 @@ def test_decoder_steps_trace_to_what_the_parent_traced(model, monkeypatch):
     """T = 4096 bypasses the one-block path: the train step of a decoder
     shaped like the benchmark's (GQA 2:1 for Mistral; full MHA + QK-norm
     and routed experts for OLMoE; 128-deep heads, selective remat, flash
-    kernels and all) is the program the parent commit traced — the old
-    three kernels, equation for equation."""
+    kernels and all) holds the old three kernels and no other, each once
+    a layer and step — the forward too — and is, equation for equation,
+    the program the commit above traced."""
     from ddl_tpu.models import llama, moe
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -706,6 +720,118 @@ def test_decoder_steps_trace_to_what_the_parent_traced(model, monkeypatch):
         lambda p, t: mod.next_token_loss(p, t, cfg)
     ))(params, tokens))
     assert set(re.findall(r"ddl_flash_\w+", text)) == BLOCK
+    calls = re.findall(r"name=(ddl_flash_\w+)", text)
+    assert sorted(calls) == sorted(BLOCK), calls  # one layer, one step
     if jax.__version__ == PARENT_JAX:  # the printed form is this JAX's
         text = re.sub(r" at (0x[0-9a-f]+|\S+:\d+)", "", text)
         assert hashlib.sha256(text.encode()).hexdigest() == PARENT_STEP_SHA256[model]
+
+
+# -- what remat="selective" keeps of a blockwise call ----------------------------
+
+CORES = {  # name: (the kernels' infix, what the layer passes besides q, k, v)
+    "causal_gqa": ("", dict(kv_repeat=2)),
+    "packed": ("", dict(segment_ids=True)),
+    "window": ("swa_", dict(kv_repeat=2, window=256)),
+    "latent": ("mla_", dict(rope=64)),
+}
+
+
+def _attn_layer(core, B, T, H, D, policy, **flash_kw):
+    """``(x, w) -> x``: one projection, a blockwise attention call of the
+    given core, the output projection — under the remat policy."""
+    from ddl_tpu.models import remat
+
+    _, kw = CORES[core]
+    kw = dict(kw)
+    rep, R = kw.get("kv_repeat", 1), kw.pop("rope", None)
+    if kw.pop("segment_ids", False):
+        kw["segment_ids"] = jnp.arange(T)[None].repeat(B, 0) // (T // 2)
+
+    def layer(x, w):
+        q = (x @ w).reshape(B, T, H, D)
+        k = v = q[:, :, ::rep]
+        if R:
+            kw.update(q_rope=q[..., :R], k_rope=k[:, :, :1, :R])
+        out = flash_attention(q, k, v, **kw, **flash_kw)
+        return x + out.reshape(B, T, H * D) @ w
+
+    return remat.wrap(layer, policy)
+
+
+@pytest.mark.parametrize("policy,forward_calls", [
+    ("none", 1), ("selective", 1), ("full", 2), ("dots", 2)])
+@pytest.mark.parametrize("core", list(CORES))
+def test_forward_kernel_calls_a_layer_under_each_remat_policy(
+        core, policy, forward_calls):
+    """The backward kernels read the output and the logsumexp, and
+    ``selective`` saves both: its backward pass runs no forward kernel,
+    as with no remat at all; ``full`` and ``dots`` keep neither and run it
+    again.  Counted in two layers' train step lowered for the TPU."""
+    B, T, H, D = 1, 1024, 2, 128
+    layer = _attn_layer(core, B, T, H, D, policy, interpret=False)
+    x = jax.ShapeDtypeStruct((B, T, H * D), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((H * D, H * D), jnp.bfloat16)
+    got = _kernel_calls(jax.value_and_grad(
+        lambda w, x: jnp.sum(layer(layer(x, w), w).astype(jnp.float32))
+    ), w, x)
+    name = "ddl_flash_" + CORES[core][0]
+    assert got == {name + "fwd": 2 * forward_calls,
+                   name + "bwd_dq": 2, name + "bwd_dkv": 2}
+
+
+@pytest.mark.parametrize("T", [1024, 1000], ids=["on_the_block", "off_the_block"])
+@pytest.mark.parametrize("core", list(CORES))
+def test_selective_saves_one_output_and_a_compact_logsumexp(core, T):
+    """A layer's saved values under ``selective``: its input, ONE tensor of
+    the attention output's bytes and one float32 (B, H, T) — not the
+    kernels' lane-padded (B, H, Tq, 1) row operand, and no second copy of
+    the output in the kernels' layout."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    B, H, D = 1, 2, 128
+    layer = _attn_layer(core, B, T, H, D, "selective", interpret=False)
+    x = jax.ShapeDtypeStruct((B, T, H * D), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((H * D, H * D), jnp.bfloat16)
+    saved = [(aval.shape, str(aval.dtype)) for aval, why in saved_residuals(layer, x, w)
+             if "from the argument" not in why and "from a constant" not in why]
+    assert sorted(saved) == sorted([((B, T, H, D), "bfloat16"),
+                                    ((B, H, T), "float32")]), saved
+
+
+def test_the_logsumexp_cotangent_reaches_q_and_k(rng):
+    """``flash_attention_with_lse`` with a non-zero ``dlse`` (the ``sp``
+    ring's combine weights make one) against the dense scores, at a T off
+    the block: the compact logsumexp the backward now reads is padded back
+    to the kernels' rows."""
+    from ddl_tpu.ops import flash_attention_with_lse
+
+    q, k, v = _qkv(rng, B=2, T=70, H=4, Hkv=2, D=16)
+    mix = jnp.asarray(rng.standard_normal((2, 4, 70)), jnp.float32)
+
+    def dense(q, k, v):
+        kk, vv = jnp.repeat(k, 2, axis=2), jnp.repeat(v, 2, axis=2)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) / 4.0
+        s = jnp.where(jnp.tril(jnp.ones((70, 70), bool)), s, -jnp.inf)
+        return (jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), vv),
+                jax.nn.logsumexp(s, -1))
+
+    def loss(fn):
+        def f(q, k, v):
+            out, lse = fn(q, k, v)
+            return jnp.sum(jnp.sin(out)) + jnp.sum(mix * lse)
+        return f
+
+    flash = lambda q, k, v: flash_attention_with_lse(  # noqa: E731
+        q, k, v, kv_repeat=2, block_q=32, block_k=32)
+    np.testing.assert_allclose(flash(q, k, v)[1], dense(q, k, v)[1],
+                               atol=2e-5, rtol=2e-5)
+    plain = jax.grad(lambda q, k, v: jnp.sum(jnp.sin(flash(q, k, v)[0])), (0, 1))
+    for got, want, bare, name in zip(
+            jax.grad(loss(flash), (0, 1, 2))(q, k, v),
+            jax.grad(loss(dense), (0, 1, 2))(q, k, v),
+            plain(q, k, v) + (None,), ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=5e-5, rtol=5e-5, err_msg=name)
+        if bare is not None:  # the lse term moved it: dlse was not dropped
+            assert float(jnp.max(jnp.abs(got - bare))) > 1e-3, name

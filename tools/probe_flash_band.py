@@ -121,8 +121,8 @@ def main() -> None:
         if ms:
             line.update(
                 ms={f: round(t, 4) for f, t in ms.items()},
-                # a sliding layer under selective remat: the forward twice
-                ms_layer=round(ms["fwd"] + sum(ms.values()), 4),
+                # a sliding layer under selective remat: each kernel once
+                ms_layer=round(sum(ms.values()), 4),
                 peak={f: peak_share(f, t, dev.device_kind)
                       for f, t in ms.items()},
             )

@@ -90,8 +90,8 @@ def main() -> None:
                 "v_head_dim": D})
             line.update(
                 ms={f: round(t, 4) for f, t in ms.items()},
-                # a layer under selective remat: the forward twice
-                ms_layer=round(ms["fwd"] + sum(ms.values()), 4),
+                # a layer under selective remat: each kernel once
+                ms_layer=round(sum(ms.values()), 4),
                 peak={f: round(100 * 2 * widths[f] * pairs / (t * 1e-3)
                                / peaks.peak_flops(dev.device_kind), 2)
                       for f, t in ms.items()},
