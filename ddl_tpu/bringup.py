@@ -31,6 +31,7 @@ def configure_compile_cache() -> str:
     environment's — JAX reads the variable itself and nothing is set in
     code.  Otherwise the cache lives in ``<checkout>/.jax_cache``.
     """
+    _salt_cache_key()
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed
@@ -38,6 +39,39 @@ def configure_compile_cache() -> str:
 
     jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
     return CHECKOUT_CACHE_DIR
+
+
+def _salt_cache_key() -> None:
+    """Make the model-layer scope table a part of every cache key.
+
+    JAX hashes a program with its debug info stripped, and a
+    ``jax.named_scope`` is debug info: a program keeps its key when a
+    scope is added, and a warm cache then hands back the executable that
+    was compiled without it, whose device trace names no scope
+    (``benchmarks/lib/scopes.py`` would read every op as unscoped).
+    ``jax_compilation_cache_include_metadata_in_key`` is no way out: it
+    puts file paths and line numbers into the key, so a checkout in
+    another directory never hits.  The key has a hook for a deployment's
+    own salt, ``jax._src.cache_key.custom_hook`` (a private module: where
+    a later JAX has no such function nothing is salted, and the first
+    run after a change of the table wants an empty cache).  The salt is
+    ``ops.naming.scope_table_digest()``, so the key moves when the table
+    does and at no other time.  Whatever hook is installed already keeps
+    its say."""
+    try:
+        from jax._src import cache_key
+    except ImportError:
+        return
+    hook = getattr(cache_key, "custom_hook", None)
+    if hook is None or getattr(hook, "ddl_scope_salt", False):
+        return
+    from ddl_tpu.ops.naming import scope_table_digest
+
+    def salted() -> str:
+        return hook() + "ddl.scopes=" + scope_table_digest()
+
+    salted.ddl_scope_salt = True
+    cache_key.custom_hook = salted
 
 
 def bring_up(request: Optional[str] = None) -> str:

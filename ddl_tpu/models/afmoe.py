@@ -51,6 +51,7 @@ from jax.sharding import PartitionSpec as P
 from ddl_tpu.models import llama as _llama
 from ddl_tpu.models import moe as _moe
 from ddl_tpu.models import remat as _remat
+from ddl_tpu.ops.naming import scope
 
 Params = Dict[str, Any]
 
@@ -240,28 +241,29 @@ def _attn_block(
     B, T = x.shape[:2]
     dt = x.dtype
     eps = cfg.norm_eps
-    h = _llama._rms_norm(x, layer["input_norm"], eps)
+    with scope("ddl.attn"):
+        h = _llama._rms_norm(x, layer["input_norm"], eps)
 
-    def heads(w: str, n: int) -> jax.Array:
-        return (h @ layer[w].astype(dt)).reshape(B, T, n, cfg.head_dim)
+        def heads(w: str, n: int) -> jax.Array:
+            return (h @ layer[w].astype(dt)).reshape(B, T, n, cfg.head_dim)
 
-    # One head_dim-long weight, applied to every head.
-    q = _llama._rms_norm(heads("wq", cfg.n_heads), layer["q_norm"], eps)
-    k = _llama._rms_norm(heads("wk", cfg.n_kv_heads), layer["k_norm"], eps)
-    v = heads("wv", cfg.n_kv_heads)
-    if sliding:  # a full layer carries no position encoding
-        q = _llama._rope(q, positions, cfg.rope_theta)
-        k = _llama._rope(k, positions, cfg.rope_theta)
-    attn = attention(
-        q, k, v, mesh=mesh, impl=cfg.attn_impl, causal=True,
-        kv_repeat=cfg.n_heads // cfg.n_kv_heads,
-        window=cfg.sliding_window if sliding else None,
-    )
-    with jax.named_scope("ddl.attn_gate"):
-        gate = jax.nn.sigmoid(h @ layer["wg"].astype(dt))
-        gated = attn.reshape(B, T, -1) * gate
-    out = gated @ layer["wo"].astype(dt)
-    return x + _llama._rms_norm(out, layer["post_attn_norm"], eps)
+        # One head_dim-long weight, applied to every head.
+        q = _llama._rms_norm(heads("wq", cfg.n_heads), layer["q_norm"], eps)
+        k = _llama._rms_norm(heads("wk", cfg.n_kv_heads), layer["k_norm"], eps)
+        v = heads("wv", cfg.n_kv_heads)
+        if sliding:  # a full layer carries no position encoding
+            q = _llama._rope(q, positions, cfg.rope_theta)
+            k = _llama._rope(k, positions, cfg.rope_theta)
+        attn = attention(
+            q, k, v, mesh=mesh, impl=cfg.attn_impl, causal=True,
+            kv_repeat=cfg.n_heads // cfg.n_kv_heads,
+            window=cfg.sliding_window if sliding else None,
+        )
+        with scope("ddl.attn_gate"):
+            gate = jax.nn.sigmoid(h @ layer["wg"].astype(dt))
+            gated = attn.reshape(B, T, -1) * gate
+        out = gated @ layer["wo"].astype(dt)
+        return x + _llama._rms_norm(out, layer["post_attn_norm"], eps)
 
 
 # The routed + shared expert layer is ``moe.sigmoid_expert_mlp``, the one
@@ -283,13 +285,14 @@ def _layer_apply(
     """One block of the stated kinds → (x, the router's picks (B, T,
     topk), or ``None`` from a dense layer)."""
     x = _attn_block(layer, x, cfg, positions, sliding, mesh)
-    h = _llama._rms_norm(x, layer["pre_mlp_norm"], cfg.norm_eps)
-    if dense:
-        out, top_e = _llama._swiglu(layer, h), None
-    else:
-        out, top_e = _moe_mlp(h, layer, cfg, mesh)
-    out = _llama._rms_norm(out, layer["post_mlp_norm"], cfg.norm_eps)
-    return x + out, top_e
+    with scope("ddl.mlp" if dense else "ddl.moe"):
+        h = _llama._rms_norm(x, layer["pre_mlp_norm"], cfg.norm_eps)
+        if dense:
+            out, top_e = _llama._swiglu(layer, h), None
+        else:
+            out, top_e = _moe_mlp(h, layer, cfg, mesh)
+        out = _llama._rms_norm(out, layer["post_mlp_norm"], cfg.norm_eps)
+        return x + out, top_e
 
 
 def forward_with_choices(
@@ -303,9 +306,10 @@ def forward_with_choices(
     here or not)."""
     dt = cfg.dtype
     positions = jnp.arange(tokens.shape[1])
-    x = params["embed"].astype(dt)[tokens]
-    if cfg.mup_enabled:
-        x = x * jnp.asarray(math.sqrt(cfg.d_model), dt)
+    with scope("ddl.embed"):
+        x = params["embed"].astype(dt)[tokens]
+        if cfg.mup_enabled:
+            x = x * jnp.asarray(math.sqrt(cfg.d_model), dt)
     picks = []
     for li, (layer, kind) in enumerate(zip(params["layers"], cfg.layer_types)):
 
@@ -315,8 +319,7 @@ def forward_with_choices(
         x, top_e = _remat.wrap(layer_fn, cfg.remat)(x, layer)
         if top_e is not None:
             picks.append(top_e)
-    x = _llama._rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
+    logits = _llama._lm_head(params, x, cfg)
     return logits, jnp.stack(picks) if picks else jnp.zeros(
         (0,) + tokens.shape + (cfg.topk,), jnp.int32
     )

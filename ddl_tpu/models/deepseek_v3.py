@@ -56,6 +56,7 @@ from jax.sharding import PartitionSpec as P
 from ddl_tpu.models import llama as _llama
 from ddl_tpu.models import moe as _moe
 from ddl_tpu.models import remat as _remat
+from ddl_tpu.ops.naming import scope
 
 Params = Dict[str, Any]
 
@@ -240,23 +241,28 @@ def _attn_block(
     B, T = x.shape[:2]
     dt = x.dtype
     H, nope, rank = cfg.n_heads, cfg.qk_nope_dim, cfg.kv_lora_rank
-    h = _llama._rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    with jax.named_scope("ddl.mla_q"):
-        q = (h @ layer["wq"].astype(dt)).reshape(B, T, H, -1)
-        q_nope = q[..., :nope]
-        q_rope = _rope_pairs(q[..., nope:], positions, cfg.rope_theta)
-    with jax.named_scope("ddl.mla_kv_up"):
-        kv_a = h @ layer["wkv_a"].astype(dt)  # (B, T, rank + rope)
-        c = _llama._rms_norm(kv_a[..., :rank], layer["kv_a_norm"], cfg.norm_eps)
-        kv = (c @ layer["wkv_b"].astype(dt)).reshape(B, T, H, -1)
-        k_nope, v = kv[..., :nope], kv[..., nope:]
-        # One rotary key a position, for all heads.
-        k_rope = _rope_pairs(kv_a[..., None, rank:], positions, cfg.rope_theta)
-    attn = attention(
-        q_nope, k_nope, v, mesh=mesh, impl=cfg.attn_impl, causal=True,
-        q_rope=q_rope, k_rope=k_rope,
-    )
-    return x + attn.reshape(B, T, -1) @ layer["wo"].astype(dt)
+    with scope("ddl.attn"):
+        h = _llama._rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+        with scope("ddl.mla_q"):
+            q = (h @ layer["wq"].astype(dt)).reshape(B, T, H, -1)
+            q_nope = q[..., :nope]
+            q_rope = _rope_pairs(q[..., nope:], positions, cfg.rope_theta)
+        with scope("ddl.mla_kv_up"):
+            kv_a = h @ layer["wkv_a"].astype(dt)  # (B, T, rank + rope)
+            c = _llama._rms_norm(
+                kv_a[..., :rank], layer["kv_a_norm"], cfg.norm_eps
+            )
+            kv = (c @ layer["wkv_b"].astype(dt)).reshape(B, T, H, -1)
+            k_nope, v = kv[..., :nope], kv[..., nope:]
+            # One rotary key a position, for all heads.
+            k_rope = _rope_pairs(
+                kv_a[..., None, rank:], positions, cfg.rope_theta
+            )
+        attn = attention(
+            q_nope, k_nope, v, mesh=mesh, impl=cfg.attn_impl, causal=True,
+            q_rope=q_rope, k_rope=k_rope,
+        )
+        return x + attn.reshape(B, T, -1) @ layer["wo"].astype(dt)
 
 
 def _layer_apply(
@@ -270,11 +276,12 @@ def _layer_apply(
     """One block → (x, the router's picks (B, T, topk), or ``None`` from a
     dense layer)."""
     x = _attn_block(layer, x, cfg, positions, mesh)
-    h = _llama._rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-    if dense:
-        return x + _llama._swiglu(layer, h), None
-    out, top_e = _moe.sigmoid_expert_mlp(h, layer, cfg, mesh)
-    return x + out, top_e
+    if dense:  # the llama block's norm, SwiGLU and residual
+        return _llama._mlp_block(layer, x, cfg), None
+    with scope("ddl.moe"):
+        h = _llama._rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+        out, top_e = _moe.sigmoid_expert_mlp(h, layer, cfg, mesh)
+        return x + out, top_e
 
 
 def forward_with_choices(
@@ -288,7 +295,8 @@ def forward_with_choices(
     here or not)."""
     dt = cfg.dtype
     positions = jnp.arange(tokens.shape[1])
-    x = params["embed"].astype(dt)[tokens]
+    with scope("ddl.embed"):
+        x = params["embed"].astype(dt)[tokens]
     picks = []
     for li, layer in enumerate(params["layers"]):
 
@@ -298,8 +306,7 @@ def forward_with_choices(
         x, top_e = _remat.wrap(layer_fn, cfg.remat)(x, layer)
         if top_e is not None:
             picks.append(top_e)
-    x = _llama._rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
+    logits = _llama._lm_head(params, x, cfg)
     return logits, jnp.stack(picks) if picks else jnp.zeros(
         (0,) + tokens.shape + (cfg.topk,), jnp.int32
     )

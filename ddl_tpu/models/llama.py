@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ddl_tpu.ops.naming import scope
+
 Params = Dict[str, Any]
 
 
@@ -237,7 +239,8 @@ def forward(
     B, T = tokens.shape
     dt = cfg.dtype
     positions = jnp.arange(T)
-    x = params["embed"].astype(dt)[tokens]  # (B, T, D)
+    with scope("ddl.embed"):
+        x = params["embed"].astype(dt)[tokens]  # (B, T, D)
 
     def layer_fn(x: jax.Array, layer: Params) -> jax.Array:
         return _layer_apply(
@@ -251,9 +254,15 @@ def forward(
     layer_fn = _remat.wrap(layer_fn, cfg.remat)
     for layer in params["layers"]:
         x = layer_fn(x, layer)
+    return _lm_head(params, x, cfg)
 
-    x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
+
+def _lm_head(params: Params, x: jax.Array, cfg: Any) -> jax.Array:
+    """Final norm + vocabulary matmul, float32 logits — the one head of
+    the four decoder families."""
+    with scope("ddl.head"):
+        x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
+        return (x @ params["lm_head"].astype(x.dtype)).astype(jnp.float32)
 
 
 def _attn_block(
@@ -273,16 +282,17 @@ def _attn_block(
 
     B, T = x.shape[:2]
     dt = x.dtype
-    h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    q, k, v = _attn_qkv(layer, h, cfg, positions)
-    # GQA k/v stay compact: expansion happens inside the attention
-    # block, so ring attention rotates 1/rep of the bytes over ICI.
-    rep = cfg.n_heads // cfg.n_kv_heads
-    attn = attention(
-        q, k, v, mesh=mesh, impl=cfg.attn_impl, causal=True,
-        kv_repeat=rep, segment_ids=segment_ids,
-    )
-    return x + attn.reshape(B, T, -1) @ layer["wo"].astype(dt)
+    with scope("ddl.attn"):
+        h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+        q, k, v = _attn_qkv(layer, h, cfg, positions)
+        # GQA k/v stay compact: expansion happens inside the attention
+        # block, so ring attention rotates 1/rep of the bytes over ICI.
+        rep = cfg.n_heads // cfg.n_kv_heads
+        attn = attention(
+            q, k, v, mesh=mesh, impl=cfg.attn_impl, causal=True,
+            kv_repeat=rep, segment_ids=segment_ids,
+        )
+        return x + attn.reshape(B, T, -1) @ layer["wo"].astype(dt)
 
 
 def _layer_apply(
@@ -350,7 +360,10 @@ def _swiglu(layer: Params, h: jax.Array) -> jax.Array:
 
 def _mlp_block(layer: Params, x: jax.Array, cfg: LlamaConfig) -> jax.Array:
     """SwiGLU MLP sub-block with residual (shared by train and decode)."""
-    return x + _swiglu(layer, _rms_norm(x, layer["mlp_norm"], cfg.norm_eps))
+    with scope("ddl.mlp"):
+        return x + _swiglu(
+            layer, _rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+        )
 
 
 def init_cache(cfg: LlamaConfig, batch: int, max_len: int) -> Params:
@@ -793,7 +806,8 @@ def forward_pp(
     B, T = tokens.shape
     dt = cfg.dtype
     positions = jnp.arange(T)
-    x = params["embed"].astype(dt)[tokens]
+    with scope("ddl.embed"):
+        x = params["embed"].astype(dt)[tokens]
 
     n_tp = (
         mesh.shape["tp"]
@@ -837,8 +851,7 @@ def forward_pp(
         stage_param_specs=_TP_STAGE_SPECS if tp_resident else None,
         schedule=schedule, n_chunks=n_chunks,
     )
-    x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
+    return _lm_head(params, x, cfg)
 
 
 def next_token_loss_pp(

@@ -13,6 +13,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ddl_tpu.ops.naming import scope
+
 
 def cross_entropy(
     logits: jax.Array,
@@ -25,14 +27,15 @@ def cross_entropy(
     (optional, broadcastable to targets' shape): positions with mask 0
     are excluded from the mean.
     """
-    sel = jnp.take_along_axis(
-        logits, targets[..., None].astype(jnp.int32), axis=-1
-    )[..., 0]
-    nll = jax.nn.logsumexp(logits, axis=-1) - sel
-    if mask is None:
-        return jnp.mean(nll)
-    mask = jnp.broadcast_to(mask.astype(nll.dtype), nll.shape)
-    return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    with scope("ddl.head"):
+        sel = jnp.take_along_axis(
+            logits, targets[..., None].astype(jnp.int32), axis=-1
+        )[..., 0]
+        nll = jax.nn.logsumexp(logits, axis=-1) - sel
+        if mask is None:
+            return jnp.mean(nll)
+        mask = jnp.broadcast_to(mask.astype(nll.dtype), nll.shape)
+        return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
 def next_token_cross_entropy(
@@ -52,11 +55,14 @@ def next_token_cross_entropy(
     shared by every model family.
     """
     T = tokens.shape[1]
-    targets = jnp.roll(tokens, -1, axis=1)
-    mask = jnp.broadcast_to((jnp.arange(T) < T - 1)[None, :], tokens.shape)
-    if segment_ids is not None:
-        boundary = segment_ids != jnp.roll(segment_ids, -1, axis=1)
-        mask = mask & jnp.logical_not(boundary)
-    if extra_mask is not None:
-        mask = mask & jnp.logical_not(extra_mask)
-    return cross_entropy(logits, targets, mask)
+    with scope("ddl.head"):
+        targets = jnp.roll(tokens, -1, axis=1)
+        mask = jnp.broadcast_to(
+            (jnp.arange(T) < T - 1)[None, :], tokens.shape
+        )
+        if segment_ids is not None:
+            boundary = segment_ids != jnp.roll(segment_ids, -1, axis=1)
+            mask = mask & jnp.logical_not(boundary)
+        if extra_mask is not None:
+            mask = mask & jnp.logical_not(extra_mask)
+        return cross_entropy(logits, targets, mask)

@@ -51,6 +51,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ddl_tpu.models import llama as _llama
+from ddl_tpu.ops.naming import scope
 
 Params = Dict[str, Any]
 
@@ -387,7 +388,7 @@ def _ragged_mlp(
     """:func:`moe_mlp_ragged` with both router losses and the router's
     picks: (out, (2,) losses, top_e (N, k)) — this family's softmax
     router in front of :func:`ragged_experts`."""
-    with jax.named_scope("ddl.moe_route"):
+    with scope("ddl.moe_route"):
         probs, top_p, top_e, z = _router_topk(x, layer, cfg)
     out = ragged_experts(x, layer, top_p, top_e)
     return out, _router_losses(probs, top_e, z, cfg), top_e
@@ -436,7 +437,7 @@ def ragged_experts(
     dt = x.dtype
     n_groups = experts["w_gate"].shape[0]
 
-    with jax.named_scope("ddl.moe_route"):
+    with scope("ddl.moe_route"):
         flat_e = top_e.reshape(-1)  # (N*k,) expert of copy i (token i//k)
         if held is not None:
             first, count = held
@@ -450,7 +451,7 @@ def ragged_experts(
             flat_e, length=n_groups + (held is not None)
         ).astype(jnp.int32)[:n_groups]
 
-    with jax.named_scope("ddl.moe_experts"):
+    with scope("ddl.moe_experts"):
         xs = jnp.take(x, order // k, axis=0)  # (N*k, D) grouped by expert
         if held is not None:
             in_a_group = (jnp.arange(N * k) < jnp.sum(group_sizes))[:, None]
@@ -463,7 +464,7 @@ def ragged_experts(
             gate * up, experts["w_down"].astype(dt), group_sizes
         )  # (N*k, D), still expert-sorted
 
-    with jax.named_scope("ddl.moe_combine"):
+    with scope("ddl.moe_combine"):
         inv = jnp.argsort(order)  # flat copy index -> its sorted row
         per_slot = jnp.take(rows, inv, axis=0).reshape(N, k, D)
         if held is not None:
@@ -500,7 +501,7 @@ def sigmoid_route(h: jax.Array, layer: Params, cfg: Any):
 def sigmoid_expert_tokens(h: jax.Array, layer: Params, cfg: Any):
     """Shared expert + the held routed experts on flat tokens (N, D):
     (out (N, D), the router's picks (N, k))."""
-    with jax.named_scope("ddl.moe_route"):
+    with scope("ddl.moe_route"):
         top_w, top_e = sigmoid_route(h, layer, cfg)
     held = None if cfg.held == (0, cfg.n_experts) else cfg.held
     if held is not None:
@@ -513,7 +514,7 @@ def sigmoid_expert_tokens(h: jax.Array, layer: Params, cfg: Any):
         # it stands, as it selects with expert_bias where it stands.
         top_w = jax.lax.stop_gradient(top_w)
     routed = ragged_experts(h, layer["experts"], top_w, top_e, held=held)
-    with jax.named_scope("ddl.moe_shared"):
+    with scope("ddl.moe_shared"):
         shared = _llama._swiglu(layer["shared"], h)
     return shared + routed, top_e
 
@@ -666,9 +667,10 @@ def _layer_apply(
     x = _llama._attn_block(
         layer, x, cfg, positions, mesh=mesh, segment_ids=segment_ids
     )
-    h = _llama._rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-    moe_out, aux, top_e = _routed_mlp(h, layer, cfg, mesh)
-    return x + moe_out, aux, top_e
+    with scope("ddl.moe"):
+        h = _llama._rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+        moe_out, aux, top_e = _routed_mlp(h, layer, cfg, mesh)
+        return x + moe_out, aux, top_e
 
 
 def forward(
@@ -714,7 +716,8 @@ def _forward(
     cfg = _resolve_impl(cfg, mesh)
     dt = cfg.dtype
     positions = jnp.arange(tokens.shape[1])
-    x = params["embed"].astype(dt)[tokens]
+    with scope("ddl.embed"):
+        x = params["embed"].astype(dt)[tokens]
     losses = jnp.zeros((2,), jnp.float32)
 
     def layer_fn(x: jax.Array, layer: Params):
@@ -731,12 +734,14 @@ def _forward(
     picks = []
     for layer in params["layers"]:
         x, layer_losses, top_e = layer_fn(x, layer)
-        losses = losses + layer_losses
+        with scope("ddl.head"):  # the auxiliary losses' reduction
+            losses = losses + layer_losses
         picks.append(top_e)
 
-    x = _llama._rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
-    return logits, losses / cfg.n_layers, jnp.stack(picks)
+    logits = _llama._lm_head(params, x, cfg)
+    with scope("ddl.head"):
+        losses = losses / cfg.n_layers
+    return logits, losses, jnp.stack(picks)
 
 
 def _router_penalty(cfg: MoeConfig, losses: jax.Array) -> jax.Array:
@@ -855,7 +860,8 @@ def _forward_pp(
     B, T = tokens.shape
     dt = cfg.dtype
     positions = jnp.arange(T)
-    x = params["embed"].astype(dt)[tokens]
+    with scope("ddl.embed"):
+        x = params["embed"].astype(dt)[tokens]
 
     def one_layer(state, layer):
         h, loss_rows = state
@@ -880,8 +886,7 @@ def _forward_pp(
         stage_fn, mesh, n_microbatches, axis=axis,
         schedule=schedule, n_chunks=n_chunks,
     )
-    x = _llama._rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
+    logits = _llama._lm_head(params, x, cfg)
     # Every row of a microbatch carries that microbatch's summed router
     # losses; the row-mean is the microbatch-mean, normalized per layer
     # as in the non-pp forward.
@@ -1014,4 +1019,5 @@ def next_token_loss(
 
     logits, losses, _ = _forward(params, tokens, cfg, mesh, segment_ids)
     ce = next_token_cross_entropy(logits, tokens, segment_ids=segment_ids)
-    return ce + _router_penalty(cfg, losses)
+    with scope("ddl.head"):
+        return ce + _router_penalty(cfg, losses)

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ddl_tpu.ops.naming import scope
+
 Params = Dict[str, Any]
 
 
@@ -148,40 +150,44 @@ def _layer_apply(
 
     B, T = x.shape[:2]
     dt = x.dtype
-    h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    q = (h @ layer["wq"].astype(dt)).reshape(B, T, cfg.n_heads,
-                                             cfg.head_dim)
-    k = (h @ layer["wk"].astype(dt)).reshape(B, T, cfg.n_heads,
-                                             cfg.head_dim)
-    v = (h @ layer["wv"].astype(dt)).reshape(B, T, cfg.n_heads,
-                                             cfg.head_dim)
-    attn = attention(
-        q, k, v, mesh=mesh, impl=cfg.attn_impl, causal=False
-    )
-    x = x + attn.reshape(B, T, -1) @ layer["wo"].astype(dt)
+    with scope("ddl.attn"):
+        h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+        q = (h @ layer["wq"].astype(dt)).reshape(B, T, cfg.n_heads,
+                                                 cfg.head_dim)
+        k = (h @ layer["wk"].astype(dt)).reshape(B, T, cfg.n_heads,
+                                                 cfg.head_dim)
+        v = (h @ layer["wv"].astype(dt)).reshape(B, T, cfg.n_heads,
+                                                 cfg.head_dim)
+        attn = attention(
+            q, k, v, mesh=mesh, impl=cfg.attn_impl, causal=False
+        )
+        x = x + attn.reshape(B, T, -1) @ layer["wo"].astype(dt)
 
-    h = _rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-    return x + jax.nn.gelu(h @ layer["w_up"].astype(dt)) @ layer[
-        "w_down"
-    ].astype(dt)
+    with scope("ddl.mlp"):
+        h = _rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+        return x + jax.nn.gelu(h @ layer["w_up"].astype(dt)) @ layer[
+            "w_down"
+        ].astype(dt)
 
 
 def _embed(params: Params, images: jax.Array, cfg: ViTConfig) -> jax.Array:
     """Patchify + project + position-embed (shared by both forwards)."""
     dt = cfg.dtype
-    if images.ndim == 2:  # the loader's flattened pixel rows
-        images = images.reshape(
-            -1, cfg.image_size, cfg.image_size, cfg.n_channels
-        )
-    x = patchify(images.astype(dt), cfg) @ params["patch_embed"].astype(dt)
-    return x + params["pos_embed"].astype(dt)[None]
+    with scope("ddl.patchify"):
+        if images.ndim == 2:  # the loader's flattened pixel rows
+            images = images.reshape(
+                -1, cfg.image_size, cfg.image_size, cfg.n_channels
+            )
+        x = patchify(images.astype(dt), cfg) @ params["patch_embed"].astype(dt)
+        return x + params["pos_embed"].astype(dt)[None]
 
 
 def _head(params: Params, x: jax.Array, cfg: ViTConfig) -> jax.Array:
     """Final norm + mean pool + classification head (shared)."""
-    x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
-    pooled = jnp.mean(x.astype(jnp.float32), axis=1)  # (B, d)
-    return pooled @ params["head"]
+    with scope("ddl.head"):
+        x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
+        pooled = jnp.mean(x.astype(jnp.float32), axis=1)  # (B, d)
+        return pooled @ params["head"]
 
 
 def forward(

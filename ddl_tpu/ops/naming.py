@@ -1,4 +1,5 @@
-"""Names of their own for the Pallas kernels on the device trace.
+"""Names on the device trace: the Pallas kernels' own, and the model-layer
+scopes every op of a train step stands under (:data:`SCOPE_NAMES`).
 
 A profiler trace's ``XLA Ops`` events carry the HLO instruction name, and
 XLA names a ``pallas_call``'s custom call after the innermost frame of
@@ -24,6 +25,8 @@ matmul of the repo's own (add it to :data:`KERNEL_NAMES` then).
 
 from __future__ import annotations
 
+import contextlib
+import hashlib
 from typing import Any, Callable
 
 import jax
@@ -40,6 +43,45 @@ KERNEL_NAMES = (
     "ddl_flash_tile_fwd", "ddl_flash_tile_bwd",
     "ddl_ici_bcast", "ddl_ici_scatter", "ddl_shuffle_exchange",
 )
+
+#: The model-layer scopes of the step program: every op of a train step
+#: is traced under one of them, at the shared routine where there is one
+#: (``docs/OBSERVABILITY.md`` has the table of what each encloses, and a
+#: test keeps the two equal).  A scope is a frame of JAX's name stack and
+#: reaches the device trace as a part of each op's ``tf_op`` path,
+#: whatever transform wraps it (``jvp(ddl.attn)/...``,
+#: ``transpose(jvp(...))/checkpoint/ddl.attn/...``,
+#: ``.../checkpoint/rematted_computation/ddl.attn/...``): the innermost
+#: ``ddl.`` / ``ddl_`` frame names the op's layer, the wrappers its pass.
+#: ``benchmarks/lib/scopes.py`` reads them.  Compile-time metadata only:
+#: no op, no run-time cost, no knob.
+SCOPE_NAMES = (
+    "ddl.embed", "ddl.patchify",
+    "ddl.attn", "ddl.attn_gate", "ddl.mla_q", "ddl.mla_kv_up",
+    "ddl.mlp",
+    "ddl.moe", "ddl.moe_route", "ddl.moe_experts", "ddl.moe_combine",
+    "ddl.moe_shared",
+    "ddl.head", "ddl.optimizer",
+)
+
+#: Raise when a scope moves to other ops and the table stays as it is:
+#: the compile cache is keyed by the table (:func:`scope_table_digest`),
+#: not by where its names are used.
+SCOPE_PLACEMENT_REV = 1
+
+
+def scope(name: str) -> contextlib.AbstractContextManager:
+    """``jax.named_scope(name)`` for a name of :data:`SCOPE_NAMES`."""
+    assert name in SCOPE_NAMES, name
+    return jax.named_scope(name)
+
+
+def scope_table_digest() -> str:
+    """What ``ddl_tpu.bringup.configure_compile_cache`` salts the compile
+    cache's key with (JAX hashes a program without its name stack: the
+    docstring there)."""
+    text = "\n".join((str(SCOPE_PLACEMENT_REV),) + SCOPE_NAMES)
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
 def named_pallas_call(name: str, kernel: Callable, **kwargs: Any) -> Callable:

@@ -209,9 +209,12 @@ def _make_apply_step(loss_fn: Callable[..., jax.Array], optimizer: Any,
     def apply_step(params: Any, opt_state: Any, batch: Any):
         import optax
 
+        from ddl_tpu.ops.naming import scope
+
         loss, grads = _grads(params, batch)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with scope("ddl.optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     return apply_step
