@@ -43,6 +43,7 @@ TPU).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -394,6 +395,114 @@ def _ragged_mlp(
     return out, _router_losses(probs, top_e, z, cfg), top_e
 
 
+# -- the routed core's two row moves --------------------------------------------
+#
+# ``ragged_experts`` moves rows twice: N·k copies out of N rows into expert
+# order, and the experts' N·k rows back into copy order.  Autodiff transposes
+# a ``take`` into a scatter-add that must allow for colliding indices, which
+# a v5e runs at 0.4-0.55 of the rate of the gather that is its equal
+# (``tools/probe_ragged_rows.py``, PERF.md section 6, PR 35; telling the
+# scatter ``unique_indices`` changes nothing).  The code knows what XLA
+# cannot: ``order`` is a permutation of the copies and ``inv`` its inverse,
+# and the forward pass holds both.  So each move carries a backward rule
+# that is a gather.  The rules are reverse-mode only (``custom_vjp`` refuses
+# ``jvp`` / ``jacfwd`` / ``linearize``; nothing in ``ddl_tpu`` differentiates
+# the routed layer forward).
+
+
+def _rows_at(rows: jax.Array, idx: jax.Array) -> jax.Array:
+    """``rows[idx]`` for in-range ``idx`` as one ``lax.gather`` traced in
+    place: the row move of the backward rules.  Not ``jnp.take``, which is
+    a jitted function: traced twice at one signature it lowers to one
+    shared private function, and XLA inlines that without the caller's
+    name stack — the backward gathers would reach the device trace under
+    no ``ddl.`` scope."""
+    return jax.lax.gather(
+        rows, idx[:, None],
+        jax.lax.GatherDimensionNumbers(
+            offset_dims=(1,), collapsed_slice_dims=(0,), start_index_map=(0,)
+        ),
+        slice_sizes=(1, rows.shape[1]),
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS,
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_copies(x: jax.Array, order: jax.Array, inv: jax.Array, k: int):
+    """``x[order // k]``: the N·k copies (copy ``c`` is row ``c // k``) of
+    the N rows of ``x`` in the order the permutation ``order`` gives, ``inv``
+    its inverse.  ``repeat(x, k)`` is never materialised.  The cotangent
+    ``d_x[order[i] // k] += g[i]`` is ``g[inv]`` — the copies back in copy
+    order — summed over each row's k: one gather and a k-way sum,
+    accumulated in float32 and rounded once (the scatter-add rounds to
+    ``x.dtype`` after each of the k additions)."""
+    return _take_copies_fwd(x, order, inv, k)[0]
+
+
+def _take_copies_fwd(x, order, inv, k):
+    # Plain ops, not the rule's own primal: a ``custom_vjp`` called inside
+    # a forward rule is traced once a signature, so every layer's
+    # ``jnp.take`` would lower to one shared function and lose its scope
+    # (see ``_rows_at``).
+    return jnp.take(x, order // k, axis=0), inv
+
+
+def _take_copies_bwd(k, inv, g):
+    per_copy = _rows_at(g, inv).reshape(-1, k, g.shape[-1])
+    d_x = jnp.sum(per_copy, axis=1, dtype=jnp.float32).astype(g.dtype)
+    return d_x, None, None
+
+
+_take_copies.defvjp(_take_copies_fwd, _take_copies_bwd)
+
+
+@jax.custom_vjp
+def _combine_copies(rows: jax.Array, top_w: jax.Array, order: jax.Array,
+                    inv: jax.Array, is_held: Optional[jax.Array]):
+    """``out[n] = sum_j top_w[n, j] * rows[inv[n·k + j]]``: the experts'
+    N·k rows back in copy order (the un-permute) and the gate-weighted sum
+    over each token's k.  ``is_held`` is ``None`` or the (N·k,) mask, in
+    copy order, of the choices whose expert is held; ``order`` then sorts
+    those first, and the other rows of ``rows`` are undefined: they are
+    ``where``-masked out, never multiplied by zero.
+
+    The rule encloses the sum so that the rows' cotangent is gathered from
+    the (N, D) cotangent of ``out``: ``d_rows[i] = top_w[order[i]] *
+    d_out[order[i] // k]``, the bits autodiff gets by materialising the
+    (N, k, D) products and scatter-adding them through ``inv`` — at a
+    quarter of its time (``tools/probe_ragged_rows.py``).
+    ``top_w``'s cotangent is autodiff's own: it reads the un-permuted
+    rows, which the forward pass made."""
+    return _combine_copies_fwd(rows, top_w, order, inv, is_held)[0]
+
+
+def _weighted_sum(top_w, per_slot):
+    return jnp.einsum("nk,nkd->nd", top_w.astype(per_slot.dtype), per_slot)
+
+
+def _combine_copies_fwd(rows, top_w, order, inv, is_held):
+    N, k = top_w.shape
+    per_slot = jnp.take(rows, inv, axis=0).reshape(N, k, rows.shape[1])
+    if is_held is not None:
+        per_slot = jnp.where(is_held.reshape(N, k, 1), per_slot, 0)
+    return _weighted_sum(top_w, per_slot), (per_slot, top_w, order, is_held)
+
+
+def _combine_copies_bwd(res, d_out):
+    per_slot, top_w, order, is_held = res
+    N, k = top_w.shape
+    (d_w,) = jax.vjp(lambda w: _weighted_sum(w, per_slot), top_w)[1](d_out)
+    w_sorted = _rows_at(top_w.reshape(N * k, 1), order).astype(d_out.dtype)
+    d_rows = w_sorted * _rows_at(d_out, order // k)
+    if is_held is not None:  # the held copies' rows: the first of the sort
+        held_rows = jnp.arange(N * k) < jnp.sum(is_held)
+        d_rows = jnp.where(held_rows[:, None], d_rows, 0)
+    return d_rows, d_w, None, None, None
+
+
+_combine_copies.defvjp(_combine_copies_fwd, _combine_copies_bwd)
+
+
 def ragged_experts(
     x: jax.Array,
     experts: Params,
@@ -429,6 +538,13 @@ def ragged_experts(
     the expert pass are ``where``-masked (never multiplied by zero), in
     the forward and, by transposition, in the backward pass.
 
+    The two row moves — the copies into expert order
+    (:func:`_take_copies`) and the un-permute with the weighted sum
+    behind it (:func:`_combine_copies`) — carry backward rules that
+    gather by the permutation the forward pass already holds, where
+    autodiff would scatter-add; both ends of the expert pass stay
+    ``where``-masked in them.
+
     Its three phases carry profiler scopes (``ddl.moe_route``,
     ``ddl.moe_experts``, ``ddl.moe_combine``): they reach the device
     trace as each op's ``tf_op`` name."""
@@ -436,6 +552,7 @@ def ragged_experts(
     k = top_e.shape[1]
     dt = x.dtype
     n_groups = experts["w_gate"].shape[0]
+    is_held = None
 
     with scope("ddl.moe_route"):
         flat_e = top_e.reshape(-1)  # (N*k,) expert of copy i (token i//k)
@@ -451,8 +568,11 @@ def ragged_experts(
             flat_e, length=n_groups + (held is not None)
         ).astype(jnp.int32)[:n_groups]
 
+    with scope("ddl.moe_combine"):  # the un-permute's index, read by both rules
+        inv = jnp.argsort(order)  # flat copy index -> its sorted row
+
     with scope("ddl.moe_experts"):
-        xs = jnp.take(x, order // k, axis=0)  # (N*k, D) grouped by expert
+        xs = _take_copies(x, order, inv, k)  # (N*k, D) grouped by expert
         if held is not None:
             in_a_group = (jnp.arange(N * k) < jnp.sum(group_sizes))[:, None]
             xs = jnp.where(in_a_group, xs, 0)
@@ -465,11 +585,7 @@ def ragged_experts(
         )  # (N*k, D), still expert-sorted
 
     with scope("ddl.moe_combine"):
-        inv = jnp.argsort(order)  # flat copy index -> its sorted row
-        per_slot = jnp.take(rows, inv, axis=0).reshape(N, k, D)
-        if held is not None:
-            per_slot = jnp.where(is_held.reshape(N, k, 1), per_slot, 0)
-        out = jnp.einsum("nk,nkd->nd", top_w.astype(dt), per_slot)
+        out = _combine_copies(rows, top_w, order, inv, is_held)
     return out
 
 

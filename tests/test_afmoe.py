@@ -10,6 +10,7 @@ Seeded weights (norm weights moved off 1 and the router scaled up, so
 that both count) and tokens.
 """
 
+import collections
 import dataclasses
 import hashlib
 import json
@@ -402,7 +403,11 @@ def test_the_benchmarks_reference_is_this_one():
 #: PR 33's tree (the child of 116395f), which changed them on purpose —
 #: selective remat saves the blockwise cores' residuals, so the backward
 #: holds no second forward kernel — and re-recorded what cc5f72a had
-#: pinned:
+#: pinned.  The two ``olmoe`` programs are PR 35's tree (the child of
+#: 8130a3b), changed on purpose: the routed layer's backward pass gathers
+#: where it scatter-added (``moe._take_copies``, ``moe._combine_copies``);
+#: the two ``mistral`` programs are PR 33's still — no routed layer, not a
+#: byte moved:
 #: ``tpu``: lowered for the TPU with each Mosaic kernel's serialised body
 #: taken out (it carries the file and line of every operation);
 #: ``interpreted``: with the kernels' bodies as the interpreter's HLO, line
@@ -412,18 +417,18 @@ PARENT_WINDOW_PROGRAM_SHA256 = {
     ("mistral", "tpu"):
         "56b98523c10c798a16fdfe39c685a64fff53cc6fef8b2d40c17d0c2f95c28e91",
     ("olmoe", "tpu"):
-        "c23a2b6193e8b5e2c797f61322be6adabab0b0817791e406621ba2281187417c",
+        "4cffcd575bb1916a5ce94bf2cbe6eefc6401319449cad9273938683c39dd54cb",
     ("mistral", "interpreted"):
         "255122d44314fd0f3455fba7ef30e9cd8847a478bdf159a8b258c8d7db9a1563",
     ("olmoe", "interpreted"):
-        "cceba8603c8dcdd78bd7c9c7f45a0f1811a08e17e89acef1ced007e1dcefb302",
+        "462021f792246948f54ce88aeeb3846c3bb9cca50838066026afdb17673a4513",
 }
 
 
-@pytest.mark.parametrize("how", ["tpu", "interpreted"])
-@pytest.mark.parametrize("model", ["mistral", "olmoe"])
-def test_the_window_programs_of_the_other_decoders_are_the_parents(model, how,
-                                                                  monkeypatch):
+def _window_program(model, how):
+    """``traced()`` -> the 2-step window program of a Mistral-shaped or an
+    OLMoE-shaped decoder, traced anew each time (a jitted function keeps
+    its first trace)."""
     import optax
     from jax.sharding import Mesh
 
@@ -443,8 +448,11 @@ def test_the_window_programs_of_the_other_decoders_are_the_parents(model, how,
             qk_norm=True, norm_topk_prob=False, router_aux_all_slots=True,
             router_z_weight=0.001, **common)
     optimizer = optax.adamw(3e-4)
+    params = jax.eval_shape(lambda: mod.init_params(cfg, jax.random.key(0)))
+    args = (params, jax.eval_shape(optimizer.init, params),
+            (jax.ShapeDtypeStruct((2, 2, 2048), jnp.int32),), True)
 
-    def traced():  # anew each time: a jitted function keeps its first trace
+    def traced():
         _, multi = make_multistep(
             lambda p, b: mod.next_token_loss(p, b[0], cfg), optimizer,
             Mesh(np.array(jax.devices()[:1]), ("dp",)), mod.param_specs(cfg),
@@ -454,9 +462,14 @@ def test_the_window_programs_of_the_other_decoders_are_the_parents(model, how,
                    if hasattr(c.cell_contents, "lower"))
         return run.trace(*args)
 
-    params = jax.eval_shape(lambda: mod.init_params(cfg, jax.random.key(0)))
-    args = (params, jax.eval_shape(optimizer.init, params),
-            (jax.ShapeDtypeStruct((2, 2, 2048), jnp.int32),), True)
+    return traced
+
+
+@pytest.mark.parametrize("how", ["tpu", "interpreted"])
+@pytest.mark.parametrize("model", ["mistral", "olmoe"])
+def test_the_window_programs_of_the_other_decoders_are_the_parents(model, how,
+                                                                  monkeypatch):
+    traced = _window_program(model, how)
     # The old three kernels and no other, each once a layer in the scanned
     # step: the forward not a second time for the backward pass.
     with monkeypatch.context() as on_tpu:
@@ -475,6 +488,54 @@ def test_the_window_programs_of_the_other_decoders_are_the_parents(model, how,
         assert hashlib.sha256(text.encode()).hexdigest() == (
             PARENT_WINDOW_PROGRAM_SHA256[model, how]
         )
+
+
+def test_the_routed_layers_backward_pass_scatters_no_rows(monkeypatch):
+    """The same OLMoE-shaped window program, lowered for the TPU: the two
+    row moves of ``moe.ragged_experts`` transpose into gathers
+    (``moe._take_copies``, ``moe._combine_copies``), so no scatter is left
+    whose updates are (N·k, D) rows — the parent had two a routed layer and
+    backward pass (the layers are scanned: two in the text).  What stays,
+    by its updates: ``bincount``'s N·k ones into the group sizes (forward,
+    its recomputation, and the auxiliary loss's counts), the two
+    ``take_along_axis`` transposes of the router's top-k, the
+    cross-entropy's, and the embedding's (B, T, D) rows."""
+    with monkeypatch.context() as on_tpu:
+        on_tpu.setattr(jax, "default_backend", lambda: "tpu")
+        text = _window_program("olmoe", "tpu")().lower(
+            lowering_platforms=("tpu",)).as_text()
+    updates = sorted(re.findall(
+        r'"stablehlo.scatter"\(.*?\n\s*\}\) : \(tensor<\S+>, tensor<\S+>, '
+        r'tensor<(\S+)>\)', text, re.S))
+    rows = [u for u in updates if re.fullmatch(r"\d+x256xbf16", u)]
+    assert rows == [], rows  # the parent: ["8192x256xbf16", "8192x256xbf16"]
+    assert updates == sorted(
+        4 * ["8192xi32"]             # bincount: one count a (token, slot) copy
+        + 2 * ["4096x2xf32"]         # the router's top-k
+        + ["2x2048x1xf32"]           # cross-entropy's take_along_axis
+        + ["2x2048x256xbf16"]        # the embedding's rows
+    ), updates
+    assert "unique_indices = true" not in text  # gathers, not hinted scatters
+
+
+def test_every_expert_layers_row_gathers_keep_a_function_of_their_own():
+    """Four expert layers that are not scanned, under ``selective``, in
+    the lowered gradient: every ``jnp.take`` of the routed core is a
+    private function with ONE caller, in the forward pass and in its
+    recomputation.  A function with two callers reaches XLA as a call, and
+    XLA inlines a call without the caller's name stack: its gather runs
+    under no ``ddl.`` scope in the device trace and reads as a gain of
+    ``moe_dispatch_device_share`` that is none (PR 35: a ``custom_vjp``
+    called inside its own forward rule, or ``jnp.take`` inside a backward
+    rule, is traced once a signature and does exactly that)."""
+    cfg = tiny(held_experts=(4, 4), remat="selective")
+    params = jax.eval_shape(lambda: afmoe.init_params(cfg, jax.random.key(0)))
+    text = jax.jit(jax.grad(
+        lambda p, t: afmoe.next_token_loss(p, t, cfg)
+    )).lower(params, jax.ShapeDtypeStruct((B, T), jnp.int32)).as_text()
+    callers = collections.Counter(re.findall(r"call @(_take\w*)\(", text))
+    assert len(callers) == 4 * 4, callers  # copies + un-permute, twice a layer
+    assert set(callers.values()) == {1}, callers
 
 
 # -- the benchmark's FLOP count of the attended pairs ---------------------------------

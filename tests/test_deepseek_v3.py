@@ -520,7 +520,10 @@ def test_the_benchmarks_reference_is_this_one():
 #: PR 33's tree (the child of 116395f), which changed them on purpose —
 #: selective remat saves the blockwise cores' residuals, so the backward
 #: holds no second forward kernel — and re-recorded what 6b7d062 had
-#: pinned:
+#: pinned.  The ``olmoe`` and ``trinity`` programs are PR 35's tree (the
+#: child of 8130a3b), changed on purpose: the routed layer's backward pass
+#: gathers where it scatter-added (``moe._take_copies``,
+#: ``moe._combine_copies``); the ``mistral`` programs are PR 33's still:
 #: ``tpu``: lowered for the TPU with each Mosaic kernel's serialised body
 #: taken out (it carries the file and line of every operation);
 #: ``interpreted``: with the kernels' bodies as the interpreter's HLO, line
@@ -534,13 +537,13 @@ PARENT_WINDOW_PROGRAM_SHA256 = {
     ("mistral", "interpreted"):
         "255122d44314fd0f3455fba7ef30e9cd8847a478bdf159a8b258c8d7db9a1563",
     ("olmoe", "tpu"):
-        "c23a2b6193e8b5e2c797f61322be6adabab0b0817791e406621ba2281187417c",
+        "4cffcd575bb1916a5ce94bf2cbe6eefc6401319449cad9273938683c39dd54cb",
     ("olmoe", "interpreted"):
-        "cceba8603c8dcdd78bd7c9c7f45a0f1811a08e17e89acef1ced007e1dcefb302",
+        "462021f792246948f54ce88aeeb3846c3bb9cca50838066026afdb17673a4513",
     ("trinity", "tpu"):
-        "4d6bf45bcedec5d9f913d0f5f767248e165b71270e905dc1cbe2e43d0f23005d",
+        "54fc32424cee3d9179ba3cdb683265c6fadf96ff025c9a1da5ff417e8a6f3ebd",
     ("trinity", "interpreted"):
-        "bceeeb574fb355464bfb1c83d8077f185c7a6827c39c5057b6e89a29dc076874",
+        "34af4acfe644f9002054c0ea57b6c6b889e0ed3aa611f732c332ed8c768f77ca",
 }
 
 

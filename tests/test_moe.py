@@ -232,6 +232,150 @@ class TestRaggedImpl:
 BOTH_DISPATCHES = pytest.mark.parametrize("impl", ["einsum", "ragged"])
 
 
+class TestRowMoveRules:
+    """``ragged_experts``' two row moves carry backward rules of their own
+    (``moe._take_copies``, ``moe._combine_copies``): gathers by the
+    permutation and its inverse where autodiff of ``jnp.take``
+    scatter-adds.  Held here to what they replace: the same function with
+    both moves as plain ``jnp.take``, differentiated by JAX."""
+
+    N, D, F, E = 24, 16, 8, 8  # E: the router's width
+
+    @staticmethod
+    def _plain(monkeypatch):
+        monkeypatch.setattr(
+            moe, "_take_copies",
+            lambda x, order, inv, k: jnp.take(x, order // k, axis=0))
+        monkeypatch.setattr(  # the rule's forward, a plain function
+            moe, "_combine_copies",
+            lambda *args: moe._combine_copies_fwd(*args)[0])
+
+    def _case(self, k, held, router, dtype):
+        """(x, expert stacks, top_w, top_e) of one case, in ``dtype``."""
+        rng = np.random.default_rng(7)
+        N, D, F, E = self.N, self.D, self.F, self.E
+        G = E if held is None else held[1]
+        if router == "one_expert":  # every choice of every token: expert 3
+            top_e = np.full((N, k), 3)
+        elif router == "empty_experts":  # 1, 2, 4, 5, 6 get no row
+            top_e = rng.choice([0, 3, 7], (N, k))
+        else:  # "ties": equal scores, so top-k names experts 0..k-1 for
+            # every token, with equal weights
+            top_e = np.asarray(jax.lax.top_k(jnp.zeros((N, E)), k)[1])
+        top_w = (np.full((N, k), 1.0 / k) if router == "ties"
+                 else rng.uniform(0.1, 1.0, (N, k)))
+        experts = {
+            name: jnp.asarray(rng.standard_normal(shape) / 4, dtype)
+            for name, shape in (("w_gate", (G, D, F)), ("w_up", (G, D, F)),
+                                ("w_down", (G, F, D)))
+        }
+        return (jnp.asarray(rng.standard_normal((N, D)), dtype), experts,
+                jnp.asarray(top_w, jnp.float32), jnp.asarray(top_e, jnp.int32))
+
+    @staticmethod
+    def _grads(x, experts, top_w, top_e, held):
+        def f(x, experts, top_w):
+            out = moe.ragged_experts(x, experts, top_w, top_e, held=held)
+            # An uneven cotangent: every row and column weighs differently.
+            weigh = jnp.cos(jnp.arange(out.size, dtype=jnp.float32))
+            return jnp.sum(out.astype(jnp.float32) * weigh.reshape(out.shape))
+
+        return jax.grad(f, argnums=(0, 1, 2))(x, experts, top_w)
+
+    @pytest.mark.parametrize("router", ["one_expert", "empty_experts", "ties"])
+    @pytest.mark.parametrize("held", [None, (2, 4)])
+    @pytest.mark.parametrize("k", [1, 2, 8])
+    def test_gradients_are_plain_takes_in_float32(self, k, held, router,
+                                                  monkeypatch):
+        """Float32: the expert stacks' and ``top_w``'s gradients — they
+        see only the permutation's cotangent, which is pure data movement
+        — are bit-equal; ``d_x`` is the same k-way sum in another order
+        (the same to the bit for k = 1)."""
+        case = self._case(k, held, router, jnp.float32)
+        d_x, d_experts, d_w = self._grads(*case, held)
+        with monkeypatch.context() as m:
+            self._plain(m)
+            want_x, want_experts, want_w = self._grads(*case, held)
+        np.testing.assert_array_equal(np.asarray(d_w), np.asarray(want_w))
+        for name, want in want_experts.items():
+            np.testing.assert_array_equal(
+                np.asarray(d_experts[name]), np.asarray(want), err_msg=name)
+        some_held = held is None or bool(
+            ((case[3] >= held[0]) & (case[3] < held[0] + held[1])).any())
+        assert bool(jnp.any(want_x != 0)) == some_held  # "ties" can miss (2, 4)
+        if k == 1:
+            np.testing.assert_array_equal(np.asarray(d_x), np.asarray(want_x))
+        else:
+            np.testing.assert_allclose(
+                np.asarray(d_x), np.asarray(want_x), rtol=1e-6,
+                atol=1e-6 * float(jnp.max(jnp.abs(want_x))))
+
+    @pytest.mark.parametrize("router", ["one_expert", "empty_experts", "ties"])
+    @pytest.mark.parametrize("held", [None, (2, 4)])
+    @pytest.mark.parametrize("k", [1, 2, 8])
+    def test_bf16_d_x_is_no_farther_from_float32_than_the_scatter_add(
+            self, k, held, router, monkeypatch):
+        """bfloat16: the rule sums a row's k cotangents in float32 and
+        rounds once, where the scatter-add rounds after every addition —
+        the same sum, no farther from the float32 one (both see the same
+        k rows to the bit: everything before them is the same program)."""
+        case = self._case(k, held, router, jnp.bfloat16)
+        d_x = self._grads(*case, held)[0]
+        assert d_x.dtype == jnp.bfloat16
+        with monkeypatch.context() as m:
+            self._plain(m)
+            scatter_x = self._grads(*case, held)[0]
+            x, experts, top_w, top_e = case
+            exact = self._grads(
+                x.astype(jnp.float32),
+                jax.tree.map(lambda w: w.astype(jnp.float32), experts),
+                top_w, top_e, held)[0]
+
+        def off(got):
+            return float(jnp.linalg.norm(got.astype(jnp.float32) - exact))
+
+        assert off(d_x) <= off(scatter_x) * (1 + 1e-6), (off(d_x), off(scatter_x))
+        if k == 1:
+            np.testing.assert_array_equal(
+                np.asarray(d_x, np.float32), np.asarray(scatter_x, np.float32))
+
+    @pytest.mark.parametrize("held", [False, True])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_the_un_permutes_cotangents_are_bit_equal(self, dtype, held):
+        """``_combine_copies`` alone: the rows' cotangent, gathered from the
+        (N, D) cotangent, is the scatter-add of the (N, k, D) products bit
+        for bit (a permutation collides nowhere), and ``top_w``'s is
+        autodiff's own."""
+        rng = np.random.default_rng(3)
+        N, k, D = 12, 8, 8
+        is_held = rng.random(N * k) < 0.4 if held else np.ones(N * k, bool)
+        order = jnp.argsort(jnp.asarray(~is_held))  # stable: the held first
+        inv = jnp.argsort(order)
+        rows = jnp.asarray(rng.standard_normal((N * k, D)), dtype)
+        rows = jnp.where((jnp.arange(N * k) < is_held.sum())[:, None], rows,
+                         jnp.nan)  # what a grouped matmul may leave there
+        top_w = jnp.asarray(rng.uniform(0.1, 1.0, (N, k)), jnp.float32)
+        g = jnp.asarray(rng.standard_normal((N, D)), dtype)
+        mask = jnp.asarray(is_held) if held else None
+        out, vjp = jax.vjp(
+            lambda r, w: moe._combine_copies(r, w, order, inv, mask),
+            rows, top_w)
+        want_out, want_vjp = jax.vjp(
+            lambda r, w: moe._combine_copies_fwd(r, w, order, inv, mask)[0],
+            rows, top_w)
+        assert bool(jnp.all(jnp.isfinite(out.astype(jnp.float32))))
+        for got, want in zip((out,) + vjp(g), (want_out,) + want_vjp(g)):
+            np.testing.assert_array_equal(
+                np.asarray(got, np.float32), np.asarray(want, np.float32))
+
+    def test_the_rules_are_reverse_mode_only(self):
+        """A ``custom_vjp`` refuses forward mode, and says so."""
+        perm = jnp.arange(4)[::-1]
+        with pytest.raises(TypeError, match="custom_vjp"):
+            jax.jvp(lambda x: moe._take_copies(x, perm, perm, 1),
+                    (jnp.ones((4, 2)),), (jnp.ones((4, 2)),))
+
+
 class TestMoeModel:
     @BOTH_DISPATCHES
     def test_forward_finite_and_shapes(self, rng, impl):

@@ -679,7 +679,11 @@ def test_which_kernels_a_call_lowers_to(case, want):
 #: addresses taken out, on PR 33's tree (the child of 116395f): that PR
 #: changed these programs on purpose — under selective remat the blockwise
 #: cores' residuals are saved, so the rematerialised backward holds no
-#: second forward kernel — and re-recorded what bf3b36b had pinned.  (The
+#: second forward kernel — and re-recorded what bf3b36b had pinned.
+#: ``olmoe`` is PR 35's tree (the child of 8130a3b), changed on purpose:
+#: the routed layer's two row moves are ``custom_vjp`` calls whose backward
+#: rules gather (``moe._take_copies``, ``moe._combine_copies``); ``mistral``
+#: is PR 33's still.  (The
 #: text LOWERED for the TPU will not do: Mosaic serialises each kernel with
 #: the file and line of every operation, so it changes with the checkout's
 #: path.)
@@ -688,7 +692,7 @@ PARENT_STEP_SHA256 = {
     "mistral":
         "428749c663ca7ab763f822afa339dac426ca3badca2afa6015c76fe3988bbdcd",
     "olmoe":
-        "4306a592046e7ba287a97c3298e62e77c69b1f1285607cfaa35f086c313cca81",
+        "f3558c9f5977a323082bfa3a7ddb1d84cfe6b9b83ee677e1df42a2c90a2df645",
 }
 
 

@@ -6,6 +6,17 @@ transpose with respect to the rows) are zero, stale or NaN, and what the
 N*k-row gather beside it costs.  It is what ``models/moe.py:ragged_experts``'
 ``held=`` masking rests on (PERF.md section 6, PR 30).
 
+Then (PR 35) the transposes of ``ragged_experts``' two row moves at the
+expert cells' shapes, N*k x 2048 bf16 for (N, k) = (16384, 8) and (16384,
+6), with ``order`` the stable sort of seeded expert ids and ``inv`` its
+inverse: the scatter-add JAX's autodiff emits for each, the same scatter
+told ``unique_indices=True`` (the permutation only: the other collides k
+times a row), and the gathers that are their equals — ``g[order]`` for the
+un-permute, ``g[inv]`` summed over k in float32 for the copies — and the
+backward combine from the (N, D) cotangent against the materialised (N, k,
+D) one.  It is what ``moe._take_copies`` / ``moe._combine_copies`` rest on
+(PERF.md section 6, PR 35).
+
     chiprun -- python3 tools/probe_ragged_rows.py
 
 Needs a TPU (``REHEARSE=1`` runs the control flow at a tiny size on the
@@ -56,4 +67,55 @@ for _ in range(10):
     y = h(x[: M // 8], idx)
 y.block_until_ready()
 out["gather_131072_rows_ms"] = (time.perf_counter() - t0) / 10 * 1e3
+
+
+def ms(fn, *args):
+    """Mean host ms of 10 calls after one that compiles."""
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(10):
+        y = fn(*args)
+    jax.block_until_ready(y)
+    return (time.perf_counter() - t0) / 10 * 1e3
+
+
+N, E = (128, 8) if REH else (16384, 64)
+for k in (8, 6):
+    rng = np.random.default_rng(k)
+    order = jnp.argsort(jnp.asarray(rng.integers(0, E, N * k), jnp.int32))
+    inv = jnp.argsort(order)
+    g = jax.random.normal(jax.random.key(k), (N * k, D), jnp.bfloat16)
+    d_out = g[:N]
+    w = jax.random.uniform(jax.random.key(k + 1), (N, k), jnp.float32)
+    r = {}
+    # The un-permute per_slot = rows[inv]: d_rows[inv[j]] += g[j].
+    as_jax = jax.jit(lambda g, inv: jax.vjp(lambda r: jnp.take(r, inv, axis=0), g)[1](g)[0])
+    hinted = jax.jit(lambda g, inv: jnp.zeros_like(g).at[inv].add(g, unique_indices=True))
+    gathered = jax.jit(lambda g, order: jnp.take(g, order, axis=0))
+    want = np.asarray(as_jax(g, inv).astype(jnp.float32))
+    for name, fn, idx in (("scatter_add", as_jax, inv), ("scatter_add_unique_indices", hinted, inv),
+                          ("gather", gathered, order)):
+        r["unpermute_T_" + name + "_ms"] = ms(fn, g, idx)
+        r["unpermute_T_" + name + "_bit_equal"] = bool((np.asarray(fn(g, idx).astype(jnp.float32)) == want).all())
+    # The copies xs = x[order // k]: d_x[order[i] // k] += g[i].
+    as_jax = jax.jit(lambda g, order: jax.vjp(lambda x: jnp.take(x, order // k, axis=0), d_out)[1](g)[0])
+    summed = jax.jit(lambda g, inv: jnp.sum(
+        jnp.take(g, inv, axis=0).reshape(N, k, D), axis=1, dtype=jnp.float32).astype(g.dtype))
+    exact = np.asarray(g.astype(jnp.float32))[np.asarray(inv)].reshape(N, k, D).sum(1)
+    for name, fn, idx in (("scatter_add", as_jax, order), ("gather_sum_f32", summed, inv)):
+        r["copies_T_" + name + "_ms"] = ms(fn, g, idx)
+        r["copies_T_" + name + "_max_err_vs_f32"] = float(np.abs(np.asarray(fn(g, idx).astype(jnp.float32)) - exact).max())
+    # The backward combine: d_rows[i] = w_flat[order[i]] * d_out[order[i] // k].
+    materialised = jax.jit(lambda d, w, order: jnp.take(
+        (w.astype(d.dtype)[:, :, None] * d[:, None, :]).reshape(N * k, D), order, axis=0))
+    from_nd = jax.jit(lambda d, w, order: jnp.take(w.reshape(-1), order).astype(d.dtype)[:, None]
+                      * jnp.take(d, order // k, axis=0))
+    r["combine_T_materialised_then_gather_ms"] = ms(materialised, d_out, w, order)
+    r["combine_T_gather_from_nd_ms"] = ms(from_nd, d_out, w, order)
+    r["combine_T_bit_equal"] = bool((np.asarray(materialised(d_out, w, order).astype(jnp.float32))
+                                     == np.asarray(from_nd(d_out, w, order).astype(jnp.float32))).all())
+    r["forward_take_copies_ms"] = ms(jax.jit(lambda x, order: jnp.take(x, order // k, axis=0)), d_out, order)
+    r["forward_unpermute_ms"] = ms(gathered, g, inv)
+    r["MB_of_rows"] = N * k * D * 2 / 1e6
+    out[f"transposes_{N * k}x{D}"] = r
 print(json.dumps(out, indent=1))
