@@ -33,6 +33,13 @@ accepted for back compat — ``True`` is ``"full"``, ``False`` is
   keeps each step's partial output and logsumexp beside the combined
   output (1 + ``sp`` local outputs a layer, where it kept the one and
   re-ran the ring's forward kernels): measured by no cell.
+  A linear-attention layer's scan (``ops/gated_delta.py``) tags the same
+  way what ITS backward reads: its output, and inside the chain's
+  ``custom_vjp`` the state every chunk starts from - ``2·B·(T/64)·H·
+  d_k·d_v`` bytes of bf16 (283 MB at one row of 16,384, 30 heads of
+  96 x 192) beside ``2·B·T·H·d_v`` of output - so the backward pass runs
+  the reverse chain and no forward one; the chunks' parallel part is
+  recomputed, a pass of heads at a time.
 - ``"dots"`` — ``jax.checkpoint_policies.dots_with_no_batch_dims_
   saveable``: save every non-batched matmul output (all weight
   projections), recompute only elementwise ops and attention — the
@@ -41,11 +48,11 @@ accepted for back compat — ``True`` is ``"full"``, ``False`` is
 
 One wrap site per model family (:func:`wrap` around the layer body),
 one tag function (:func:`tag_attn_out`), called by the one attention
-dispatcher (``parallel.ring_attention.attention``) and by the flash
-kernels' rules, never by a model — so a value is tagged once (a second
+dispatcher (``parallel.ring_attention.attention``), by the flash
+kernels' rules and by the gated-delta scan, never by a model — so a value is tagged once (a second
 tag on the same output would save it twice) and the policy semantics
-cannot drift between llama, moe, afmoe, deepseek_v3 and the pipelined
-forwards.
+cannot drift between llama, moe, afmoe, deepseek_v3, olmo_hybrid and the
+pipelined forwards.
 """
 
 from __future__ import annotations

@@ -41,6 +41,9 @@ KERNEL_NAMES = (
     # ... and with latent attention's rotary product (``q_rope=``)
     "ddl_flash_mla_fwd", "ddl_flash_mla_bwd_dq", "ddl_flash_mla_bwd_dkv",
     "ddl_flash_tile_fwd", "ddl_flash_tile_bwd",
+    # the gated delta rule's chain over chunk states, forward and reverse
+    # (``ops/gated_delta.py``); ``gdn_device_share`` reads ``ddl_gdn_``
+    "ddl_gdn_fwd", "ddl_gdn_bwd",
     "ddl_ici_bcast", "ddl_ici_scatter", "ddl_shuffle_exchange",
 )
 
@@ -58,6 +61,7 @@ KERNEL_NAMES = (
 SCOPE_NAMES = (
     "ddl.embed", "ddl.patchify",
     "ddl.attn", "ddl.attn_gate", "ddl.mla_q", "ddl.mla_kv_up",
+    "ddl.gdn_proj", "ddl.gdn_conv", "ddl.gdn_scan", "ddl.gdn_out",
     "ddl.mlp",
     "ddl.moe", "ddl.moe_route", "ddl.moe_experts", "ddl.moe_combine",
     "ddl.moe_shared",

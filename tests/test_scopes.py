@@ -29,7 +29,7 @@ REPO = os.path.dirname(cells.HERE)
 
 def _family(name):
     """(module, tiny config under selective remat, loss(params, batch))."""
-    from ddl_tpu.models import afmoe, deepseek_v3, llama, moe, vit
+    from ddl_tpu.models import afmoe, deepseek_v3, llama, moe, olmo_hybrid, vit
 
     common = dict(vocab=64, d_model=32, n_layers=2, n_heads=2, max_seq=16,
                   remat="selective")
@@ -45,6 +45,8 @@ def _family(name):
         "afmoe": lambda: (afmoe, afmoe.AfmoeConfig(remat="selective")),
         "deepseek_v3": lambda: (
             deepseek_v3, deepseek_v3.DeepseekV3Config(remat="selective")),
+        "olmo_hybrid": lambda: (
+            olmo_hybrid, olmo_hybrid.OlmoHybridConfig(remat="selective")),
     }[name]()
     return mod, cfg, lambda p, b: mod.next_token_loss(p, b[0], cfg)
 
@@ -71,7 +73,9 @@ def _compiled_step_text(name):
         batch = (jax.ShapeDtypeStruct((2, 2, pixels), jnp.float32),
                  jax.ShapeDtypeStruct((2, 2, 1), jnp.int32))
     else:
-        batch = (jax.ShapeDtypeStruct((2, 2, 16), jnp.int32),)
+        # the linear-attention scan over whole chunks and a ragged one
+        seq = 160 if name == "olmo_hybrid" else 16
+        batch = (jax.ShapeDtypeStruct((2, 2, seq), jnp.int32),)
     args = (params, jax.eval_shape(optimizer.init, params), batch, True)
     return run.lower(*args).compile().as_text()
 
@@ -80,7 +84,8 @@ MATMUL = re.compile(r"= \S+ (dot|convolution|custom-call)\(")
 OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
-@pytest.mark.parametrize("family", ["llama", "moe", "afmoe", "deepseek_v3", "vit"])
+@pytest.mark.parametrize(
+    "family", ["llama", "moe", "afmoe", "deepseek_v3", "vit", "olmo_hybrid"])
 def test_every_matmul_of_a_train_step_stands_under_a_scope(family):
     text = _compiled_step_text(family)
     seen = {}
@@ -100,6 +105,12 @@ def test_every_matmul_of_a_train_step_stands_under_a_scope(family):
         assert "ddl.attn" in seen[which] or "ddl.mla_q" in seen[which], seen
         assert seen[which] & {"ddl.mlp", "ddl.moe_experts", "ddl.moe_shared"}, seen
     assert "ddl.head" in seen["forward"] and "ddl.head" in seen["backward"]
+    if family == "olmo_hybrid":
+        # A linear-attention layer's projections, its scan (the chunks'
+        # matmuls and the interpreted chain) and its output, in every pass:
+        # the pass's intermediates of the scan are recomputed, not kept.
+        for which in passes:
+            assert {"ddl.gdn_proj", "ddl.gdn_scan", "ddl.gdn_out"} <= seen[which], seen
     # The module's name is what the reduction looks for.
     assert "HloModule jit__run" in text
 
@@ -115,7 +126,12 @@ def test_the_table_is_whole():
     assert len(set(naming.SCOPE_NAMES)) == len(naming.SCOPE_NAMES)
     assert all(n.startswith("ddl.") for n in naming.SCOPE_NAMES)
     grouped = [s for scopes_ in S.GROUPS.values() for s in scopes_]
-    assert sorted(grouped) == sorted(naming.SCOPE_NAMES)
+    # The benchmark's groups, and the scopes its reader counts as ``other``:
+    # exactly the four the linear-attention readers select themselves.
+    from benchmarks.layers import gdn_dense_device_share, gdn_device_share
+
+    gdn = gdn_dense_device_share.DENSE_SCOPES + (gdn_device_share.SCAN_SCOPE,)
+    assert sorted(grouped + list(gdn)) == sorted(naming.SCOPE_NAMES)
     with pytest.raises(AssertionError):
         naming.scope("ddl.not_in_the_table")
     # No model file names a scope past the helper.
@@ -267,6 +283,9 @@ def _made_up_trace(path, scoped=True):
         ("fusion.7", "jit(_run)/while/body/closed_call/transpose(jvp(jvp()))/checkpoint/rematted_computation/ddl.mlp/dot_general", 590, 80, 0, "convolution fusion"),
         ("fusion.8", "jit(_run)/while/body/closed_call/transpose(jvp(jvp()))/checkpoint/ddl.attn/dot_general", 670, 90, 0, "convolution fusion"),
         ("fusion.9", "jit(_run)/while/body/closed_call/ddl.optimizer/mul", 760, 110, 0, "loop fusion"),
+        ("fusion.10", "jit(_run)/while/body/closed_call/jvp(ddl.gdn_scan)/while/body/checkpoint/dot_general", 870, 6, 0, "convolution fusion"),
+        ("ddl_gdn_fwd.1", "jit(_run)/while/body/closed_call/jvp(ddl.gdn_scan)/while/body/checkpoint/ddl_gdn_fwd", 876, 10, 0, "custom-call"),
+        ("fusion.11", "jit(_run)/while/body/closed_call/transpose(jvp(jvp()))/checkpoint/ddl.gdn_out/dot_general", 886, 4, 0, "convolution fusion"),
     ]
     if not scoped:
         ops = [(n, re.sub(r"ddl\.[a-z_]+/", "", re.sub(r"jvp\(ddl\.[a-z_]+\)", "jvp()", p)),
@@ -338,14 +357,25 @@ def test_shares_add_up_to_the_step_programs_own_time(made_up, capsys):
     assert got["moe_dispatch"] == pytest.approx(2 * 40 * us)
     assert got["head"] == pytest.approx(2 * (20 + 70) * us)
     assert got["optimizer"] == pytest.approx(2 * 110 * us)
-    # XLA's own copy and the %while's own 40 us; the other program's op
+    # XLA's own copy and the %while's own 20 us; the other program's op
     # is nobody's.
-    assert got["unscoped"] == pytest.approx(2 * (10 + 40) * us)
+    assert got["unscoped"] == pytest.approx(2 * (10 + 20) * us)
     assert _read("recompute_device_share", m) == pytest.approx(2 * 80 * us)
     kernels = _read("flash_device_share", m) + _read("gmm_device_share", m)
     assert kernels == pytest.approx(2 * (50 + 60) * us)
+    # The linear-attention layers' three: the chain's kernels by their
+    # family, what XLA runs of the recurrence under ``ddl.gdn_scan``, and
+    # the three scopes outside the recurrence - together what the
+    # benchmark's own table calls ``other``.
+    gdn = _read("gdn_device_share", m)
+    gdn_scan = _read("gdn_scan_device_share", m)
+    gdn_dense = _read("gdn_dense_device_share", m)
+    assert gdn == pytest.approx(2 * 10 * us)
+    assert gdn_scan == pytest.approx(2 * 6 * us)
+    assert gdn_dense == pytest.approx(2 * 4 * us)
     table = S.table_of_run(m)
-    assert sum(got.values()) + kernels == pytest.approx(
+    assert gdn + gdn_scan + gdn_dense == pytest.approx(table.summary()["other"])
+    assert sum(got.values()) + kernels + gdn + gdn_scan + gdn_dense == pytest.approx(
         100.0 * table.step_own_s / w, rel=1e-9)
     # ... which is every op of the window but the other program's.
     assert table.step_own_s == pytest.approx(

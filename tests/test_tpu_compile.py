@@ -127,6 +127,43 @@ def test_flash_attention_compiles(v5e, grad, packed):
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+def test_gated_delta_rule_compiles_at_olmo_hybrids_geometry(v5e, grad):
+    """One row of 16,384 positions, 30 heads of 96-wide keys and 192-wide
+    values (neither fills 128 lanes), bf16: the chain over the 256 chunk
+    states and, for the backward pass, the same chain in reverse with
+    ``M^T``, each compiled once - inside the loop over the five passes of
+    six heads - on a grid of (1 head group, 256 chunks)."""
+    from ddl_tpu.ops.gated_delta import gated_delta_rule
+
+    one = SingleDeviceSharding(v5e[0])
+    T_, H_, DK, DV = 16384, 30, 96, 192
+    args = (
+        jax.ShapeDtypeStruct((1, T_, H_, DK), jnp.bfloat16, sharding=one),
+        jax.ShapeDtypeStruct((1, T_, H_, DK), jnp.bfloat16, sharding=one),
+        jax.ShapeDtypeStruct((1, T_, H_, DV), jnp.bfloat16, sharding=one),
+        jax.ShapeDtypeStruct((1, T_, H_), jnp.float32, sharding=one),
+        jax.ShapeDtypeStruct((1, T_, H_), jnp.float32, sharding=one),
+    )
+
+    def scan(*a):
+        return gated_delta_rule(*a, interpret=False)
+
+    fn = scan
+    if grad:
+        def fn(*a):
+            return jax.grad(
+                lambda *a: scan(*a).astype(jnp.float32).sum(), argnums=range(5)
+            )(*a)
+
+    lowered = jax.jit(fn).lower(*args)
+    want = {"ddl_gdn_fwd", "ddl_gdn_bwd"} if grad else {"ddl_gdn_fwd"}
+    assert kernel_names(lowered.compile().as_text()) == want
+    assert want <= set(KERNEL_NAMES)
+    # the backward pass reads the saved chunk states: no second forward chain
+    assert mosaic_grids(lowered.as_text()) == {(1, 256): 2 if grad else 1}
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
 def test_windowed_flash_attention_compiles_at_trinity_minis_geometry(v5e, grad):
     """2 x 8192 tokens, 32 query / 4 kv heads x 128, window 2048, bf16:
     the band's kernels under names of their own, beside the causal-full
