@@ -22,8 +22,8 @@ residual stream itself::
     o_t = S_t q_t                       S in R^{d_v x d_k}, float32, S_0 = 0
     y_t = RMSNorm_{d_v}(o_t) * SiLU(h_t Wg),   out = y Wo
 
-The recurrence is ``ops/gated_delta.gated_delta_rule`` (chunked; its
-chain over chunk states a Pallas kernel with a backward pass); ``g`` and
+The recurrence is ``ops/gated_delta.gated_delta_rule`` (chunked; what of
+a chunk touches the state a Pallas kernel with a backward pass); ``g`` and
 ``beta`` are float32.
 
 ``full_attention``: causal softmax attention, ``n_heads`` x ``head_dim``,

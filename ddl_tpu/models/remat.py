@@ -34,12 +34,12 @@ accepted for back compat — ``True`` is ``"full"``, ``False`` is
   output (1 + ``sp`` local outputs a layer, where it kept the one and
   re-ran the ring's forward kernels): measured by no cell.
   A linear-attention layer's scan (``ops/gated_delta.py``) tags the same
-  way what ITS backward reads: its output, and inside the chain's
+  way what ITS backward reads: its output, and inside the kernels'
   ``custom_vjp`` the state every chunk starts from - ``2·B·(T/64)·H·
   d_k·d_v`` bytes of bf16 (283 MB at one row of 16,384, 30 heads of
   96 x 192) beside ``2·B·T·H·d_v`` of output - so the backward pass runs
-  the reverse chain and no forward one; the chunks' parallel part is
-  recomputed, a pass of heads at a time.
+  the backward kernel and no forward one; what XLA prepares of the chunks
+  is recomputed, a pass of heads at a time.
 - ``"dots"`` — ``jax.checkpoint_policies.dots_with_no_batch_dims_
   saveable``: save every non-batched matmul output (all weight
   projections), recompute only elementwise ops and attention — the

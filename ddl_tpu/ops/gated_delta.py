@@ -17,47 +17,62 @@ With ``H = S^T``, inside one chunk ``gam_i = sum_{j<=i} g_j``,
     W   = T (beta exp(gam) k),     U = T (beta v)           (C, d_k), (C, d_v)
     P   = lower(q_i . k_j D_ij)                             (C, C)
 
-the chunk is an AFFINE map of the state it starts from::
+the chunk meets the state it starts from in three lines::
 
-    H_next = M H + N        M = exp(gam_C) I - Kd^T W,  N = Kd^T U,
-                            Kd_j = exp(gam_C - gam_j) k_j
-    O      = (exp(gam) q - P W) H + P U
+    Vn     = U - W H                    what the chunk writes, given H
+    O      = Qg H + P Vn                Qg = exp(gam) q
+    H_next = a H + Kd^T Vn              a = exp(gam_C),
+                                        Kd_j = exp(gam_C - gam_j) k_j
 
-Everything but the first line is independent of the other chunks: batched
-matmuls over all of them at once, which XLA runs and differentiates
-(:func:`_one_pass` writes them as they stand above, for a pass of heads at
-a time: a pass's intermediates are recomputed in the backward pass, not
-kept, so that they are never all heads' at once).  What is left
-is the serial chain ``H_{c+1} = M_c H_c + N_c`` over the chunks, and that
-is the kernel here, with the backward pass that belongs to it:
+(the affine map ``H_next = M H + N`` with ``M = a I - Kd^T W``, ``N = Kd^T
+U`` and ``O = (Qg - P W) H + P U``, never formed: a chunk's and head's ``M``
+and ``N`` are more bytes than its q, k, v and o together).  What does not
+touch the state - decay sums, Gram matrices, the triangular inverse, ``W``,
+``U``, ``P``, ``Qg``, ``Kd`` - is independent of the other chunks: batched
+matmuls over all of them at once, head-major ((row, head, chunk) leading,
+the layout the kernels' blocks take), which XLA runs and differentiates
+(:func:`_one_pass`, for a pass of heads at a time: a pass's intermediates
+are recomputed in the backward pass, not kept, so that they are never all
+heads' at once).  The three lines are the kernels, one ``custom_vjp`` of
+``(W, U, Kd, Qg, P, a) -> O``:
 
 - ``ddl_gdn_fwd``: grid (head groups, chunks), the chunk axis sequential;
   the state lives in VMEM scratch, float32, for the whole row; each step
-  writes the state its chunk STARTS from (what ``O`` and the backward
-  read) and applies the chunk's map, the ``d_k x d_k`` by ``d_k x d_v``
-  product in float32 at the MXU's full precision: an operand rounded to
-  bfloat16 inside the chain is a state carried in bfloat16.
-- ``ddl_gdn_bwd``: the same chain run from the last chunk to the first
-  with ``M^T``: ``G_c = dH_c + M_c^T G_{c+1}``.  It writes ``G_{c+1}``,
-  which IS ``dN_c``; ``dM_c = G_{c+1} H_c^T`` is one batched matmul
-  outside.  One kernel body serves both.
+  reads a chunk's operands (68 KB a head in bfloat16), writes ``O`` and the
+  state the chunk STARTS from (what the backward reads), and hands the
+  next state on.  The two products on the path from state to state (``W
+  H``, ``Kd^T Vn``) keep every bit of their float32 operand
+  (:func:`_carried_dot`): an operand rounded to bfloat16 there is a state
+  carried in bfloat16.  ``Qg H`` and ``P Vn`` take ``H`` and ``Vn`` in the
+  operands' dtype, as any matmul of the model does.
+- ``ddl_gdn_bwd``: the chunks last to first, ``G = dH_next`` in scratch,
+  ``Vn`` computed again from the saved state::
 
-The chain's ``custom_vjp`` keeps the chunk states it wrote, tagged with
+      dVn = P^T dO + Kd G       dQg = dO H^T      dP  = dO Vn^T
+      dU  = dVn                 dW  = -dVn H^T    dKd = Vn G^T
+      da  = <G, H>              G  <- a G + Qg^T dO - W^T dVn
+
+  with ``Kd G`` and ``W^T dVn``, the path from ``G`` to ``G``, carried the
+  same way.  No forward kernel runs in a backward pass.
+
+The ``custom_vjp`` keeps the chunk states the forward wrote, tagged with
 the name ``remat="selective"`` saves, and :func:`gated_delta_rule` tags
-its output: a rematerialised backward recomputes the parallel part, reads
-both, and runs no forward kernel (``models/remat.py``).  The states leave
-the chain in the operands' dtype - what the output's matmul takes: ``2
-(T/C) H d_k d_v`` bytes of bfloat16 a row, 283 MB at 16,384 positions, 30
-heads, 96 x 192; the state the chain CARRIES stays float32.
+its output: a rematerialised backward recomputes XLA's part, reads both,
+and runs the backward kernel alone (``models/remat.py``).  The states
+leave the kernel in the operands' dtype: ``2 (T/C) H d_k d_v`` bytes of
+bfloat16 a row, 283 MB at 16,384 positions, 30 heads, 96 x 192; the state
+the kernel CARRIES stays float32.  The triangular inverse has a backward
+of its own (``-X^T dX X^T``: two products where autodiff would transpose
+the ten that built it).
 
-The chunk length, the heads a grid step holds and the heads whose maps
-are computed together come from the shapes (:func:`_chunk_len`,
-:func:`_heads_per_step`, :func:`_heads_per_pass`); rows that are no multiple of
-the chunk are padded with steps that leave the state alone (``beta`` 0,
-``g`` 0).  ``d_k`` and ``d_v`` need not fill 128 lanes (96 and 192 do
-not): a block spans the whole of its last two axes.  Off the TPU the
-kernels run in Pallas' interpret mode, which is how the CPU tests hold
-them to the plain recurrence.
+The chunk length, the heads a grid step holds and the heads whose chunks
+are prepared together come from the shapes and the operands' dtype
+(:func:`_chunk_len`, :func:`_heads_per_step`, :func:`_heads_per_pass`);
+rows that are no multiple of the chunk are padded with steps that leave
+the state alone (``beta`` 0, ``g`` 0).  ``d_k`` and ``d_v`` need not fill
+128 lanes (96 and 192 do not): a block spans the whole of its last two
+axes.  Off the TPU the kernels run in Pallas' interpret mode, which is how
+the CPU tests hold them to the plain recurrence.
 """
 
 from __future__ import annotations
@@ -74,7 +89,8 @@ from ddl_tpu.ops.flash_attention import _precision_for
 from ddl_tpu.ops.naming import named_pallas_call
 
 #: Positions a chunk (arXiv:2406.06484's and its implementations' 64: the
-#: inverse of ``I + A`` grows with the chunk, the chain shortens with it).
+#: inverse of ``I + A`` grows with the chunk, the row's chain of states
+#: shortens with it).
 _CHUNK = 64
 #: Side of the diagonal blocks whose inverse is the finite Neumann product;
 #: larger blocks are merged from them (:func:`_unit_lower_inverse`).
@@ -85,72 +101,153 @@ _STATE_DTYPE = jnp.float32
 _BLOCK_BUDGET = 8 * 2**20
 _LANES = 128
 
+_NN = (((1,), (0,)), ((), ()))  # x y
+_NT = (((1,), (1,)), ((), ()))  # x y^T
+_TN = (((0,), (0,)), ((), ()))  # x^T y
+
 
 def _chunk_len(T: int) -> int:
     """:data:`_CHUNK`, or for a shorter row the power of two that holds it."""
     return min(_CHUNK, max(8, 1 << (T - 1).bit_length()))
 
 
-def _heads_per_step(groups: int, dk: int, dv: int) -> int:
+def _heads_per_step(groups: int, C: int, dk: int, dv: int, itemsize: int) -> int:
     """(batch x head) rows a grid step holds: the largest divisor of their
-    number, up to 8, whose double-buffered float32 blocks (``M``, ``N`` in,
-    the state out, lane-padded) fit :data:`_BLOCK_BUDGET`."""
+    number, up to 8, whose double-buffered blocks fit :data:`_BLOCK_BUDGET`
+    at the operands' ``itemsize`` - the backward kernel's, which are the
+    more: a chunk's ``W``, ``Kd``, ``Qg``, ``P``, ``U``, ``dO`` and the
+    state it starts from in, five cotangents out, lane-padded."""
     pad = lambda n: -(-n // _LANES) * _LANES
-    per_row = 2 * 4 * dk * (pad(dk) + 2 * pad(dv))
+    narrow, square, wide = C * pad(dk), C * pad(C), C * pad(dv)
+    per_row = 2 * itemsize * (
+        (3 * narrow + square + 2 * wide + dk * pad(dv))  # in
+        + (3 * narrow + square + wide)  # out
+    )
     for heads in range(min(groups, 8), 0, -1):
         if groups % heads == 0 and heads * per_row <= _BLOCK_BUDGET:
             return heads
     return 1
 
 
-def _chain_kernel(m_ref, n_ref, out_ref, state_ref, *, heads, transpose):
-    """One chunk of ``state <- M state + N`` (``M^T`` where ``transpose``)
-    for ``heads`` rows; ``out`` gets the state BEFORE the chunk."""
+def _dot(x, y, dims):
+    """A product of two operands in the operands' dtype, summed in float32:
+    bfloat16 as the MXU takes it, float32 at its full precision."""
+    return jax.lax.dot_general(
+        x, y, dims, precision=_precision_for(x.dtype),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _carried_dot(x, y, dims):
+    """A product on the path from one chunk's state to the next: ``y`` is
+    float32 (the state, or what was computed from it) and none of it is
+    dropped.  A bfloat16 ``x`` - a chunk's operand, exact as it is - meets
+    ``y`` as the sum of three bfloat16 parts, all 24 bits of it, in three
+    passes of the MXU (``HIGHEST`` would split ``x`` too and take six); a
+    float32 ``x`` takes ``HIGHEST``."""
+    if x.dtype != jnp.bfloat16:
+        return jax.lax.dot_general(
+            x.astype(jnp.float32), y, dims, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+    hi = y.astype(jnp.bfloat16)
+    rest = y - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return _dot(x, hi, dims) + (_dot(x, mid, dims) + _dot(x, lo, dims))
+
+
+def _each_head(carried_ref, heads, one_head):
+    """``one_head(h)`` for the ``heads`` rows of a grid step, behind a
+    scratch zeroed at the row's first chunk.  Unrolled: the rows are
+    independent, so their products interleave (the two kernels as one
+    program each ran 12% faster so on the chip, PR 37) and both still
+    compile for a v5e in about a second."""
 
     @pl.when(pl.program_id(1) == 0)
     def _():
-        state_ref[...] = jnp.zeros_like(state_ref)
+        carried_ref[...] = jnp.zeros_like(carried_ref)
 
-    contract = (((0,), (0,)), ((), ())) if transpose else (((1,), (0,)), ((), ()))
-    for h in range(heads):
+    def body(h, carry):
+        one_head(h)
+        return carry
+
+    jax.lax.fori_loop(0, heads, body, 0, unroll=True)
+
+
+def _fwd_kernel(w_ref, u_ref, kd_ref, qg_ref, p_ref, a_ref, o_ref, states_ref,
+                state_ref, *, heads):
+    """One chunk of ``heads`` rows: the output, the state the chunk starts
+    from (``states``), and in scratch the state it hands on."""
+
+    def one_head(h):
+        cd = o_ref.dtype
         state = state_ref[h].astype(jnp.float32)
-        out_ref[h, 0] = state.astype(out_ref.dtype)
+        low = state.astype(cd)
+        states_ref[h, 0] = low
+        vn = u_ref[h, 0].astype(jnp.float32) - _carried_dot(w_ref[h, 0], state, _NN)
+        o_ref[h, 0] = (
+            _dot(qg_ref[h, 0], low, _NN) + _dot(p_ref[h, 0], vn.astype(cd), _NN)
+        ).astype(cd)
         state_ref[h] = (
-            jax.lax.dot_general(
-                m_ref[h, 0], state, contract,
-                precision=jax.lax.Precision.HIGHEST,
-                preferred_element_type=jnp.float32,
-            )
-            + n_ref[h, 0].astype(jnp.float32)
+            a_ref[h, 0] * state + _carried_dot(kd_ref[h, 0], vn, _TN)
         ).astype(state_ref.dtype)
 
+    _each_head(state_ref, heads, one_head)
 
-def _chain(m, n, reverse: bool, out_dtype, interpret: bool):
-    """States of ``H <- M_c H + N_c`` from zero: ``out[:, c]`` is the state
-    chunk ``c`` starts from, rounded to ``out_dtype`` on its way out (the
-    chain itself stays float32).  ``reverse``: the chain of the backward
-    pass, from the last chunk to the first with ``M_c^T``.  ``m`` (G, n,
-    dk, dk) float32, ``n`` (G, n, dk, dv)."""
-    G, chunks, dk, dv = n.shape
-    heads = _heads_per_step(G, dk, dv)
+
+def _bwd_kernel(w_ref, u_ref, kd_ref, qg_ref, p_ref, a_ref, states_ref, do_ref,
+                dw_ref, du_ref, dkd_ref, dqg_ref, dp_ref, da_ref, grad_ref, *,
+                heads):
+    """The same chunk on the way back (the grid runs the chunks last to
+    first): ``grad`` in scratch is the cotangent of the state the chunk
+    hands on; ``Vn`` is computed again from the saved state."""
+
+    def one_head(h):
+        cd = do_ref.dtype
+        grad = grad_ref[h].astype(jnp.float32)
+        state, d_o = states_ref[h, 0], do_ref[h, 0]
+        w, kd, qg = w_ref[h, 0], kd_ref[h, 0], qg_ref[h, 0]
+        vn = (u_ref[h, 0].astype(jnp.float32) - _dot(w, state, _NN)).astype(cd)
+        d_vn = _dot(p_ref[h, 0], d_o, _TN) + _carried_dot(kd, grad, _NN)
+        low = d_vn.astype(cd)
+        du_ref[h, 0] = low
+        dw_ref[h, 0] = (-_dot(low, state, _NT)).astype(cd)
+        dqg_ref[h, 0] = _dot(d_o, state, _NT).astype(cd)
+        dp_ref[h, 0] = _dot(d_o, vn, _NT).astype(cd)
+        dkd_ref[h, 0] = _dot(vn, grad.astype(cd), _NT).astype(cd)
+        da_ref[h, 0] = jnp.sum(grad * state.astype(jnp.float32), axis=0, keepdims=True)
+        grad_ref[h] = (
+            a_ref[h, 0] * grad + _dot(qg, d_o, _TN) - _carried_dot(w, d_vn, _TN)
+        ).astype(grad_ref.dtype)
+
+    _each_head(grad_ref, heads, one_head)
+
+
+def _call(name, kernel, ins, outs, reverse, interpret):
+    """``kernel`` over the grid (groups of rows, chunks), the chunk axis
+    sequential - last to first where ``reverse`` - each operand a block of
+    ``heads`` rows' one chunk, a float32 state in scratch.  ``ins``: the
+    arrays, (G, chunks, ., .); ``outs``: their shapes and dtypes."""
+    G, chunks, C, dk = ins[0].shape
+    dv = ins[1].shape[-1]
+    heads = _heads_per_step(G, C, dk, dv, ins[0].dtype.itemsize)
     last = chunks - 1
     at = (lambda i, c: (i, last - c, 0, 0)) if reverse else (lambda i, c: (i, c, 0, 0))
+    spec = lambda x: pl.BlockSpec((heads, 1) + tuple(x.shape[2:]), at)
     return named_pallas_call(
-        "ddl_gdn_bwd" if reverse else "ddl_gdn_fwd",
-        functools.partial(_chain_kernel, heads=heads, transpose=reverse),
+        name,
+        functools.partial(kernel, heads=heads),
         grid=(G // heads, chunks),
-        in_specs=[
-            pl.BlockSpec((heads, 1, dk, dk), at),
-            pl.BlockSpec((heads, 1, dk, dv), at),
-        ],
-        out_specs=pl.BlockSpec((heads, 1, dk, dv), at),
-        out_shape=jax.ShapeDtypeStruct((G, chunks, dk, dv), out_dtype),
+        in_specs=[spec(x) for x in ins],
+        out_specs=[spec(x) for x in outs],
+        out_shape=outs,
         scratch_shapes=[pltpu.VMEM((heads, dk, dv), _STATE_DTYPE)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(m, n)
+    )(*ins)
 
 
 def _tag(x):
@@ -161,30 +258,41 @@ def _tag(x):
     return tag_attn_out(x)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _chunk_states(m, n, dtype, interpret):
-    """The state every chunk starts from, (G, chunks, dk, dv) in ``dtype``
-    (the operands' own: what the output's matmul takes)."""
-    return _chain(m, n, False, dtype, interpret)
-
-
-def _chunk_states_fwd(m, n, dtype, interpret):
-    states = _tag(_chain(m, n, False, dtype, interpret))
-    return states, (m, states)
-
-
-def _chunk_states_bwd(dtype, interpret, res, d_states):
-    m, states = res
-    # G_{c+1}, the cotangent of the state chunk c hands on: dN_c itself.
-    d_n = _chain(m, d_states, True, jnp.float32, interpret)
-    d_m = jnp.einsum(
-        "gcik,gcjk->gcij", d_n, states.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST,
+def _forward(w, u, kd, qg, p, a, interpret):
+    """The chunks' outputs (G, chunks, C, dv) and the state each starts
+    from (G, chunks, dk, dv), both in the operands' dtype."""
+    G, chunks, C, dk = w.shape
+    like = lambda *shape: jax.ShapeDtypeStruct((G, chunks) + shape, w.dtype)
+    return _call(
+        "ddl_gdn_fwd", _fwd_kernel, (w, u, kd, qg, p, a),
+        [like(C, u.shape[-1]), like(dk, u.shape[-1])], False, interpret,
     )
-    return d_m, d_n
 
 
-_chunk_states.defvjp(_chunk_states_fwd, _chunk_states_bwd)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _chunks(w, u, kd, qg, p, a, interpret):
+    """``O`` of every chunk, each met with the state the chunks before it
+    leave: ``w``, ``kd``, ``qg`` (G, chunks, C, dk), ``u`` (G, chunks, C,
+    dv), ``p`` (G, chunks, C, C) in the operands' dtype, ``a`` (G, chunks,
+    1, dv) float32, the chunk's whole decay along a lane-dense row."""
+    return _forward(w, u, kd, qg, p, a, interpret)[0]
+
+
+def _chunks_fwd(w, u, kd, qg, p, a, interpret):
+    o, states = _forward(w, u, kd, qg, p, a, interpret)
+    return o, (w, u, kd, qg, p, a, _tag(states))
+
+
+def _chunks_bwd(interpret, res, d_o):
+    w, u, kd, qg, p, a, states = res
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+    return tuple(_call(
+        "ddl_gdn_bwd", _bwd_kernel, res + (d_o,),
+        [like(x) for x in (w, u, kd, qg, p, a)], True, interpret,
+    ))
+
+
+_chunks.defvjp(_chunks_fwd, _chunks_bwd)
 
 
 def _blocks(x, size: int, below: bool):
@@ -198,6 +306,7 @@ def _blocks(x, size: int, below: bool):
     ], axis=-3)
 
 
+@jax.custom_vjp
 def _unit_lower_inverse(a):
     """``(I + a)^-1`` for strictly lower triangular ``a`` (..., C, C), C a
     power of two, in float32 at full precision.  Diagonal blocks of
@@ -228,6 +337,19 @@ def _unit_lower_inverse(a):
     return inv[..., 0, :, :]
 
 
+def _unit_lower_inverse_bwd(inv, d_inv):
+    # d (I + a)^-1 = -X da X: two products with the inverse itself, where
+    # autodiff would keep and transpose every product that built it
+    mm = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+    inv_t = jnp.swapaxes(inv, -1, -2)
+    return (-mm(mm(inv_t, d_inv), inv_t),)
+
+
+_unit_lower_inverse.defvjp(
+    lambda a: (_unit_lower_inverse(a),) * 2, _unit_lower_inverse_bwd
+)
+
+
 def _heads_per_pass(B: int, T: int, H: int) -> int:
     """Heads whose chunks' maps are computed together: the largest divisor
     of ``H`` with at most 2^17 (row, position, head) triples a pass (6 of
@@ -242,54 +364,42 @@ def _heads_per_pass(B: int, T: int, H: int) -> int:
 
 def _one_pass(q, k, v, g, beta, interpret):
     """The output for the heads given (the module's docstring has the
-    algebra): ``q``, ``k`` (B, chunks, C, H d_k), ``v`` (B, chunks, C, H
-    d_v) in the operands' dtype - the projections' own layout, heads side by
-    side, cut into chunks - ``g``, ``beta`` (B, chunks, C, H) float32 ->
-    (B, chunks, C, H d_v).  Index letters: b row, n chunk, i / j / c
-    position in the chunk, h head, k / l key axis, v value axis."""
-    B, chunks, C, H = g.shape
-    heads = lambda x: x.reshape(x.shape[:3] + (H, x.shape[3] // H))
-    q, k, v = heads(q), heads(k), heads(v)
+    algebra), head-major: ``q``, ``k`` (B, H, chunks, C, d_k), ``v`` (B, H,
+    chunks, C, d_v) in the operands' dtype, ``g``, ``beta`` (B, H, chunks,
+    C) float32 -> (B, H, chunks, C, d_v).  Every product is a matmul
+    batched over the leading three axes; i / j are positions in the chunk."""
+    B, H, chunks, C = g.shape
     dk, dv, cd, f32 = q.shape[-1], v.shape[-1], q.dtype, jnp.float32
     ein = functools.partial(
         jnp.einsum, precision=_precision_for(cd), preferred_element_type=f32
     )
-    gam = jnp.cumsum(g, axis=2)  # (b, n, c, h)
+    gam = jnp.cumsum(g, axis=-1)
     grown = jnp.exp(gam)  # decay since the chunk's start, <= 1
-    left = gam[:, :, -1:]  # the whole chunk's log decay
-    gam_h, beta_h = jnp.moveaxis(gam, 2, 3), jnp.moveaxis(beta, 2, 3)  # (b, n, h, c)
+    left = gam[..., -1:]  # the whole chunk's log decay
     row = jnp.arange(C)[:, None]
     lower, strict = row >= row.T, row > row.T
     # exp only where it is kept: above the diagonal the difference is positive
     decay = jnp.where(
         lower,
-        jnp.exp(jnp.where(lower, gam_h[..., :, None] - gam_h[..., None, :], 0.0)),
+        jnp.exp(jnp.where(lower, gam[..., :, None] - gam[..., None, :], 0.0)),
         0.0,
-    )  # (b, n, h, i, j)
+    )  # (B, H, chunks, i, j)
     a = jnp.where(
-        strict, beta_h[..., None] * ein("bnihk,bnjhk->bnhij", k, k) * decay, 0.0
+        strict, beta[..., None] * ein("...ik,...jk->...ij", k, k) * decay, 0.0
     )
     t = _unit_lower_inverse(a).astype(cd)
-    rhs = jnp.concatenate([
-        k.astype(f32) * (beta * grown)[..., None], v.astype(f32) * beta[..., None]
-    ], -1).astype(cd)
-    wu = ein("bnhij,bnjhd->bnihd", t, rhs).astype(cd)
-    w, u = wu[..., :dk], wu[..., dk:]
-    p = jnp.where(lower, ein("bnihk,bnjhk->bnhij", q, k) * decay, 0.0).astype(cd)
-    q_eff = (
-        q.astype(f32) * grown[..., None] - ein("bnhij,bnjhk->bnihk", p, w)
-    ).astype(cd)
-    kd = (k.astype(f32) * jnp.exp(left - gam)[..., None]).astype(cd)
-    m = jnp.moveaxis(jnp.exp(left[:, :, 0]), 1, 2)[..., None, None] * jnp.eye(
-        dk, dtype=f32
-    ) - ein("bnchk,bnchl->bhnkl", kd, w)
-    n = ein("bnchk,bnchv->bhnkv", kd, u)
-    states = _chunk_states(
-        m.reshape(B * H, chunks, dk, dk), n.reshape(B * H, chunks, dk, dv), cd,
+    scaled = lambda x, by: (x.astype(f32) * by[..., None]).astype(cd)
+    w = ein("...ij,...jk->...ik", t, scaled(k, beta * grown)).astype(cd)
+    u = ein("...ij,...jv->...iv", t, scaled(v, beta)).astype(cd)
+    p = jnp.where(lower, ein("...ik,...jk->...ij", q, k) * decay, 0.0).astype(cd)
+    rows = lambda x: x.reshape((B * H,) + x.shape[2:])
+    o = _chunks(
+        rows(w), rows(u), rows(scaled(k, jnp.exp(left - gam))),
+        rows(scaled(q, grown)), rows(p),
+        rows(jnp.broadcast_to(jnp.exp(left)[..., None], (B, H, chunks, 1, dv))),
         interpret,
-    ).reshape(B, H, chunks, dk, dv)
-    o = ein("bnhij,bnjhv->bnihv", p, u) + ein("bnchk,bhnkv->bnchv", q_eff, states)
-    return o.astype(cd).reshape(B, chunks, C, H * dv)
+    )
+    return o.reshape(B, H, chunks, C, dv)
 
 
 def gated_delta_rule(q, k, v, g, beta, interpret: Optional[bool] = None):
@@ -312,16 +422,20 @@ def gated_delta_rule(q, k, v, g, beta, interpret: Optional[bool] = None):
     heads = _heads_per_pass(B, T, H)
 
     def by_pass(x):
-        """(B, T, H[, d]) -> (H / heads, B, chunks, C, heads [x d]), zero
-        steps behind the row: a pass's heads stay side by side, as the
-        projections left them, one lane-dense axis."""
+        """(B, T, H[, d]) -> (H / heads, B, heads, chunks, C[, d]), zero
+        steps behind the row: head-major, the layout the kernels' blocks
+        take, by the one transpose an operand gets."""
         x = jnp.pad(x, ((0, 0), (0, chunks * C - T)) + ((0, 0),) * (x.ndim - 2))
-        x = x.reshape(B, chunks, C, H // heads, -1)
-        return jnp.moveaxis(x, 3, 0)
+        x = jnp.moveaxis(x, 2, 1)  # (B, H, T[, d])
+        x = x.reshape((B, H // heads, heads, chunks, C) + x.shape[3:])
+        return jnp.moveaxis(x, 1, 0)
 
     # One pass of heads at a time, and in a backward pass again: a pass's
     # intermediates are never all heads' at once.  What a pass keeps for
     # its backward is what ``remat="selective"`` keeps: the chunk states.
+    # (B, H, T, d) before the heads are split into passes: a (.., passes,
+    # heads, d) view of the projections' layout has a 6-row second-minor axis
+    # and every float32 copy XLA makes of it is padded 8 / 6 x 256 / 192.
     one_pass = jax.checkpoint(
         functools.partial(_one_pass, interpret=interpret),
         policy=jax.checkpoint_policies.save_only_these_names(ATTN_OUT_NAME),
@@ -330,6 +444,6 @@ def gated_delta_rule(q, k, v, g, beta, interpret: Optional[bool] = None):
         by_pass(q), by_pass(k), by_pass(v),
         by_pass(g.astype(jnp.float32)), by_pass(beta.astype(jnp.float32)),
     ))
-    # (H / heads, B, chunks, C, heads x d_v) -> (B, T, H, d_v)
-    o = jnp.moveaxis(o, 0, 3).reshape(B, chunks * C, H, -1)
-    return _tag(o[:, :T])
+    # (H / heads, B, heads, chunks, C, d_v) -> (B, T, H, d_v)
+    o = jnp.moveaxis(o, 0, 1).reshape(B, H, chunks * C, -1)
+    return _tag(jnp.moveaxis(o, 1, 2)[:, :T])

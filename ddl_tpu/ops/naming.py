@@ -41,7 +41,9 @@ KERNEL_NAMES = (
     # ... and with latent attention's rotary product (``q_rope=``)
     "ddl_flash_mla_fwd", "ddl_flash_mla_bwd_dq", "ddl_flash_mla_bwd_dkv",
     "ddl_flash_tile_fwd", "ddl_flash_tile_bwd",
-    # the gated delta rule's chain over chunk states, forward and reverse
+    # the gated delta rule's chunk meeting its state (``Vn = U - W H``,
+    # ``O = Qg H + P Vn``, ``H <- a H + Kd^T Vn``, the state in VMEM for the
+    # whole row) and its backward pass, the chunks last to first
     # (``ops/gated_delta.py``); ``gdn_device_share`` reads ``ddl_gdn_``
     "ddl_gdn_fwd", "ddl_gdn_bwd",
     "ddl_ici_bcast", "ddl_ici_scatter", "ddl_shuffle_exchange",

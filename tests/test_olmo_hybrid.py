@@ -61,22 +61,31 @@ def rel_rms(got, want):
 # -- the scan against the recurrence ------------------------------------------------
 
 #: (B, T, H, d_k, d_v): the published head (96 / 192, off the 128-lane tile)
-#: over three chunks and a ragged fourth; whole chunks; one short chunk;
-#: a row shorter than the smallest chunk.
+#: over three chunks and a ragged fourth; whole chunks of two rows (six
+#: (row, head) pairs a grid step); one short chunk; a row shorter than the
+#: smallest chunk; eleven heads (no grid step above one divides them) and
+#: two rows of five (a step of five) over a ragged row.
 SHAPES = {
     "published_head_ragged": (1, 200, 2, 96, 192),
     "whole_chunks": (2, 128, 3, 16, 32),
     "one_short_chunk": (1, 37, 2, 8, 16),
     "shorter_than_a_chunk": (1, 5, 1, 8, 16),
+    "eleven_heads_ragged": (1, 130, 11, 8, 16),
+    "two_rows_of_five_heads_ragged": (2, 70, 5, 16, 32),
 }
 NAMES = ("o", "dq", "dk", "dv", "dg", "dbeta")
 
 
 @functools.lru_cache(maxsize=None)
-def both_sides(shape_name, heads_per_pass=None):
+def both_sides(shape_name, heads_per_pass=None, dtype=jnp.float32):
     """{name: (scan's, recurrence's)} for the output and the five
-    gradients of a seeded weighted sum of it."""
+    gradients of a seeded weighted sum of it; with ``dtype`` bfloat16 both
+    sides get q, k, v rounded to it, the recurrence as float32 again."""
     x = operands(0, *SHAPES[shape_name])
+    x = tuple(a.astype(dtype) for a in x[:3]) + x[3:]
+    widened = lambda fn: lambda q, k, v, g, b: fn(
+        *(a.astype(jnp.float32) for a in (q, k, v)), g, b
+    ).astype(q.dtype)
     weights = jnp.asarray(
         np.random.default_rng(1).standard_normal(x[2].shape), jnp.float32
     )
@@ -86,12 +95,19 @@ def both_sides(shape_name, heads_per_pass=None):
         grads = jax.grad(lambda *a: jnp.sum(fn(*a) * weights), argnums=range(5))(*x)
         return (out,) + tuple(grads)
 
-    want = sides(plain)
+    want = sides(widened(plain))
     chosen = gated_delta._heads_per_pass
     forced = chosen if heads_per_pass is None else lambda B, T, H: heads_per_pass
     with mock.patch.object(gated_delta, "_heads_per_pass", forced):
         got = sides(gated_delta_rule)
     return dict(zip(NAMES, zip(got, want)))
+
+
+#: bfloat16 operands against the recurrence on the same rounded operands: a
+#: few of bfloat16's roundings (2^-9 each) in the output, and in a gradient
+#: the roundings of the cotangents the kernel hands back in bfloat16 too.
+#: Measured: output 4.2e-3-4.6e-3, a gradient up to 5.8e-3.
+BF16_TOL = 8 * 2.0**-9
 
 
 @pytest.mark.parametrize("name", NAMES)
@@ -100,6 +116,16 @@ def test_the_scan_is_the_recurrence_forward_and_in_every_gradient(shape, name):
     got, want = both_sides(shape)[name]
     assert got.shape == want.shape and got.dtype == want.dtype
     assert rel_rms(got, want) < SCAN_TOL
+
+
+@pytest.mark.parametrize("name", NAMES)
+@pytest.mark.parametrize("shape", [
+    "published_head_ragged", "whole_chunks", "two_rows_of_five_heads_ragged",
+])
+def test_bfloat16_operands_stay_on_the_recurrence_in_every_gradient(shape, name):
+    got, want = both_sides(shape, dtype=jnp.bfloat16)[name]
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert rel_rms(got.astype(jnp.float32), want.astype(jnp.float32)) < BF16_TOL
 
 
 @pytest.mark.parametrize("name", NAMES)
@@ -151,7 +177,7 @@ def test_bfloat16_operands_keep_a_float32_state():
 
 def test_a_state_carried_in_bfloat16_shows_in_float32(monkeypatch):
     """What the benchmark's ``core_rel_rms`` limit is for: with float32
-    operands the scan is the recurrence to 1e-5; with the chain's state
+    operands the scan is the recurrence to 1e-5; with the kernel's state
     rounded to bfloat16 from chunk to chunk it is a thousandth off."""
     q, k, v, g, beta = operands(6, 1, 640, 2, 16, 32)
     g = g / 50
@@ -169,6 +195,19 @@ def test_the_triangular_inverse_is_the_inverse(C):
     assert float(np.max(np.abs(np.asarray(got) - want))) < 1e-4 * np.max(np.abs(want))
 
 
+@pytest.mark.parametrize("C", [8, 16, 32, 64])
+def test_the_triangular_inverses_own_backward_is_autodiffs(C):
+    """``-X^T dX X^T`` against autodiff through the blocked algorithm, where
+    ``a`` lives: below the diagonal."""
+    r = np.random.default_rng(C)
+    a = jnp.asarray(np.tril(r.standard_normal((3, 2, C, C)) * 0.3, -1), jnp.float32)
+    w = jnp.asarray(r.standard_normal((3, 2, C, C)), jnp.float32)
+    grad = lambda inverse: jnp.tril(jax.grad(lambda a: jnp.sum(inverse(a) * w))(a), -1)
+    with jax.default_matmul_precision("highest"):
+        want = grad(gated_delta._unit_lower_inverse.fun)
+    assert rel_rms(grad(gated_delta._unit_lower_inverse), want) < 1e-5
+
+
 @pytest.mark.parametrize("T,want", [(5, 8), (37, 64), (64, 64), (100, 64), (16384, 64)])
 def test_the_chunk_comes_from_the_row(T, want):
     assert gated_delta._chunk_len(T) == want
@@ -176,14 +215,22 @@ def test_the_chunk_comes_from_the_row(T, want):
 
 def test_the_grid_and_the_passes_come_from_the_shapes():
     # one row of 16,384 at the published 30 heads of 96 / 192: five passes
-    # of six heads, each one grid step a chunk
+    # of six heads, each one grid step a chunk, in bfloat16 and (the
+    # benchmark's core check) in float32
     assert gated_delta._heads_per_pass(1, 16384, 30) == 6
-    assert gated_delta._heads_per_step(6, 96, 192) == 6
+    assert gated_delta._heads_per_step(6, 64, 96, 192, 2) == 6
+    assert gated_delta._heads_per_step(6, 64, 96, 192, 4) == 6
     assert gated_delta._heads_per_pass(2, 16384, 30) == 3
     assert gated_delta._heads_per_pass(1, 3072, 30) == 30
-    assert gated_delta._heads_per_step(30, 96, 192) == 6
-    assert gated_delta._heads_per_step(7, 96, 192) == 7
-    assert gated_delta._heads_per_step(4, 8, 16) == 4
+    # a step's blocks are held to the budget at the operands' width ...
+    assert gated_delta._heads_per_step(30, 64, 96, 192, 2) == 6
+    assert gated_delta._heads_per_step(8, 64, 96, 192, 2) == 8
+    assert gated_delta._heads_per_step(8, 64, 96, 192, 4) == 4
+    assert gated_delta._heads_per_step(7, 64, 96, 192, 4) == 7
+    # ... and divide the rows: eleven go one at a time
+    assert gated_delta._heads_per_step(11, 64, 8, 16, 4) == 1
+    assert gated_delta._heads_per_step(10, 64, 16, 32, 4) == 5
+    assert gated_delta._heads_per_step(4, 8, 8, 16, 4) == 4
 
 
 # -- the model against the reference ------------------------------------------------
@@ -424,9 +471,9 @@ def test_the_benchmarks_reference_is_this_one():
 @pytest.mark.parametrize("remat,fwd", [("none", 1), ("selective", 1), ("full", 2)])
 def test_the_backward_pass_reads_the_saved_states(remat, fwd, monkeypatch):
     """The train step lowered for the TPU: under ``selective`` each linear
-    layer runs the forward chain once - its chunk states and its output
-    are saved residuals - and the reverse chain once; ``full`` keeps
-    neither and runs the forward chain again."""
+    layer runs the forward kernel once - the chunk states it writes and the
+    scan's output are saved residuals - and the backward kernel once;
+    ``full`` keeps neither and runs the forward kernel again."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = olmo_hybrid.OlmoHybridConfig(
         vocab=256, d_model=256, n_heads=2, d_ff=256, n_linear_heads=2,

@@ -126,23 +126,41 @@ def test_flash_attention_compiles(v5e, grad, packed):
     )
 
 
+def _gdn_calls(lowered_text):
+    """{kernel name: (operand types, result types)} of the ``ddl_gdn_*``
+    custom calls of a lowered program."""
+    calls = {}
+    for line in lowered_text.splitlines():
+        m = re.search(r'kernel_name = "(ddl_gdn_\w+)"', line)
+        if m:
+            operands, results = line.rsplit(" : ", 1)[1].split(" -> ")
+            calls[m.group(1)] = tuple(
+                re.findall(r"tensor<([\w]+)>", part) for part in (operands, results)
+            )
+    return calls
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
-def test_gated_delta_rule_compiles_at_olmo_hybrids_geometry(v5e, grad):
-    """One row of 16,384 positions, 30 heads of 96-wide keys and 192-wide
-    values (neither fills 128 lanes), bf16: the chain over the 256 chunk
-    states and, for the backward pass, the same chain in reverse with
-    ``M^T``, each compiled once - inside the loop over the five passes of
-    six heads - on a grid of (1 head group, 256 chunks)."""
+@pytest.mark.parametrize("dtype,heads", [(jnp.bfloat16, 30), (jnp.float32, 6)],
+                         ids=["cell_bf16_30_heads", "core_check_f32_6_heads"])
+def test_gated_delta_rule_compiles_at_olmo_hybrids_geometry(v5e, grad, dtype, heads):
+    """One row of 16,384 positions, heads of 96-wide keys and 192-wide
+    values (neither fills 128 lanes): the cell's 30 in bfloat16 - five
+    passes of six - and the benchmark's core check's six in float32.  Each
+    chunk meets its state inside the kernel, forward and (the chunks last to
+    first) backward, each compiled once - inside the loop over the passes -
+    on a grid of (1 group of six heads, 256 chunks): a VMEM overflow or a
+    relayout Mosaic does not have fails here, not on the chip."""
     from ddl_tpu.ops.gated_delta import gated_delta_rule
 
     one = SingleDeviceSharding(v5e[0])
-    T_, H_, DK, DV = 16384, 30, 96, 192
+    T_, DK, DV = 16384, 96, 192
     args = (
-        jax.ShapeDtypeStruct((1, T_, H_, DK), jnp.bfloat16, sharding=one),
-        jax.ShapeDtypeStruct((1, T_, H_, DK), jnp.bfloat16, sharding=one),
-        jax.ShapeDtypeStruct((1, T_, H_, DV), jnp.bfloat16, sharding=one),
-        jax.ShapeDtypeStruct((1, T_, H_), jnp.float32, sharding=one),
-        jax.ShapeDtypeStruct((1, T_, H_), jnp.float32, sharding=one),
+        jax.ShapeDtypeStruct((1, T_, heads, DK), dtype, sharding=one),
+        jax.ShapeDtypeStruct((1, T_, heads, DK), dtype, sharding=one),
+        jax.ShapeDtypeStruct((1, T_, heads, DV), dtype, sharding=one),
+        jax.ShapeDtypeStruct((1, T_, heads), jnp.float32, sharding=one),
+        jax.ShapeDtypeStruct((1, T_, heads), jnp.float32, sharding=one),
     )
 
     def scan(*a):
@@ -159,8 +177,19 @@ def test_gated_delta_rule_compiles_at_olmo_hybrids_geometry(v5e, grad):
     want = {"ddl_gdn_fwd", "ddl_gdn_bwd"} if grad else {"ddl_gdn_fwd"}
     assert kernel_names(lowered.compile().as_text()) == want
     assert want <= set(KERNEL_NAMES)
-    # the backward pass reads the saved chunk states: no second forward chain
-    assert mosaic_grids(lowered.as_text()) == {(1, 256): 2 if grad else 1}
+    # the backward pass reads the saved chunk states: no second forward kernel
+    text = lowered.as_text()
+    assert mosaic_grids(text) == {(1, 256): 2 if grad else 1}
+    calls = _gdn_calls(text)
+    assert set(calls) == want
+    if dtype == jnp.bfloat16:
+        # no chunk's map crosses HBM: nothing float32 of (chunks, 96, 96) or
+        # (chunks, 96, 192) enters or leaves a kernel (the states it writes
+        # and reads are the operands' bfloat16)
+        for operands, results in calls.values():
+            assert "6x256x96x192xbf16" in operands + results
+            for t in operands + results:
+                assert not re.fullmatch(r"\d+x256x96x(96|192)xf32", t), (t, calls)
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
