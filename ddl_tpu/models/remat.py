@@ -40,6 +40,14 @@ accepted for back compat — ``True`` is ``"full"``, ``False`` is
   96 x 192) beside ``2·B·T·H·d_v`` of output - so the backward pass runs
   the backward kernel and no forward one; what XLA prepares of the chunks
   is recomputed, a pass of heads at a time.
+  The fixed-decay scan (``ops/lightning_attention.py``) tags its output and
+  its chunk states the same way (``2·B·(T/128)·H·d²`` bytes: 134 MB at one
+  row of 16,384, 32 heads of 128), and a block-sparse attention layer
+  (``ops/sparse_attention.py``) its output, its compact logsumexp and what
+  its selection made - the visibility bitmap (``2·B·G·T·T/64`` bytes: 16.8
+  MB at 2 groups) and the merged block lists and their transposes (256 KB)
+  - so the backward pass selects nothing again and runs the three
+  backward kernels alone.
 - ``"dots"`` — ``jax.checkpoint_policies.dots_with_no_batch_dims_
   saveable``: save every non-batched matmul output (all weight
   projections), recompute only elementwise ops and attention — the
@@ -49,10 +57,11 @@ accepted for back compat — ``True`` is ``"full"``, ``False`` is
 One wrap site per model family (:func:`wrap` around the layer body),
 one tag function (:func:`tag_attn_out`), called by the one attention
 dispatcher (``parallel.ring_attention.attention``), by the flash
-kernels' rules and by the gated-delta scan, never by a model — so a value is tagged once (a second
+kernels' rules (the block-sparse ones and their selection among them) and
+by the two scans, never by a model — so a value is tagged once (a second
 tag on the same output would save it twice) and the policy semantics
-cannot drift between llama, moe, afmoe, deepseek_v3, olmo_hybrid and the
-pipelined forwards.
+cannot drift between llama, moe, afmoe, deepseek_v3, olmo_hybrid,
+minicpm_sala and the pipelined forwards.
 """
 
 from __future__ import annotations

@@ -46,6 +46,15 @@ KERNEL_NAMES = (
     # whole row) and its backward pass, the chunks last to first
     # (``ops/gated_delta.py``); ``gdn_device_share`` reads ``ddl_gdn_``
     "ddl_gdn_fwd", "ddl_gdn_bwd",
+    # linear attention with a fixed decay a head: a chunk from q, k, v to o,
+    # the state in VMEM for the whole row, and its backward pass
+    # (``ops/lightning_attention.py``)
+    "ddl_lightning_fwd", "ddl_lightning_bwd",
+    # block-sparse attention over a per-query selection of key blocks
+    # (``ops/sparse_attention.py``): the selection's block scores, and the
+    # flash kernels whose key blocks come from scalar-prefetched lists
+    "ddl_sparse_select",
+    "ddl_flash_sparse_fwd", "ddl_flash_sparse_bwd_dq", "ddl_flash_sparse_bwd_dkv",
     "ddl_ici_bcast", "ddl_ici_scatter", "ddl_shuffle_exchange",
 )
 
@@ -64,6 +73,8 @@ SCOPE_NAMES = (
     "ddl.embed", "ddl.patchify",
     "ddl.attn", "ddl.attn_gate", "ddl.mla_q", "ddl.mla_kv_up",
     "ddl.gdn_proj", "ddl.gdn_conv", "ddl.gdn_scan", "ddl.gdn_out",
+    "ddl.lightning_proj", "ddl.lightning_scan", "ddl.lightning_out",
+    "ddl.sparse_select",
     "ddl.mlp",
     "ddl.moe", "ddl.moe_route", "ddl.moe_experts", "ddl.moe_combine",
     "ddl.moe_shared",

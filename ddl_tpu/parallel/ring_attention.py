@@ -287,6 +287,7 @@ def attention(
     window: Optional[int] = None,
     q_rope: Optional[jax.Array] = None,
     k_rope: Optional[jax.Array] = None,
+    selection: Optional[Any] = None,
 ) -> jax.Array:
     """The single attention dispatcher — one source of truth for impl/mesh
     routing (models call this, not the individual strategies):
@@ -310,6 +311,14 @@ def attention(
       refuses it by name: the shared key would have to ride the ring
       beside k and v, and no ring step takes it.
 
+    - ``selection`` (``ops.sparse_attention.Selection``, which carries
+      its sizes): block-sparse attention - a query sees the key blocks
+      its key-value group's selection names, and in them the keys up to
+      itself - on one device (causal, no window, no packed rows):
+      the ``ddl_flash_sparse_*`` kernels, or the same mask as a dense
+      softmax.  A mesh refuses it by name: neither the selection nor its
+      lists are shard-mapped.  ``None`` leaves every program what it was.
+
     The output is tagged ``models.remat.ATTN_OUT_NAME`` on every route,
     here or by the flash kernels' own rules, so a layer under
     ``remat="selective"`` keeps it whatever attention it ran.
@@ -323,6 +332,19 @@ def attention(
     use_flash = impl == "flash" or (
         impl == "auto" and jax.default_backend() == "tpu"
     )
+    if selection is not None:
+        from ddl_tpu.ops import sparse_attention as _sparse
+
+        if (mesh is not None or not causal or window is not None
+                or segment_ids is not None or q_rope is not None):
+            raise NotImplementedError(
+                "attention(selection=): block-sparse attention is causal "
+                "self-attention on one device; a mesh, a sliding window, "
+                "packed rows and the latent form have no kernel"
+            )
+        if use_flash:  # tags its output beside its logsumexp itself
+            return _sparse.sparse_attention(q, k, v, selection)
+        return tag_attn_out(_sparse.attention_dense(q, k, v, selection))
     if mesh is not None and axis in mesh.axis_names and mesh.shape[axis] > 1:
         if q_rope is not None:
             raise NotImplementedError(

@@ -29,7 +29,9 @@ REPO = os.path.dirname(cells.HERE)
 
 def _family(name):
     """(module, tiny config under selective remat, loss(params, batch))."""
-    from ddl_tpu.models import afmoe, deepseek_v3, llama, moe, olmo_hybrid, vit
+    from ddl_tpu.models import (
+        afmoe, deepseek_v3, llama, minicpm_sala, moe, olmo_hybrid, vit)
+    from ddl_tpu.ops.sparse_attention import SparseConfig
 
     common = dict(vocab=64, d_model=32, n_layers=2, n_heads=2, max_seq=16,
                   remat="selective")
@@ -47,6 +49,11 @@ def _family(name):
             deepseek_v3, deepseek_v3.DeepseekV3Config(remat="selective")),
         "olmo_hybrid": lambda: (
             olmo_hybrid, olmo_hybrid.OlmoHybridConfig(remat="selective")),
+        # rows past ``dense_len``: the selection and the sparse path are live
+        "minicpm_sala": lambda: (
+            minicpm_sala, minicpm_sala.MiniCPMSalaConfig(
+                remat="selective", dense_len=64, sparse=SparseConfig(
+                    block=16, kernel=8, stride=4, topk=4, local_blocks=2))),
     }[name]()
     return mod, cfg, lambda p, b: mod.next_token_loss(p, b[0], cfg)
 
@@ -74,7 +81,7 @@ def _compiled_step_text(name):
                  jax.ShapeDtypeStruct((2, 2, 1), jnp.int32))
     else:
         # the linear-attention scan over whole chunks and a ragged one
-        seq = 160 if name == "olmo_hybrid" else 16
+        seq = 160 if name in ("olmo_hybrid", "minicpm_sala") else 16
         batch = (jax.ShapeDtypeStruct((2, 2, seq), jnp.int32),)
     args = (params, jax.eval_shape(optimizer.init, params), batch, True)
     return run.lower(*args).compile().as_text()
@@ -85,7 +92,8 @@ OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
 @pytest.mark.parametrize(
-    "family", ["llama", "moe", "afmoe", "deepseek_v3", "vit", "olmo_hybrid"])
+    "family", ["llama", "moe", "afmoe", "deepseek_v3", "vit", "olmo_hybrid",
+               "minicpm_sala"])
 def test_every_matmul_of_a_train_step_stands_under_a_scope(family):
     text = _compiled_step_text(family)
     seen = {}
@@ -111,6 +119,18 @@ def test_every_matmul_of_a_train_step_stands_under_a_scope(family):
         # the pass's intermediates of the scan are recomputed, not kept.
         for which in passes:
             assert {"ddl.gdn_proj", "ddl.gdn_scan", "ddl.gdn_out"} <= seen[which], seen
+    if family == "minicpm_sala":
+        # A lightning layer's projections and output in every pass; its
+        # scan forward and backward but never recomputed (one kernel from
+        # q, k, v to o: ``selective`` saves its output and chunk states);
+        # the selection in the forward pass alone: it has no gradient, and
+        # ``selective`` saves its lists.
+        for which in passes:
+            assert {"ddl.lightning_proj", "ddl.lightning_out"} <= seen[which], seen
+        assert "ddl.lightning_scan" in seen["forward"] & seen["backward"], seen
+        assert "ddl.lightning_scan" not in seen["recompute"], seen
+        assert "ddl.sparse_select" in seen["forward"], seen
+        assert "ddl.sparse_select" not in seen["backward"] | seen["recompute"], seen
     # The module's name is what the reduction looks for.
     assert "HloModule jit__run" in text
 
@@ -127,11 +147,16 @@ def test_the_table_is_whole():
     assert all(n.startswith("ddl.") for n in naming.SCOPE_NAMES)
     grouped = [s for scopes_ in S.GROUPS.values() for s in scopes_]
     # The benchmark's groups, and the scopes its reader counts as ``other``:
-    # exactly the four the linear-attention readers select themselves.
-    from benchmarks.layers import gdn_dense_device_share, gdn_device_share
+    # exactly the eight the linear-attention and selection readers select
+    # themselves.
+    from benchmarks.layers import (
+        gdn_dense_device_share, gdn_device_share, lightning_dense_device_share,
+        lightning_device_share, sparse_select_device_share)
 
     gdn = gdn_dense_device_share.DENSE_SCOPES + (gdn_device_share.SCAN_SCOPE,)
-    assert sorted(grouped + list(gdn)) == sorted(naming.SCOPE_NAMES)
+    sala = lightning_dense_device_share.DENSE_SCOPES + (
+        lightning_device_share.SCAN_SCOPE, sparse_select_device_share.SELECT_SCOPE)
+    assert sorted(grouped + list(gdn + sala)) == sorted(naming.SCOPE_NAMES)
     with pytest.raises(AssertionError):
         naming.scope("ddl.not_in_the_table")
     # No model file names a scope past the helper.
@@ -271,6 +296,10 @@ def _made_up_trace(path, scoped=True):
     us = 1_000_000  # ps
     ops = [  # name, path, start us, length us, flops, category
         ("while", "jit(_run)/while", 0, 900, 0, "while"),
+        ("fusion.12", "jit(_run)/while/body/closed_call/jvp(ddl.lightning_proj)/dot_general", 0, 2, 0, "convolution fusion"),
+        ("ddl_lightning_fwd.1", "jit(_run)/while/body/closed_call/jvp(ddl.lightning_scan)/ddl_lightning_fwd", 2, 3, 0, "custom-call"),
+        ("fusion.13", "jit(_run)/while/body/closed_call/jvp(ddl.lightning_scan)/pad", 5, 1, 0, "loop fusion"),
+        ("fusion.14", "jit(_run)/while/body/closed_call/transpose(jvp(jvp()))/checkpoint/ddl.lightning_out/dot_general", 6, 2, 0, "convolution fusion"),
         ("fusion.1", "jit(_run)/while/body/closed_call/jvp(ddl.embed)/gather", 10, 20, 0, "loop fusion"),
         ("fusion.2", "jit(_run)/while/body/closed_call/jvp(ddl.attn)/dot_general", 30, 100, 4_000_000, "convolution fusion"),
         ("ddl_flash_fwd.1", "jit(_run)/while/body/closed_call/jvp(ddl.attn)/ddl_flash_fwd", 130, 50, 0, "custom-call"),
@@ -286,6 +315,9 @@ def _made_up_trace(path, scoped=True):
         ("fusion.10", "jit(_run)/while/body/closed_call/jvp(ddl.gdn_scan)/while/body/checkpoint/dot_general", 870, 6, 0, "convolution fusion"),
         ("ddl_gdn_fwd.1", "jit(_run)/while/body/closed_call/jvp(ddl.gdn_scan)/while/body/checkpoint/ddl_gdn_fwd", 876, 10, 0, "custom-call"),
         ("fusion.11", "jit(_run)/while/body/closed_call/transpose(jvp(jvp()))/checkpoint/ddl.gdn_out/dot_general", 886, 4, 0, "convolution fusion"),
+        ("fusion.15", "jit(_run)/while/body/closed_call/jvp(ddl.attn)/ddl.sparse_select/top_k", 890, 1, 0, "loop fusion"),
+        ("ddl_sparse_select.1", "jit(_run)/while/body/closed_call/jvp(ddl.attn)/ddl.sparse_select/ddl_sparse_select", 891, 2, 0, "custom-call"),
+        ("ddl_flash_sparse_fwd.1", "jit(_run)/while/body/closed_call/jvp(ddl.attn)/ddl_flash_sparse_fwd", 893, 3, 0, "custom-call"),
     ]
     if not scoped:
         ops = [(n, re.sub(r"ddl\.[a-z_]+/", "", re.sub(r"jvp\(ddl\.[a-z_]+\)", "jvp()", p)),
@@ -357,12 +389,12 @@ def test_shares_add_up_to_the_step_programs_own_time(made_up, capsys):
     assert got["moe_dispatch"] == pytest.approx(2 * 40 * us)
     assert got["head"] == pytest.approx(2 * (20 + 70) * us)
     assert got["optimizer"] == pytest.approx(2 * 110 * us)
-    # XLA's own copy and the %while's own 20 us; the other program's op
+    # XLA's own copy and the %while's own 6 us; the other program's op
     # is nobody's.
-    assert got["unscoped"] == pytest.approx(2 * (10 + 20) * us)
+    assert got["unscoped"] == pytest.approx(2 * (10 + 6) * us)
     assert _read("recompute_device_share", m) == pytest.approx(2 * 80 * us)
     kernels = _read("flash_device_share", m) + _read("gmm_device_share", m)
-    assert kernels == pytest.approx(2 * (50 + 60) * us)
+    assert kernels == pytest.approx(2 * (50 + 3 + 60) * us)
     # The linear-attention layers' three: the chain's kernels by their
     # family, what XLA runs of the recurrence under ``ddl.gdn_scan``, and
     # the three scopes outside the recurrence - together what the
@@ -373,9 +405,19 @@ def test_shares_add_up_to_the_step_programs_own_time(made_up, capsys):
     assert gdn == pytest.approx(2 * 10 * us)
     assert gdn_scan == pytest.approx(2 * 6 * us)
     assert gdn_dense == pytest.approx(2 * 4 * us)
+    # MiniCPM-SALA's three: the fixed-decay recurrence as executed (its
+    # scope and its kernels' family), the lightning blocks outside it, and
+    # the block selection (its scope, the selection kernel included).
+    lightning = _read("lightning_device_share", m)
+    lightning_dense = _read("lightning_dense_device_share", m)
+    select = _read("sparse_select_device_share", m)
+    assert lightning == pytest.approx(2 * (3 + 1) * us)
+    assert lightning_dense == pytest.approx(2 * (2 + 2) * us)
+    assert select == pytest.approx(2 * (1 + 2) * us)
     table = S.table_of_run(m)
-    assert gdn + gdn_scan + gdn_dense == pytest.approx(table.summary()["other"])
-    assert sum(got.values()) + kernels + gdn + gdn_scan + gdn_dense == pytest.approx(
+    other = gdn + gdn_scan + gdn_dense + lightning + lightning_dense + select
+    assert other == pytest.approx(table.summary()["other"])
+    assert sum(got.values()) + kernels + other == pytest.approx(
         100.0 * table.step_own_s / w, rel=1e-9)
     # ... which is every op of the window but the other program's.
     assert table.step_own_s == pytest.approx(

@@ -193,6 +193,73 @@ def test_gated_delta_rule_compiles_at_olmo_hybrids_geometry(v5e, grad, dtype, he
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["cell_bf16", "core_check_f32"])
+def test_lightning_attention_compiles_at_minicpm_salas_geometry(v5e, grad, dtype):
+    """One row of 16,384 positions, 32 heads of 128: a chunk from q, k, v to
+    o in one kernel a pass, the operands read where the projections left
+    them (no transpose in HBM), on a grid of (head groups, 128 chunks)."""
+    from ddl_tpu.ops.lightning_attention import lightning_attention
+
+    one = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct((1, 16384, 32, 128), dtype, sharding=one)
+
+    def scan(q, k, v):
+        return lightning_attention(q, k, v, interpret=False)
+
+    fn = scan
+    if grad:
+        def fn(q, k, v):
+            return jax.grad(
+                lambda *a: scan(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+            )(q, k, v)
+
+    lowered = jax.jit(fn).lower(x, x, x)
+    want = {"ddl_lightning_fwd", "ddl_lightning_bwd"} if grad else {"ddl_lightning_fwd"}
+    assert kernel_names(lowered.compile().as_text()) == want
+    assert want <= set(KERNEL_NAMES)
+    groups = 4 if dtype == jnp.bfloat16 else 8  # 8 heads a step, or 4 in float32
+    assert mosaic_grids(lowered.as_text()) == {(groups, 128): 2 if grad else 1}
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["cell_bf16", "core_check_f32"])
+def test_sparse_attention_compiles_at_minicpm_salas_geometry(v5e, grad, dtype):
+    """One row of 16,384 positions, 32 query heads over 2 key-value heads of
+    128, blocks of 64, top-64: the selection kernel and the three flash
+    kernels whose key blocks come from scalar-prefetched lists.  The forward
+    and dq grids' inner axis is the longest list a tile can hold, two
+    blocks a step (128 of 256 blocks); the dkv grid's the query tiles."""
+    from ddl_tpu.ops import sparse_attention as S
+
+    one = SingleDeviceSharding(v5e[0])
+    q = jax.ShapeDtypeStruct((1, 16384, 32, 128), dtype, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 16384, 2, 128), dtype, sharding=one)
+    sc = S.SparseConfig()
+
+    def attend(q, k, v):
+        sel = S.select_blocks(q, k, sc, interpret=False)
+        return S.sparse_attention(q, k, v, sel, interpret=False)
+
+    fn = attend
+    if grad:
+        def fn(q, k, v):
+            return jax.grad(
+                lambda *a: attend(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+            )(q, k, v)
+
+    lowered = jax.jit(fn).lower(q, kv, kv)
+    want = {"ddl_sparse_select", "ddl_flash_sparse_fwd"}
+    if grad:
+        want |= {"ddl_flash_sparse_bwd_dq", "ddl_flash_sparse_bwd_dkv"}
+    assert kernel_names(lowered.compile().as_text()) == want
+    assert want <= set(KERNEL_NAMES)
+    grids = {(1, 2, 128): 1, (1, 2, 128, 128): 3 if grad else 1}
+    assert mosaic_grids(lowered.as_text()) == grids
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
 def test_windowed_flash_attention_compiles_at_trinity_minis_geometry(v5e, grad):
     """2 x 8192 tokens, 32 query / 4 kv heads x 128, window 2048, bf16:
     the band's kernels under names of their own, beside the causal-full
