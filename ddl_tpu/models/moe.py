@@ -19,7 +19,10 @@ as absent).  Two dispatches live here, chosen by what the mesh shows
   ``held=(first, count)`` of a wider router, it routes over all of them,
   computes the rows whose choice falls on a held expert and leaves the
   rest out — one chip's share of an expert-parallel layer, without the
-  exchange and with nothing standing in for it.
+  exchange and with nothing standing in for it.  A range narrower than
+  half the router runs its row passes over a static bound of held rows
+  (:func:`held_row_bound`) and at full width past it, one ``lax.cond`` a
+  pass: still dropless, at any routing.
 - **Capacity-bounded einsums** (:func:`moe_mlp`, only where an ``ep`` mesh
   axis shards the expert stacks): the GShard/Switch formulation — fully
   static dispatch/combine one-hots, per-expert capacity ``C =
@@ -42,6 +45,7 @@ TPU).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -503,12 +507,295 @@ def _combine_copies_bwd(res, d_out):
 _combine_copies.defvjp(_combine_copies_fwd, _combine_copies_bwd)
 
 
+# -- a share's rows: a static bound, and full width past it ------------------------
+
+#: Rows the bound is rounded up to: what XLA's grouped-matmul kernels want
+#: of their row count on a v5e (``tools/probe_ragged_rows.py``, my chip run,
+#: PR 40: the same groups over 32,768 rows 0.80 ms, over 8 more 12.4, 128
+#: more 1.21, 256 more 0.91, 512 more 0.82).
+ROW_TILE = 512
+
+
+def held_row_bound(n_rows: int, count: int, n_experts: int) -> int:
+    """How many of the ``n_rows`` sorted (token, slot) rows a share of
+    ``count`` of the router's ``n_experts`` experts runs its row passes
+    over: twice what a balanced router sends it, in whole row tiles — and
+    all of them for half the experts or more.  A rule of what the code can
+    see, not a knob: past it the layer runs at full width
+    (:func:`_held_rows`), so it bounds time, never the result."""
+    twice_the_share = -(-2 * n_rows * count // n_experts)
+    return min(n_rows, -(-twice_the_share // ROW_TILE) * ROW_TILE)
+
+
+@contextlib.contextmanager
+def _phase(name: str, overflow: bool):
+    """The routed core's phase ``name``; the full-width fallback's ops
+    stand under ``ddl.moe_overflow`` inside it, the innermost scope, so
+    that the device trace says which branch ran."""
+    with scope(name):
+        if overflow:
+            with scope("ddl.moe_overflow"):
+                yield
+        else:
+            yield
+
+
+def _sorted_rows(x, experts, top_w, order, group_sizes, is_held,
+                 overflow: bool = False) -> jax.Array:
+    """The expert pass over all N·k sorted rows and the combine behind
+    it: :func:`ragged_experts` after its sort."""
+    N, k = top_w.shape
+    with _phase("ddl.moe_combine", overflow):  # the un-permute's index, read by both rules
+        inv = jnp.argsort(order)  # flat copy index -> its sorted row
+
+    with _phase("ddl.moe_experts", overflow):
+        xs = _take_copies(x, order, inv, k)  # (N*k, D) grouped by expert
+        if is_held is not None:
+            in_a_group = (jnp.arange(N * k) < jnp.sum(group_sizes))[:, None]
+            xs = jnp.where(in_a_group, xs, 0)
+        rows = _swiglu_rows(xs, experts, group_sizes)  # still expert-sorted
+
+    with _phase("ddl.moe_combine", overflow):
+        return _combine_copies(rows, top_w, order, inv, is_held)
+
+
+def _swiglu_rows(xs: jax.Array, experts: Params, group_sizes: jax.Array):
+    """The experts' SwiGLU on rows grouped by expert: three grouped
+    matmuls.  The rows of the result past the last group are UNWRITTEN."""
+    dt = xs.dtype
+    gate = jax.nn.silu(
+        jax.lax.ragged_dot(xs, experts["w_gate"].astype(dt), group_sizes)
+    )
+    up = jax.lax.ragged_dot(xs, experts["w_up"].astype(dt), group_sizes)
+    return jax.lax.ragged_dot(gate * up, experts["w_down"].astype(dt), group_sizes)
+
+
+# The bounded pass.  Its two row moves are not the full-width ones over
+# fewer rows: the N·k slots gathered out of a (B, D) source cost what the
+# full permutation costs (``tools/probe_ragged_rows.py``, PR 40: 4.8 ms
+# against 4.4 at 131,072 slots — a gather pays by the row it writes), so
+# the combine and the copies' cotangent would keep their time.  Both are
+# one operation, "sum the B rows by their token", and B rows can be summed
+# without a scatter and without the slots: sorted by token (B keys), each
+# block of 128 tokens owns a contiguous run of rows, and the run's sums are
+# a (128, run) x (run, D) product with a one-hot — XLA's grouped matmul
+# with the runs as its groups, the kernel that computes an expert stack's
+# weight gradient.
+
+TOKEN_LANES = 128  # tokens a group of the summing matmul: one-hot's width
+
+_SUM_BY_GROUP = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[],
+)
+
+
+def _by_token(tokens: jax.Array, live: jax.Array, n_tokens: int):
+    """How to sum B rows by token: (perm, lane, block_sizes) — the stable
+    order that sorts the rows by ``tokens`` (B,) with the rows that are
+    not ``live`` last, each sorted row's lane in its block of
+    ``TOKEN_LANES`` tokens, and the rows a block (the live ones only)."""
+    n_blocks = -(-n_tokens // TOKEN_LANES)
+    key = jnp.where(live, tokens, n_blocks * TOKEN_LANES)
+    perm = jnp.argsort(key)
+    key = key[perm]
+    # Sorted keys: a block's rows lie between two binary searches (a
+    # ``bincount`` is a scatter-add of B ones, 1.1 ms at 131,072 on a v5e).
+    edges = jnp.searchsorted(key, jnp.arange(n_blocks + 1) * TOKEN_LANES)
+    return perm, key % TOKEN_LANES, jnp.diff(edges).astype(jnp.int32)
+
+
+def _sum_by_token(rows: jax.Array, by_token, weights: Optional[jax.Array],
+                  n_tokens: int) -> jax.Array:
+    """``out[n] = sum of weights[i] * rows[i]`` over the live rows ``i`` of
+    token ``n`` — (n_tokens, D) out of (B, D), accumulated in float32 and
+    rounded once.  ``rows`` is zero (``where``-masked by the caller) in the
+    rows that are not live: the kernel's last tile may read them."""
+    perm, lane, sizes = by_token
+    dt = rows.dtype
+    hot = lane[:, None] == jnp.arange(TOKEN_LANES)
+    one = jnp.ones((), dt) if weights is None else weights[perm].astype(dt)[:, None]
+    sums = jax.lax.ragged_dot_general(
+        jnp.where(hot, one, 0), _rows_at(rows, perm), sizes, _SUM_BY_GROUP,
+        precision=jax.lax.Precision.HIGHEST if dt == jnp.float32 else None,
+        preferred_element_type=jnp.float32,
+    )  # (blocks, TOKEN_LANES, D)
+    return sums.reshape(-1, rows.shape[1])[:n_tokens].astype(dt)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _head_copies(x: jax.Array, tokens: jax.Array, live: jax.Array, by_token,
+                 n_tokens: int):
+    """``x[tokens]`` in the live rows and zero in the others: the first B
+    sorted copies of the ``n_tokens`` rows of ``x``.  The cotangent sums
+    the B rows by token (the others ``where``-masked first: a grouped
+    matmul's transpose leaves them unwritten)."""
+    return _head_copies_fwd(x, tokens, live, by_token, n_tokens)[0]
+
+
+def _head_copies_fwd(x, tokens, live, by_token, n_tokens):
+    out = jnp.where(live[:, None], jnp.take(x, tokens, axis=0), 0)
+    return out, (live, by_token)
+
+
+def _head_copies_bwd(n_tokens, res, g):
+    live, by_token = res
+    g = jnp.where(live[:, None], g, 0)
+    return _sum_by_token(g, by_token, None, n_tokens), None, None, None
+
+
+_head_copies.defvjp(_head_copies_fwd, _head_copies_bwd)
+
+
+@jax.custom_vjp
+def _head_combine(rows, top_w, head, live, by_token):
+    """``out[n] = sum_j top_w[n, j] * rows[inv[n·k + j]]`` over the held
+    slots, from the first B sorted rows alone (``head = order[:B]``, the
+    held rows the ``live`` ones): the rows weighted and summed by token.
+    Neither ``inv`` nor the (N, k, D) slots exist.  Cotangents: the rows'
+    is gathered from the (N, D) cotangent as :func:`_combine_copies`' is;
+    ``top_w``'s is each live row's product with its token's cotangent, set
+    at the row's copy (B scalars; the other slots' is zero)."""
+    return _head_combine_fwd(rows, top_w, head, live, by_token)[0]
+
+
+def _head_combine_fwd(rows, top_w, head, live, by_token):
+    N, k = top_w.shape
+    rows = jnp.where(live[:, None], rows, 0)
+    w_sorted = _rows_at(top_w.reshape(N * k, 1), head)[:, 0]
+    out = _sum_by_token(rows, by_token, w_sorted, N)
+    return out, (rows, top_w, w_sorted, head, live)
+
+
+def _head_combine_bwd(res, d_out):
+    rows, top_w, w_sorted, head, live = res
+    N, k = top_w.shape
+    at = _rows_at(d_out, head // k)  # each row's token's cotangent
+    d_rows = jnp.where(live[:, None], w_sorted.astype(at.dtype)[:, None] * at, 0)
+    d_w_sorted = jnp.sum(rows.astype(jnp.float32) * at.astype(jnp.float32), axis=1)
+    d_w = jnp.zeros((N * k,), top_w.dtype).at[head].set(
+        jnp.where(live, d_w_sorted, 0).astype(top_w.dtype), unique_indices=True)
+    return d_rows, d_w.reshape(N, k), None, None, None
+
+
+_head_combine.defvjp(_head_combine_fwd, _head_combine_bwd)
+
+
+def _head_rows(x, experts, top_w, order, group_sizes, n_rows: int) -> jax.Array:
+    """:func:`_sorted_rows`' result from the first ``n_rows`` sorted rows —
+    the held rows in expert order, and slack — where ``sum(group_sizes)``
+    does not pass ``n_rows``: the copies, the masks, the three grouped
+    matmuls (the same groups, the same tiles visited), ``silu * up`` and
+    both row moves with their cotangents are (n_rows, ·)."""
+    N, k = top_w.shape
+    with scope("ddl.moe_route"):
+        head = order[:n_rows]
+        tokens = head // k
+        live = jnp.arange(n_rows) < jnp.sum(group_sizes)
+        by_token = _by_token(tokens, live, N)
+    with scope("ddl.moe_experts"):
+        xs = _head_copies(x, tokens, live, by_token, N)
+        rows = _swiglu_rows(xs, experts, group_sizes)
+    with scope("ddl.moe_combine"):
+        return _head_combine(rows, top_w, head, live, by_token)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_rows(n_rows: int, x, experts, top_w, order, group_sizes, is_held):
+    """The expert pass and the combine over the first ``n_rows`` sorted
+    rows where the held rows fit in them (:func:`_head_rows`), and over all
+    N·k where they do not (:func:`_sorted_rows`, the full-width code): one
+    ``lax.cond`` a pass, so dropless whatever the router does, and only
+    the taken branch runs on the device.
+
+    A ``custom_vjp`` because of what autodiff does to a ``cond``: every
+    residual of BOTH branches becomes an output of the forward ``cond``,
+    zero-filled by the branch not taken — N·k-row memsets and buffers
+    that cost what the bound saves.  So no residual crosses a ``cond``
+    here: the rule's residuals are its inputs, and the backward rule
+    branches again and differentiates the taken branch inside its branch.
+    That runs the copies and two grouped matmuls of the taken branch a
+    second time — what the layers' remat policies do anyway; under
+    ``selective`` the forward rule's ``cond`` is dead code in the
+    recomputation: nothing of the layer reads the result, and what follows
+    the layer (AFMoE's post-MLP norm) finds it saved (the forward rule tags
+    it as the attention kernels tag theirs)."""
+    return _held_rows_fwd(n_rows, x, experts, top_w, order, group_sizes,
+                          is_held)[0]
+
+
+@functools.lru_cache(maxsize=None)
+def _held_rows_passes(n_rows: int):
+    """(forward branches, backward branches) of :func:`_held_rows` at a
+    bound: each pair (the pass over ``n_rows`` rows, the pass over all) as
+    functions of the ``cond``'s operands alone.  The SAME function objects
+    at every call: ``lax.cond`` keeps a branch's traced form by the
+    function, so the routed layers of a model trace each branch once and
+    not once a layer and pass — the branches double a routed layer's ops,
+    and a train step's set-up is mostly tracing them."""
+
+    def bounded(x, experts, top_w, order, group_sizes, is_held):
+        return _head_rows(x, experts, top_w, order, group_sizes, n_rows)
+
+    def full(x, experts, top_w, order, group_sizes, is_held):
+        return _sorted_rows(
+            x, experts, top_w, order, group_sizes, is_held, overflow=True)
+
+    def whole(branch, overflow):
+        # XLA moves what both branches end in out of a conditional, and
+        # both end in a sum over a token's rows: the full-width branch's
+        # (N, k, D) slots would be written out of it and read again behind
+        # it.  The barrier keeps each branch's last ops in the branch.
+        def run(*operands):
+            out = branch(*operands)
+            with _phase("ddl.moe_combine", overflow):
+                return jax.lax.optimization_barrier(out)
+
+        return run
+
+    def pull(branch):
+        def run(x, experts, top_w, order, group_sizes, is_held, d_out):
+            return jax.vjp(
+                lambda *primals: branch(*primals, order, group_sizes, is_held),
+                x, experts, top_w)[1](d_out)
+
+        return run
+
+    return (whole(bounded, False), whole(full, True)), (pull(bounded), pull(full))
+
+
+def _held_rows_fit(n_rows, operands):
+    return jnp.sum(operands[4]) <= n_rows  # group_sizes: the held rows, counted
+
+
+def _held_rows_fwd(n_rows, *operands):
+    from ddl_tpu.models import remat as _remat
+
+    bounded, full = _held_rows_passes(n_rows)[0]
+    out = jax.lax.cond(_held_rows_fit(n_rows, operands), bounded, full, *operands)
+    # ``selective`` keeps the result where something behind the layer reads
+    # it in the backward pass (AFMoE's post-MLP norm: 2·N·D bytes a layer),
+    # as it keeps a kernel's: the recomputation then holds no ``cond`` at
+    # all - a third of the routed layer's program, and its forward's time.
+    return _remat.tag_attn_out(out), operands
+
+
+def _held_rows_bwd(n_rows, operands, d_out):
+    bounded, full = _held_rows_passes(n_rows)[1]
+    d_x, d_experts, d_top_w = jax.lax.cond(
+        _held_rows_fit(n_rows, operands), bounded, full, *operands, d_out)
+    return d_x, d_experts, d_top_w, None, None, None
+
+
+_held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
+
+
 def ragged_experts(
     x: jax.Array,
     experts: Params,
     top_w: jax.Array,
     top_e: jax.Array,
-    held: Optional[Tuple[int, int]] = None,
+    held: Optional[Tuple[int, ...]] = None,
 ) -> jax.Array:
     """The dropless routed-expert core: ``sum_k top_w[n, k] *
     expert_{top_e[n, k]}(x[n])`` over flat tokens ``x`` (N, D), for the
@@ -518,25 +805,44 @@ def ragged_experts(
     D, F) and ``w_down`` (G, F, D); ``top_w``/``top_e`` (N, k) are the
     router's weights and expert ids, whatever scoring produced them.
     ``held=None``: the stack is every expert the router can name.
-    ``held=(first, count)``: the stack is experts ``first .. first +
-    count - 1`` of a wider router (G = count) — the part of the layer's
-    result that those experts give is returned, the other choices add
-    nothing (no capacity, no dropped held row, no stand-in for the chips
-    that hold the rest).
+    ``held=(first, count)`` or ``(first, count, n_experts)``: the stack is
+    experts ``first .. first + count - 1`` of a wider router (G = count),
+    ``n_experts`` wide where stated — the part of the layer's result that
+    those experts give is returned, the other choices add nothing (no
+    capacity, no dropped held row, no stand-in for the chips that hold the
+    rest).
 
     Each choice is a row; rows are stably sorted by expert so that each
     expert's rows are one contiguous group and the three matmuls run as
-    ``jax.lax.ragged_dot``.  Shapes are static: N·k rows whatever the
-    router chose.  With a held range the unheld choices sort behind the
-    last group and belong to none.  What the chip then does with them
-    (``tools/probe_ragged_rows.py``, my chip run, PR 30, TPU v5 lite;
-    PERF.md section 6): the gather and the elementwise passes run over
-    all N·k rows; XLA's grouped-matmul kernels visit the grouped rows'
-    tiles only (0.57 ms for an eighth of 131,072 rows against 4.10 ms for
-    all) and leave the other rows of their result UNWRITTEN — stale
-    values, NaN after a NaN fill, in the transposes too — so both ends of
-    the expert pass are ``where``-masked (never multiplied by zero), in
-    the forward and, by transposition, in the backward pass.
+    ``jax.lax.ragged_dot``.  With a held range the unheld choices sort
+    behind the last group and belong to none.  Shapes are static, and how
+    many rows the passes run over is a rule of the static facts
+    (:func:`held_row_bound`):
+
+    - ``held=None``, a range of half the router's experts or more, or a
+      range whose router's width is not stated: all N·k rows, whatever the
+      router chose.  XLA's grouped-matmul kernels visit the grouped rows'
+      tiles only (0.57 ms for an eighth of 131,072 rows against 4.10 ms
+      for all; ``tools/probe_ragged_rows.py``, my chip run, PR 30, TPU v5
+      lite; PERF.md section 6); the gather, the masks and the elementwise
+      passes around them run over every row.
+    - a narrower range: the first B sorted rows — the held rows in expert
+      order and slack — with B twice the balanced share in whole row
+      tiles (:func:`_head_rows`).  The copies, the masks, the three
+      grouped matmuls (same groups, same tiles visited), ``silu * up`` and
+      the rows' cotangent are (B, ·); the combine and the copies'
+      cotangent sum those B rows by token in a fourth grouped matmul
+      (:func:`_sum_by_token`), so no pass writes N·k rows or slots.
+      Where the router sends the range more than B choices the layer
+      runs at full width instead (:func:`_held_rows`: one ``lax.cond`` a
+      pass, nothing dropped, the fallback's ops under
+      ``ddl.moe_overflow``).
+
+    Either way the kernels leave the rows past the last group of their
+    result UNWRITTEN — stale values, NaN after a NaN fill, in the
+    transposes too — so both ends of the expert pass are ``where``-masked
+    (never multiplied by zero), in the forward and, by transposition, in
+    the backward pass.
 
     The two row moves — the copies into expert order
     (:func:`_take_copies`) and the un-permute with the weighted sum
@@ -550,15 +856,17 @@ def ragged_experts(
     trace as each op's ``tf_op`` name."""
     N, D = x.shape
     k = top_e.shape[1]
-    dt = x.dtype
     n_groups = experts["w_gate"].shape[0]
     is_held = None
+    n_rows = N * k
 
     with scope("ddl.moe_route"):
         flat_e = top_e.reshape(-1)  # (N*k,) expert of copy i (token i//k)
         if held is not None:
-            first, count = held
+            first, count, *width = held
             assert count == n_groups, (held, n_groups)
+            if width:
+                n_rows = held_row_bound(N * k, count, *width)
             is_held = (flat_e >= first) & (flat_e < first + count)
             # Unheld choices get the id past the last group: they sort
             # behind every group and are counted in none.
@@ -568,25 +876,10 @@ def ragged_experts(
             flat_e, length=n_groups + (held is not None)
         ).astype(jnp.int32)[:n_groups]
 
-    with scope("ddl.moe_combine"):  # the un-permute's index, read by both rules
-        inv = jnp.argsort(order)  # flat copy index -> its sorted row
-
-    with scope("ddl.moe_experts"):
-        xs = _take_copies(x, order, inv, k)  # (N*k, D) grouped by expert
-        if held is not None:
-            in_a_group = (jnp.arange(N * k) < jnp.sum(group_sizes))[:, None]
-            xs = jnp.where(in_a_group, xs, 0)
-        gate = jax.nn.silu(
-            jax.lax.ragged_dot(xs, experts["w_gate"].astype(dt), group_sizes)
-        )
-        up = jax.lax.ragged_dot(xs, experts["w_up"].astype(dt), group_sizes)
-        rows = jax.lax.ragged_dot(
-            gate * up, experts["w_down"].astype(dt), group_sizes
-        )  # (N*k, D), still expert-sorted
-
-    with scope("ddl.moe_combine"):
-        out = _combine_copies(rows, top_w, order, inv, is_held)
-    return out
+    rest = (x, experts, top_w, order, group_sizes, is_held)
+    if n_rows == N * k:
+        return _sorted_rows(*rest)
+    return _held_rows(n_rows, *rest)
 
 
 # -- sigmoid-routed experts with a shared expert (AFMoE, DeepSeek-V3) ----------
@@ -619,7 +912,8 @@ def sigmoid_expert_tokens(h: jax.Array, layer: Params, cfg: Any):
     (out (N, D), the router's picks (N, k))."""
     with scope("ddl.moe_route"):
         top_w, top_e = sigmoid_route(h, layer, cfg)
-    held = None if cfg.held == (0, cfg.n_experts) else cfg.held
+    # A share states its router's width: a narrow one bounds its row passes.
+    held = None if cfg.held == (0, cfg.n_experts) else (*cfg.held, cfg.n_experts)
     if held is not None:
         # A share cannot train its router: the absent experts add exactly
         # nothing here, so this chip's part of the router's gradient says

@@ -48,6 +48,13 @@ accepted for back compat — ``True`` is ``"full"``, ``False`` is
   MB at 2 groups) and the merged block lists and their transposes (256 KB)
   - so the backward pass selects nothing again and runs the three
   backward kernels alone.
+  A share's routed layer (``models/moe.py:_held_rows``, PR 40: a range
+  narrower than half the router, two ``cond`` branches a pass) tags its
+  result the same way, ``2·N·D`` bytes a layer (64 MiB at 16,384 tokens
+  of 2048): where something behind the layer reads it in the backward
+  pass (AFMoE's post-MLP norm) the recomputation holds no ``cond`` and
+  no routed forward; where nothing does (DeepSeek-V3's stack) nothing is
+  kept.
 - ``"dots"`` — ``jax.checkpoint_policies.dots_with_no_batch_dims_
   saveable``: save every non-batched matmul output (all weight
   projections), recompute only elementwise ops and attention — the
@@ -57,8 +64,8 @@ accepted for back compat — ``True`` is ``"full"``, ``False`` is
 One wrap site per model family (:func:`wrap` around the layer body),
 one tag function (:func:`tag_attn_out`), called by the one attention
 dispatcher (``parallel.ring_attention.attention``), by the flash
-kernels' rules (the block-sparse ones and their selection among them) and
-by the two scans, never by a model — so a value is tagged once (a second
+kernels' rules (the block-sparse ones and their selection among them), by
+the two scans and by the routed core's ``_held_rows`` rule, never by a model — so a value is tagged once (a second
 tag on the same output would save it twice) and the policy semantics
 cannot drift between llama, moe, afmoe, deepseek_v3, olmo_hybrid,
 minicpm_sala and the pipelined forwards.

@@ -77,7 +77,7 @@ SCOPE_NAMES = (
     "ddl.sparse_select",
     "ddl.mlp",
     "ddl.moe", "ddl.moe_route", "ddl.moe_experts", "ddl.moe_combine",
-    "ddl.moe_shared",
+    "ddl.moe_overflow", "ddl.moe_shared",
     "ddl.head", "ddl.optimizer",
 )
 

@@ -210,19 +210,29 @@ def test_a_float32_configuration_run_in_bf16_fails_the_float32_tolerance(tokens)
 # -- the share ---------------------------------------------------------------------
 
 
-def test_the_shares_add_up_to_the_uncut_layer(tokens):
+@pytest.mark.parametrize("n_tokens,favoured", [(B * T, ()), (512, (0, 1, 9))],
+                         ids=["no_bound_at_this_size", "shares_past_their_bound"])
+def test_the_shares_add_up_to_the_uncut_layer(n_tokens, favoured):
     """One expert layer's MLP on the same hidden states: the routed parts
     that all 8 shares of 2 experts give, plus the shared expert - which
     every chip computes alike - counted once, are what the uncut reference
     gives for the whole layer."""
     whole = tiny()
     layer = seeded(whole)["layers"][2]
-    h = jax.random.normal(jax.random.key(5), (B * T, whole.d_model), jnp.float32)
+    # Every token picks the favoured experts: their shares get more rows
+    # than the static bound of held rows (``moe.held_row_bound``: twice the
+    # balanced share, 512 rows here) and run at full width, the others
+    # under the bound - and the parts still add up, nothing dropped.
+    bias = np.zeros(whole.n_experts, np.float32)
+    bias[list(favoured)] = 10.0
+    layer = {**layer, "expert_bias": layer["expert_bias"] + bias}
+    bound = moe.held_row_bound(n_tokens * whole.topk, 2, whole.n_experts)
+    h = jax.random.normal(jax.random.key(5), (n_tokens, whole.d_model), jnp.float32)
     want, want_picks = ref.expert_mlp(h, layer, ref_config(whole))
     shared = llama._swiglu(layer["shared"], h)
 
     routed = jnp.zeros_like(h)
-    held_choices = 0
+    held_choices, past_the_bound = 0, []
     for first in range(0, whole.n_experts, 2):
         cfg = tiny(held_experts=(first, 2))
         mine = {**layer, "experts": jax.tree.map(
@@ -234,8 +244,15 @@ def test_the_shares_add_up_to_the_uncut_layer(tokens):
         share_want, _ = ref.expert_mlp(h, mine, ref_config(cfg))
         close(out, share_want, F32_TOL, f"share {first}")
         routed = routed + (out - shared)
-        held_choices += int(np.sum((picks >= first) & (picks < first + 2)))
-    assert held_choices == B * T * whole.topk  # every choice is held once
+        mine_held = int(np.sum((picks >= first) & (picks < first + 2)))
+        past_the_bound.append(mine_held > bound)
+        held_choices += mine_held
+    assert held_choices == n_tokens * whole.topk  # every choice is held once
+    if favoured:  # both branches ran: share 0 (two favoured experts) at full width
+        assert bound == 512 < n_tokens * whole.topk
+        assert past_the_bound[0] and not all(past_the_bound), past_the_bound
+    else:
+        assert bound == n_tokens * whole.topk  # no bound below a row tile
     close(shared + routed, want, F32_TOL, "sum of the shares")
     # The uncut system layer is the same thing in one piece.
     close(afmoe._moe_tokens(h, layer, whole)[0], want, F32_TOL, "uncut")
@@ -442,11 +459,17 @@ def _window_program(model, how):
     if model == "mistral":
         mod, cfg = llama, llama.LlamaConfig(
             n_kv_heads=1, d_ff=512, rope_theta=1e6, **common)
-    else:
+    elif model == "olmoe":
         mod, cfg = moe, moe.MoeConfig(
             n_kv_heads=2, d_ff=128, n_experts=8, topk=2, rope_theta=1e4,
             qk_norm=True, norm_topk_prob=False, router_aux_all_slots=True,
             router_z_weight=0.001, **common)
+    else:  # "trinity": a share of 4 of 16 experts, top-4: 16,384 rows, bound 8,192
+        del common["n_layers"]
+        mod, cfg = afmoe, afmoe.AfmoeConfig(
+            n_kv_heads=1, head_dim=128, d_ff=512, d_expert=128, n_experts=16,
+            topk=4, layer_types=(S, S, F), n_dense_layers=1, sliding_window=512,
+            route_scale=2.826, held_experts=(0, 4), **common)
     optimizer = optax.adamw(3e-4)
     params = jax.eval_shape(lambda: mod.init_params(cfg, jax.random.key(0)))
     args = (params, jax.eval_shape(optimizer.init, params),
@@ -490,32 +513,82 @@ def test_the_window_programs_of_the_other_decoders_are_the_parents(model, how,
         )
 
 
-def test_the_routed_layers_backward_pass_scatters_no_rows(monkeypatch):
-    """The same OLMoE-shaped window program, lowered for the TPU: the two
-    row moves of ``moe.ragged_experts`` transpose into gathers
-    (``moe._take_copies``, ``moe._combine_copies``), so no scatter is left
-    whose updates are (N·k, D) rows — the parent had two a routed layer and
-    backward pass (the layers are scanned: two in the text).  What stays,
-    by its updates: ``bincount``'s N·k ones into the group sizes (forward,
-    its recomputation, and the auxiliary loss's counts), the two
-    ``take_along_axis`` transposes of the router's top-k, the
+@pytest.mark.parametrize("model", ["olmoe", "trinity"])
+def test_the_routed_layers_backward_pass_scatters_no_rows(model, monkeypatch):
+    """The OLMoE-shaped window program and a share's (4 of 16 experts: the
+    bounded pass and its full-width fallback, both in the text), lowered
+    for the TPU: the row moves of ``moe.ragged_experts`` transpose into
+    gathers (``moe._take_copies``, ``moe._combine_copies``) or sum their
+    rows in a grouped matmul (``moe._head_copies``, ``moe._head_combine``),
+    so no scatter is left whose updates are (N·k, D) or (B, D) rows — PR
+    35's parent had two a routed layer and backward pass (the layers are
+    scanned: two in the text).  What stays, by its updates: ``bincount``'s
+    N·k ones into the group sizes (forward, its recomputation, and OLMoE's
+    auxiliary loss's counts; the bounded pass finds its token blocks' sizes
+    by binary search in its sorted keys, ``moe._by_token``), the
+    two ``take_along_axis`` transposes of a trained router's top-k, the
     cross-entropy's, and the embedding's (B, T, D) rows."""
     with monkeypatch.context() as on_tpu:
         on_tpu.setattr(jax, "default_backend", lambda: "tpu")
-        text = _window_program("olmoe", "tpu")().lower(
+        text = _window_program(model, "tpu")().lower(
             lowering_platforms=("tpu",)).as_text()
     updates = sorted(re.findall(
         r'"stablehlo.scatter"\(.*?\n\s*\}\) : \(tensor<\S+>, tensor<\S+>, '
         r'tensor<(\S+)>\)', text, re.S))
     rows = [u for u in updates if re.fullmatch(r"\d+x256xbf16", u)]
     assert rows == [], rows  # the parent: ["8192x256xbf16", "8192x256xbf16"]
-    assert updates == sorted(
-        4 * ["8192xi32"]             # bincount: one count a (token, slot) copy
-        + 2 * ["4096x2xf32"]         # the router's top-k
+    assert updates == sorted({
+        "olmoe": 4 * ["8192xi32"]    # bincount: one count a (token, slot) copy
+        + 2 * ["4096x2xf32"],        # the router's top-k
+        "trinity": 2 * 2 * ["16384xi32"],  # bincount, forward and recomputed
+    }[model]
         + ["2x2048x1xf32"]           # cross-entropy's take_along_axis
         + ["2x2048x256xbf16"]        # the embedding's rows
     ), updates
     assert "unique_indices = true" not in text  # gathers, not hinted scatters
+
+
+def test_a_shares_conds_pass_no_rows_and_name_their_fallback(monkeypatch):
+    """The share's window program again, by its name stacks: two expert
+    layers, two ``cond``s each - forward and backward: ``selective`` saves
+    the routed result (AFMoE's post-MLP norm reads it in the backward
+    pass), so the recomputation holds no ``cond``.  No ``cond``
+    returns an array of N·k or of B rows: a residual that crossed one would
+    be zero-filled by the branch not taken, the memsets that eat the gain
+    (``moe._held_rows``).  Every op of the full-width fallback stands under
+    ``ddl.moe_overflow`` and no op of the bounded pass does, so the device
+    trace says which ran.  And every private ``_take`` function's callers
+    stand under ONE name stack (two layers share a function now; a function
+    shared across name stacks is what loses a gather its scope, PR 35)."""
+    with monkeypatch.context() as on_tpu:
+        on_tpu.setattr(jax, "default_backend", lambda: "tpu")
+        text = _window_program("trinity", "tpu")().lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+    results = re.findall(r'"stablehlo.case"\(.*?\n\s*\}\) : \(tensor<i32>\) -> (.*?) loc',
+                         text, re.S)
+    assert len(results) == 2 * 2, len(results)
+    for types in results:
+        assert not re.search(r"tensor<(16384|8192)x", types), types
+    assert sum("4096x256xbf16" in t for t in results) == 4  # out, or d_x
+
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    paths = set(locs.values())
+    fallback = {p for p in paths if "/branch_0_fun" in p}
+    bounded = {p for p in paths if "/branch_1_fun" in p}
+    assert len(fallback) > 20 and len(bounded) > 20
+    assert all("ddl.moe_overflow" in p for p in fallback), [
+        p for p in fallback if "ddl.moe_overflow" not in p][:5]
+    assert not any("ddl.moe_overflow" in p for p in paths - fallback)
+    for p in fallback | bounded:  # and each under a phase of the routed core
+        assert re.search(r"ddl\.moe_(route|experts|combine)", p), p
+
+    callers = collections.defaultdict(set)
+    for name, loc in re.findall(r"call @(_take\w*)\(.*?loc\((#loc\d+)\)", text):
+        callers[name].add(locs[loc])
+    # Full width: the copies and the un-permute forward, the copies again
+    # in the backward pass; bounded: the copies, a pass.
+    assert len(callers) == 3 + 2, sorted(callers)
+    assert all(len(stacks) == 1 for stacks in callers.values()), callers
 
 
 def test_every_expert_layers_row_gathers_keep_a_function_of_their_own():

@@ -334,19 +334,29 @@ def test_what_has_no_latent_form_refuses_it_by_name():
 # -- the share ---------------------------------------------------------------------
 
 
-def test_the_shares_add_up_to_the_uncut_layer(tokens):
+@pytest.mark.parametrize("n_tokens,favoured", [(B * T, ()), (512, (0, 1, 9))],
+                         ids=["no_bound_at_this_size", "shares_past_their_bound"])
+def test_the_shares_add_up_to_the_uncut_layer(n_tokens, favoured):
     """One expert layer's MLP on the same hidden states: the routed parts
     that all 8 shares of 2 experts give, plus the shared experts - which
     every chip computes alike - counted once, are what the uncut reference
     gives for the whole layer."""
     whole = tiny()
     layer = seeded(whole)["layers"][2]
-    h = jax.random.normal(jax.random.key(5), (B * T, whole.d_model), jnp.float32)
+    # Every token picks the favoured experts: their shares get more rows
+    # than the static bound of held rows (``moe.held_row_bound``: twice the
+    # balanced share, 512 rows here) and run at full width, the others
+    # under the bound - and the parts still add up, nothing dropped.
+    bias = np.zeros(whole.n_experts, np.float32)
+    bias[list(favoured)] = 10.0
+    layer = {**layer, "expert_bias": layer["expert_bias"] + bias}
+    bound = moe.held_row_bound(n_tokens * whole.topk, 2, whole.n_experts)
+    h = jax.random.normal(jax.random.key(5), (n_tokens, whole.d_model), jnp.float32)
     want, want_picks = ref.expert_mlp(h, layer, ref_config(whole))
     shared = llama._swiglu(layer["shared"], h)
 
     routed = jnp.zeros_like(h)
-    held_choices = 0
+    held_choices, past_the_bound = 0, []
     for first in range(0, whole.n_experts, 2):
         cfg = tiny(held_experts=(first, 2))
         mine = {**layer, "experts": jax.tree.map(
@@ -358,8 +368,15 @@ def test_the_shares_add_up_to_the_uncut_layer(tokens):
         share_want, _ = ref.expert_mlp(h, mine, ref_config(cfg))
         close(out, share_want, F32_TOL, f"share {first}")
         routed = routed + (out - shared)
-        held_choices += int(np.sum((picks >= first) & (picks < first + 2)))
-    assert held_choices == B * T * whole.topk  # every choice is held once
+        mine_held = int(np.sum((picks >= first) & (picks < first + 2)))
+        past_the_bound.append(mine_held > bound)
+        held_choices += mine_held
+    assert held_choices == n_tokens * whole.topk  # every choice is held once
+    if favoured:  # both branches ran: share 0 (two favoured experts) at full width
+        assert bound == 512 < n_tokens * whole.topk
+        assert past_the_bound[0] and not all(past_the_bound), past_the_bound
+    else:
+        assert bound == n_tokens * whole.topk  # no bound below a row tile
     close(shared + routed, want, F32_TOL, "sum of the shares")
     # The uncut system layer is the same thing in one piece.
     close(moe.sigmoid_expert_tokens(h, layer, whole)[0], want, F32_TOL, "uncut")
@@ -520,10 +537,17 @@ def test_the_benchmarks_reference_is_this_one():
 #: PR 33's tree (the child of 116395f), which changed them on purpose —
 #: selective remat saves the blockwise cores' residuals, so the backward
 #: holds no second forward kernel — and re-recorded what 6b7d062 had
-#: pinned.  The ``olmoe`` and ``trinity`` programs are PR 35's tree (the
+#: pinned.  The ``olmoe`` program is PR 35's tree (the
 #: child of 8130a3b), changed on purpose: the routed layer's backward pass
 #: gathers where it scatter-added (``moe._take_copies``,
-#: ``moe._combine_copies``); the ``mistral`` programs are PR 33's still:
+#: ``moe._combine_copies``); the ``mistral`` programs are PR 33's still.
+#: ``trinity`` is PR 40's tree (the child of 2b9d22c), changed on purpose: a
+#: share of 4 of 16 experts runs its row passes over a static bound of held
+#: rows, one ``cond`` a pass with the full-width code as the other branch
+#: (``moe._held_rows``); ``trinity_half`` is the same stack holding 8 of the
+#: 16 - half the router, so no bound - recorded on PR 40's PARENT: with
+#: ``olmoe`` (``held=None``) it holds PR 40 to "no bound, the parent's
+#: program":
 #: ``tpu``: lowered for the TPU with each Mosaic kernel's serialised body
 #: taken out (it carries the file and line of every operation);
 #: ``interpreted``: with the kernels' bodies as the interpreter's HLO, line
@@ -541,14 +565,18 @@ PARENT_WINDOW_PROGRAM_SHA256 = {
     ("olmoe", "interpreted"):
         "462021f792246948f54ce88aeeb3846c3bb9cca50838066026afdb17673a4513",
     ("trinity", "tpu"):
-        "54fc32424cee3d9179ba3cdb683265c6fadf96ff025c9a1da5ff417e8a6f3ebd",
+        "3e76b0d2b2961ea40f1c6cac8aabee21e876ab918e883a27aaa19016ef046d32",
     ("trinity", "interpreted"):
-        "34af4acfe644f9002054c0ea57b6c6b889e0ed3aa611f732c332ed8c768f77ca",
+        "1ad884d400cda7420296dc7863206fb71d7a313e201246cc3df2f0acdbe10b7a",
+    ("trinity_half", "tpu"):
+        "39e8ab4d55b2a3a785c85873ed390b5000f80da38d3ef0c5f8e7904d9754b2e2",
+    ("trinity_half", "interpreted"):
+        "ef9c138582c7afd792bb72d68c5a75986c2884b5d1e93322dd6340f6eb1590b4",
 }
 
 
 @pytest.mark.parametrize("how", ["tpu", "interpreted"])
-@pytest.mark.parametrize("model", ["mistral", "olmoe", "trinity"])
+@pytest.mark.parametrize("model", ["mistral", "olmoe", "trinity", "trinity_half"])
 def test_the_three_decoder_cells_window_programs_are_the_parents(model, how,
                                                                 monkeypatch):
     import optax
@@ -574,7 +602,7 @@ def test_the_three_decoder_cells_window_programs_are_the_parents(model, how,
             n_kv_heads=1, head_dim=128, d_ff=512, d_expert=128, n_experts=16,
             topk=4, layer_types=(afmoe.SLIDING, afmoe.SLIDING, afmoe.FULL),
             n_dense_layers=1, sliding_window=512, route_scale=2.826,
-            held_experts=(0, 4), **common)
+            held_experts=(0, 8 if model == "trinity_half" else 4), **common)
     optimizer = optax.adamw(3e-4)
 
     def traced():  # anew each time: a jitted function keeps its first trace
@@ -596,7 +624,7 @@ def test_the_three_decoder_cells_window_programs_are_the_parents(model, how,
     with monkeypatch.context() as on_tpu:
         on_tpu.setattr(jax, "default_backend", lambda: "tpu")
         text = traced().lower(lowering_platforms=("tpu",)).as_text()
-    kinds = ["swa_", "swa_", ""] if model == "trinity" else ["", ""]
+    kinds = ["swa_", "swa_", ""] if model.startswith("trinity") else ["", ""]
     calls = re.findall(r'kernel_name = "(ddl_flash_\w+)"', text)
     assert sorted(calls) == sorted(
         f"ddl_flash_{kind}{kernel}" for kind in kinds

@@ -376,6 +376,184 @@ class TestRowMoveRules:
                     (jnp.ones((4, 2)),), (jnp.ones((4, 2)),))
 
 
+class TestHeldRowBound:
+    """A share of a wider router runs its row passes over a static bound of
+    held rows (``moe.held_row_bound``, ``moe._held_rows``) and at full width
+    past it.  Held to the full-width code (``held=(first, count)``: the
+    router's width not stated, so no bound) and to a plain sum over the
+    experts; dropless at every routing."""
+
+    N, k, D, F, E = 512, 4, 16, 8, 16  # N·k = 2048 rows; E: the router's width
+    HELD = (4, 2)  # an eighth of the experts: the bound is 512 rows
+
+    @pytest.mark.parametrize("n_rows,count,n_experts,want", [
+        (131072, 16, 128, 32768),    # Trinity-Mini's share: twice an eighth
+        (98304, 16, 128, 24576),     # Kanana-2's
+        (2048, 2, 16, 512),          # this class
+        (2048, 1, 16, 512),          # 256 rows, in whole tiles of 512
+        (2048, 3, 16, 1024),         # 768 rows -> 1024
+        (131080, 16, 128, 33280),    # 32770 rows -> the next tile
+        (2048, 8, 16, 2048),         # half the experts: every row
+        (2048, 12, 16, 2048),        # more than half
+        (2048, 16, 16, 2048),        # all of them
+        (256, 1, 128, 256),          # fewer rows than a tile
+        (16384, 16, 128, 4096),      # a dp shard's rows: the rule is per call
+    ])
+    def test_the_bound_is_twice_the_balanced_share_in_whole_tiles(
+            self, n_rows, count, n_experts, want):
+        assert moe.held_row_bound(n_rows, count, n_experts) == want
+        assert want % moe.ROW_TILE == 0 or want == n_rows
+
+    def _case(self, router, dtype, held=HELD):
+        """(x, the held experts' stacks, top_w, top_e) of one case."""
+        rng = np.random.default_rng(11)
+        N, k, D, F, E = self.N, self.k, self.D, self.F, self.E
+        first, count = held
+        if router == "spread":  # k distinct experts a token, uniformly
+            top_e = np.argsort(rng.random((N, E)), axis=-1)[:, :k]
+        elif router == "every_choice_held":  # n_held = N·k: four times the bound
+            top_e = first + rng.integers(0, count, (N, k))
+        else:  # an int: exactly that many choices on the held range
+            top_e = np.full((N, k), (first + count) % E)  # an unheld expert
+            top_e.reshape(-1)[rng.permutation(N * k)[:router]] = first
+        top_w = rng.uniform(0.1, 1.0, (N, k))
+        experts = {
+            name: jnp.asarray(rng.standard_normal(shape) / 4, dtype)
+            for name, shape in (("w_gate", (count, D, F)), ("w_up", (count, D, F)),
+                                ("w_down", (count, F, D)))
+        }
+        return (jnp.asarray(rng.standard_normal((N, D)), dtype), experts,
+                jnp.asarray(top_w, jnp.float32), jnp.asarray(top_e, jnp.int32))
+
+    @staticmethod
+    def _run(x, experts, top_w, top_e, held):
+        """(out, d_x, the three stacks' gradients, d_top_w) under an uneven
+        cotangent."""
+        def f(x, experts, top_w):
+            out = moe.ragged_experts(x, experts, top_w, top_e, held=held)
+            weigh = jnp.cos(jnp.arange(out.size, dtype=jnp.float32))
+            return jnp.sum(out.astype(jnp.float32) * weigh.reshape(out.shape)), out
+
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            f, argnums=(0, 1, 2), has_aux=True))(x, experts, top_w)
+        return [out] + jax.tree.leaves(grads)
+
+    @staticmethod
+    def _plain(x, experts, top_w, top_e, held):
+        """The layer as a sum over the held experts, every token through
+        every one of them: no sort, no group, no bound."""
+        first, count = held
+        out = jnp.zeros_like(x)
+        for g in range(count):
+            y = (jax.nn.silu(x @ experts["w_gate"][g]) * (x @ experts["w_up"][g])
+                 ) @ experts["w_down"][g]
+            gate = jnp.sum(jnp.where(top_e == first + g, top_w, 0), axis=1)
+            out = out + gate[:, None].astype(x.dtype) * y
+        return out
+
+    @staticmethod
+    def _nan_past_the_groups(monkeypatch):
+        """Every grouped matmul leaves NaN in the rows of its result past
+        the last group, as the chip's kernels may (they are UNWRITTEN)."""
+        real = jax.lax.ragged_dot
+
+        def poisoned(lhs, rhs, group_sizes, **kw):
+            out = real(lhs, rhs, group_sizes, **kw)
+            past = jnp.arange(out.shape[0]) >= jnp.sum(group_sizes)
+            return jnp.where(past[:, None], jnp.nan, out)
+
+        monkeypatch.setattr(jax.lax, "ragged_dot", poisoned)
+
+    @pytest.mark.parametrize("router", ["spread", "every_choice_held"])
+    def test_float32_is_the_full_width_result(self, router, monkeypatch):
+        """Output and every gradient, NaN planted past the groups: the
+        bounded pass (a spread router: an eighth of the choices held, half
+        the bound) is the full-width one but for the order of a token's
+        sum; the fallback (every choice held) IS the full-width code."""
+        case = self._case(router, jnp.float32)
+        n_held = int(np.sum((np.asarray(case[3]) >= 4) & (np.asarray(case[3]) < 6)))
+        bound = moe.held_row_bound(self.N * self.k, self.HELD[1], self.E)
+        assert (n_held > bound) == (router == "every_choice_held"), (n_held, bound)
+        self._nan_past_the_groups(monkeypatch)
+        got = self._run(*case, self.HELD + (self.E,))
+        want = self._run(*case, self.HELD)
+        for g, w in zip(got, want):
+            assert np.all(np.isfinite(np.asarray(g)))
+            if router == "every_choice_held":
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+            else:
+                np.testing.assert_allclose(
+                    np.asarray(g), np.asarray(w), rtol=2e-6,
+                    atol=2e-6 * float(jnp.max(jnp.abs(w))))
+        np.testing.assert_allclose(
+            np.asarray(got[0]), np.asarray(self._plain(*case, self.HELD)),
+            rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("router", ["spread", "every_choice_held"])
+    def test_bf16_is_no_farther_from_float32_than_full_width(self, router,
+                                                             monkeypatch):
+        """bfloat16: the bounded pass sums a token's rows in float32 and
+        rounds once, in the combine and in the copies' cotangent — each
+        result no farther from the float32 one than the full-width code's;
+        the fallback is that code, bit for bit."""
+        case = self._case(router, jnp.bfloat16)
+        x, experts, top_w, top_e = case
+        exact = self._run(
+            x.astype(jnp.float32),
+            jax.tree.map(lambda w: w.astype(jnp.float32), experts),
+            top_w, top_e, self.HELD)
+        self._nan_past_the_groups(monkeypatch)
+        got = self._run(*case, self.HELD + (self.E,))
+        full = self._run(*case, self.HELD)
+
+        def off(a, e):
+            return float(jnp.linalg.norm(a.astype(jnp.float32) - e))
+
+        for g, w, e in zip(got, full, exact):
+            assert g.dtype == w.dtype and np.all(np.isfinite(np.asarray(g, np.float32)))
+            if router == "every_choice_held":
+                np.testing.assert_array_equal(
+                    np.asarray(g, np.float32), np.asarray(w, np.float32))
+            else:
+                assert off(g, e) <= off(w, e) * 1.05 + 1e-6, (off(g, e), off(w, e))
+
+    @pytest.mark.parametrize("n_held", [0, 1, 511, 512, 513, 2048])
+    def test_dropless_at_the_edge_of_the_bound(self, n_held):
+        """One choice under the bound, at it and past it (and none, one and
+        all): the plain sum's result, and the full-width code's gradients."""
+        case = self._case(n_held, jnp.float32)
+        assert moe.held_row_bound(self.N * self.k, self.HELD[1], self.E) == 512
+        got = self._run(*case, self.HELD + (self.E,))
+        want = self._run(*case, self.HELD)
+        np.testing.assert_allclose(
+            np.asarray(got[0]), np.asarray(self._plain(*case, self.HELD)),
+            rtol=1e-5, atol=1e-5)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(
+                np.asarray(g), np.asarray(w), rtol=2e-6,
+                atol=2e-6 * float(jnp.max(jnp.abs(w))) + 1e-30)
+
+    @pytest.mark.parametrize("held", [None, (0, 8, 16), (4, 12, 16), (0, 16, 16)])
+    def test_no_bound_no_new_program(self, held):
+        """``held=None`` and a range of half the router's experts or more:
+        the program of the range without its router's width — the parent's
+        (``tests/test_afmoe.py`` and ``tests/test_deepseek_v3.py`` pin whole
+        window programs) — to the letter, no ``cond`` in it."""
+        count = self.E if held is None else held[1]
+        case = self._case("spread", jnp.float32, held=(0, count))
+
+        def text(h, case=case):
+            def f(x, experts, top_w):
+                return jnp.sum(moe.ragged_experts(x, experts, top_w, case[3], held=h))
+            return jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(*case[:3]).as_text()
+
+        got = text(held)
+        assert got == text(held and held[:2])
+        assert "stablehlo.case" not in got
+        # ... which a narrower range's program has.
+        assert "stablehlo.case" in text((0, 2, 16), self._case("spread", jnp.float32))
+
+
 class TestMoeModel:
     @BOTH_DISPATCHES
     def test_forward_finite_and_shapes(self, rng, impl):

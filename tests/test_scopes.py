@@ -148,15 +148,17 @@ def test_the_table_is_whole():
     grouped = [s for scopes_ in S.GROUPS.values() for s in scopes_]
     # The benchmark's groups, and the scopes its reader counts as ``other``:
     # exactly the eight the linear-attention and selection readers select
-    # themselves.
+    # themselves, and the routed layer's full-width fallback.
     from benchmarks.layers import (
         gdn_dense_device_share, gdn_device_share, lightning_dense_device_share,
-        lightning_device_share, sparse_select_device_share)
+        lightning_device_share, moe_overflow_device_share,
+        sparse_select_device_share)
 
     gdn = gdn_dense_device_share.DENSE_SCOPES + (gdn_device_share.SCAN_SCOPE,)
     sala = lightning_dense_device_share.DENSE_SCOPES + (
         lightning_device_share.SCAN_SCOPE, sparse_select_device_share.SELECT_SCOPE)
-    assert sorted(grouped + list(gdn + sala)) == sorted(naming.SCOPE_NAMES)
+    overflow = (moe_overflow_device_share.OVERFLOW_SCOPE,)
+    assert sorted(grouped + list(gdn + sala + overflow)) == sorted(naming.SCOPE_NAMES)
     with pytest.raises(AssertionError):
         naming.scope("ddl.not_in_the_table")
     # No model file names a scope past the helper.
@@ -191,6 +193,20 @@ def test_the_documented_table_is_the_programs():
      ("ddl.attn", "ddl_flash_fwd", "forward")),
     ("jit(_run)/while/body/closed_call/jvp(ddl.moe)/ddl.moe_shared/dot_general",
      ("ddl.moe_shared", "ddl.moe_shared", "forward")),
+    # A share's two branches (PR 40): the bounded pass under the phase, the
+    # full-width fallback under ``ddl.moe_overflow`` inside it.
+    ("jit(_run)/while/body/closed_call/jvp(ddl.moe)/cond/branch_1_fun/"
+     "ddl.moe_experts/jit(_take)/gather",
+     ("ddl.moe_experts", "ddl.moe_experts", "forward")),
+    ("jit(_run)/while/body/closed_call/jvp(ddl.moe)/cond/branch_0_fun/"
+     "ddl.moe_experts/ddl.moe_overflow/jit(_take)/gather",
+     ("ddl.moe_overflow", "ddl.moe_overflow", "forward")),
+    ("jit(_run)/while/body/closed_call/transpose(jvp(jvp()))/checkpoint/ddl.moe/cond/"
+     "branch_0_fun/transpose(jvp(ddl.moe_combine))/ddl.moe_overflow/gather",
+     ("ddl.moe_overflow", "ddl.moe_overflow", "backward")),
+    ("jit(_run)/while/body/closed_call/transpose(jvp(jvp()))/checkpoint/"
+     "rematted_computation/ddl.moe/cond/branch_1_fun/ddl.moe_combine/gather",
+     ("ddl.moe_combine", "ddl.moe_combine", "recompute")),
     ("jit(_run)/while/body/closed_call/ddl.optimizer/mul",
      ("ddl.optimizer", "ddl.optimizer", "forward")),
     ("jit(_run)/while/body/closed_call/transpose(jvp(ddl.head))/mul;"
