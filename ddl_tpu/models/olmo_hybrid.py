@@ -22,9 +22,9 @@ residual stream itself::
     o_t = S_t q_t                       S in R^{d_v x d_k}, float32, S_0 = 0
     y_t = RMSNorm_{d_v}(o_t) * SiLU(h_t Wg),   out = y Wo
 
-The recurrence is ``ops/gated_delta.gated_delta_rule`` (chunked; what of
-a chunk touches the state a Pallas kernel with a backward pass); ``g`` and
-``beta`` are float32.
+The recurrence is ``ops/gated_delta.gated_delta_rule`` (chunked; a chunk
+from q, k, v, g and beta to o a Pallas kernel with a backward pass); ``g``
+and ``beta`` are float32.
 
 ``full_attention``: causal softmax attention, ``n_heads`` x ``head_dim``,
 NO position encoding (``rope_theta`` null), RMSNorm with a learned weight

@@ -38,8 +38,8 @@ accepted for back compat — ``True`` is ``"full"``, ``False`` is
   ``custom_vjp`` the state every chunk starts from - ``2·B·(T/64)·H·
   d_k·d_v`` bytes of bf16 (283 MB at one row of 16,384, 30 heads of
   96 x 192) beside ``2·B·T·H·d_v`` of output - so the backward pass runs
-  the backward kernel and no forward one; what XLA prepares of the chunks
-  is recomputed, a pass of heads at a time.
+  the backward kernel and no forward one; the kernel prepares a chunk
+  again itself, and what is recomputed is its operands.
   The fixed-decay scan (``ops/lightning_attention.py``) tags its output and
   its chunk states the same way (``2·B·(T/128)·H·d²`` bytes: 134 MB at one
   row of 16,384, 32 heads of 128), and a block-sparse attention layer

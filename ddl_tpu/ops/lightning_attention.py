@@ -114,9 +114,9 @@ def _heads_per_step(H: int, C: int, d: int, itemsize: int, lanes_ok: bool) -> in
 
 def _each_head(carried_ref, heads, one_head):
     """``one_head(h)`` for the ``heads`` heads of a grid step, behind a
-    scratch zeroed at the row's first chunk.  ``gated_delta._each_head``
-    with ``h`` static: a head here is a slice of LANES, which the chip
-    takes at a static offset only."""
+    scratch zeroed at the row's first chunk.  A loop with ``h`` static: a
+    head here is a slice of LANES, which the chip takes at a static offset
+    only."""
 
     @pl.when(pl.program_id(1) == 0)
     def _():

@@ -41,10 +41,11 @@ KERNEL_NAMES = (
     # ... and with latent attention's rotary product (``q_rope=``)
     "ddl_flash_mla_fwd", "ddl_flash_mla_bwd_dq", "ddl_flash_mla_bwd_dkv",
     "ddl_flash_tile_fwd", "ddl_flash_tile_bwd",
-    # the gated delta rule's chunk meeting its state (``Vn = U - W H``,
-    # ``O = Qg H + P Vn``, ``H <- a H + Kd^T Vn``, the state in VMEM for the
-    # whole row) and its backward pass, the chunks last to first
-    # (``ops/gated_delta.py``); ``gdn_device_share`` reads ``ddl_gdn_``
+    # the gated delta rule's chunks from q, k, v, decay sums and beta to o
+    # (the WY preparation, then ``Vn = U - W H``, ``O = Qg H + P Vn``, ``H <-
+    # a H + Kd^T Vn``, the state in VMEM for the whole row) and the backward
+    # pass, the chunks last to first (``ops/gated_delta.py``);
+    # ``gdn_device_share`` reads ``ddl_gdn_``
     "ddl_gdn_fwd", "ddl_gdn_bwd",
     # linear attention with a fixed decay a head: a chunk from q, k, v to o,
     # the state in VMEM for the whole row, and its backward pass

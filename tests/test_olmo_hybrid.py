@@ -77,10 +77,11 @@ NAMES = ("o", "dq", "dk", "dv", "dg", "dbeta")
 
 
 @functools.lru_cache(maxsize=None)
-def both_sides(shape_name, heads_per_pass=None, dtype=jnp.float32):
+def both_sides(shape_name, heads_per_step=None, dtype=jnp.float32):
     """{name: (scan's, recurrence's)} for the output and the five
     gradients of a seeded weighted sum of it; with ``dtype`` bfloat16 both
-    sides get q, k, v rounded to it, the recurrence as float32 again."""
+    sides get q, k, v rounded to it, the recurrence as float32 again;
+    with ``heads_per_step`` that many (row, head) groups to a grid step."""
     x = operands(0, *SHAPES[shape_name])
     x = tuple(a.astype(dtype) for a in x[:3]) + x[3:]
     widened = lambda fn: lambda q, k, v, g, b: fn(
@@ -96,9 +97,9 @@ def both_sides(shape_name, heads_per_pass=None, dtype=jnp.float32):
         return (out,) + tuple(grads)
 
     want = sides(widened(plain))
-    chosen = gated_delta._heads_per_pass
-    forced = chosen if heads_per_pass is None else lambda B, T, H: heads_per_pass
-    with mock.patch.object(gated_delta, "_heads_per_pass", forced):
+    chosen = gated_delta._heads_per_step
+    forced = chosen if heads_per_step is None else lambda *shape: heads_per_step
+    with mock.patch.object(gated_delta, "_heads_per_step", forced):
         got = sides(gated_delta_rule)
     return dict(zip(NAMES, zip(got, want)))
 
@@ -129,11 +130,14 @@ def test_bfloat16_operands_stay_on_the_recurrence_in_every_gradient(shape, name)
 
 
 @pytest.mark.parametrize("name", NAMES)
-def test_heads_taken_a_pass_at_a_time_change_nothing(name):
-    """Three heads as three passes of one (the map the real shape takes
-    five times): the same numbers as one pass of three."""
-    got, want = both_sides("whole_chunks", heads_per_pass=1)[name]
+@pytest.mark.parametrize("heads", [1, 2, 6])
+def test_the_groups_a_grid_step_holds_change_nothing(heads, name):
+    """Two rows of three heads as six groups of one to a grid step, three
+    of two and (what the shapes choose) one of six: the same numbers."""
+    got, want = both_sides("whole_chunks", heads_per_step=heads)[name]
     assert rel_rms(got, want) < SCAN_TOL
+    chosen, _ = both_sides("whole_chunks")[name]
+    assert float(jnp.max(jnp.abs(got - chosen))) == 0.0
 
 
 def test_without_decay_it_is_the_plain_delta_rule():
@@ -187,25 +191,62 @@ def test_a_state_carried_in_bfloat16_shows_in_float32(monkeypatch):
     assert rel_rms(gated_delta_rule(q, k, v, g, beta), want) > 3e-4
 
 
-@pytest.mark.parametrize("C", [8, 16, 32, 64])
-def test_the_triangular_inverse_is_the_inverse(C):
-    a = np.tril(np.random.default_rng(C).standard_normal((3, 2, C, C)) * 0.3, -1)
-    got = gated_delta._unit_lower_inverse(jnp.asarray(a, jnp.float32))
-    want = np.linalg.inv(np.eye(C) + a)
-    assert float(np.max(np.abs(np.asarray(got) - want))) < 1e-4 * np.max(np.abs(want))
-
-
-@pytest.mark.parametrize("C", [8, 16, 32, 64])
-def test_the_triangular_inverses_own_backward_is_autodiffs(C):
-    """``-X^T dX X^T`` against autodiff through the blocked algorithm, where
-    ``a`` lives: below the diagonal."""
+def one_chunk(C):
+    """Operands of one head and one chunk of ``C`` positions whose output
+    IS the kernel's triangular inverse: no decay, beta 1, the values the
+    identity and ``q k^T`` the identity too (``q = (k k^T)^-1 k``), so that
+    ``o = P T (beta v) = T`` with ``T = (I + strict_lower(k k^T))^-1``;
+    and that ``T`` by numpy, float64."""
     r = np.random.default_rng(C)
-    a = jnp.asarray(np.tril(r.standard_normal((3, 2, C, C)) * 0.3, -1), jnp.float32)
-    w = jnp.asarray(r.standard_normal((3, 2, C, C)), jnp.float32)
-    grad = lambda inverse: jnp.tril(jax.grad(lambda a: jnp.sum(inverse(a) * w))(a), -1)
+    k = np.eye(C) + 0.3 * r.standard_normal((C, C))
+    q = np.linalg.solve(k @ k.T, k)
+    heads = lambda x: jnp.asarray(x, jnp.float32)[None, :, None]  # (1, C, 1, .)
+    operands = (heads(q), heads(k), heads(np.eye(C)),
+                jnp.zeros((1, C, 1), jnp.float32), jnp.ones((1, C, 1), jnp.float32))
+    return operands, np.linalg.inv(np.eye(C) + np.tril(k @ k.T, -1))
+
+
+@pytest.mark.parametrize("C", [8, 16, 32, 64])
+def test_the_kernels_triangular_inverse_is_the_inverse(C):
+    """The diagonal blocks' finite product and the merges of two blocks
+    into one, on masked tiles, at every size a chunk takes."""
+    operands, want = one_chunk(C)
+    assert gated_delta._chunk_len(C) == C
+    got = np.asarray(gated_delta_rule(*operands))[0, :, 0]
+    assert float(np.max(np.abs(np.triu(got, 1)))) < 1e-5
+    assert float(np.max(np.abs(got - want))) < 1e-4 * np.max(np.abs(want))
+
+
+def plain_one_chunk(q, k, v, g, beta):
+    """A first chunk (no state yet) as its formula reads, the inverse
+    ``jnp.linalg.inv``'s: (1, C, 1, .) operands -> (1, C, 1, d_v)."""
+    q, k, v, g, beta = q[0, :, 0], k[0, :, 0], v[0, :, 0], g[0, :, 0], beta[0, :, 0]
+    C = q.shape[0]
+    gam = jnp.cumsum(g)
+    decay = jnp.tril(jnp.exp(jnp.tril(gam[:, None] - gam[None, :])))
+    a = jnp.tril(beta[:, None] * (k @ k.T) * decay, -1)
+    t = jnp.linalg.inv(jnp.eye(C) + a)
+    return (jnp.tril((q @ k.T) * decay) @ t @ (beta[:, None] * v))[None, :, None]
+
+
+@pytest.mark.parametrize("C", [8, 16, 32, 64])
+def test_the_kernels_backward_through_the_inverse_is_autodiffs_of_the_plain_one(C):
+    """``dA = -T^T dT T^T`` and what leads to it and from it, in the
+    backward kernel, against autodiff through ``jnp.linalg.inv``: the
+    gradients that cross the inverse (k's, beta's, g's) and the two that do
+    not, with a decay and a beta that count."""
+    (q, k, _, _, _), _ = one_chunk(C)
+    r = np.random.default_rng(100 + C)
+    v = jnp.asarray(r.standard_normal((1, C, 1, 2 * C)), jnp.float32)
+    g = jnp.asarray(-np.exp(r.uniform(np.log(1e-3), np.log(0.5), (1, C, 1))), jnp.float32)
+    beta = jnp.asarray(2.0 / (1.0 + np.exp(-r.standard_normal((1, C, 1)))), jnp.float32)
+    w = jnp.asarray(r.standard_normal(v.shape), jnp.float32)
+    grads = lambda fn: jax.grad(
+        lambda *a: jnp.sum(fn(*a) * w), argnums=range(5))(q, k / 2, v, g, beta)
     with jax.default_matmul_precision("highest"):
-        want = grad(gated_delta._unit_lower_inverse.fun)
-    assert rel_rms(grad(gated_delta._unit_lower_inverse), want) < 1e-5
+        want = grads(plain_one_chunk)
+    for name, got, wanted in zip(NAMES[1:], grads(gated_delta_rule), want):
+        assert rel_rms(got, wanted) < 1e-5, name
 
 
 @pytest.mark.parametrize("T,want", [(5, 8), (37, 64), (64, 64), (100, 64), (16384, 64)])
@@ -213,23 +254,27 @@ def test_the_chunk_comes_from_the_row(T, want):
     assert gated_delta._chunk_len(T) == want
 
 
-def test_the_grid_and_the_passes_come_from_the_shapes():
-    # one row of 16,384 at the published 30 heads of 96 / 192: five passes
-    # of six heads, each one grid step a chunk, in bfloat16 and (the
-    # benchmark's core check) in float32
+def test_the_grid_comes_from_the_shapes():
+    # a grid step takes a tile of two chunks, or the one a short row has
+    assert gated_delta._tile_len(16384, 64) == gated_delta._tile_len(65, 64) == 128
+    assert gated_delta._tile_len(64, 64) == 64 and gated_delta._tile_len(5, 8) == 8
+    # one row of 16,384 at the published 30 heads of 96 / 192: five groups
+    # of six heads on one grid, in bfloat16; the benchmark's core check's
+    # six heads in float32 two groups of three
     assert gated_delta._heads_per_pass(1, 16384, 30) == 6
-    assert gated_delta._heads_per_step(6, 64, 96, 192, 2) == 6
-    assert gated_delta._heads_per_step(6, 64, 96, 192, 4) == 6
-    assert gated_delta._heads_per_pass(2, 16384, 30) == 3
-    assert gated_delta._heads_per_pass(1, 3072, 30) == 30
-    # a step's blocks are held to the budget at the operands' width ...
-    assert gated_delta._heads_per_step(30, 64, 96, 192, 2) == 6
-    assert gated_delta._heads_per_step(8, 64, 96, 192, 2) == 8
-    assert gated_delta._heads_per_step(8, 64, 96, 192, 4) == 4
-    assert gated_delta._heads_per_step(7, 64, 96, 192, 4) == 7
-    # ... and divide the rows: eleven go one at a time
-    assert gated_delta._heads_per_step(11, 64, 8, 16, 4) == 1
-    assert gated_delta._heads_per_step(10, 64, 16, 32, 4) == 5
+    assert gated_delta._heads_per_step(30, 128, 96, 192, 2) == 6
+    assert gated_delta._heads_per_step(6, 128, 96, 192, 4) == 3
+    # the most a grid step holds is eight ...
+    assert gated_delta._heads_per_pass(2, 16384, 30) == 6
+    assert gated_delta._heads_per_pass(1, 3072, 32) == 8
+    assert gated_delta._heads_per_step(8, 128, 16, 32, 2) == 8
+    # ... held to the budget at the operands' width, the body's float32
+    # tiles counted ...
+    assert gated_delta._heads_per_step(8, 128, 96, 192, 2) == 4
+    assert gated_delta._heads_per_step(8, 128, 256, 512, 4) == 2
+    # ... and they divide the rows: eleven go one at a time
+    assert gated_delta._heads_per_step(11, 128, 8, 16, 4) == 1
+    assert gated_delta._heads_per_step(10, 128, 16, 32, 4) == 5
     assert gated_delta._heads_per_step(4, 8, 8, 16, 4) == 4
 
 
@@ -468,12 +513,9 @@ def test_the_benchmarks_reference_is_this_one():
 # -- what selective remat keeps ------------------------------------------------------
 
 
-@pytest.mark.parametrize("remat,fwd", [("none", 1), ("selective", 1), ("full", 2)])
-def test_the_backward_pass_reads_the_saved_states(remat, fwd, monkeypatch):
-    """The train step lowered for the TPU: under ``selective`` each linear
-    layer runs the forward kernel once - the chunk states it writes and the
-    scan's output are saved residuals - and the backward kernel once;
-    ``full`` keeps neither and runs the forward kernel again."""
+def lowered_train_step(remat, monkeypatch, **as_text):
+    """The text of a small model's train step (two heads of 96 / 192, one
+    row of 1,024 positions: sixteen chunks of 64) lowered for the TPU."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = olmo_hybrid.OlmoHybridConfig(
         vocab=256, d_model=256, n_heads=2, d_ff=256, n_linear_heads=2,
@@ -481,10 +523,65 @@ def test_the_backward_pass_reads_the_saved_states(remat, fwd, monkeypatch):
         param_dtype=jnp.bfloat16, remat=remat,
     )
     params = jax.eval_shape(lambda: olmo_hybrid.init_params(cfg, jax.random.key(0)))
-    text = jax.jit(jax.value_and_grad(
+    return jax.jit(jax.value_and_grad(
         lambda p, t: olmo_hybrid.next_token_loss(p, t, cfg)
     )).trace(params, jax.ShapeDtypeStruct((1, 1024), jnp.int32)).lower(
-        lowering_platforms=("tpu",)).as_text()
+        lowering_platforms=("tpu",)).as_text(**as_text)
+
+
+@pytest.mark.parametrize("remat,fwd", [("none", 1), ("selective", 1), ("full", 2)])
+def test_the_backward_pass_reads_the_saved_states(remat, fwd, monkeypatch):
+    """The train step lowered for the TPU: under ``selective`` each linear
+    layer runs the forward kernel once - the chunk states it writes and the
+    scan's output are saved residuals - and the backward kernel once;
+    ``full`` keeps neither and runs the forward kernel again."""
+    text = lowered_train_step(remat, monkeypatch)
     got = collections.Counter(re.findall(r'kernel_name = "(ddl_\w+)"', text))
     assert got["ddl_gdn_fwd"] == 3 * fwd and got["ddl_gdn_bwd"] == 3, got
     assert got["ddl_flash_fwd"] == (2 if remat == "full" else 1), got
+
+
+def test_nothing_of_a_chunks_preparation_is_left_to_xla(monkeypatch):
+    """The same step under ``selective``, by its name stacks: inside
+    ``ddl.gdn_scan`` and outside the two kernels' custom calls there is no
+    matmul and no tensor whose last two axes are a chunk by a chunk (Gram
+    matrix, decay, the triangular inverse, ``P``) - what XLA keeps of the
+    scan is the head-major transposes, the pad and the decay sums, in
+    every pass.  Still exactly three forward and three backward kernels."""
+    text = lowered_train_step("selective", monkeypatch, debug_info=True)
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    # a private function's ops carry no caller's name stack: its lines are
+    # the scan's if a line of the scan's calls it
+    bodies, name = collections.defaultdict(list), None
+    for line in text.splitlines():
+        start = re.match(r"\s*func\.func (?:private |public )?@(\w+)\(", line)
+        name = start.group(1) if start else name
+        bodies[name].append(line)
+    scans, called = [], set()
+
+    def take(line):
+        scans.append(line)
+        for callee in re.findall(r"call @(\w+)", line):
+            if callee not in called:
+                called.add(callee)
+                for inner in bodies[callee]:
+                    take(inner)
+
+    for line in text.splitlines():
+        at = re.search(r"loc\((#loc\d+)\)\s*$", line)
+        if at and "ddl.gdn_scan" in locs.get(at.group(1), ""):
+            take(line)
+    ops, kernels = collections.Counter(), collections.Counter()
+    for line in scans:
+        kernel = re.search(r'kernel_name = "(ddl_gdn_\w+)"', line)
+        if kernel:
+            kernels[kernel.group(1)] += 1
+            continue
+        op = re.search(r"\b(stablehlo\.\w+|call @[a-z]+)", line)
+        ops[op.group(1) if op else line.strip()[:40]] += 1
+        assert "dot_general" not in line and "convolution" not in line, line
+        assert not re.search(r"tensor<(\d+x)*64x64x\w+>", line), line
+    assert kernels == {"ddl_gdn_fwd": 3, "ddl_gdn_bwd": 3}, kernels
+    # ... and the scope is not empty of XLA's part: the transposes to and
+    # from head-major, and the decay sums
+    assert ops["stablehlo.transpose"] >= 3 * 8 and ops["call @cumsum"] >= 3 * 3, ops

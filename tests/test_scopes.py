@@ -114,11 +114,15 @@ def test_every_matmul_of_a_train_step_stands_under_a_scope(family):
         assert seen[which] & {"ddl.mlp", "ddl.moe_experts", "ddl.moe_shared"}, seen
     assert "ddl.head" in seen["forward"] and "ddl.head" in seen["backward"]
     if family == "olmo_hybrid":
-        # A linear-attention layer's projections, its scan (the chunks'
-        # matmuls and the interpreted chain) and its output, in every pass:
-        # the pass's intermediates of the scan are recomputed, not kept.
+        # A linear-attention layer's projections and its output in every
+        # pass; its scan (the interpreted kernels: since PR 41 a chunk from
+        # q, k, v, decay sums and beta to o) forward and backward but never
+        # recomputed: ``selective`` saves its output and chunk states, and
+        # the backward kernel prepares a chunk again itself.
         for which in passes:
-            assert {"ddl.gdn_proj", "ddl.gdn_scan", "ddl.gdn_out"} <= seen[which], seen
+            assert {"ddl.gdn_proj", "ddl.gdn_out"} <= seen[which], seen
+        assert "ddl.gdn_scan" in seen["forward"] & seen["backward"], seen
+        assert "ddl.gdn_scan" not in seen["recompute"], seen
     if family == "minicpm_sala":
         # A lightning layer's projections and output in every pass; its
         # scan forward and backward but never recomputed (one kernel from

@@ -145,12 +145,15 @@ def _gdn_calls(lowered_text):
                          ids=["cell_bf16_30_heads", "core_check_f32_6_heads"])
 def test_gated_delta_rule_compiles_at_olmo_hybrids_geometry(v5e, grad, dtype, heads):
     """One row of 16,384 positions, heads of 96-wide keys and 192-wide
-    values (neither fills 128 lanes): the cell's 30 in bfloat16 - five
-    passes of six - and the benchmark's core check's six in float32.  Each
-    chunk meets its state inside the kernel, forward and (the chunks last to
-    first) backward, each compiled once - inside the loop over the passes -
-    on a grid of (1 group of six heads, 256 chunks): a VMEM overflow or a
-    relayout Mosaic does not have fails here, not on the chip."""
+    values (neither fills 128 lanes): the cell's 30 in bfloat16 - no passes
+    of heads: five groups of six on one grid - and the benchmark's core
+    check's six in float32, two groups of three.  A tile of two chunks is
+    prepared (decay, Gram matrices, the triangular inverse, ``W``, ``U``,
+    ``P``) and its chunks meet their state inside the kernel, forward and
+    (tiles and chunks last to first, prepared again and transposed in
+    place) backward, each compiled once on a grid of (groups of heads, 128
+    tiles): a VMEM overflow or a relayout Mosaic does not have fails here,
+    not on the chip."""
     from ddl_tpu.ops.gated_delta import gated_delta_rule
 
     one = SingleDeviceSharding(v5e[0])
@@ -179,17 +182,24 @@ def test_gated_delta_rule_compiles_at_olmo_hybrids_geometry(v5e, grad, dtype, he
     assert want <= set(KERNEL_NAMES)
     # the backward pass reads the saved chunk states: no second forward kernel
     text = lowered.as_text()
-    assert mosaic_grids(text) == {(1, 256): 2 if grad else 1}
+    a_step = 6 if dtype == jnp.bfloat16 else 3
+    assert mosaic_grids(text) == {(heads // a_step, 128): 2 if grad else 1}
     calls = _gdn_calls(text)
     assert set(calls) == want
-    if dtype == jnp.bfloat16:
-        # no chunk's map crosses HBM: nothing float32 of (chunks, 96, 96) or
-        # (chunks, 96, 192) enters or leaves a kernel (the states it writes
-        # and reads are the operands' bfloat16)
-        for operands, results in calls.values():
-            assert "6x256x96x192xbf16" in operands + results
-            for t in operands + results:
-                assert not re.fullmatch(r"\d+x256x96x(96|192)xf32", t), (t, calls)
+    low = "bf16" if dtype == jnp.bfloat16 else "f32"
+    for operands, results in calls.values():
+        # the states a kernel writes and reads, one a chunk, are in the
+        # operands' dtype ...
+        assert f"{heads}x256x96x192x{low}" in operands + results
+        # ... and nothing of a chunk's preparation crosses HBM: besides q, k,
+        # v, o (tiles of 128 positions), the states and their cotangents a
+        # kernel takes and hands back only the decay sums and beta, (2, 128)
+        # float32 a tile
+        for t in operands + results:
+            assert re.fullmatch(
+                rf"{heads}x128x128x(96|192)x{low}|{heads}x256x96x192x{low}"
+                rf"|{heads}x128x2x128xf32", t
+            ), (t, calls)
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])

@@ -1,22 +1,24 @@
 #!/usr/bin/env python3
 """The gated delta rule's kernels alone, and the whole scan, timed on the
 chip at the Olmo-Hybrid cell's shape (one row of 16,384 positions, 30 heads
-of 96 / 192, bfloat16; PERF.md section 6, PR 37).
+of 96 / 192, bfloat16; PERF.md section 6, PR 37 and PR 41).
 
     chiprun -- python3 tools/probe_gdn_kernel.py
     chiprun -- python3 tools/probe_gdn_kernel.py --whole-only   # any tree
 
 Host clock around ``--reps`` calls of a jitted program that end in one
 ``block_until_ready``.  ``whole``: ``jax.grad`` of ``gated_delta_rule`` over
-all heads, forward and backward in one program; the same line from a
-parent's checkout (``--whole-only``: PR 36's module has no fused kernels to
-time alone) is the comparison.  ``pieces``: XLA's part of one pass of six
-heads - the triangular inverse alone, then the pass (kernels included),
-forward and with its backward.  ``kernels``: a program that is one call of
-``ddl_gdn_fwd`` / ``ddl_gdn_bwd`` on seeded operands of one pass of heads
-(what a linear layer calls five times a step and pass); the cell's own trace
-reads the kernels' device time at well under half of these (PERF.md section
-7, PR 37): a lead for comparing builds, not the kernels' time.
+all heads, forward and backward in one program (the two head-major
+transposes, the decay sums and both kernels); the same line from a parent's
+checkout (``--whole-only``: the kernels' signatures differ from tree to
+tree) is the comparison.  ``kernels``: a program that is one call of
+``ddl_gdn_fwd`` / ``ddl_gdn_bwd`` - since PR 41 a chunk from q, k, v, its
+decay sums and beta to o, and back, a tile of two chunks a grid step - on
+seeded operands of one group of heads (what a grid step holds) and of all of
+them (what a linear layer calls once a pass).  The cell's own trace reads the kernels' device time
+well under this line's - 2.3x under for PR 37's kernels, 1.7x for PR 41's
+(ROADMAP M14; PERF.md section 7, PR 37 and PR 41): a lead for comparing
+builds, not the kernels' time.
 
 Needs a TPU (``--rehearsal cpu``: a tiny shape in interpret mode, timings
 that mean nothing).
@@ -85,36 +87,25 @@ def main() -> int:
         return 0
 
     C = gated_delta._chunk_len(T)
-    G, chunks = B * gated_delta._heads_per_pass(B, T, H), T // C
-    # XLA's part of one pass of heads, by piece: the triangular inverse
-    # alone, then the pass (kernels included), forward and with its backward
-    tri = jnp.asarray(np.tril(0.3 * r.standard_normal((G, chunks, C, C)), -1), jnp.float32)
-    inverse = lambda a: jnp.sum(gated_delta._unit_lower_inverse(a))
-    cut = lambda x: jnp.moveaxis(x.reshape((B, chunks, C, H) + x.shape[3:]), 3, 1)[:, : G // B]
-    one = tuple(cut(x) for x in (q, k, v, g, beta))
-    one_pass = lambda *a: jnp.sum(gated_delta._one_pass(*a, bool(args.rehearsal)).astype(jnp.float32))
-    print(json.dumps({
-        "line": "pieces", "rows": G, "chunks": chunks, "passes_a_layer": H // (G // B),
-        "inverse_fwd_ms": timed(jax.jit(inverse), (tri,), args.reps),
-        "inverse_fwd_bwd_ms": timed(jax.jit(jax.grad(inverse)), (tri,), args.reps),
-        "pass_fwd_ms": timed(jax.jit(one_pass), one, args.reps),
-        "pass_fwd_bwd_ms": timed(jax.jit(jax.grad(one_pass, argnums=range(5))), one, args.reps),
-        **where,
-    }), flush=True)
-    arr = lambda *shape: jnp.asarray(0.1 * r.standard_normal((G, chunks) + shape), dt)
-    ins = (arr(C, dk), arr(C, dv), arr(C, dk), arr(C, dk), arr(C, C),
-           jnp.full((G, chunks, 1, dv), 0.9, jnp.float32))
-    d_o = arr(C, dv)
-    interpret = bool(args.rehearsal)
-    fwd = jax.jit(lambda *a: gated_delta._forward(*a, interpret))
-    bwd = jax.jit(lambda *a: gated_delta._chunks_bwd(interpret, a[:-1], a[-1]))
-    fwd_ms = timed(fwd, ins, args.reps)
-    bwd_ms = timed(bwd, ins + (fwd(*ins)[1], d_o), args.reps)
-    print(json.dumps({
-        "line": "kernels", "rows": G, "chunks": chunks,
-        "fwd_ms_a_call": fwd_ms, "bwd_ms_a_call": bwd_ms,
-        "ms_a_step_3_layers": 3 * (H // (G // B)) * (fwd_ms + bwd_ms), **where,
-    }), flush=True)
+    P, interpret = gated_delta._tile_len(T, C), bool(args.rehearsal)
+    tiles = T // P
+    fwd = jax.jit(lambda *a: gated_delta._forward(*a, C, interpret))
+    bwd = jax.jit(lambda *a: gated_delta._chunks_bwd(C, interpret, a[:-1], a[-1]))
+    for G in sorted({B * gated_delta._heads_per_pass(B, T, H), B * H}):
+        # head-major tiles, as ``gated_delta_rule`` lays its operands out
+        cut = lambda x, span=P: jnp.moveaxis(x, 2, 1)[:, : G // B].reshape(
+            (G, -1, span) + x.shape[3:])
+        gam = jnp.cumsum(cut(g, C), axis=-1).reshape(G, tiles, P)
+        ins = (cut(q), cut(k), cut(v), jnp.stack([gam, cut(beta)], axis=2))
+        d_o = jnp.asarray(0.1 * r.standard_normal((G, tiles, P, dv)), dt)
+        fwd_ms = timed(fwd, ins, args.reps)
+        bwd_ms = timed(bwd, ins + (fwd(*ins)[1], d_o), args.reps)
+        heads = gated_delta._heads_per_step(G, P, dk, dv, q.dtype.itemsize)
+        print(json.dumps({
+            "line": "kernels", "rows": G, "grid": [G // heads, tiles],
+            "fwd_ms_a_call": fwd_ms, "bwd_ms_a_call": bwd_ms,
+            "ms_a_step_3_layers": 3 * (B * H // G) * (fwd_ms + bwd_ms), **where,
+        }), flush=True)
     return 0
 
 
