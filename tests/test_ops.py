@@ -4,6 +4,7 @@ Runs in interpret mode on the CPU test backend (conftest); on a real TPU
 the same code path compiles via Mosaic.
 """
 
+import collections
 import hashlib
 import re
 
@@ -693,42 +694,144 @@ PARENT_STEP_SHA256 = {
         "428749c663ca7ab763f822afa339dac426ca3badca2afa6015c76fe3988bbdcd",
     "olmoe":
         "f3558c9f5977a323082bfa3a7ddb1d84cfe6b9b83ee677e1df42a2c90a2df645",
+    # PR 42's parent (6d42264), recorded before PR 42 touched a model file:
+    "trinity":
+        "b0912109156b7916ccdf1175054dc849c9f03abbf060a678458c6d1df0c57696",
+    "kanana":
+        "a8a3d511777fbc01ef0546386dda9b1a9ebe2ec940108681f534f6d30d3be983",
+    "olmo_hybrid":
+        "b81ccd3656983f59ed2f5033037579091062f40a37280d1d2f28bd686c74dfa2",
+    "minicpm_sala":
+        "921389e4b214acba64f2eaa4cbdbbb80376b51db6d2f8cbe3b5345ae42d57239",
 }
 
 
-@pytest.mark.parametrize("model", ["mistral", "olmoe"])
+def _decoder_case(model):
+    """(module, config, row length) of a decoder shaped like the
+    benchmark's cell of that family, at a size a trace takes seconds of:
+    128-deep heads, flash kernels, selective remat, bf16 storage; a share of
+    4 of 16 experts behind a dense first layer for the two share families,
+    both mixer kinds for the two hybrids (a row past ``dense_len`` for
+    MiniCPM-SALA's selection)."""
+    from ddl_tpu.models import (
+        afmoe, deepseek_v3, llama, minicpm_sala, moe, olmo_hybrid)
+
+    common = dict(
+        vocab=256, d_model=256, n_heads=2, max_seq=4096, attn_impl="flash",
+        remat="selective", param_dtype=jnp.bfloat16,
+    )
+    if model == "mistral":
+        return llama, llama.LlamaConfig(
+            n_layers=1, n_kv_heads=1, d_ff=128, **common), 4096
+    if model == "olmoe":
+        return moe, moe.MoeConfig(
+            n_layers=1, d_ff=128, n_kv_heads=2, n_experts=4, topk=2,
+            qk_norm=True, norm_topk_prob=False, **common), 4096
+    share = dict(d_ff=128, d_expert=64, n_experts=16, topk=4,
+                 n_dense_layers=1, held_experts=(4, 4), route_scale=2.5)
+    if model == "trinity":
+        S, F = afmoe.SLIDING, afmoe.FULL
+        return afmoe, afmoe.AfmoeConfig(
+            n_kv_heads=1, head_dim=128, layer_types=(S, S, F, S),
+            sliding_window=512, **share, **common), 2048
+    if model == "kanana":
+        return deepseek_v3, deepseek_v3.DeepseekV3Config(
+            n_layers=3, qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128,
+            kv_lora_rank=128, **share, **common), 2048
+    if model == "olmo_hybrid":
+        L, F = olmo_hybrid.LINEAR, olmo_hybrid.FULL
+        return olmo_hybrid, olmo_hybrid.OlmoHybridConfig(
+            d_ff=128, layer_types=(L, L, F, L), n_linear_heads=2,
+            linear_key_dim=96, linear_value_dim=192, **common), 1024
+    assert model == "minicpm_sala", model
+    G, A = minicpm_sala.LIGHTNING, minicpm_sala.SPARSE
+    return minicpm_sala, minicpm_sala.MiniCPMSalaConfig(
+        n_kv_heads=1, head_dim=128, n_lightning_heads=2,
+        lightning_head_dim=128, d_ff=128, mixer_types=(A, G, G, A),
+        dense_len=1024, dim_model_base=32, **common), 2048
+
+
+DECODERS = ["mistral", "olmoe", "trinity", "kanana", "olmo_hybrid",
+            "minicpm_sala"]
+
+
+@pytest.mark.parametrize("model", DECODERS)
 def test_decoder_steps_trace_to_what_the_parent_traced(model, monkeypatch):
     """T = 4096 bypasses the one-block path: the train step of a decoder
     shaped like the benchmark's (GQA 2:1 for Mistral; full MHA + QK-norm
     and routed experts for OLMoE; 128-deep heads, selective remat, flash
     kernels and all) holds the old three kernels and no other, each once
     a layer and step — the forward too — and is, equation for equation,
-    the program the commit above traced."""
-    from ddl_tpu.models import llama, moe
-
+    the program the commit above traced.  So are the four later families'
+    (``_decoder_case``): traced, never lowered."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    common = dict(
-        vocab=256, d_model=256, n_layers=1, n_heads=2, d_ff=128,
-        max_seq=4096, attn_impl="flash", remat="selective",
-        param_dtype=jnp.bfloat16,
-    )
-    if model == "mistral":
-        mod, cfg = llama, llama.LlamaConfig(n_kv_heads=1, **common)
-    else:
-        mod, cfg = moe, moe.MoeConfig(
-            n_kv_heads=2, n_experts=4, topk=2, qk_norm=True,
-            norm_topk_prob=False, **common)
+    mod, cfg, T = _decoder_case(model)
     params = jax.eval_shape(lambda: mod.init_params(cfg, jax.random.key(0)))
-    tokens = jax.ShapeDtypeStruct((1, 4096), jnp.int32)
+    tokens = jax.ShapeDtypeStruct((1, T), jnp.int32)
     text = str(jax.make_jaxpr(jax.value_and_grad(
         lambda p, t: mod.next_token_loss(p, t, cfg)
     ))(params, tokens))
-    assert set(re.findall(r"ddl_flash_\w+", text)) == BLOCK
-    calls = re.findall(r"name=(ddl_flash_\w+)", text)
-    assert sorted(calls) == sorted(BLOCK), calls  # one layer, one step
+    calls = collections.Counter(re.findall(r"name=(ddl_\w+)", text))
+    del calls["ddl_attn_out"]  # the checkpoint name, not a kernel's
+    # each kernel once a layer of its kind and step, the forward too
+    assert calls == {
+        "mistral": dict.fromkeys(BLOCK, 1),
+        "olmoe": dict.fromkeys(BLOCK, 1),
+        "trinity": {**dict.fromkeys(BLOCK, 1), **dict.fromkeys(SWA, 3)},
+        "kanana": dict.fromkeys(
+            ("ddl_flash_mla_fwd", "ddl_flash_mla_bwd_dq", "ddl_flash_mla_bwd_dkv"), 3),
+        "olmo_hybrid": {**dict.fromkeys(BLOCK, 1), "ddl_gdn_fwd": 3, "ddl_gdn_bwd": 3},
+        "minicpm_sala": dict.fromkeys(
+            ("ddl_sparse_select", "ddl_flash_sparse_fwd", "ddl_flash_sparse_bwd_dq",
+             "ddl_flash_sparse_bwd_dkv", "ddl_lightning_fwd", "ddl_lightning_bwd"), 2),
+    }[model], calls
+    if model in ("mistral", "olmoe"):
+        assert set(re.findall(r"ddl_flash_\w+", text)) == BLOCK
     if jax.__version__ == PARENT_JAX:  # the printed form is this JAX's
         text = re.sub(r" at (0x[0-9a-f]+|\S+:\d+)", "", text)
         assert hashlib.sha256(text.encode()).hexdigest() == PARENT_STEP_SHA256[model]
+
+
+#: sha256 over ``init_params(cfg, key(0))`` of the six cases above: every
+#: leaf's path, dtype, shape, ``PartitionSpec`` and bytes, on PR 42's parent (6d42264).  The
+#: order in which a family draws its keys is part of it.
+PARENT_INIT_SHA256 = {
+    "mistral":
+        "7f9322dbab1eeb65a755c2278dc61dda831df81acac34f5125a581279591e6be",
+    "olmoe":
+        "06b83c19e58cda67cd9652aa957a7e2090a1c2f9ed6331dfaddf62218dcdb761",
+    "trinity":
+        "c2b648d48ca3f301aefdbdeb1ff1135e0f6a017617e5713d22ad13cde90f051a",
+    "kanana":
+        "4858a41fb65d790b98f9e84ea138212e2f8c39ec93d94058c84c3ae77814d1d9",
+    "olmo_hybrid":
+        "fe07a9905af886f1f5cc4ab3613e515e8370a040bd74e31c97f67bd65ec97078",
+    "minicpm_sala":
+        "af73273354f19c83c7f2564128391f89e0e2ca97738de0ab994c33f0b5997f50",
+}
+
+
+@pytest.mark.parametrize("model", DECODERS)
+def test_initial_weights_for_a_key_are_the_parents(model):
+    mod, cfg, _ = _decoder_case(model)
+    params = mod.init_params(cfg, jax.random.key(0))
+    is_spec = lambda s: isinstance(s, jax.sharding.PartitionSpec)
+    specs = jax.tree.leaves(mod.param_specs(cfg), is_leaf=is_spec)
+    leaves = jax.tree_util.tree_leaves_with_path(params)
+    assert jax.tree.structure(params) == jax.tree.structure(
+        mod.param_specs(cfg), is_leaf=is_spec)
+    digest = hashlib.sha256()
+    for (path, leaf), spec in zip(leaves, specs):
+        assert len(spec) == leaf.ndim, (path, spec)
+        digest.update(
+            f"{jax.tree_util.keystr(path)} {leaf.dtype} {leaf.shape} {spec}\n".encode())
+        digest.update(np.asarray(leaf).tobytes())
+    if hasattr(mod, "param_shapes"):  # the same tree, no weight made
+        shapes = jax.tree.leaves(mod.param_shapes(cfg))
+        assert [(s.shape, s.dtype) for s in shapes] == [
+            (leaf.shape, leaf.dtype) for _, leaf in leaves]
+    if jax.__version__ == PARENT_JAX:
+        assert digest.hexdigest() == PARENT_INIT_SHA256[model]
 
 
 # -- what remat="selective" keeps of a blockwise call ----------------------------
