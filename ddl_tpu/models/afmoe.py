@@ -18,9 +18,9 @@ data of the config (``layer_types``, ``n_dense_layers``):
 - the block: sandwich norms, ``x + post_norm(f(pre_norm(x)))`` for both
   halves; the embedding is scaled by ``sqrt(d_model)`` (``mup_enabled``).
 
-What is llama's is llama's: ``_rope``, ``_rms_norm``, ``_swiglu``,
-``_dense_init`` and the attention dispatcher
-(``window=`` for the sliding layers).  The routed experts are
+What every decoder shares is ``models/decoder.py``'s (``rope``,
+``rms_norm``, ``swiglu``, the stack, the parameter table) and the attention
+dispatcher's (``window=`` for the sliding layers).  The routed experts are
 ``moe.ragged_experts``, the dropless core OLMoE runs, handed this
 family's scoring and the RANGE OF EXPERTS HELD HERE
 (``held_experts=(first, count)`` of the router's ``n_experts``): one
@@ -42,13 +42,12 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
-from ddl_tpu.models import llama as _llama
+from ddl_tpu.models import decoder as _decoder
 from ddl_tpu.models import moe as _moe
 from ddl_tpu.models import remat as _remat
 from ddl_tpu.ops.naming import scope
@@ -140,91 +139,33 @@ class AfmoeConfig:
         )
 
 
-def init_params(cfg: AfmoeConfig, key: jax.Array) -> Params:
-    """Seeded normal / sqrt(fan_in) matrices, norm weights 1,
-    ``expert_bias`` 0 (float32 whatever the storage dtype: it is compared
-    with float32 scores)."""
-    keys = iter(jax.random.split(key, 2 + cfg.n_layers * 12))
-    pdt = cfg.param_dtype
+def _kinds(cfg: AfmoeConfig) -> Tuple[Tuple[bool, bool], ...]:
+    """A layer's kind: (its attention slides, its MLP is dense)."""
+    return tuple(
+        (kind == SLIDING, cfg.is_dense(li)) for li, kind in enumerate(cfg.layer_types)
+    )
 
-    def dense(fan_in, shape):
-        return _llama._dense_init(next(keys), fan_in, shape, pdt)
 
-    def swiglu(d_in, width, lead=()):
-        return {
-            "w_gate": dense(d_in, lead + (d_in, width)),
-            "w_up": dense(d_in, lead + (d_in, width)),
-            "w_down": dense(width, lead + (width, d_in)),
-        }
-
+def _layer_rows(cfg: AfmoeConfig, kind: Tuple[bool, bool]) -> List[_decoder.Row]:
+    """The parameter table of a layer (the Megatron fsdp x tp layout)."""
     d, hd = cfg.d_model, cfg.head_dim
-    q_out, kv_out = cfg.n_heads * hd, cfg.n_kv_heads * hd
-    layers = []
-    for li in range(cfg.n_layers):
-        layer = {
-            "input_norm": jnp.ones((d,), pdt),
-            "post_attn_norm": jnp.ones((d,), pdt),
-            "pre_mlp_norm": jnp.ones((d,), pdt),
-            "post_mlp_norm": jnp.ones((d,), pdt),
-            "wq": dense(d, (d, q_out)),
-            "wk": dense(d, (d, kv_out)),
-            "wv": dense(d, (d, kv_out)),
-            "wg": dense(d, (d, q_out)),
-            "wo": dense(q_out, (q_out, d)),
-            "q_norm": jnp.ones((hd,), pdt),
-            "k_norm": jnp.ones((hd,), pdt),
-        }
-        if cfg.is_dense(li):
-            layer.update(swiglu(d, cfg.d_ff))
-        else:
-            layer.update(
-                w_router=dense(d, (d, cfg.n_experts)),
-                expert_bias=jnp.zeros((cfg.n_experts,), jnp.float32),
-                shared=swiglu(d, cfg.d_expert * cfg.n_shared_experts),
-                experts=swiglu(d, cfg.d_expert, lead=(cfg.held[1],)),
-            )
-        layers.append(layer)
-    return {
-        "embed": dense(d, (cfg.vocab, d)),
-        "layers": layers,
-        "final_norm": jnp.ones((d,), pdt),
-        "lm_head": dense(d, (d, cfg.vocab)),
-    }
+    return [
+        _decoder.ones("input_norm", d),
+        _decoder.ones("post_attn_norm", d),
+        _decoder.ones("pre_mlp_norm", d),
+        _decoder.ones("post_mlp_norm", d),
+        *_decoder.attn_rows(d, cfg.n_heads * hd, cfg.n_kv_heads * hd, gated=True),
+        _decoder.ones("q_norm", hd),
+        _decoder.ones("k_norm", hd),
+        *(_decoder.swiglu_rows(d, cfg.d_ff) if kind[1]
+          else _moe.sigmoid_expert_rows(cfg)),
+    ]
 
 
-def param_specs(cfg: AfmoeConfig) -> Params:
-    """PartitionSpecs mirroring :func:`init_params` (the Megatron fsdp x
-    tp layout; the held experts' leading axis is this chip's own and is
-    not sharded)."""
-    col, row = P("fsdp", "tp"), P("tp", "fsdp")
-    swiglu = {"w_gate": col, "w_up": col, "w_down": row}
-    layers = []
-    for li in range(cfg.n_layers):
-        layer = {
-            "input_norm": P(None), "post_attn_norm": P(None),
-            "pre_mlp_norm": P(None), "post_mlp_norm": P(None),
-            "wq": col, "wk": col, "wv": col, "wg": col, "wo": row,
-            "q_norm": P(None), "k_norm": P(None),
-        }
-        if cfg.is_dense(li):
-            layer.update(swiglu)
-        else:
-            layer.update(
-                w_router=P(None, None), expert_bias=P(None),
-                shared=dict(swiglu),
-                experts={
-                    "w_gate": P(None, "fsdp", "tp"),
-                    "w_up": P(None, "fsdp", "tp"),
-                    "w_down": P(None, "tp", "fsdp"),
-                },
-            )
-        layers.append(layer)
-    return {
-        "embed": P(None, "fsdp"),
-        "layers": layers,
-        "final_norm": P(None),
-        "lm_head": P("fsdp", "tp"),
-    }
+#: ``init_params(cfg, key)`` — seeded normal / sqrt(fan_in) matrices, norm
+#: weights 1, ``expert_bias`` 0 — and ``param_specs(cfg)`` of one table.
+_TABLE = _decoder.Table(_kinds, _layer_rows, (2, 12))
+init_params, param_specs = _TABLE.init_params, _TABLE.param_specs
 
 
 def _attn_block(
@@ -242,18 +183,18 @@ def _attn_block(
     dt = x.dtype
     eps = cfg.norm_eps
     with scope("ddl.attn"):
-        h = _llama._rms_norm(x, layer["input_norm"], eps)
+        h = _decoder.rms_norm(x, layer["input_norm"], eps)
 
         def heads(w: str, n: int) -> jax.Array:
             return (h @ layer[w].astype(dt)).reshape(B, T, n, cfg.head_dim)
 
         # One head_dim-long weight, applied to every head.
-        q = _llama._rms_norm(heads("wq", cfg.n_heads), layer["q_norm"], eps)
-        k = _llama._rms_norm(heads("wk", cfg.n_kv_heads), layer["k_norm"], eps)
+        q = _decoder.rms_norm(heads("wq", cfg.n_heads), layer["q_norm"], eps)
+        k = _decoder.rms_norm(heads("wk", cfg.n_kv_heads), layer["k_norm"], eps)
         v = heads("wv", cfg.n_kv_heads)
         if sliding:  # a full layer carries no position encoding
-            q = _llama._rope(q, positions, cfg.rope_theta)
-            k = _llama._rope(k, positions, cfg.rope_theta)
+            q = _decoder.rope(q, positions, cfg.rope_theta)
+            k = _decoder.rope(k, positions, cfg.rope_theta)
         attn = attention(
             q, k, v, mesh=mesh, impl=cfg.attn_impl, causal=True,
             kv_repeat=cfg.n_heads // cfg.n_kv_heads,
@@ -263,7 +204,7 @@ def _attn_block(
             gate = jax.nn.sigmoid(h @ layer["wg"].astype(dt))
             gated = attn.reshape(B, T, -1) * gate
         out = gated @ layer["wo"].astype(dt)
-        return x + _llama._rms_norm(out, layer["post_attn_norm"], eps)
+        return x + _decoder.rms_norm(out, layer["post_attn_norm"], eps)
 
 
 # The routed + shared expert layer is ``moe.sigmoid_expert_mlp``, the one
@@ -283,16 +224,16 @@ def _layer_apply(
     mesh: Optional[Any],
 ):
     """One block of the stated kinds → (x, the router's picks (B, T,
-    topk), or ``None`` from a dense layer)."""
+    topk), or ``None`` from a dense layer, no auxiliary loss)."""
     x = _attn_block(layer, x, cfg, positions, sliding, mesh)
     with scope("ddl.mlp" if dense else "ddl.moe"):
-        h = _llama._rms_norm(x, layer["pre_mlp_norm"], cfg.norm_eps)
+        h = _decoder.rms_norm(x, layer["pre_mlp_norm"], cfg.norm_eps)
         if dense:
-            out, top_e = _llama._swiglu(layer, h), None
+            out, top_e = _decoder.swiglu(layer, h), None
         else:
             out, top_e = _moe_mlp(h, layer, cfg, mesh)
-        out = _llama._rms_norm(out, layer["post_mlp_norm"], cfg.norm_eps)
-        return x + out, top_e
+        out = _decoder.rms_norm(out, layer["post_mlp_norm"], cfg.norm_eps)
+        return x + out, top_e, None
 
 
 def forward_with_choices(
@@ -304,25 +245,18 @@ def forward_with_choices(
     """(logits (B, T, vocab) float32, the expert ids every expert layer's
     router picked (L_expert, B, T, topk) — out of all ``n_experts``, held
     here or not)."""
-    dt = cfg.dtype
     positions = jnp.arange(tokens.shape[1])
-    with scope("ddl.embed"):
-        x = params["embed"].astype(dt)[tokens]
-        if cfg.mup_enabled:
-            x = x * jnp.asarray(math.sqrt(cfg.d_model), dt)
-    picks = []
-    for li, (layer, kind) in enumerate(zip(params["layers"], cfg.layer_types)):
 
-        def layer_fn(x, layer, sliding=kind == SLIDING, dense=cfg.is_dense(li)):
-            return _layer_apply(layer, x, cfg, positions, sliding, dense, mesh)
+    def block(kind: Tuple[bool, bool]):
+        return lambda x, layer: _layer_apply(layer, x, cfg, positions, *kind, mesh)
 
-        x, top_e = _remat.wrap(layer_fn, cfg.remat)(x, layer)
-        if top_e is not None:
-            picks.append(top_e)
-    logits = _llama._lm_head(params, x, cfg)
-    return logits, jnp.stack(picks) if picks else jnp.zeros(
-        (0,) + tokens.shape + (cfg.topk,), jnp.int32
+    scale = (
+        jnp.asarray(math.sqrt(cfg.d_model), cfg.dtype) if cfg.mup_enabled else None
     )
+    logits, picks, _ = _decoder.forward(
+        params, tokens, cfg, _kinds(cfg), block, embed_scale=scale
+    )
+    return logits, _decoder.stack_picks(picks, tokens, cfg.topk)
 
 
 def forward(
@@ -335,29 +269,11 @@ def forward(
     return forward_with_choices(params, tokens, cfg, mesh)[0]
 
 
-def next_token_loss(
-    params: Params,
-    tokens: jax.Array,
-    cfg: AfmoeConfig,
-    mesh: Optional[Any] = None,
-) -> jax.Array:
-    """Mean next-token cross-entropy.  No auxiliary router loss: the
-    published recipe balances by moving ``expert_bias``, not by a term of
-    the loss."""
-    from ddl_tpu.models.losses import next_token_cross_entropy
+#: Mean next-token cross-entropy.  No auxiliary router loss: the published
+#: recipe balances by moving ``expert_bias``, not by a term of the loss.
+next_token_loss = _decoder.loss_of(forward)
 
-    return next_token_cross_entropy(forward(params, tokens, cfg, mesh), tokens)
-
-
-def forward_with_cache(*args: Any, **kwargs: Any):
-    raise NotImplementedError(
-        "afmoe.forward_with_cache: a sliding_attention layer's KV cache is "
-        "a ring of sliding_window entries, which does not exist yet"
-    )
-
-
-def generate(*args: Any, **kwargs: Any):
-    raise NotImplementedError(
-        "afmoe.generate: serving needs the windowed KV cache "
-        "(see forward_with_cache)"
-    )
+forward_with_cache, generate = _decoder.no_decode(
+    "afmoe", "the windowed KV cache: a sliding_attention layer's is a ring "
+    "of sliding_window entries, which does not exist yet",
+)

@@ -12,7 +12,7 @@ Every layer, with ``h = RMSNorm(x)`` and pre-norm residuals
   rotary key a position, shared by all heads.  RoPE on ``q_rope`` and
   ``k_r`` only, on adjacent pairs ``(x[2i], x[2i+1])``
   (``rope_interleave``): the pairs are de-interleaved and then rotated in
-  ``llama._rope``'s half-split form — q and k are permuted alike, so the
+  ``decoder.rope``'s half-split form — q and k are permuted alike, so the
   scores are the published ones on the published weight layout.
   ``s = (q_nope . k_nope + q_rope . k_r) / sqrt(qk_nope_dim + qk_rope_dim)``,
   causal softmax, ``o = softmax(s) v`` (``v_head_dim`` a head),
@@ -28,8 +28,8 @@ Every layer, with ``h = RMSNorm(x)`` and pre-norm residuals
   ``sum_k w_k Expert_sel_k(h) + Shared(h)``, the shared experts one ungated
   SwiGLU of ``n_shared_experts * d_expert``.
 
-What is llama's is llama's (``_rms_norm``, ``_rope``, ``_swiglu``,
-``_dense_init``); the experts are
+What every decoder shares is ``models/decoder.py``'s (``rms_norm``,
+``rope``, ``mlp_block``, the stack, the parameter table); the experts are
 ``moe.ragged_experts`` with the RANGE OF EXPERTS HELD HERE
 (``held_experts=(first, count)`` of the router's ``n_experts``): one
 chip's share of a layer divided over chips by experts, as in
@@ -47,13 +47,13 @@ into the query and the output); neither exists, so
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ddl_tpu.models import llama as _llama
+from ddl_tpu.models import decoder as _decoder
 from ddl_tpu.models import moe as _moe
 from ddl_tpu.models import remat as _remat
 from ddl_tpu.ops.naming import scope
@@ -136,86 +136,34 @@ class DeepseekV3Config:
         )
 
 
-def init_params(cfg: DeepseekV3Config, key: jax.Array) -> Params:
-    """Seeded normal / sqrt(fan_in) matrices, norm weights 1,
-    ``expert_bias`` 0 (float32 whatever the storage dtype: it is compared
-    with float32 scores)."""
-    keys = iter(jax.random.split(key, 2 + cfg.n_layers * 11))
-    pdt = cfg.param_dtype
+def _kinds(cfg: DeepseekV3Config) -> Tuple[bool, ...]:
+    """A layer's kind: its MLP is dense."""
+    return tuple(cfg.is_dense(li) for li in range(cfg.n_layers))
 
-    def dense(fan_in, shape):
-        return _llama._dense_init(next(keys), fan_in, shape, pdt)
 
-    def swiglu(d_in, width, lead=()):
-        return {
-            "w_gate": dense(d_in, lead + (d_in, width)),
-            "w_up": dense(d_in, lead + (d_in, width)),
-            "w_down": dense(width, lead + (width, d_in)),
-        }
-
+def _layer_rows(cfg: DeepseekV3Config, dense: bool) -> List[_decoder.Row]:
+    """The parameter table of a layer (the Megatron fsdp x tp layout: heads
+    over ``tp`` in ``wq``, ``wkv_b`` and ``wo``; the latent projection,
+    shared by all heads, is not head-sharded)."""
     d, H, rank = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
-    layers = []
-    for li in range(cfg.n_layers):
-        layer = {
-            "attn_norm": jnp.ones((d,), pdt),
-            "mlp_norm": jnp.ones((d,), pdt),
-            "wq": dense(d, (d, H * (cfg.qk_nope_dim + cfg.qk_rope_dim))),
-            "wkv_a": dense(d, (d, rank + cfg.qk_rope_dim)),
-            "kv_a_norm": jnp.ones((rank,), pdt),
-            "wkv_b": dense(rank, (rank, H * (cfg.qk_nope_dim + cfg.v_head_dim))),
-            "wo": dense(H * cfg.v_head_dim, (H * cfg.v_head_dim, d)),
-        }
-        if cfg.is_dense(li):
-            layer.update(swiglu(d, cfg.d_ff))
-        else:
-            layer.update(
-                w_router=dense(d, (d, cfg.n_experts)),
-                expert_bias=jnp.zeros((cfg.n_experts,), jnp.float32),
-                shared=swiglu(d, cfg.d_expert * cfg.n_shared_experts),
-                experts=swiglu(d, cfg.d_expert, lead=(cfg.held[1],)),
-            )
-        layers.append(layer)
-    return {
-        "embed": dense(d, (cfg.vocab, d)),
-        "layers": layers,
-        "final_norm": jnp.ones((d,), pdt),
-        "lm_head": dense(d, (d, cfg.vocab)),
-    }
+    col = _decoder.COL
+    return [
+        _decoder.ones("attn_norm", d),
+        _decoder.ones("mlp_norm", d),
+        _decoder.Row("wq", (d, H * (cfg.qk_nope_dim + cfg.qk_rope_dim)), col),
+        _decoder.Row("wkv_a", (d, rank + cfg.qk_rope_dim), P("fsdp", None)),
+        _decoder.ones("kv_a_norm", rank),
+        _decoder.Row("wkv_b", (rank, H * (cfg.qk_nope_dim + cfg.v_head_dim)), col),
+        _decoder.Row("wo", (H * cfg.v_head_dim, d), _decoder.ROW),
+        *(_decoder.swiglu_rows(d, cfg.d_ff) if dense
+          else _moe.sigmoid_expert_rows(cfg)),
+    ]
 
 
-def param_specs(cfg: DeepseekV3Config) -> Params:
-    """PartitionSpecs mirroring :func:`init_params` (the Megatron fsdp x
-    tp layout: heads over ``tp`` in ``wq``, ``wkv_b`` and ``wo``; the
-    latent projection, shared by all heads, is not head-sharded; the held
-    experts' leading axis is this chip's own)."""
-    col, row = P("fsdp", "tp"), P("tp", "fsdp")
-    swiglu = {"w_gate": col, "w_up": col, "w_down": row}
-    layers = []
-    for li in range(cfg.n_layers):
-        layer = {
-            "attn_norm": P(None), "mlp_norm": P(None), "wq": col,
-            "wkv_a": P("fsdp", None), "kv_a_norm": P(None), "wkv_b": col,
-            "wo": row,
-        }
-        if cfg.is_dense(li):
-            layer.update(swiglu)
-        else:
-            layer.update(
-                w_router=P(None, None), expert_bias=P(None),
-                shared=dict(swiglu),
-                experts={
-                    "w_gate": P(None, "fsdp", "tp"),
-                    "w_up": P(None, "fsdp", "tp"),
-                    "w_down": P(None, "tp", "fsdp"),
-                },
-            )
-        layers.append(layer)
-    return {
-        "embed": P(None, "fsdp"),
-        "layers": layers,
-        "final_norm": P(None),
-        "lm_head": P("fsdp", "tp"),
-    }
+#: ``init_params(cfg, key)`` — seeded normal / sqrt(fan_in) matrices, norm
+#: weights 1, ``expert_bias`` 0 — and ``param_specs(cfg)`` of one table.
+_TABLE = _decoder.Table(_kinds, _layer_rows, (2, 11))
+init_params, param_specs = _TABLE.init_params, _TABLE.param_specs
 
 
 def _rope_pairs(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
@@ -225,7 +173,7 @@ def _rope_pairs(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     stays de-interleaved, in q and in k alike, so their product is the
     interleaved form's."""
     x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
-    return _llama._rope(x, positions, theta)
+    return _decoder.rope(x, positions, theta)
 
 
 def _attn_block(
@@ -242,14 +190,14 @@ def _attn_block(
     dt = x.dtype
     H, nope, rank = cfg.n_heads, cfg.qk_nope_dim, cfg.kv_lora_rank
     with scope("ddl.attn"):
-        h = _llama._rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+        h = _decoder.rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         with scope("ddl.mla_q"):
             q = (h @ layer["wq"].astype(dt)).reshape(B, T, H, -1)
             q_nope = q[..., :nope]
             q_rope = _rope_pairs(q[..., nope:], positions, cfg.rope_theta)
         with scope("ddl.mla_kv_up"):
             kv_a = h @ layer["wkv_a"].astype(dt)  # (B, T, rank + rope)
-            c = _llama._rms_norm(
+            c = _decoder.rms_norm(
                 kv_a[..., :rank], layer["kv_a_norm"], cfg.norm_eps
             )
             kv = (c @ layer["wkv_b"].astype(dt)).reshape(B, T, H, -1)
@@ -274,14 +222,14 @@ def _layer_apply(
     mesh: Optional[Any],
 ):
     """One block → (x, the router's picks (B, T, topk), or ``None`` from a
-    dense layer)."""
+    dense layer, no auxiliary loss)."""
     x = _attn_block(layer, x, cfg, positions, mesh)
     if dense:  # the llama block's norm, SwiGLU and residual
-        return _llama._mlp_block(layer, x, cfg), None
+        return _decoder.mlp_block(layer, x, cfg), None, None
     with scope("ddl.moe"):
-        h = _llama._rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+        h = _decoder.rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
         out, top_e = _moe.sigmoid_expert_mlp(h, layer, cfg, mesh)
-        return x + out, top_e
+        return x + out, top_e, None
 
 
 def forward_with_choices(
@@ -293,23 +241,13 @@ def forward_with_choices(
     """(logits (B, T, vocab) float32, the expert ids every expert layer's
     router picked (L_expert, B, T, topk) — out of all ``n_experts``, held
     here or not)."""
-    dt = cfg.dtype
     positions = jnp.arange(tokens.shape[1])
-    with scope("ddl.embed"):
-        x = params["embed"].astype(dt)[tokens]
-    picks = []
-    for li, layer in enumerate(params["layers"]):
 
-        def layer_fn(x, layer, dense=cfg.is_dense(li)):
-            return _layer_apply(layer, x, cfg, positions, dense, mesh)
+    def block(dense: bool):
+        return lambda x, layer: _layer_apply(layer, x, cfg, positions, dense, mesh)
 
-        x, top_e = _remat.wrap(layer_fn, cfg.remat)(x, layer)
-        if top_e is not None:
-            picks.append(top_e)
-    logits = _llama._lm_head(params, x, cfg)
-    return logits, jnp.stack(picks) if picks else jnp.zeros(
-        (0,) + tokens.shape + (cfg.topk,), jnp.int32
-    )
+    logits, picks, _ = _decoder.forward(params, tokens, cfg, _kinds(cfg), block)
+    return logits, _decoder.stack_picks(picks, tokens, cfg.topk)
 
 
 def forward(
@@ -322,30 +260,11 @@ def forward(
     return forward_with_choices(params, tokens, cfg, mesh)[0]
 
 
-def next_token_loss(
-    params: Params,
-    tokens: jax.Array,
-    cfg: DeepseekV3Config,
-    mesh: Optional[Any] = None,
-) -> jax.Array:
-    """Mean next-token cross-entropy.  No auxiliary router loss
-    (``topk_method: noaux_tc`` balances by moving ``expert_bias``, not by
-    a term of the loss)."""
-    from ddl_tpu.models.losses import next_token_cross_entropy
+#: Mean next-token cross-entropy.  No auxiliary router loss (``topk_method:
+#: noaux_tc`` balances by moving ``expert_bias``, not by a term of the loss).
+next_token_loss = _decoder.loss_of(forward)
 
-    return next_token_cross_entropy(forward(params, tokens, cfg, mesh), tokens)
-
-
-def forward_with_cache(*args: Any, **kwargs: Any):
-    raise NotImplementedError(
-        "deepseek_v3.forward_with_cache: a latent KV cache (the normalised "
-        "latent and the shared rotary key a position) and the absorbed "
-        "decode form do not exist yet"
-    )
-
-
-def generate(*args: Any, **kwargs: Any):
-    raise NotImplementedError(
-        "deepseek_v3.generate: serving needs the latent KV cache "
-        "(see forward_with_cache)"
-    )
+forward_with_cache, generate = _decoder.no_decode(
+    "deepseek_v3", "a latent KV cache (the normalised latent and the shared "
+    "rotary key a position) and the absorbed decode form, which do not exist yet",
+)

@@ -17,12 +17,15 @@ loop on the consumer side.  This is a TPU-first functional implementation:
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ddl_tpu.models import decoder as _decoder
+from ddl_tpu.models import remat as _remat
+from ddl_tpu.models.losses import next_token_cross_entropy
 from ddl_tpu.ops.naming import scope
 
 Params = Dict[str, Any]
@@ -72,8 +75,6 @@ class LlamaConfig:
                 f"attn_impl must be 'auto', 'flash', or 'dense', "
                 f"got {self.attn_impl!r}"
             )
-        from ddl_tpu.models import remat as _remat
-
         _remat.resolve(self.remat)  # fail on junk at config build time
 
     @property
@@ -108,114 +109,45 @@ class LlamaConfig:
         return LlamaConfig()
 
 
-def _dense_init(k: jax.Array, fan_in: int, shape: Any, pdt: Any) -> jax.Array:
-    """1/sqrt(fan_in)-scaled normal init in ``pdt`` storage — shared by
-    every model family (moe reuses it like the norm/qkv blocks)."""
-    return (
-        jax.random.normal(k, shape, jnp.float32) / jnp.sqrt(fan_in)
-    ).astype(pdt)
-
-
-def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
-    """Initialise a params pytree (``cfg.param_dtype`` storage; fp32
-    master weights by default)."""
-    keys = iter(jax.random.split(key, 4 + cfg.n_layers * 7))
-    pdt = cfg.param_dtype
-
-    def dense(k, fan_in, shape):
-        return _dense_init(k, fan_in, shape, pdt)
-
-    d, hd = cfg.d_model, cfg.head_dim
-    layers = []
-    for _ in range(cfg.n_layers):
-        layers.append(
-            {
-                "attn_norm": jnp.ones((d,), pdt),
-                "wq": dense(next(keys), d, (d, cfg.n_heads * hd)),
-                "wk": dense(next(keys), d, (d, cfg.n_kv_heads * hd)),
-                "wv": dense(next(keys), d, (d, cfg.n_kv_heads * hd)),
-                "wo": dense(next(keys), cfg.n_heads * hd, (cfg.n_heads * hd, d)),
-                "mlp_norm": jnp.ones((d,), pdt),
-                "w_gate": dense(next(keys), d, (d, cfg.d_ff)),
-                "w_up": dense(next(keys), d, (d, cfg.d_ff)),
-                "w_down": dense(next(keys), cfg.d_ff, (cfg.d_ff, d)),
-                **_qk_norm_params(cfg),
-            }
-        )
-    return {
-        "embed": dense(next(keys), d, (cfg.vocab, d)),
-        "layers": layers,
-        "final_norm": jnp.ones((d,), pdt),
-        "lm_head": dense(next(keys), d, (d, cfg.vocab)),
-    }
-
-
-def _qk_norm_params(cfg: Any) -> Params:
+def qk_norm_rows(cfg: Any) -> List[_decoder.Row]:
     """A layer's two QK-norm weight vectors — present only where
     ``cfg.qk_norm`` is on (shared by the llama and moe trees)."""
     if not cfg.qk_norm:
-        return {}
-    hd, pdt = cfg.head_dim, cfg.param_dtype
-    return {
-        "q_norm": jnp.ones((cfg.n_heads * hd,), pdt),
-        "k_norm": jnp.ones((cfg.n_kv_heads * hd,), pdt),
-    }
+        return []
+    hd = cfg.head_dim
+    return [
+        _decoder.ones("q_norm", cfg.n_heads * hd),
+        _decoder.ones("k_norm", cfg.n_kv_heads * hd),
+    ]
 
 
-def _qk_norm_specs(cfg: Any) -> Params:
-    if not cfg.qk_norm:
-        return {}
-    return {"q_norm": P(None), "k_norm": P(None)}
+def attn_rows(cfg: Any) -> List[_decoder.Row]:
+    """The attention sub-block's norm and four projections (the llama and
+    moe trees)."""
+    d, hd = cfg.d_model, cfg.head_dim
+    return [
+        _decoder.ones("attn_norm", d),
+        *_decoder.attn_rows(d, cfg.n_heads * hd, cfg.n_kv_heads * hd),
+    ]
 
 
-def param_shapes(cfg: LlamaConfig) -> Params:
-    """Abstract (ShapeDtypeStruct) params pytree via ``eval_shape`` —
-    the zero-FLOP input for optimizer HBM accounting
-    (:func:`ddl_tpu.parallel.optimizer.hbm_accounting`, the
-    fits-only-with-zero1 test): a 4B-param layout prices without
-    materialising a single weight."""
-    return jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+def _layer_rows(cfg: LlamaConfig, kind: Any = None) -> List[_decoder.Row]:
+    """The parameter table of a layer."""
+    return [
+        *attn_rows(cfg),
+        _decoder.ones("mlp_norm", cfg.d_model),
+        *_decoder.swiglu_rows(cfg.d_model, cfg.d_ff),
+        *qk_norm_rows(cfg),
+    ]
 
 
-def param_specs(cfg: LlamaConfig) -> Params:
-    """PartitionSpecs mirroring init_params: fsdp shards the d_model-ish
-    axis, tp shards heads / ffn-hidden — the standard Megatron layout
-    realised declaratively (GSPMD inserts all-reduce/all-gather)."""
-    layer = {
-        "attn_norm": P(None),
-        "wq": P("fsdp", "tp"),
-        "wk": P("fsdp", "tp"),
-        "wv": P("fsdp", "tp"),
-        "wo": P("tp", "fsdp"),
-        "mlp_norm": P(None),
-        "w_gate": P("fsdp", "tp"),
-        "w_up": P("fsdp", "tp"),
-        "w_down": P("tp", "fsdp"),
-        **_qk_norm_specs(cfg),
-    }
-    return {
-        "embed": P(None, "fsdp"),
-        "layers": [dict(layer) for _ in range(cfg.n_layers)],
-        "final_norm": P(None),
-        "lm_head": P("fsdp", "tp"),
-    }
-
-
-def _rms_norm(x: jax.Array, gain: jax.Array, eps: float) -> jax.Array:
-    xf = x.astype(jnp.float32)
-    scale = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (xf * scale * gain).astype(x.dtype)
-
-
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding; x: (B, T, H, D), positions: (T,)."""
-    d_half = x.shape[-1] // 2
-    freqs = theta ** (-jnp.arange(0, d_half, dtype=jnp.float32) / d_half)
-    angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # (T, Dh)
-    cos = jnp.cos(angles)[None, :, None, :].astype(x.dtype)
-    sin = jnp.sin(angles)[None, :, None, :].astype(x.dtype)
-    x1, x2 = x[..., :d_half], x[..., d_half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+#: ``init_params(cfg, key)`` (``cfg.param_dtype`` storage; fp32 master
+#: weights by default), ``param_specs(cfg)`` and ``param_shapes(cfg)`` of one
+#: table (:class:`ddl_tpu.models.decoder.Table`); one kind of layer.
+_TABLE = _decoder.Table(lambda cfg: (None,) * cfg.n_layers, _layer_rows, (4, 7))
+init_params, param_specs, param_shapes = (
+    _TABLE.init_params, _TABLE.param_specs, _TABLE.param_shapes
+)
 
 
 def forward(
@@ -236,36 +168,18 @@ def forward(
     within each packed document (kernel-level masking; RoPE positions
     remain row-global, the common packed-training convention).
     """
-    B, T = tokens.shape
-    dt = cfg.dtype
-    positions = jnp.arange(T)
-    with scope("ddl.embed"):
-        x = params["embed"].astype(dt)[tokens]  # (B, T, D)
+    positions = jnp.arange(tokens.shape[1])
 
     def layer_fn(x: jax.Array, layer: Params) -> jax.Array:
         return _layer_apply(
             layer, x, cfg, positions, mesh=mesh, segment_ids=segment_ids
         )
 
-    # The configured remat policy (none/full/selective/dots —
-    # ddl_tpu.models.remat): what the backward pass saves vs recomputes.
-    from ddl_tpu.models import remat as _remat
-
-    layer_fn = _remat.wrap(layer_fn, cfg.remat)
-    for layer in params["layers"]:
-        x = layer_fn(x, layer)
-    return _lm_head(params, x, cfg)
+    kinds = (None,) * len(params["layers"])
+    return _decoder.forward(params, tokens, cfg, kinds, lambda _: layer_fn)[0]
 
 
-def _lm_head(params: Params, x: jax.Array, cfg: Any) -> jax.Array:
-    """Final norm + vocabulary matmul, float32 logits — the one head of
-    the four decoder families."""
-    with scope("ddl.head"):
-        x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
-        return (x @ params["lm_head"].astype(x.dtype)).astype(jnp.float32)
-
-
-def _attn_block(
+def attn_block(
     layer: Params,
     x: jax.Array,
     cfg: Any,
@@ -275,7 +189,7 @@ def _attn_block(
 ) -> jax.Array:
     """Attention sub-block (norm → qkv/rope → attention → wo residual)
     on the residual stream — the train-side twin of
-    :func:`_attn_with_cache`, shared by the llama AND moe blocks (only
+    :func:`attn_with_cache`, shared by the llama AND moe blocks (only
     the MLP that follows differs, so attention semantics cannot drift
     between families)."""
     from ddl_tpu.parallel.ring_attention import attention
@@ -283,7 +197,7 @@ def _attn_block(
     B, T = x.shape[:2]
     dt = x.dtype
     with scope("ddl.attn"):
-        h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+        h = _decoder.rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         q, k, v = _attn_qkv(layer, h, cfg, positions)
         # GQA k/v stay compact: expansion happens inside the attention
         # block, so ring attention rotates 1/rep of the bytes over ICI.
@@ -306,10 +220,10 @@ def _layer_apply(
     """One transformer block on the residual stream — the single layer
     body shared by :func:`forward` and the pipeline-parallel
     :func:`forward_pp` (same math, so pp/non-pp cannot diverge)."""
-    x = _attn_block(
+    x = attn_block(
         layer, x, cfg, positions, mesh=mesh, segment_ids=segment_ids
     )
-    return _mlp_block(layer, x, cfg)
+    return _decoder.mlp_block(layer, x, cfg)
 
 
 def _attn_qkv(layer: Params, h: jax.Array, cfg: LlamaConfig,
@@ -335,35 +249,17 @@ def _attn_qkv(layer: Params, h: jax.Array, cfg: LlamaConfig,
     def project(w: str, heads: int, norm: Optional[str] = None) -> jax.Array:
         y = h @ layer[w].astype(dt)
         if norm:
-            y = _rms_norm(y, layer[norm], cfg.norm_eps)
+            y = _decoder.rms_norm(y, layer[norm], cfg.norm_eps)
         return y.reshape(B, T, heads, cfg.head_dim)
 
     q = project("wq", nh, "q_norm" if cfg.qk_norm else None)
     k = project("wk", nkv, "k_norm" if cfg.qk_norm else None)
     v = project("wv", nkv)
     return (
-        _rope(q, positions, cfg.rope_theta),
-        _rope(k, positions, cfg.rope_theta),
+        _decoder.rope(q, positions, cfg.rope_theta),
+        _decoder.rope(k, positions, cfg.rope_theta),
         v,
     )
-
-
-def _swiglu(layer: Params, h: jax.Array) -> jax.Array:
-    """The SwiGLU core (no norm, no residual) — shared by the plain
-    block and the tp-resident stage (whose row-sharded ``w_down`` makes
-    this a PARTIAL sum completed by a psum)."""
-    dt = h.dtype
-    gate = jax.nn.silu(h @ layer["w_gate"].astype(dt))
-    up = h @ layer["w_up"].astype(dt)
-    return (gate * up) @ layer["w_down"].astype(dt)
-
-
-def _mlp_block(layer: Params, x: jax.Array, cfg: LlamaConfig) -> jax.Array:
-    """SwiGLU MLP sub-block with residual (shared by train and decode)."""
-    with scope("ddl.mlp"):
-        return x + _swiglu(
-            layer, _rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        )
 
 
 def init_cache(cfg: LlamaConfig, batch: int, max_len: int) -> Params:
@@ -402,22 +298,19 @@ def forward_with_cache(
 
     # The stacked cache buffers thread through the layers as one value
     # chain (each layer writes only its new-token slot), so XLA keeps
-    # the update in place inside the decode scan — see _attn_with_cache.
+    # the update in place inside the decode scan — see attn_with_cache.
     k_all, v_all = cache["k"], cache["v"]
     for li, layer in enumerate(params["layers"]):
-        x, k_all, v_all = _attn_with_cache(
+        x, k_all, v_all = attn_with_cache(
             layer, x, cfg, k_all, v_all, li, pos, positions, cache_idx,
         )
-        x = _mlp_block(layer, x, cfg)
+        x = _decoder.mlp_block(layer, x, cfg)
 
-    x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if last_only:
-        x = x[:, -1:]
-    logits = (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
+    logits = _decoder.lm_head(params, x[:, -1:] if last_only else x, cfg)
     return logits, {"k": k_all, "v": v_all}
 
 
-def _attn_with_cache(
+def attn_with_cache(
     layer: Params,
     x: jax.Array,
     cfg: Any,
@@ -449,7 +342,7 @@ def _attn_with_cache(
     dt = x.dtype
     rep = cfg.n_heads // cfg.n_kv_heads
     scale = 1.0 / (cfg.head_dim**0.5)
-    h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+    h = _decoder.rms_norm(x, layer["attn_norm"], cfg.norm_eps)
     q, k, v = _attn_qkv(layer, h, cfg, positions)
     k_all = jax.lax.dynamic_update_slice(
         k_all, k.astype(dt)[None], (li, 0, pos, 0, 0)
@@ -495,7 +388,7 @@ def generate(
     matmuls on the MXU); decode steps run under ``lax.scan`` with a
     static-shape KV cache — no recompilation per step, no Python loop.
     """
-    return _generate(
+    return generate_with(
         forward_with_cache, init_cache, params, prompt, cfg,
         max_new_tokens, temperature, key, top_k=top_k, top_p=top_p,
         eos_id=eos_id,
@@ -534,7 +427,7 @@ def _sample_filter(
     return logits_t
 
 
-def _generate(
+def generate_with(
     fwd_cache: Any,
     init_cache_fn: Any,
     params: Params,
@@ -648,8 +541,6 @@ def next_token_loss(
     and the loss additionally drops positions whose next token belongs to
     a different document (the cross-document boundary predictions).
     """
-    from ddl_tpu.models.losses import next_token_cross_entropy
-
     logits = forward(params, tokens, cfg, mesh, segment_ids=segment_ids)
     return next_token_cross_entropy(logits, tokens, segment_ids=segment_ids)
 
@@ -660,51 +551,20 @@ def next_token_loss(
 def stage_params(
     params: Params, n_stages: int, n_chunks: int = 1
 ) -> Params:
-    """Rearrange a :func:`init_params` pytree for pipeline parallelism.
-
-    The ``n_layers`` per-layer dicts regroup into ``n_stages`` equal
-    stages and stack into leaves with leading ``(S, L/S)`` axes —
-    :func:`ddl_tpu.parallel.pipeline_apply`'s stacked-stage layout, with
-    the S axis sharded over ``pp`` so each device stores only its own
-    stage's layers.  ``n_chunks > 1`` builds the interleaved
-    ``(S, V, L/(S·V))`` layout for ``schedule="1f1b"`` (device d chunk c
-    holds global stage c·S+d).  Embedding, final norm and lm head stay
-    outside the pipe (they run replicated over pp, before/after the
-    schedule).
-
-    Inverse-free by design: training checkpoints save THIS layout; the
-    non-pp layout is only an initialization convenience.
-    """
-    from ddl_tpu.parallel.pipeline import stack_layer_stages
-
-    return {
-        "embed": params["embed"],
-        "stages": stack_layer_stages(
-            params["layers"], n_stages, n_chunks=n_chunks
-        ),
-        "final_norm": params["final_norm"],
-        "lm_head": params["lm_head"],
-    }
+    """Rearrange a :func:`init_params` pytree for pipeline parallelism
+    (:func:`ddl_tpu.models.decoder.stage_params`: stacked stages with
+    leading ``(S, L/S)`` axes, ``(S, V, L/(S·V))`` for 1f1b; embedding,
+    final norm and lm head stay outside the pipe)."""
+    return _decoder.stage_params(params, n_stages, n_chunks)
 
 
 def pp_param_specs(
     cfg: LlamaConfig, axis: str = "pp", n_chunks: int = 1
 ) -> Params:
     """PartitionSpecs for the :func:`stage_params` layout: ``pp`` shards
-    the stage axis (at-rest storage is one stage per pp group), the
-    chunk (1f1b only) and per-stage layer axes are unsharded, and the
-    trailing axes keep the Megatron fsdp/tp layout of
+    the stage axis, the trailing axes keep the Megatron fsdp/tp layout of
     :func:`param_specs`."""
-    from ddl_tpu.parallel.pipeline import stage_spec_tree
-
-    return {
-        "embed": P(None, "fsdp"),
-        "stages": stage_spec_tree(
-            param_specs(cfg)["layers"][0], axis, n_chunks=n_chunks
-        ),
-        "final_norm": P(None),
-        "lm_head": P("fsdp", "tp"),
-    }
+    return _decoder.pp_param_specs(param_specs(cfg), axis, n_chunks)
 
 
 def _layer_apply_tp_local(
@@ -732,7 +592,7 @@ def _layer_apply_tp_local(
     dt = x.dtype
     lh = cfg.n_heads // n_tp  # local query heads
     lkv = cfg.n_kv_heads // n_tp  # local KV heads
-    h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+    h = _decoder.rms_norm(x, layer["attn_norm"], cfg.norm_eps)
     # The SAME projection/rope/SwiGLU helpers as the plain block — only
     # the head counts and the two completing psums differ, so tp-resident
     # numerics cannot drift from forward's.
@@ -748,8 +608,8 @@ def _layer_apply_tp_local(
     x = x + lax.psum(
         attn.reshape(B, T, -1) @ layer["wo"].astype(dt), tp_axis
     )
-    h = _rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-    return x + lax.psum(_swiglu(layer, h), tp_axis)
+    h = _decoder.rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+    return x + lax.psum(_decoder.swiglu(layer, h), tp_axis)
 
 
 #: Per-stage inner PartitionSpecs for tp-RESIDENT pipeline stages
@@ -834,8 +694,6 @@ def forward_pp(
         def one_layer(x: jax.Array, layer: Params) -> jax.Array:
             return _layer_apply(layer, x, cfg, positions, mesh=None)
 
-    from ddl_tpu.models import remat as _remat
-
     layer_fn = _remat.wrap(one_layer, cfg.remat)
 
     def stage_fn(stage: Params, h: jax.Array) -> jax.Array:
@@ -851,7 +709,7 @@ def forward_pp(
         stage_param_specs=_TP_STAGE_SPECS if tp_resident else None,
         schedule=schedule, n_chunks=n_chunks,
     )
-    return _lm_head(params, x, cfg)
+    return _decoder.lm_head(params, x, cfg)
 
 
 def next_token_loss_pp(
@@ -868,8 +726,6 @@ def next_token_loss_pp(
     hand :func:`ddl_tpu.parallel.train.make_train_step` (or the Trainer)
     for a pp-axis mesh; backward runs the reverse schedule through
     ``jax.grad`` automatically."""
-    from ddl_tpu.models.losses import next_token_cross_entropy
-
     logits = forward_pp(
         params, tokens, cfg, mesh, n_microbatches, axis=axis,
         schedule=schedule, n_chunks=n_chunks,

@@ -31,8 +31,8 @@ SELECTION of key blocks (``ops/sparse_attention.py``: compressed keys,
 block scores, top-k, one selection a key-value group; no gradient flows
 through it), through the one attention dispatcher either way.
 
-What is llama's is llama's: ``_rms_norm``, ``_rope``, ``_swiglu``,
-``_lm_head``, ``_dense_init``.  Serving is not here: a lightning layer's
+What every decoder shares is ``models/decoder.py``'s: ``rms_norm``,
+``rope``, ``swiglu``, the stack, the parameter table.  Serving is not here: a lightning layer's
 cache is its recurrent state, a sparse layer's its keys, values AND
 compressed keys, which nothing holds or measures, so
 :func:`forward_with_cache` and :func:`generate` raise by name; nor is a
@@ -42,13 +42,12 @@ mesh (neither the scan nor the selection is shard-mapped yet).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
-from ddl_tpu.models import llama as _llama
+from ddl_tpu.models import decoder as _decoder
 from ddl_tpu.models import remat as _remat
 from ddl_tpu.ops.lightning_attention import lightning_attention
 from ddl_tpu.ops.naming import scope
@@ -128,67 +127,36 @@ class MiniCPMSalaConfig:
         )
 
 
-def init_params(cfg: MiniCPMSalaConfig, key: jax.Array) -> Params:
-    """Seeded normal / sqrt(fan_in) matrices, unit-variance embedding rows,
-    norm weights 1."""
-    keys = iter(jax.random.split(key, 2 + cfg.n_layers * 8))
-    pdt = cfg.param_dtype
-
-    def dense(fan_in, shape):
-        return _llama._dense_init(next(keys), fan_in, shape, pdt)
-
+def _layer_rows(cfg: MiniCPMSalaConfig, kind: str) -> List[_decoder.Row]:
+    """The parameter table of a layer (the Megatron fsdp x tp layout of the
+    other families; per-head vectors replicated)."""
     d = cfg.d_model
-    layers = []
-    for kind in cfg.mixer_types:
-        if kind == LIGHTNING:
-            hd = cfg.lightning_head_dim
-            qw = kw = cfg.n_lightning_heads * hd
-        else:
-            hd = cfg.head_dim
-            qw, kw = cfg.n_heads * hd, cfg.n_kv_heads * hd
-        layer = {
-            "input_norm": jnp.ones((d,), pdt),
-            "pre_mlp_norm": jnp.ones((d,), pdt),
-            "w_gate": dense(d, (d, cfg.d_ff)),
-            "w_up": dense(d, (d, cfg.d_ff)),
-            "w_down": dense(cfg.d_ff, (cfg.d_ff, d)),
-            "wq": dense(d, (d, qw)), "wk": dense(d, (d, kw)),
-            "wv": dense(d, (d, kw)), "wg": dense(d, (d, qw)),
-            "wo": dense(qw, (qw, d)),
-            "q_norm": jnp.ones((hd,), pdt), "k_norm": jnp.ones((hd,), pdt),
-        }
-        if kind == LIGHTNING:
-            layer["o_norm"] = jnp.ones((hd,), pdt)
-        layers.append(layer)
-    return {
-        "embed": dense(1, (cfg.vocab, d)),
-        "layers": layers,
-        "final_norm": jnp.ones((d,), pdt),
-        "lm_head": dense(d, (d, cfg.vocab)),
-    }
+    if kind == LIGHTNING:
+        hd = cfg.lightning_head_dim
+        qw = kw = cfg.n_lightning_heads * hd
+    else:
+        hd = cfg.head_dim
+        qw, kw = cfg.n_heads * hd, cfg.n_kv_heads * hd
+    rows = [
+        _decoder.ones("input_norm", d),
+        _decoder.ones("pre_mlp_norm", d),
+        *_decoder.swiglu_rows(d, cfg.d_ff),
+        *_decoder.attn_rows(d, qw, kw, gated=True),
+        _decoder.ones("q_norm", hd),
+        _decoder.ones("k_norm", hd),
+    ]
+    if kind == LIGHTNING:
+        rows.append(_decoder.ones("o_norm", hd))
+    return rows
 
 
-def param_specs(cfg: MiniCPMSalaConfig) -> Params:
-    """PartitionSpecs mirroring :func:`init_params` (the Megatron fsdp x
-    tp layout of the other families; per-head vectors replicated)."""
-    col, row = P("fsdp", "tp"), P("tp", "fsdp")
-    layers = []
-    for kind in cfg.mixer_types:
-        layer = {
-            "input_norm": P(None), "pre_mlp_norm": P(None),
-            "w_gate": col, "w_up": col, "w_down": row,
-            "wq": col, "wk": col, "wv": col, "wg": col, "wo": row,
-            "q_norm": P(None), "k_norm": P(None),
-        }
-        if kind == LIGHTNING:
-            layer["o_norm"] = P(None)
-        layers.append(layer)
-    return {
-        "embed": P(None, "fsdp"),
-        "layers": layers,
-        "final_norm": P(None),
-        "lm_head": P("fsdp", "tp"),
-    }
+#: ``init_params(cfg, key)`` — seeded normal / sqrt(fan_in) matrices,
+#: unit-variance embedding rows, norm weights 1 — and ``param_specs(cfg)`` of
+#: one table.
+_TABLE = _decoder.Table(
+    lambda cfg: cfg.mixer_types, _layer_rows, (2, 8), embed_fan_in=1
+)
+init_params, param_specs = _TABLE.init_params, _TABLE.param_specs
 
 
 def _lightning_mixer(layer: Params, h: jax.Array, cfg: MiniCPMSalaConfig,
@@ -199,16 +167,16 @@ def _lightning_mixer(layer: Params, h: jax.Array, cfg: MiniCPMSalaConfig,
     H, d = cfg.n_lightning_heads, cfg.lightning_head_dim
     with scope("ddl.lightning_proj"):
         heads = lambda w: (h @ layer[w].astype(dt)).reshape(B, T, H, d)
-        q = _llama._rms_norm(heads("wq"), layer["q_norm"], eps)
-        k = _llama._rms_norm(heads("wk"), layer["k_norm"], eps)
-        q = _llama._rope(q, positions, cfg.rope_theta)
-        k = _llama._rope(k, positions, cfg.rope_theta)
+        q = _decoder.rms_norm(heads("wq"), layer["q_norm"], eps)
+        k = _decoder.rms_norm(heads("wk"), layer["k_norm"], eps)
+        q = _decoder.rope(q, positions, cfg.rope_theta)
+        k = _decoder.rope(k, positions, cfg.rope_theta)
         v = heads("wv")
     with scope("ddl.lightning_scan"):
         o = lightning_attention(q, k, v)
     with scope("ddl.lightning_out"):
         gate = jax.nn.sigmoid(h @ layer["wg"].astype(dt)).reshape(B, T, H, d)
-        y = _llama._rms_norm(o, layer["o_norm"], eps) * gate
+        y = _decoder.rms_norm(o, layer["o_norm"], eps) * gate
         return y.reshape(B, T, -1) @ layer["wo"].astype(dt)
 
 
@@ -225,8 +193,8 @@ def _sparse_mixer(layer: Params, h: jax.Array, cfg: MiniCPMSalaConfig,
         def heads(w: str, n: int) -> jax.Array:
             return (h @ layer[w].astype(dt)).reshape(B, T, n, cfg.head_dim)
 
-        q = _llama._rms_norm(heads("wq", cfg.n_heads), layer["q_norm"], eps)
-        k = _llama._rms_norm(heads("wk", cfg.n_kv_heads), layer["k_norm"], eps)
+        q = _decoder.rms_norm(heads("wq", cfg.n_heads), layer["q_norm"], eps)
+        k = _decoder.rms_norm(heads("wk", cfg.n_kv_heads), layer["k_norm"], eps)
         v = heads("wv", cfg.n_kv_heads)
         selection = None
         if T > cfg.dense_len:
@@ -247,7 +215,7 @@ def _layer_apply(layer: Params, x: jax.Array, cfg: MiniCPMSalaConfig,
     """One block of the stated mixer kind."""
     a = cfg.residual_scale
     with scope("ddl.attn" if sparse else "ddl.lightning_proj"):
-        h = _llama._rms_norm(x, layer["input_norm"], cfg.norm_eps)
+        h = _decoder.rms_norm(x, layer["input_norm"], cfg.norm_eps)
     if sparse:
         out = _sparse_mixer(layer, h, cfg, mesh)
     else:
@@ -255,8 +223,8 @@ def _layer_apply(layer: Params, x: jax.Array, cfg: MiniCPMSalaConfig,
     with scope("ddl.attn" if sparse else "ddl.lightning_out"):
         x = x + (a * out).astype(x.dtype)
     with scope("ddl.mlp"):
-        h = _llama._rms_norm(x, layer["pre_mlp_norm"], cfg.norm_eps)
-        return x + (a * _llama._swiglu(layer, h)).astype(x.dtype)
+        h = _decoder.rms_norm(x, layer["pre_mlp_norm"], cfg.norm_eps)
+        return x + (a * _decoder.swiglu(layer, h)).astype(x.dtype)
 
 
 def forward(
@@ -272,44 +240,23 @@ def forward(
             "block selection is shard-mapped over a mesh yet"
         )
     positions = jnp.arange(tokens.shape[1])
-    with scope("ddl.embed"):
-        x = params["embed"].astype(cfg.dtype)[tokens] * cfg.scale_emb
-    for layer, kind in zip(params["layers"], cfg.mixer_types):
 
-        def layer_fn(x, layer, sparse=kind == SPARSE):
-            return _layer_apply(layer, x, cfg, positions, sparse, mesh)
+    def block(kind: str):
+        return lambda x, layer: _layer_apply(
+            layer, x, cfg, positions, kind == SPARSE, mesh
+        )
 
-        x = _remat.wrap(layer_fn, cfg.remat)(x, layer)
-    # RMSNorm(x) / (d_model / dim_model_base): the division rides the norm's
-    # weight into llama's head
-    head = dict(params, final_norm=params["final_norm"].astype(jnp.float32) * (
-        cfg.dim_model_base / cfg.d_model
-    ))
-    return _llama._lm_head(head, x, cfg)
+    # logits = (RMSNorm(x) / (d_model / dim_model_base)) W_head
+    return _decoder.forward(
+        params, tokens, cfg, cfg.mixer_types, block, embed_scale=cfg.scale_emb,
+        head_scale=cfg.dim_model_base / cfg.d_model,
+    )[0]
 
 
-def next_token_loss(
-    params: Params,
-    tokens: jax.Array,
-    cfg: MiniCPMSalaConfig,
-    mesh: Optional[Any] = None,
-) -> jax.Array:
-    """Mean next-token cross-entropy."""
-    from ddl_tpu.models.losses import next_token_cross_entropy
+next_token_loss = _decoder.loss_of(forward)
 
-    return next_token_cross_entropy(forward(params, tokens, cfg, mesh), tokens)
-
-
-def forward_with_cache(*args: Any, **kwargs: Any):
-    raise NotImplementedError(
-        "minicpm_sala.forward_with_cache: a lightning-attn layer's cache is "
-        "its recurrent state and a minicpm4 layer's holds compressed keys "
-        "beside keys and values; neither exists yet"
-    )
-
-
-def generate(*args: Any, **kwargs: Any):
-    raise NotImplementedError(
-        "minicpm_sala.generate: serving needs the recurrent-state and "
-        "compressed-key caches (see forward_with_cache)"
-    )
+forward_with_cache, generate = _decoder.no_decode(
+    "minicpm_sala", "the recurrent-state and compressed-key caches: a "
+    "lightning-attn layer's is its recurrent state, a minicpm4 layer's holds "
+    "compressed keys beside keys and values; neither exists yet",
+)

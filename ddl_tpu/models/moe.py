@@ -49,13 +49,16 @@ import contextlib
 import dataclasses
 import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ddl_tpu.models import decoder as _decoder
 from ddl_tpu.models import llama as _llama
+from ddl_tpu.models import remat as _remat
+from ddl_tpu.models.losses import next_token_cross_entropy
 from ddl_tpu.ops.naming import scope
 
 Params = Dict[str, Any]
@@ -129,8 +132,6 @@ class MoeConfig:
     moe_impl: str = "auto"
 
     def __post_init__(self) -> None:
-        from ddl_tpu.models import remat as _remat
-
         _remat.resolve(self.remat)  # fail on junk at config build time
 
     @property
@@ -170,71 +171,28 @@ class MoeConfig:
         )
 
 
-def init_params(cfg: MoeConfig, key: jax.Array) -> Params:
-    # 8 dense draws per layer + embed + lm_head.
-    keys = iter(jax.random.split(key, 2 + cfg.n_layers * 8))
-    pdt = cfg.param_dtype
-
-    def dense(k, fan_in, shape):
-        return _llama._dense_init(k, fan_in, shape, pdt)
-
-    d, hd, E, F = cfg.d_model, cfg.head_dim, cfg.n_experts, cfg.d_ff
-    layers = []
-    for _ in range(cfg.n_layers):
-        layers.append(
-            {
-                "attn_norm": jnp.ones((d,), pdt),
-                "wq": dense(next(keys), d, (d, cfg.n_heads * hd)),
-                "wk": dense(next(keys), d, (d, cfg.n_kv_heads * hd)),
-                "wv": dense(next(keys), d, (d, cfg.n_kv_heads * hd)),
-                "wo": dense(next(keys), cfg.n_heads * hd, (cfg.n_heads * hd, d)),
-                "mlp_norm": jnp.ones((d,), pdt),
-                "w_router": dense(next(keys), d, (d, E)),
-                "w_gate": dense(next(keys), d, (E, d, F)),
-                "w_up": dense(next(keys), d, (E, d, F)),
-                "w_down": dense(next(keys), F, (E, F, d)),
-                **_llama._qk_norm_params(cfg),
-            }
-        )
-    return {
-        "embed": dense(next(keys), d, (cfg.vocab, d)),
-        "layers": layers,
-        "final_norm": jnp.ones((d,), pdt),
-        "lm_head": dense(next(keys), d, (d, cfg.vocab)),
-    }
+def _layer_rows(cfg: MoeConfig, kind: Any = None) -> List[_decoder.Row]:
+    """The parameter table of a layer: llama's attention rows, a router,
+    and the expert stacks — their leading E axis sharded over ``ep``,
+    within an expert the dense Megatron layout (fsdp × tp)."""
+    d, E = cfg.d_model, cfg.n_experts
+    return [
+        *_llama.attn_rows(cfg),
+        _decoder.ones("mlp_norm", d),
+        _decoder.Row("w_router", (d, E), P(None, None)),
+        *_decoder.swiglu_rows(d, cfg.d_ff, lead=(E,), lead_spec=("ep",)),
+        *_llama.qk_norm_rows(cfg),
+    ]
 
 
-def param_shapes(cfg: MoeConfig) -> Params:
-    """Abstract params pytree via ``eval_shape`` — the optimizer HBM
-    accounting input (``parallel.optimizer.hbm_accounting``); a
-    Mixtral-scale layout prices without materialising the expert
-    stacks."""
-    return jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
-
-
-def param_specs(cfg: MoeConfig) -> Params:
-    """Expert weights shard their leading E axis over ``ep``; within an
-    expert the dense Megatron layout (fsdp × tp) applies.  Axes absent from
-    the mesh are dropped by the train-step factory."""
-    layer = {
-        "attn_norm": P(None),
-        "wq": P("fsdp", "tp"),
-        "wk": P("fsdp", "tp"),
-        "wv": P("fsdp", "tp"),
-        "wo": P("tp", "fsdp"),
-        "mlp_norm": P(None),
-        "w_router": P(None, None),
-        "w_gate": P("ep", "fsdp", "tp"),
-        "w_up": P("ep", "fsdp", "tp"),
-        "w_down": P("ep", "tp", "fsdp"),
-        **_llama._qk_norm_specs(cfg),
-    }
-    return {
-        "embed": P(None, "fsdp"),
-        "layers": [dict(layer) for _ in range(cfg.n_layers)],
-        "final_norm": P(None),
-        "lm_head": P("fsdp", "tp"),
-    }
+#: ``init_params(cfg, key)`` (8 dense draws a layer + embed + lm_head),
+#: ``param_specs(cfg)`` and ``param_shapes(cfg)`` — a Mixtral-scale layout
+#: prices without materialising the expert stacks — of one table
+#: (:class:`ddl_tpu.models.decoder.Table`); one kind of layer.
+_TABLE = _decoder.Table(lambda cfg: (None,) * cfg.n_layers, _layer_rows, (2, 8))
+init_params, param_specs, param_shapes = (
+    _TABLE.init_params, _TABLE.param_specs, _TABLE.param_shapes
+)
 
 
 def _router_topk(
@@ -769,8 +727,6 @@ def _held_rows_fit(n_rows, operands):
 
 
 def _held_rows_fwd(n_rows, *operands):
-    from ddl_tpu.models import remat as _remat
-
     bounded, full = _held_rows_passes(n_rows)[0]
     out = jax.lax.cond(_held_rows_fit(n_rows, operands), bounded, full, *operands)
     # ``selective`` keeps the result where something behind the layer reads
@@ -890,6 +846,23 @@ def ragged_experts(
 # ``n_experts`` (the router's width) and ``held`` ((first, count) of them).
 
 
+def sigmoid_expert_rows(cfg: Any) -> List[_decoder.Row]:
+    """The parameters :func:`sigmoid_expert_mlp` reads of a layer: the
+    router, its selection bias (zeros, float32 whatever the storage dtype:
+    it is compared with float32 scores), the shared experts as one SwiGLU
+    and the held experts' stacks — their leading axis is this chip's own
+    and is not sharded."""
+    d = cfg.d_model
+    return [
+        _decoder.Row("w_router", (d, cfg.n_experts), P(None, None)),
+        _decoder.Row("expert_bias", (cfg.n_experts,), P(None), fill=0.0,
+                     dtype=jnp.float32),
+        *_decoder.swiglu_rows(d, cfg.d_expert * cfg.n_shared_experts, "shared."),
+        *_decoder.swiglu_rows(d, cfg.d_expert, "experts.", lead=(cfg.held[1],),
+                              lead_spec=(None,)),
+    ]
+
+
 def sigmoid_route(h: jax.Array, layer: Params, cfg: Any):
     """The router on flat tokens ``h`` (N, D): (weights (N, k) float32,
     expert ids (N, k)).  Scores leave their matmul in float32 (bf16
@@ -925,7 +898,7 @@ def sigmoid_expert_tokens(h: jax.Array, layer: Params, cfg: Any):
         top_w = jax.lax.stop_gradient(top_w)
     routed = ragged_experts(h, layer["experts"], top_w, top_e, held=held)
     with scope("ddl.moe_shared"):
-        shared = _llama._swiglu(layer["shared"], h)
+        shared = _decoder.swiglu(layer["shared"], h)
     return shared + routed, top_e
 
 
@@ -1068,19 +1041,19 @@ def _layer_apply(
     mesh: Optional[Any] = None,
     segment_ids: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One MoE block on the residual stream → (x, (2,) router losses,
-    the router's picks (B, T, topk)) — the single layer body shared by
+    """One MoE block on the residual stream → (x, the router's picks
+    (B, T, topk), (2,) router losses) — the single layer body shared by
     :func:`forward` and the pipelined :func:`forward_pp`.  The attention
-    sub-block is llama's ``_attn_block`` (one implementation across
+    sub-block is llama's ``attn_block`` (one implementation across
     families); only the MLP differs — routed experts instead of
     SwiGLU."""
-    x = _llama._attn_block(
+    x = _llama.attn_block(
         layer, x, cfg, positions, mesh=mesh, segment_ids=segment_ids
     )
     with scope("ddl.moe"):
-        h = _llama._rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+        h = _decoder.rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
         moe_out, aux, top_e = _routed_mlp(h, layer, cfg, mesh)
-        return x + moe_out, aux, top_e
+        return x + moe_out, top_e, aux
 
 
 def forward(
@@ -1124,31 +1097,20 @@ def _forward(
     (L, B, T, topk)).  A caller that drops the picks pays nothing for
     them: they are the ids the dispatch sorts by anyway."""
     cfg = _resolve_impl(cfg, mesh)
-    dt = cfg.dtype
     positions = jnp.arange(tokens.shape[1])
-    with scope("ddl.embed"):
-        x = params["embed"].astype(dt)[tokens]
-    losses = jnp.zeros((2,), jnp.float32)
 
     def layer_fn(x: jax.Array, layer: Params):
         return _layer_apply(
             layer, x, cfg, positions, mesh=mesh, segment_ids=segment_ids
         )
 
-    # Configured remat policy (ddl_tpu.models.remat): "full" recomputes
-    # the routing/dispatch/expert internals in the backward pass;
-    # "selective" additionally keeps the attention outputs saved.
-    from ddl_tpu.models import remat as _remat
-
-    layer_fn = _remat.wrap(layer_fn, cfg.remat)
-    picks = []
-    for layer in params["layers"]:
-        x, layer_losses, top_e = layer_fn(x, layer)
-        with scope("ddl.head"):  # the auxiliary losses' reduction
-            losses = losses + layer_losses
-        picks.append(top_e)
-
-    logits = _llama._lm_head(params, x, cfg)
+    # Under the remat policy "full" recomputes the routing/dispatch/expert
+    # internals in the backward pass; "selective" additionally keeps the
+    # attention outputs saved.
+    kinds = (None,) * len(params["layers"])
+    logits, picks, losses = _decoder.forward(
+        params, tokens, cfg, kinds, lambda _: layer_fn, n_aux=2
+    )
     with scope("ddl.head"):
         losses = losses / cfg.n_layers
     return logits, losses, jnp.stack(picks)
@@ -1165,22 +1127,11 @@ def _router_penalty(cfg: MoeConfig, losses: jax.Array) -> jax.Array:
 def stage_params(
     params: Params, n_stages: int, n_chunks: int = 1
 ) -> Params:
-    """Regroup an :func:`init_params` pytree for pipeline parallelism —
-    the shared ``(S, L/S)`` stage layout (interleaved ``(S, V,
-    L/(S·V))`` when ``n_chunks > 1``, for ``schedule="1f1b"``;
-    ``parallel.pipeline.stack_layer_stages``); embed and head stay
-    outside the pipe.  Expert stacks keep their leading E axis inside
-    each stage leaf: ``(S, [V,] L/S, E, ...)``."""
-    from ddl_tpu.parallel.pipeline import stack_layer_stages
-
-    return {
-        "embed": params["embed"],
-        "stages": stack_layer_stages(
-            params["layers"], n_stages, n_chunks=n_chunks
-        ),
-        "final_norm": params["final_norm"],
-        "lm_head": params["lm_head"],
-    }
+    """Regroup an :func:`init_params` pytree for pipeline parallelism
+    (:func:`ddl_tpu.models.decoder.stage_params`).  Expert stacks keep
+    their leading E axis inside each stage leaf: ``(S, [V,] L/S, E,
+    ...)``."""
+    return _decoder.stage_params(params, n_stages, n_chunks)
 
 
 def pp_param_specs(
@@ -1190,16 +1141,7 @@ def pp_param_specs(
     shards stages; within a stage the expert/Megatron layout of
     :func:`param_specs` applies (``ep`` still shards the expert axis of
     the at-rest storage)."""
-    from ddl_tpu.parallel.pipeline import stage_spec_tree
-
-    return {
-        "embed": P(None, "fsdp"),
-        "stages": stage_spec_tree(
-            param_specs(cfg)["layers"][0], axis, n_chunks=n_chunks
-        ),
-        "final_norm": P(None),
-        "lm_head": P("fsdp", "tp"),
-    }
+    return _decoder.pp_param_specs(param_specs(cfg), axis, n_chunks)
 
 
 def forward_pp(
@@ -1275,10 +1217,8 @@ def _forward_pp(
 
     def one_layer(state, layer):
         h, loss_rows = state
-        h, losses, _ = _layer_apply(layer, h, cfg, positions, mesh=None)
+        h, _, losses = _layer_apply(layer, h, cfg, positions, mesh=None)
         return h, loss_rows + losses.astype(loss_rows.dtype)
-
-    from ddl_tpu.models import remat as _remat
 
     layer_fn = _remat.wrap(one_layer, cfg.remat)
 
@@ -1296,7 +1236,7 @@ def _forward_pp(
         stage_fn, mesh, n_microbatches, axis=axis,
         schedule=schedule, n_chunks=n_chunks,
     )
-    logits = _llama._lm_head(params, x, cfg)
+    logits = _decoder.lm_head(params, x, cfg)
     # Every row of a microbatch carries that microbatch's summed router
     # losses; the row-mean is the microbatch-mean, normalized per layer
     # as in the non-pp forward.
@@ -1315,8 +1255,6 @@ def next_token_loss_pp(
 ) -> jax.Array:
     """Cross-entropy + weighted router losses over the pipelined
     forward."""
-    from ddl_tpu.models.losses import next_token_cross_entropy
-
     logits, losses = _forward_pp(
         params, tokens, cfg, mesh, n_microbatches, axis, schedule, n_chunks
     )
@@ -1330,7 +1268,7 @@ def next_token_loss_pp(
 def init_cache(cfg: MoeConfig, batch: int, max_len: int) -> Params:
     """Per-layer KV cache buffers for autoregressive decoding — THE
     llama cache layout (one delegation, so the layout backing the shared
-    ``_attn_with_cache`` math cannot drift between families); the routed
+    ``attn_with_cache`` math cannot drift between families); the routed
     MLP needs no cache of its own, routing re-decides per decoded
     token."""
     return _llama.init_cache(cfg, batch, max_len)
@@ -1347,7 +1285,7 @@ def forward_with_cache(
     """Cached MoE forward (prefill: T = prompt length; decode: T = 1).
 
     The attention sub-block is the shared cache math
-    (``llama._attn_with_cache``: compact GQA cache, causal-position
+    (``llama.attn_with_cache``: compact GQA cache, causal-position
     mask); each decoded token then routes through the SAME top-k gate
     and dispatch impl as training (``cfg.moe_impl``; ``auto`` is
     dropless ragged here, there being no mesh — via
@@ -1375,17 +1313,14 @@ def forward_with_cache(
     # layer writes only its new-token slot so the scan updates in place.
     k_all, v_all = cache["k"], cache["v"]
     for li, layer in enumerate(params["layers"]):
-        x, k_all, v_all = _llama._attn_with_cache(
+        x, k_all, v_all = _llama.attn_with_cache(
             layer, x, cfg, k_all, v_all, li, pos, positions, cache_idx,
         )
-        h = _llama._rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+        h = _decoder.rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
         moe_out = _moe_mlp_dispatch(h.reshape(B * T, -1), layer, cfg)[0]
         x = x + moe_out.reshape(B, T, -1)
 
-    x = _llama._rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if last_only:
-        x = x[:, -1:]
-    logits = (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
+    logits = _decoder.lm_head(params, x[:, -1:] if last_only else x, cfg)
     return logits, {"k": k_all, "v": v_all}
 
 
@@ -1405,7 +1340,7 @@ def generate(
     optional top-k / nucleus top-p filtering and EOS masking; prefill
     in one cached forward, scanned decode steps), completing inference
     parity across the model families."""
-    return _llama._generate(
+    return _llama.generate_with(
         forward_with_cache, init_cache, params, prompt, cfg,
         max_new_tokens, temperature, key, top_k=top_k, top_p=top_p,
         eos_id=eos_id,
@@ -1425,8 +1360,6 @@ def next_token_loss(
     With ``segment_ids`` (packed batches), attention is segment-masked
     and cross-document boundary predictions drop from the CE, matching
     ``models.llama.next_token_loss``."""
-    from ddl_tpu.models.losses import next_token_cross_entropy
-
     logits, losses, _ = _forward(params, tokens, cfg, mesh, segment_ids)
     ce = next_token_cross_entropy(logits, tokens, segment_ids=segment_ids)
     with scope("ddl.head"):

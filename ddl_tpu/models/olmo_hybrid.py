@@ -31,8 +31,8 @@ NO position encoding (``rope_theta`` null), RMSNorm with a learned weight
 over the whole projected q and k (the form ``LlamaConfig.qk_norm`` has),
 through the one attention dispatcher.
 
-What is llama's is llama's: ``_rms_norm``, ``_swiglu``, ``_lm_head``,
-``_dense_init``.  Serving is not here: a linear layer's cache is its
+What every decoder shares is ``models/decoder.py``'s: ``rms_norm``,
+``swiglu``, the stack, the parameter table.  Serving is not here: a linear layer's cache is its
 recurrent state and the convolution's last three inputs, which nothing
 holds or measures, so :func:`forward_with_cache` and :func:`generate`
 raise by name; nor is a mesh (the scan is not shard-mapped yet).
@@ -42,13 +42,13 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ddl_tpu.models import llama as _llama
+from ddl_tpu.models import decoder as _decoder
 from ddl_tpu.models import remat as _remat
 from ddl_tpu.ops.gated_delta import gated_delta_rule
 from ddl_tpu.ops.naming import scope
@@ -114,89 +114,59 @@ class OlmoHybridConfig:
         )
 
 
-def init_params(cfg: OlmoHybridConfig, key: jax.Array) -> Params:
-    """Seeded normal / sqrt(fan_in) matrices (the convolutions' fan-in is
-    their kernel), unit-variance embedding rows (no norm stands between
-    them and the first block), norm weights 1; ``A_log = log A`` with ``A``
-    uniform on (0, 16) and ``dt_bias`` the inverse softplus of a step
-    log-uniform on (1e-3, 1e-1), as Gated DeltaNet's reference
-    implementation draws them; both float32 whatever the storage dtype."""
-    keys = iter(jax.random.split(key, 2 + cfg.n_layers * 16))
-    pdt = cfg.param_dtype
+def _draw_dt_bias(key: jax.Array, shape: Tuple[int, ...]) -> jax.Array:
+    """The inverse softplus of a step log-uniform on (1e-3, 1e-1)."""
+    step = jnp.exp(jax.random.uniform(
+        key, shape, jnp.float32, math.log(1e-3), math.log(1e-1)
+    ))
+    return step + jnp.log(-jnp.expm1(-step))
 
-    def dense(fan_in, shape):
-        return _llama._dense_init(next(keys), fan_in, shape, pdt)
 
-    d, H = cfg.d_model, cfg.n_linear_heads
+def _draw_a_log(key: jax.Array, shape: Tuple[int, ...]) -> jax.Array:
+    """``log A`` with ``A`` uniform on (0, 16)."""
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1e-3, 16.0))
+
+
+def _layer_rows(cfg: OlmoHybridConfig, kind: str) -> List[_decoder.Row]:
+    """The parameter table of a layer, in the order its keys are drawn (the
+    Megatron fsdp x tp layout of the other families; per-head vectors and
+    the convolutions' taps replicated).  The convolutions' fan-in is their
+    kernel; ``A_log`` and ``dt_bias`` are drawn as Gated DeltaNet's
+    reference implementation draws them, both float32 whatever the storage
+    dtype."""
+    Row, col, row = _decoder.Row, _decoder.COL, _decoder.ROW
+    d, H, K = cfg.d_model, cfg.n_linear_heads, cfg.conv_kernel
     qk, vv = H * cfg.linear_key_dim, H * cfg.linear_value_dim
-    layers = []
-    for kind in cfg.layer_types:
-        layer = {
-            "post_attn_norm": jnp.ones((d,), pdt),
-            "post_mlp_norm": jnp.ones((d,), pdt),
-            "w_gate": dense(d, (d, cfg.d_ff)),
-            "w_up": dense(d, (d, cfg.d_ff)),
-            "w_down": dense(cfg.d_ff, (cfg.d_ff, d)),
-        }
-        if kind == LINEAR:
-            step = jnp.exp(jax.random.uniform(
-                next(keys), (H,), jnp.float32, math.log(1e-3), math.log(1e-1)
-            ))
-            layer.update(
-                wq=dense(d, (d, qk)), wk=dense(d, (d, qk)), wv=dense(d, (d, vv)),
-                wa=dense(d, (d, H)), wb=dense(d, (d, H)), wg=dense(d, (d, vv)),
-                wo=dense(vv, (vv, d)),
-                conv_q=dense(cfg.conv_kernel, (cfg.conv_kernel, qk)),
-                conv_k=dense(cfg.conv_kernel, (cfg.conv_kernel, qk)),
-                conv_v=dense(cfg.conv_kernel, (cfg.conv_kernel, vv)),
-                A_log=jnp.log(jax.random.uniform(
-                    next(keys), (H,), jnp.float32, 1e-3, 16.0
-                )),
-                dt_bias=step + jnp.log(-jnp.expm1(-step)),
-                o_norm=jnp.ones((cfg.linear_value_dim,), pdt),
-            )
-        else:
-            layer.update(
-                wq=dense(d, (d, d)), wk=dense(d, (d, d)), wv=dense(d, (d, d)),
-                wo=dense(d, (d, d)),
-                q_norm=jnp.ones((d,), pdt), k_norm=jnp.ones((d,), pdt),
-            )
-        layers.append(layer)
-    return {
-        "embed": dense(1, (cfg.vocab, d)),
-        "layers": layers,
-        "final_norm": jnp.ones((d,), pdt),
-        "lm_head": dense(d, (d, cfg.vocab)),
-    }
+    rows = [
+        _decoder.ones("post_attn_norm", d),
+        _decoder.ones("post_mlp_norm", d),
+        *_decoder.swiglu_rows(d, cfg.d_ff),
+    ]
+    if kind == FULL:
+        return rows + [
+            *_decoder.attn_rows(d, d, d),
+            _decoder.ones("q_norm", d), _decoder.ones("k_norm", d),
+        ]
+    none = P(None, None)
+    return rows + [
+        Row("dt_bias", (H,), P(None), draw=_draw_dt_bias, dtype=jnp.float32),
+        Row("wq", (d, qk), col), Row("wk", (d, qk), col), Row("wv", (d, vv), col),
+        Row("wa", (d, H), none), Row("wb", (d, H), none), Row("wg", (d, vv), col),
+        Row("wo", (vv, d), row),
+        Row("conv_q", (K, qk), none), Row("conv_k", (K, qk), none),
+        Row("conv_v", (K, vv), none),
+        Row("A_log", (H,), P(None), draw=_draw_a_log, dtype=jnp.float32),
+        _decoder.ones("o_norm", cfg.linear_value_dim),
+    ]
 
 
-def param_specs(cfg: OlmoHybridConfig) -> Params:
-    """PartitionSpecs mirroring :func:`init_params` (the Megatron fsdp x
-    tp layout of the other families; per-head vectors and the
-    convolutions' taps replicated)."""
-    col, row = P("fsdp", "tp"), P("tp", "fsdp")
-    layers = []
-    for kind in cfg.layer_types:
-        layer = {
-            "post_attn_norm": P(None), "post_mlp_norm": P(None),
-            "w_gate": col, "w_up": col, "w_down": row,
-            "wq": col, "wk": col, "wv": col, "wo": row,
-        }
-        if kind == LINEAR:
-            layer.update(
-                wa=P(None, None), wb=P(None, None), wg=col,
-                conv_q=P(None, None), conv_k=P(None, None), conv_v=P(None, None),
-                A_log=P(None), dt_bias=P(None), o_norm=P(None),
-            )
-        else:
-            layer.update(q_norm=P(None), k_norm=P(None))
-        layers.append(layer)
-    return {
-        "embed": P(None, "fsdp"),
-        "layers": layers,
-        "final_norm": P(None),
-        "lm_head": P("fsdp", "tp"),
-    }
+#: ``init_params(cfg, key)`` — seeded normal / sqrt(fan_in) matrices,
+#: unit-variance embedding rows (no norm stands between them and the first
+#: block), norm weights 1 — and ``param_specs(cfg)`` of one table.
+_TABLE = _decoder.Table(
+    lambda cfg: cfg.layer_types, _layer_rows, (2, 16), embed_fan_in=1
+)
+init_params, param_specs = _TABLE.init_params, _TABLE.param_specs
 
 
 def _taps_sum(padded: jax.Array, taps: jax.Array, T: int, flip: bool) -> jax.Array:
@@ -278,11 +248,11 @@ def _linear_block(layer: Params, x: jax.Array, cfg: OlmoHybridConfig) -> jax.Arr
     with scope("ddl.gdn_scan"):
         o = gated_delta_rule(q.astype(dt), k.astype(dt), v, g, beta)
     with scope("ddl.gdn_out"):
-        y = _llama._rms_norm(o, layer["o_norm"], cfg.norm_eps) * jax.nn.silu(
+        y = _decoder.rms_norm(o, layer["o_norm"], cfg.norm_eps) * jax.nn.silu(
             gate.reshape(B, T, H, dv)
         )
         out = y.reshape(B, T, -1) @ layer["wo"].astype(dt)
-        return x + _llama._rms_norm(out, layer["post_attn_norm"], cfg.norm_eps)
+        return x + _decoder.rms_norm(out, layer["post_attn_norm"], cfg.norm_eps)
 
 
 def _full_block(
@@ -299,12 +269,12 @@ def _full_block(
         def heads(y: jax.Array) -> jax.Array:
             return y.reshape(B, T, cfg.n_heads, cfg.head_dim)
 
-        q = heads(_llama._rms_norm(x @ layer["wq"].astype(dt), layer["q_norm"], eps))
-        k = heads(_llama._rms_norm(x @ layer["wk"].astype(dt), layer["k_norm"], eps))
+        q = heads(_decoder.rms_norm(x @ layer["wq"].astype(dt), layer["q_norm"], eps))
+        k = heads(_decoder.rms_norm(x @ layer["wk"].astype(dt), layer["k_norm"], eps))
         v = heads(x @ layer["wv"].astype(dt))
         attn = attention(q, k, v, mesh=mesh, impl=cfg.attn_impl, causal=True)
         out = attn.reshape(B, T, -1) @ layer["wo"].astype(dt)
-        return x + _llama._rms_norm(out, layer["post_attn_norm"], eps)
+        return x + _decoder.rms_norm(out, layer["post_attn_norm"], eps)
 
 
 def _layer_apply(
@@ -314,8 +284,8 @@ def _layer_apply(
     """One block of the stated mixer kind."""
     x = _linear_block(layer, x, cfg) if linear else _full_block(layer, x, cfg, mesh)
     with scope("ddl.mlp"):
-        out = _llama._swiglu(layer, x)
-        return x + _llama._rms_norm(out, layer["post_mlp_norm"], cfg.norm_eps)
+        out = _decoder.swiglu(layer, x)
+        return x + _decoder.rms_norm(out, layer["post_mlp_norm"], cfg.norm_eps)
 
 
 def forward(
@@ -330,39 +300,16 @@ def forward(
             "olmo_hybrid.forward(mesh=): the gated-delta scan is not "
             "shard-mapped over a mesh yet"
         )
-    with scope("ddl.embed"):
-        x = params["embed"].astype(cfg.dtype)[tokens]
-    for layer, kind in zip(params["layers"], cfg.layer_types):
 
-        def layer_fn(x, layer, linear=kind == LINEAR):
-            return _layer_apply(layer, x, cfg, linear, mesh)
+    def block(kind: str):
+        return lambda x, layer: _layer_apply(layer, x, cfg, kind == LINEAR, mesh)
 
-        x = _remat.wrap(layer_fn, cfg.remat)(x, layer)
-    return _llama._lm_head(params, x, cfg)
+    return _decoder.forward(params, tokens, cfg, cfg.layer_types, block)[0]
 
 
-def next_token_loss(
-    params: Params,
-    tokens: jax.Array,
-    cfg: OlmoHybridConfig,
-    mesh: Optional[Any] = None,
-) -> jax.Array:
-    """Mean next-token cross-entropy."""
-    from ddl_tpu.models.losses import next_token_cross_entropy
+next_token_loss = _decoder.loss_of(forward)
 
-    return next_token_cross_entropy(forward(params, tokens, cfg, mesh), tokens)
-
-
-def forward_with_cache(*args: Any, **kwargs: Any):
-    raise NotImplementedError(
-        "olmo_hybrid.forward_with_cache: a linear_attention layer's cache is "
-        "its recurrent state and the convolution's last inputs, which do not "
-        "exist yet"
-    )
-
-
-def generate(*args: Any, **kwargs: Any):
-    raise NotImplementedError(
-        "olmo_hybrid.generate: serving needs the recurrent-state cache "
-        "(see forward_with_cache)"
-    )
+forward_with_cache, generate = _decoder.no_decode(
+    "olmo_hybrid", "the recurrent-state cache: a linear_attention layer's is "
+    "its recurrent state and the convolution's last inputs, which do not exist yet",
+)

@@ -61,8 +61,9 @@ accepted for back compat — ``True`` is ``"full"``, ``False`` is
   memory-heavier point between none and selective, which RE-RUNS the
   attention kernel in the backward pass (names are not its criterion).
 
-One wrap site per model family (:func:`wrap` around the layer body),
-one tag function (:func:`tag_attn_out`), called by the one attention
+One wrap site for the six decoder families (:func:`wrap` around a layer's
+body in ``models/decoder.py:forward``) and one in each pipelined forward
+(``llama.forward_pp``, ``moe._forward_pp``), one tag function (:func:`tag_attn_out`), called by the one attention
 dispatcher (``parallel.ring_attention.attention``), by the flash
 kernels' rules (the block-sparse ones and their selection among them), by
 the two scans and by the routed core's ``_held_rows`` rule, never by a model — so a value is tagged once (a second

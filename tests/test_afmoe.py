@@ -24,7 +24,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 import reference_afmoe as ref
-from ddl_tpu.models import afmoe, llama, moe
+from ddl_tpu.models import afmoe, decoder, llama, moe
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 B, T = 2, 32
@@ -166,7 +166,7 @@ def test_leaving_out_part_of_the_mathematics_fails(tokens, left_out, monkeypatch
         cfg = dataclasses.replace(cfg, layer_types=(S,) * 5, sliding_window=T)
         c = c._replace(layer_types=(S, S, S, S, F))
     elif left_out == "no_rope_in_sliding_layers":
-        monkeypatch.setattr(llama, "_rope", lambda x, positions, theta: x)
+        monkeypatch.setattr(decoder, "rope", lambda x, positions, theta: x)
     elif left_out == "no_embedding_scale":
         cfg = dataclasses.replace(cfg, mup_enabled=False)
         c = ref_config(cfg, mup_enabled=True)
@@ -187,8 +187,8 @@ def test_leaving_out_part_of_the_mathematics_fails(tokens, left_out, monkeypatch
         monkeypatch.setattr(moe, "sigmoid_route", biased)
     elif left_out == "no_shared_expert":
         monkeypatch.setattr(
-            llama, "_swiglu",
-            lambda layer, h, real=llama._swiglu: (
+            decoder, "swiglu",
+            lambda layer, h, real=decoder.swiglu: (
                 real(layer, h) if layer["w_gate"].shape[-1] != 32
                 else jnp.zeros_like(h)
             ),
@@ -229,7 +229,7 @@ def test_the_shares_add_up_to_the_uncut_layer(n_tokens, favoured):
     bound = moe.held_row_bound(n_tokens * whole.topk, 2, whole.n_experts)
     h = jax.random.normal(jax.random.key(5), (n_tokens, whole.d_model), jnp.float32)
     want, want_picks = ref.expert_mlp(h, layer, ref_config(whole))
-    shared = llama._swiglu(layer["shared"], h)
+    shared = decoder.swiglu(layer["shared"], h)
 
     routed = jnp.zeros_like(h)
     held_choices, past_the_bound = 0, []

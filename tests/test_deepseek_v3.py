@@ -22,7 +22,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 import reference_deepseek_v3 as ref
-from ddl_tpu.models import afmoe, deepseek_v3, llama, moe
+from ddl_tpu.models import afmoe, decoder, deepseek_v3, llama, moe
 from ddl_tpu.ops import flash_attention, flash_tile
 from ddl_tpu.parallel import ring_attention
 from ddl_tpu.parallel.ring_attention import attention, attention_reference
@@ -171,23 +171,23 @@ def test_leaving_out_part_of_the_mathematics_fails(tokens, left_out, monkeypatch
                 wrong * q, k, v, q_rope=wrong * q_rope, k_rope=k_rope, **kw),
         )
     elif left_out == "kv_a_norm_missing":
-        real_norm = llama._rms_norm
+        real_norm = decoder.rms_norm
         monkeypatch.setattr(
-            llama, "_rms_norm",
+            decoder, "rms_norm",
             lambda x, gain, eps: x if x.shape[-1] == 32 else real_norm(x, gain, eps),
         )
     elif left_out == "rope_on_the_nope_part":
         monkeypatch.setattr(
             ring_attention, "attention",
             lambda q, k, v, q_rope, k_rope, **kw: real_attention(
-                llama._rope(q, jnp.arange(T), 1e6), llama._rope(k, jnp.arange(T), 1e6),
+                decoder.rope(q, jnp.arange(T), 1e6), decoder.rope(k, jnp.arange(T), 1e6),
                 v, q_rope=q_rope, k_rope=k_rope, **kw),
         )
     elif left_out == "half_split_rope_in_the_reference":
         # Without the de-interleave the program's rotation pairs (x[i],
         # x[i + R/2]): another function of the same weights.
         monkeypatch.setattr(
-            deepseek_v3, "_rope_pairs", lambda x, pos, theta: llama._rope(x, pos, theta)
+            deepseek_v3, "_rope_pairs", lambda x, pos, theta: decoder.rope(x, pos, theta)
         )
     elif left_out == "bias_in_the_weights":
         monkeypatch.setattr(jax.lax, "stop_gradient", lambda x: x)
@@ -211,16 +211,16 @@ def test_leaving_out_part_of_the_mathematics_fails(tokens, left_out, monkeypatch
             return {k: x[:32] if k == "w_down" else x[:, :32] for k, x in w.items()}
 
         monkeypatch.setattr(
-            llama, "_swiglu",
-            lambda layer, h, real=llama._swiglu: (
+            decoder, "swiglu",
+            lambda layer, h, real=decoder.swiglu: (
                 real(layer, h) if layer["w_gate"].shape[-1] != 64
                 else real(half(layer), h)
             ),
         )
     elif left_out == "no_shared_expert":
         monkeypatch.setattr(
-            llama, "_swiglu",
-            lambda layer, h, real=llama._swiglu: (
+            decoder, "swiglu",
+            lambda layer, h, real=decoder.swiglu: (
                 real(layer, h) if layer["w_gate"].shape[-1] != 64
                 else jnp.zeros_like(h)
             ),
@@ -353,7 +353,7 @@ def test_the_shares_add_up_to_the_uncut_layer(n_tokens, favoured):
     bound = moe.held_row_bound(n_tokens * whole.topk, 2, whole.n_experts)
     h = jax.random.normal(jax.random.key(5), (n_tokens, whole.d_model), jnp.float32)
     want, want_picks = ref.expert_mlp(h, layer, ref_config(whole))
-    shared = llama._swiglu(layer["shared"], h)
+    shared = decoder.swiglu(layer["shared"], h)
 
     routed = jnp.zeros_like(h)
     held_choices, past_the_bound = 0, []

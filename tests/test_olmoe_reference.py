@@ -18,7 +18,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 import reference_olmoe as ref
-from ddl_tpu.models import llama, moe
+from ddl_tpu.models import decoder, llama, moe
 
 B, T = 2, 32
 
@@ -137,7 +137,7 @@ def _per_head_qkv(layer, h, cfg, positions, n_heads=None, n_kv_heads=None):
     def project(w, norm, heads):
         y = (h @ layer[w]).reshape(B_, T_, heads, cfg.head_dim)
         if norm:
-            y = llama._rms_norm(
+            y = decoder.rms_norm(
                 y, layer[norm].reshape(heads, cfg.head_dim), cfg.norm_eps
             )
         return y
@@ -145,8 +145,8 @@ def _per_head_qkv(layer, h, cfg, positions, n_heads=None, n_kv_heads=None):
     q = project("wq", "q_norm", cfg.n_heads)
     k = project("wk", "k_norm", cfg.n_kv_heads)
     return (
-        llama._rope(q, positions, cfg.rope_theta),
-        llama._rope(k, positions, cfg.rope_theta),
+        decoder.rope(q, positions, cfg.rope_theta),
+        decoder.rope(k, positions, cfg.rope_theta),
         project("wv", None, cfg.n_kv_heads),
     )
 

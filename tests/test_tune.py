@@ -200,6 +200,16 @@ class TestBreakEven:
 # ---------------------------------------------------------------------------
 
 
+#: The declared link of the cases that assert WHICH wire format wins.  The
+#: codecs are timed by wall clock on a 1 MiB sample, and the link is slow
+#: enough that no load on the host decides instead: int8 (a quarter of the
+#: bytes) loses to bf16 (half) only if its encode + decode take 0.25 * 1 MiB
+#: / 8e3 B/s = 32 s longer - more than the calibration's whole budget.  At
+#: 8e6 B/s it was 32 ms, one descheduling under six loaded test workers:
+#: tier-1 exited 1 on PRs 32, 35, 40 and 41.
+SLOW_LINK = 8e3
+
+
 class TestCalibrator:
     def test_zero_budget_decides_everything_default(self):
         m = Metrics()
@@ -228,7 +238,7 @@ class TestCalibrator:
     def test_declared_slow_link_flips_wire(self):
         cal = Calibrator(
             deadline_s=30.0,
-            link_costs=LinkCosts({(0, 1): 8e6}, source="declared"),
+            link_costs=LinkCosts({(0, 1): SLOW_LINK}, source="declared"),
             metrics=Metrics(),
         )
         tuned = cal.calibrate(LoaderConfig(wire_dtype="raw"))
@@ -238,7 +248,7 @@ class TestCalibrator:
         assert tuned.overlay["wire_dtype"] == "int8"
         # The evidence rides the decision: the measured break-even
         # table vs the declared bottleneck link.
-        assert d.signals["link_bytes_per_s"] == pytest.approx(8e6)
+        assert d.signals["link_bytes_per_s"] == pytest.approx(SLOW_LINK)
         assert any(k.startswith("break_even.") for k in d.signals)
         assert not tuned.deadline_hit
 
@@ -304,7 +314,7 @@ class TestCalibrator:
     def test_apply_overlays_without_mutating(self):
         cal = Calibrator(
             deadline_s=30.0,
-            link_costs=LinkCosts({(0, 1): 8e6}, source="declared"),
+            link_costs=LinkCosts({(0, 1): SLOW_LINK}, source="declared"),
             metrics=Metrics(),
         )
         seed = LoaderConfig(wire_dtype="raw", prefetch_depth=1)
@@ -692,7 +702,7 @@ class TestSelfTuningE2E:
         seed = LoaderConfig(wire_dtype="raw", prefetch_depth=1)
         cal = Calibrator(
             deadline_s=30.0,
-            link_costs=LinkCosts({(0, 1): 8e6}, source="declared"),
+            link_costs=LinkCosts({(0, 1): SLOW_LINK}, source="declared"),
             metrics=Metrics(),
         )
         tuned = cal.calibrate(seed)
