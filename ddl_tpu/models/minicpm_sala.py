@@ -248,7 +248,7 @@ def forward(
 
     # logits = (RMSNorm(x) / (d_model / dim_model_base)) W_head
     return _decoder.forward(
-        params, tokens, cfg, cfg.mixer_types, block, embed_scale=cfg.scale_emb,
+        params, tokens, cfg, _TABLE, block, embed_scale=cfg.scale_emb,
         head_scale=cfg.dim_model_base / cfg.d_model,
     )[0]
 

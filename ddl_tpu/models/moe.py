@@ -1107,9 +1107,8 @@ def _forward(
     # Under the remat policy "full" recomputes the routing/dispatch/expert
     # internals in the backward pass; "selective" additionally keeps the
     # attention outputs saved.
-    kinds = (None,) * len(params["layers"])
     logits, picks, losses = _decoder.forward(
-        params, tokens, cfg, kinds, lambda _: layer_fn, n_aux=2
+        params, tokens, cfg, _TABLE, lambda _: layer_fn, n_aux=2
     )
     with scope("ddl.head"):
         losses = losses / cfg.n_layers
@@ -1124,14 +1123,10 @@ def _router_penalty(cfg: MoeConfig, losses: jax.Array) -> jax.Array:
 # -- pipeline parallelism ----------------------------------------------------
 
 
-def stage_params(
-    params: Params, n_stages: int, n_chunks: int = 1
-) -> Params:
-    """Regroup an :func:`init_params` pytree for pipeline parallelism
-    (:func:`ddl_tpu.models.decoder.stage_params`).  Expert stacks keep
-    their leading E axis inside each stage leaf: ``(S, [V,] L/S, E,
-    ...)``."""
-    return _decoder.stage_params(params, n_stages, n_chunks)
+#: ``stage_params(params, n_stages, n_chunks=1)``: an :func:`init_params`
+#: pytree regrouped for pipeline parallelism.  Expert stacks keep their
+#: leading E axis inside each stage leaf: ``(S, [V,] L/S, E, ...)``.
+stage_params = _decoder.stage_params
 
 
 def pp_param_specs(
@@ -1320,7 +1315,7 @@ def forward_with_cache(
         moe_out = _moe_mlp_dispatch(h.reshape(B * T, -1), layer, cfg)[0]
         x = x + moe_out.reshape(B, T, -1)
 
-    logits = _decoder.lm_head(params, x[:, -1:] if last_only else x, cfg)
+    logits = _llama.cached_head(params, x, cfg, last_only)
     return logits, {"k": k_all, "v": v_all}
 
 

@@ -304,7 +304,7 @@ def forward(
     def block(kind: str):
         return lambda x, layer: _layer_apply(layer, x, cfg, kind == LINEAR, mesh)
 
-    return _decoder.forward(params, tokens, cfg, cfg.layer_types, block)[0]
+    return _decoder.forward(params, tokens, cfg, _TABLE, block)[0]
 
 
 next_token_loss = _decoder.loss_of(forward)

@@ -834,6 +834,52 @@ def test_initial_weights_for_a_key_are_the_parents(model):
         assert digest.hexdigest() == PARENT_INIT_SHA256[model]
 
 
+#: sha256 of the traced cache paths of the two families that decode — the
+#: prefill, which asks for the frontier's logits alone, and a one-token step
+#: — on PR 42's parent (6d42264).  The head's order is part of it: the final
+#: norm over every position, then the frontier's slice (a slice in front of
+#: the norm XLA sinks into the last layer's matmuls, and bf16 logits move).
+PARENT_DECODE_SHA256 = {
+    "mistral":
+        "d5a685a3fc7c16f13833bcf66b5c18b6628c88a11c3311ae04fb7ec73ed34af8",
+    "olmoe":
+        "9084357c374a22c6ef4c0edd37c31dad5cca7e9d936a56d3a5dab612f2c62803",
+}
+
+
+@pytest.mark.parametrize("model", DECODERS[:2])
+def test_decode_programs_trace_to_what_the_parent_traced(model):
+    mod, cfg, _ = _decoder_case(model)
+    params = jax.eval_shape(lambda: mod.init_params(cfg, jax.random.key(0)))
+    cache = jax.eval_shape(lambda: mod.init_cache(cfg, 2, 32))
+    text = "".join(
+        str(jax.make_jaxpr(
+            lambda p, t, c, pos: mod.forward_with_cache(
+                p, t, cfg, c, pos, last_only=last_only)
+        )(params, jax.ShapeDtypeStruct((2, T), jnp.int32), cache,
+          jax.ShapeDtypeStruct((), jnp.int32)))
+        for T, last_only in [(16, True), (1, False)]
+    )
+    if jax.__version__ == PARENT_JAX:
+        text = re.sub(r" at (0x[0-9a-f]+|\S+:\d+)", "", text)
+        assert hashlib.sha256(text.encode()).hexdigest() == PARENT_DECODE_SHA256[model]
+
+
+@pytest.mark.parametrize("model", DECODERS[2:])
+def test_the_train_loss_is_the_cross_entropy_of_the_familys_forward(model):
+    """What ``benchmarks/tests`` (not tier-1) reads of ``next_token_loss`` in
+    the families whose loss ``decoder.loss_of`` builds — the check's loss is
+    the model's train loss: the text, and that the ``forward`` the loss
+    closes over is the family's own."""
+    import inspect
+
+    mod, _, _ = _decoder_case(model)
+    assert "next_token_cross_entropy(forward(" in inspect.getsource(
+        mod.next_token_loss)
+    assert inspect.getclosurevars(mod.next_token_loss).nonlocals == {
+        "forward": mod.forward}
+
+
 # -- what remat="selective" keeps of a blockwise call ----------------------------
 
 CORES = {  # name: (the kernels' infix, what the layer passes besides q, k, v)
