@@ -78,6 +78,8 @@ class AfmoeConfig:
     sliding_window: int = 16
     route_norm: bool = True
     route_scale: float = 1.0
+    #: Added to the picked scores' sum where ``route_norm`` divides by it.
+    route_eps: float = 1e-20
     mup_enabled: bool = True
     #: ``(first, count)`` of the ``n_experts`` whose weights live here;
     #: ``None`` is all of them.

@@ -3,7 +3,7 @@ RoPE, SwiGLU, the head, the seeded initialiser), the walk over a stack of
 layers, and the declaration of a stack's parameters.
 
 A family (``llama``, ``moe``, ``afmoe``, ``deepseek_v3``, ``olmo_hybrid``,
-``minicpm_sala``) is a config, a parameter table, its mixers and a tuple of
+``minicpm_sala``, ``lfm2_moe``) is a config, a parameter table, its mixers and a tuple of
 layer KINDS — one hashable a layer, whatever tells its layers apart
 (``"sliding_attention"`` and dense; ``"linear_attention"``):
 
@@ -80,18 +80,36 @@ def mlp_block(layer: Params, x: jax.Array, cfg: Any) -> jax.Array:
         return x + swiglu(layer, rms_norm(x, layer["mlp_norm"], cfg.norm_eps))
 
 
+def taps_sum(padded: jax.Array, taps: jax.Array, T: int, flip: bool) -> jax.Array:
+    """The causal depthwise convolution's sum, shared by the families that
+    have one (``olmo_hybrid``'s SiLU convolutions, ``lfm2_moe``'s gated short
+    convolution) and by their backward passes: ``sum_j taps[j] * padded[:,
+    j' : j' + T]`` in float32, ``j' = j`` (or ``K - 1 - j`` with ``flip``):
+    one pass over ``padded`` (B, T + K - 1, C) read at K offsets."""
+    K = taps.shape[0]
+    taps = taps.astype(jnp.float32)
+    return sum(
+        padded[:, (K - 1 - j if flip else j):][:, :T].astype(jnp.float32) * taps[j]
+        for j in range(K)
+    )
+
+
 def lm_head(
     params: Params, x: jax.Array, cfg: Any, scale: Optional[float] = None
 ) -> jax.Array:
     """Final norm + vocabulary matmul, float32 logits — the one head of
     every decoder family.  ``scale`` multiplies the normed stream (MiniCPM's
-    ``dim_model_base / d_model``): it rides the norm's weight."""
+    ``dim_model_base / d_model``): it rides the norm's weight.  A TIED head
+    (a :class:`Table` with ``tied``: no ``lm_head`` among the parameters)
+    reads the embedding's own rows, transposed; the embedding's gradient is
+    then the sum of its two uses, the lookup's scatter-add and this matmul's."""
     gain = params["final_norm"]
     if scale is not None:
         gain = gain.astype(jnp.float32) * scale
     with scope("ddl.head"):
         x = rms_norm(x, gain, cfg.norm_eps)
-        return (x @ params["lm_head"].astype(x.dtype)).astype(jnp.float32)
+        head = params["lm_head"] if "lm_head" in params else params["embed"].T
+        return (x @ head.astype(x.dtype)).astype(jnp.float32)
 
 
 # -- the stack -----------------------------------------------------------------------
@@ -237,13 +255,13 @@ def swiglu_rows(d_in: int, width: int, prefix: str = "", lead: Tuple[int, ...] =
     ]
 
 
-def _top_rows(cfg: Any, embed_fan_in: Optional[int]) -> List[Row]:
+def _top_rows(cfg: Any, embed_fan_in: Optional[int], tied: bool) -> List[Row]:
     d = cfg.d_model
-    return [
+    rows = [
         Row("embed", (cfg.vocab, d), P(None, "fsdp"), fan_in=embed_fan_in or d),
         ones("final_norm", d),
-        Row("lm_head", (d, cfg.vocab), COL),
     ]
+    return rows if tied else rows + [Row("lm_head", (d, cfg.vocab), COL)]
 
 
 def _tree(rows: Sequence[Row], leaf: Callable[[Row], Any]) -> Params:
@@ -275,10 +293,13 @@ class Table(NamedTuple):
     n_keys: Tuple[int, int]
     #: The embedding's rows are normal / sqrt(this); ``None``: ``d_model``.
     embed_fan_in: Optional[int] = None
+    #: The head is the embedding's rows (:func:`lm_head`): no ``lm_head`` row.
+    tied: bool = False
 
     def _tree(self, cfg: Any, leaf: Callable[[Row], Any]) -> Params:
         layers = [_tree(self.layer_rows(cfg, k), leaf) for k in self.kinds(cfg)]
-        return {**_tree(_top_rows(cfg, self.embed_fan_in), leaf), "layers": layers}
+        top = _top_rows(cfg, self.embed_fan_in, self.tied)
+        return {**_tree(top, leaf), "layers": layers}
 
     def init_params(self, cfg: Any, key: jax.Array) -> Params:
         """The params pytree (``cfg.param_dtype`` storage but where a row

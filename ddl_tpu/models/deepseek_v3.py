@@ -82,6 +82,8 @@ class DeepseekV3Config:
     n_dense_layers: int = 1
     route_norm: bool = True
     route_scale: float = 1.0
+    #: Added to the picked scores' sum where ``route_norm`` divides by it.
+    route_eps: float = 1e-20
     #: ``(first, count)`` of the ``n_experts`` whose weights live here;
     #: ``None`` is all of them.
     held_experts: Optional[Tuple[int, int]] = None

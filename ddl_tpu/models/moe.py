@@ -838,26 +838,29 @@ def ragged_experts(
     return _held_rows(n_rows, *rest)
 
 
-# -- sigmoid-routed experts with a shared expert (AFMoE, DeepSeek-V3) ----------
+# -- sigmoid-routed experts, with or without a shared expert (AFMoE, DeepSeek-V3, LFM2) --
 #
 # One routine for every family that scores by sigmoid, selects under a bias,
-# normalises, scales, and adds an ungated shared expert.  ``cfg`` is the
-# family's own config; read here: ``topk``, ``route_norm``, ``route_scale``,
-# ``n_experts`` (the router's width) and ``held`` ((first, count) of them).
+# normalises, scales, and adds an ungated shared expert where it has one.
+# ``cfg`` is the family's own config; read here: ``topk``, ``route_norm``,
+# ``route_eps`` (what the normalisation adds to the picked scores' sum),
+# ``route_scale``, ``n_experts`` (the router's width), ``held`` ((first,
+# count) of them) and ``n_shared_experts`` (0: no ``shared`` rows, no
+# ``ddl.moe_shared`` op).
 
 
 def sigmoid_expert_rows(cfg: Any) -> List[_decoder.Row]:
     """The parameters :func:`sigmoid_expert_mlp` reads of a layer: the
     router, its selection bias (zeros, float32 whatever the storage dtype:
     it is compared with float32 scores), the shared experts as one SwiGLU
-    and the held experts' stacks — their leading axis is this chip's own
-    and is not sharded."""
-    d = cfg.d_model
+    (none where the family has none) and the held experts' stacks — their
+    leading axis is this chip's own and is not sharded."""
+    d, shared = cfg.d_model, cfg.d_expert * cfg.n_shared_experts
     return [
         _decoder.Row("w_router", (d, cfg.n_experts), P(None, None)),
         _decoder.Row("expert_bias", (cfg.n_experts,), P(None), fill=0.0,
                      dtype=jnp.float32),
-        *_decoder.swiglu_rows(d, cfg.d_expert * cfg.n_shared_experts, "shared."),
+        *(_decoder.swiglu_rows(d, shared, "shared.") if shared else ()),
         *_decoder.swiglu_rows(d, cfg.d_expert, "experts.", lead=(cfg.held[1],),
                               lead_spec=(None,)),
     ]
@@ -876,13 +879,13 @@ def sigmoid_route(h: jax.Array, layer: Params, cfg: Any):
     _, top_e = jax.lax.top_k(scores + bias, cfg.topk)
     top_w = jnp.take_along_axis(scores, top_e, axis=-1)
     if cfg.route_norm:
-        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + cfg.route_eps)
     return top_w * cfg.route_scale, top_e
 
 
 def sigmoid_expert_tokens(h: jax.Array, layer: Params, cfg: Any):
-    """Shared expert + the held routed experts on flat tokens (N, D):
-    (out (N, D), the router's picks (N, k))."""
+    """Shared expert (where the family has one) + the held routed experts
+    on flat tokens (N, D): (out (N, D), the router's picks (N, k))."""
     with scope("ddl.moe_route"):
         top_w, top_e = sigmoid_route(h, layer, cfg)
     # A share states its router's width: a narrow one bounds its row passes.
@@ -897,6 +900,8 @@ def sigmoid_expert_tokens(h: jax.Array, layer: Params, cfg: Any):
         # it stands, as it selects with expert_bias where it stands.
         top_w = jax.lax.stop_gradient(top_w)
     routed = ragged_experts(h, layer["experts"], top_w, top_e, held=held)
+    if not cfg.n_shared_experts:
+        return routed, top_e
     with scope("ddl.moe_shared"):
         shared = _decoder.swiglu(layer["shared"], h)
     return shared + routed, top_e
@@ -915,7 +920,8 @@ def sigmoid_expert_mlp(h: jax.Array, layer: Params, cfg: Any,
         return out.reshape(B, T, D), top_e.reshape(B, T, -1)
     from jax import shard_map
 
-    read = {k: layer[k] for k in ("w_router", "expert_bias", "shared", "experts")}
+    read = {k: layer[k] for k in ("w_router", "expert_bias", "shared", "experts")
+            if k in layer}
 
     def body(hs: jax.Array, lyr: Params):
         b, t, _ = hs.shape

@@ -169,18 +169,6 @@ _TABLE = _decoder.Table(
 init_params, param_specs = _TABLE.init_params, _TABLE.param_specs
 
 
-def _taps_sum(padded: jax.Array, taps: jax.Array, T: int, flip: bool) -> jax.Array:
-    """``sum_j taps[j] * padded[:, j' : j' + T]`` in float32, ``j' = j`` (or
-    ``K - 1 - j`` with ``flip``): one pass over ``padded`` (B, T + K - 1, C)
-    read at K offsets."""
-    K = taps.shape[0]
-    taps = taps.astype(jnp.float32)
-    return sum(
-        padded[:, (K - 1 - j if flip else j):][:, :T].astype(jnp.float32) * taps[j]
-        for j in range(K)
-    )
-
-
 @jax.custom_vjp
 def _silu_conv(x: jax.Array, taps: jax.Array) -> jax.Array:
     """``SiLU`` of the causal depthwise convolution of ``x`` (B, T, C) with
@@ -192,7 +180,7 @@ def _silu_conv(x: jax.Array, taps: jax.Array) -> jax.Array:
     pre-activation is recomputed there, not kept."""
     K, T = taps.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
-    return jax.nn.silu(_taps_sum(padded, taps, T, False)).astype(x.dtype)
+    return jax.nn.silu(_decoder.taps_sum(padded, taps, T, False)).astype(x.dtype)
 
 
 def _silu_conv_fwd(x, taps):
@@ -203,13 +191,13 @@ def _silu_conv_bwd(res, dy):
     x, taps = res
     K, T = taps.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
-    pre = _taps_sum(padded, taps, T, False)
+    pre = _decoder.taps_sum(padded, taps, T, False)
     sig = jax.nn.sigmoid(pre)
     d_pre = dy.astype(jnp.float32) * sig * (1.0 + pre * (1.0 - sig))
     # x[t] met tap j at output t + K - 1 - j: the same sum over the
     # cotangent padded BEHIND the row, taps in reverse.
     behind = jnp.pad(d_pre, ((0, 0), (0, K - 1), (0, 0)))
-    d_x = _taps_sum(behind, taps, T, True).astype(x.dtype)
+    d_x = _decoder.taps_sum(behind, taps, T, True).astype(x.dtype)
     d_taps = jnp.stack([
         jnp.sum(d_pre * padded[:, j : j + T].astype(jnp.float32), axis=(0, 1))
         for j in range(K)

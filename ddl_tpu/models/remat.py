@@ -55,21 +55,31 @@ accepted for back compat — ``True`` is ``"full"``, ``False`` is
   pass (AFMoE's post-MLP norm) the recomputation holds no ``cond`` and
   no routed forward; where nothing does (DeepSeek-V3's stack) nothing is
   kept.
+  A gated short convolution (``models/lfm2_moe.py:gated_short_conv``)
+  tags ``BCx = h W_in`` inside its ``custom_vjp`` rule, the ONE value its
+  backward pass reads (it computes ``a = B * u`` and the taps' output
+  again): ``2·N·3·D`` bytes of bf16 a conv layer (201 MB at 16,384 tokens
+  of 2048), for which the rematerialised backward runs neither the operator
+  norm nor the layer's largest matmul again (25 MFLOP a token).  ``y`` is
+  NOT saved: ``W_out``'s gradient needs it, and one more bandwidth-bound
+  pass over ``BCx`` (16 KB a token) is cheaper than 67 MB a layer held from
+  the forward pass to the backward.  So a conv layer and step is two
+  forward passes of the convolution and one backward.
 - ``"dots"`` — ``jax.checkpoint_policies.dots_with_no_batch_dims_
   saveable``: save every non-batched matmul output (all weight
   projections), recompute only elementwise ops and attention — the
   memory-heavier point between none and selective, which RE-RUNS the
   attention kernel in the backward pass (names are not its criterion).
 
-One wrap site for the six decoder families (:func:`wrap` around a layer's
+One wrap site for the seven decoder families (:func:`wrap` around a layer's
 body in ``models/decoder.py:forward``) and one in each pipelined forward
 (``llama.forward_pp``, ``moe._forward_pp``), one tag function (:func:`tag_attn_out`), called by the one attention
 dispatcher (``parallel.ring_attention.attention``), by the flash
 kernels' rules (the block-sparse ones and their selection among them), by
-the two scans and by the routed core's ``_held_rows`` rule, never by a model — so a value is tagged once (a second
+the two scans, by the gated short convolution's rule and by the routed core's ``_held_rows`` rule, never by a model's layer — so a value is tagged once (a second
 tag on the same output would save it twice) and the policy semantics
 cannot drift between llama, moe, afmoe, deepseek_v3, olmo_hybrid,
-minicpm_sala and the pipelined forwards.
+minicpm_sala, lfm2_moe and the pipelined forwards.
 """
 
 from __future__ import annotations

@@ -76,6 +76,7 @@ SCOPE_NAMES = (
     "ddl.gdn_proj", "ddl.gdn_conv", "ddl.gdn_scan", "ddl.gdn_out",
     "ddl.lightning_proj", "ddl.lightning_scan", "ddl.lightning_out",
     "ddl.sparse_select",
+    "ddl.shortconv_proj", "ddl.shortconv", "ddl.shortconv_out",
     "ddl.mlp",
     "ddl.moe", "ddl.moe_route", "ddl.moe_experts", "ddl.moe_combine",
     "ddl.moe_overflow", "ddl.moe_shared",

@@ -30,7 +30,7 @@ REPO = os.path.dirname(cells.HERE)
 def _family(name):
     """(module, tiny config under selective remat, loss(params, batch))."""
     from ddl_tpu.models import (
-        afmoe, deepseek_v3, llama, minicpm_sala, moe, olmo_hybrid, vit)
+        afmoe, deepseek_v3, lfm2_moe, llama, minicpm_sala, moe, olmo_hybrid, vit)
     from ddl_tpu.ops.sparse_attention import SparseConfig
 
     common = dict(vocab=64, d_model=32, n_layers=2, n_heads=2, max_seq=16,
@@ -54,6 +54,8 @@ def _family(name):
             minicpm_sala, minicpm_sala.MiniCPMSalaConfig(
                 remat="selective", dense_len=64, sparse=SparseConfig(
                     block=16, kernel=8, stride=4, topk=4, local_blocks=2))),
+        "lfm2_moe": lambda: (
+            lfm2_moe, lfm2_moe.Lfm2MoeConfig(remat="selective", held_experts=(0, 2))),
     }[name]()
     return mod, cfg, lambda p, b: mod.next_token_loss(p, b[0], cfg)
 
@@ -93,7 +95,7 @@ OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 @pytest.mark.parametrize(
     "family", ["llama", "moe", "afmoe", "deepseek_v3", "vit", "olmo_hybrid",
-               "minicpm_sala"])
+               "minicpm_sala", "lfm2_moe"])
 def test_every_matmul_of_a_train_step_stands_under_a_scope(family):
     text = _compiled_step_text(family)
     seen = {}
@@ -135,6 +137,15 @@ def test_every_matmul_of_a_train_step_stands_under_a_scope(family):
         assert "ddl.lightning_scan" not in seen["recompute"], seen
         assert "ddl.sparse_select" in seen["forward"], seen
         assert "ddl.sparse_select" not in seen["backward"] | seen["recompute"], seen
+    if family == "lfm2_moe":
+        # A conv layer's ``W_out`` in every pass; its ``W_in`` forward and
+        # backward but never recomputed: ``selective`` saves ``BCx``, the
+        # gated short convolution's one residual.  No shared expert.
+        for which in passes:
+            assert "ddl.shortconv_out" in seen[which], seen
+            assert "ddl.moe_shared" not in seen[which], seen
+        assert "ddl.shortconv_proj" in seen["forward"] & seen["backward"], seen
+        assert "ddl.shortconv_proj" not in seen["recompute"], seen
     # The module's name is what the reduction looks for.
     assert "HloModule jit__run" in text
 
@@ -151,18 +162,22 @@ def test_the_table_is_whole():
     assert all(n.startswith("ddl.") for n in naming.SCOPE_NAMES)
     grouped = [s for scopes_ in S.GROUPS.values() for s in scopes_]
     # The benchmark's groups, and the scopes its reader counts as ``other``:
-    # exactly the eight the linear-attention and selection readers select
-    # themselves, and the routed layer's full-width fallback.
+    # exactly the eleven the linear-attention, selection and short-convolution
+    # readers select themselves, and the routed layer's full-width fallback.
     from benchmarks.layers import (
         gdn_dense_device_share, gdn_device_share, lightning_dense_device_share,
         lightning_device_share, moe_overflow_device_share,
+        shortconv_dense_device_share, shortconv_device_share,
         sparse_select_device_share)
 
     gdn = gdn_dense_device_share.DENSE_SCOPES + (gdn_device_share.SCAN_SCOPE,)
     sala = lightning_dense_device_share.DENSE_SCOPES + (
         lightning_device_share.SCAN_SCOPE, sparse_select_device_share.SELECT_SCOPE)
     overflow = (moe_overflow_device_share.OVERFLOW_SCOPE,)
-    assert sorted(grouped + list(gdn + sala + overflow)) == sorted(naming.SCOPE_NAMES)
+    conv = shortconv_dense_device_share.DENSE_SCOPES + (
+        shortconv_device_share.CONV_SCOPE,)
+    assert sorted(grouped + list(gdn + sala + overflow + conv)) == sorted(
+        naming.SCOPE_NAMES)
     with pytest.raises(AssertionError):
         naming.scope("ddl.not_in_the_table")
     # No model file names a scope past the helper.

@@ -126,6 +126,34 @@ def test_flash_attention_compiles(v5e, grad, packed):
     )
 
 
+def test_the_blockwise_flash_kernels_compile_at_head_width_64(v5e):
+    """LFM2-24B-A2B's attention layer as the ``lfm2-24b-a2b.tokens-8k`` cell
+    runs it: 2 rows x 8,192 positions, 32 query heads over 8 key heads of
+    64 - half a lane tile a head, a 64-deep score product, ``kv_repeat`` 4 -
+    through the blockwise kernels (T past the one-block path), forward and
+    both backward kernels.  Until PR 43 the blockwise kernels had compiled
+    and run at head widths 128 and 192 / 128 only; a block spec or a scratch
+    shape that assumed 128 lanes a head fails here, not on the chip."""
+    one = SingleDeviceSharding(v5e[0])
+    q = jax.ShapeDtypeStruct((2, 8192, 32, 64), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((2, 8192, 8, 64), jnp.bfloat16, sharding=one)
+
+    def attn(q, k, v):
+        return flash_attention(q, k, v, causal=True, kv_repeat=4, interpret=False)
+
+    def grads(q, k, v):
+        return jax.grad(
+            lambda *a: attn(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+        )(q, k, v)
+
+    lowered = jax.jit(grads).lower(q, kv, kv)
+    assert kernel_names(lowered.compile().as_text()) == {
+        "ddl_flash_fwd", "ddl_flash_bwd_dq", "ddl_flash_bwd_dkv"}
+    # the row's default blocks: 8 query blocks x 8 key blocks a query head,
+    # in all three kernels
+    assert mosaic_grids(lowered.as_text()) == {(2, 32, 8, 8): 3}
+
+
 def _gdn_calls(lowered_text):
     """{kernel name: (operand types, result types)} of the ``ddl_gdn_*``
     custom calls of a lowered program."""
