@@ -13,14 +13,23 @@ VMEM):
   :func:`flash_attention_with_lse`) for cross-device online-softmax
   combination — ring attention calls this kernel once per ring step and
   merges steps with the logsumexp identity.
-- Backward (the standard two-kernel flash backward): dQ accumulates over
-  KV blocks for a fixed Q block; dK/dV accumulate over Q blocks for a
-  fixed KV block.  Probabilities are recomputed from the saved logsumexp —
-  nothing quadratic is ever materialised.  An incoming lse cotangent
-  (from the ring combine) folds into the score gradient as
-  ``ds += p * dlse`` (since d lse_i / d s_ik = p_ik).  Under GQA the
-  per-Q-head dK/dV are summed over each query-head group outside the
-  kernel.
+- Backward: ONE kernel on the dK/dV grid (batch, heads, KV blocks, Q
+  blocks).  dK/dV accumulate over Q blocks for a fixed KV block, and the
+  step's ``ds`` also goes into dQ, whose float32 rows of the whole (batch,
+  head) stay in VMEM across the head's steps (``_dkv_kernel``'s ``dq``;
+  each Q block is rounded and written out once, after its last addition:
+  ``_dq_block_of``) — so a block pair's scores, probabilities and
+  ``dO Vᵀ`` are computed once, five products a pair where a ``dq`` kernel
+  beside a ``dkv`` kernel does seven.  A row whose dQ would not fit the
+  VMEM the kernel may take (``_BWD_ROW_BYTES``: past 65,536 positions at
+  128 lanes) keeps those two kernels, ``_dq_kernel`` on the forward's grid
+  and ``_dkv_kernel`` without dQ: decided from the operands' shapes alone,
+  the same bodies, the same gradients bit for bit.  Probabilities are
+  recomputed from the saved logsumexp — nothing quadratic is ever
+  materialised.  An incoming lse cotangent (from the ring combine) folds
+  into the score gradient as ``ds += p * dlse`` (since d lse_i / d s_ik =
+  p_ik).  Under GQA the per-Q-head dK/dV are summed over each query-head
+  group outside the kernel.
 - What a forward call keeps for its backward (the ``custom_vjp``
   residuals) is its output as the caller gets it, ``(B, T, H, D)``, and
   the compact logsumexp ``(B, H, T)`` float32, both tagged with the name
@@ -36,7 +45,7 @@ VMEM):
   masked future costs DMAs but no FLOPs.
 - K/V stay compact under grouped-query attention — the head index map
   divides by ``kv_repeat``.
-- A sliding ``window`` (offsets (0, 0) only) is the same three kernels on
+- A sliding ``window`` (offsets (0, 0) only) is the same kernels on
   grids whose innermost axis covers the band's blocks alone
   (``band_grid``), under the names ``ddl_flash_swa_*``.
 - Latent attention (``q_rope`` / ``k_rope``): the score is the sum of two
@@ -44,9 +53,10 @@ VMEM):
   over a rotary width whose key is ONE a position, shared by all heads
   (its index map sends every head to it, as ``kv_repeat`` sends a group);
   the scale is ``1/sqrt`` of the two widths together and the value keeps
-  the heads' own.  The same three kernels with two more operands, under
-  the names ``ddl_flash_mla_*``; ``dk_rope`` is summed over the heads
-  outside the kernel, as a GQA group's ``dk`` is.
+  the heads' own.  The same kernels with two more operands, under the
+  names ``ddl_flash_mla_*`` (dQ_rope rides the backward kernel beside dQ);
+  ``dk_rope`` is summed over the heads outside the kernel, as a GQA
+  group's ``dk`` is.
 
 The public wrappers pad ragged sequence lengths to the block size (padded
 keys are masked out, padded query rows sliced off) and fall back to
@@ -393,6 +403,20 @@ def _needs_mask_bwd(offs_ref, i, j, block_q, block_k, causal, seq_len,
     )
 
 
+def _dq_add(accs, rows, ds, k_ref, rope, precision):
+    """``dq += ds k`` (and for the latent form ``dq_rope += ds k_rope``)
+    into ``rows`` of the float32 accumulators ``accs``: the whole of the
+    ``dq`` kernel's, or in the one kernel of the backward pass Q block ``i``
+    of the row's."""
+    keys = (k_ref,) if rope is None else (k_ref, rope[1])
+    for acc, key in zip(accs, keys):
+        k = key[0, 0]
+        acc[rows] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision,
+        )
+
+
 def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                dlse_ref, dq_ref, dq_acc, *, scale: float, causal: bool,
                block_q: int, block_k: int, seq_len: int, kv_len: int,
@@ -411,7 +435,6 @@ def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             rope[3][:] = jnp.zeros_like(rope[3])
 
     def _accum(p):
-        k = k_ref[0, 0]
         v = v_ref[0, 0]
         do = do_ref[0, 0]
         dp = jax.lax.dot_general(
@@ -419,15 +442,8 @@ def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32, precision=precision,
         )  # (bq, bk) fp32
         ds = p * (dp - delta_ref[0, 0] + dlse_ref[0, 0]) * scale
-        dq_acc[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=precision,
-        )
-        if rope is not None:
-            rope[3][:] += jax.lax.dot_general(
-                ds.astype(k.dtype), rope[1][0, 0], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32, precision=precision,
-            )
+        _dq_add((dq_acc,) if rope is None else (dq_acc, rope[3]),
+                slice(None), ds, k_ref, rope, precision)
 
     _bwd_p_dispatch(
         offs_ref, q_ref, k_ref, lse_ref, i, j, _accum, scale=scale,
@@ -447,7 +463,15 @@ def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dlse_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
                 causal: bool, block_q: int, block_k: int, seq_len: int,
                 kv_len: int, precision, seg=None, window=None, band=None,
-                rope=None):
+                rope=None, dq=None):
+    """dK/dV of key block ``j`` over its Q blocks.  With ``dq`` — (dQ's
+    output refs, their float32 accumulators), one each or with dQ_rope's
+    two — the whole backward pass: the step's ``ds`` also goes into Q block
+    ``i`` of the accumulators, which hold the (batch, head)'s whole row in
+    VMEM ((Q blocks, block_q, width): zeroed at the head's first step,
+    added to in the ``dq`` kernel's order, ``j`` ascending for a fixed
+    ``i``), and Q block ``i`` is rounded and written out on the last step
+    that touches it (``_dq_final``)."""
     j = pl.program_id(2)  # KV block
     ii = pl.program_id(3)  # inner step (sequential): the Q block, or ...
     i, in_row = ii, None
@@ -461,6 +485,12 @@ def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_acc[:] = jnp.zeros_like(dv_acc)
         if rope is not None:  # (q_rope, k_rope, dk_rope, its accumulator)
             rope[3][:] = jnp.zeros_like(rope[3])
+
+    if dq is not None:
+        @pl.when((j == 0) & (ii == 0))
+        def _init_row():
+            for acc in dq[1]:
+                acc[:] = jnp.zeros_like(acc)
 
     def _accum(p):
         q = q_ref[0, 0]
@@ -484,6 +514,8 @@ def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 ds.astype(q.dtype), rope[0][0, 0], (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32, precision=precision,
             )
+        if dq is not None:
+            _dq_add(dq[1], i, ds, k_ref, rope, precision)
 
     _bwd_p_dispatch(
         offs_ref, q_ref, k_ref, lse_ref, i, j, _accum, scale=scale,
@@ -498,6 +530,44 @@ def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
         if rope is not None:
             rope[2][0, 0] = rope[3][:].astype(rope[2].dtype)
+
+    if dq is not None:
+        final = _dq_final(j, i, pl.num_programs(2), block_q, block_k, band)
+        if in_row is not None:
+            final = final & in_row
+
+        @pl.when(final)
+        def _finish_rows():
+            for out, acc in zip(*dq):
+                out[0, 0] = acc[i].astype(out.dtype)
+
+
+def _dq_final(j, i, nkb, block_q, block_k, band):
+    """True on the last step of the dK/dV grid that touches Q block ``i``:
+    the row's last key block, or with a band the block that holds the Q
+    block's last position (the diagonal's: ``band_grid``'s spans end on
+    it, and a key block's Q blocks start at the first such ``i``)."""
+    if band is None:
+        return j == nkb - 1
+    return jnp.minimum(((i + 1) * block_q - 1) // block_k, nkb - 1) == j
+
+
+def _dq_block_of(nqb, nkb, block_q, block_k, band, q_block):
+    """(key block, inner step) -> the Q block dQ's output block stands at,
+    for its index map on the dK/dV grid.  The pipeline writes an output
+    block back when the next step's index differs, so the index is the
+    step's own Q block where the step finishes it (``_dq_final``) and
+    otherwise the NEXT block to be finished - block 0 until the row's
+    last key block, or with a band the first Q block of the next key block
+    - which no step writes before its turn: every block leaves VMEM once,
+    rounded, after its last addition."""
+    def at(j, ii):
+        i = q_block(j, ii)  # clamped into the row by the band's map
+        park = 0 if band is None else jnp.minimum(
+            _band_q_block(j + 1, 0, block_q, block_k), nqb - 1)
+        return jnp.where(
+            _dq_final(j, i, nkb, block_q, block_k, band), i, park)
+    return at
 
 
 # Packed-segment kernel adapters: same bodies, two extra int32 input refs
@@ -551,6 +621,28 @@ def _dkv_kernel_mla(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dlse_ref, dk_ref, dv_ref, dk_acc, dv_acc,
                 rope=(qr_ref, kr_ref, dkr_ref, dkr_acc), **kw)
+
+
+def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dlse_ref, *refs, packed: bool, latent: bool, **kw):
+    """The one kernel of the backward pass: ``_dkv_kernel`` carrying dQ,
+    whatever the form - the packed ids and the rotary operands come after
+    the row operands as in the adapters above, dQ's outputs after dK/dV's
+    and its accumulators after theirs."""
+    refs = iter(refs)
+
+    def take(n):
+        return tuple(next(refs) for _ in range(n))
+
+    seg = take(2) if packed else None
+    qr_kr = take(2) if latent else None
+    n = 3 if latent else 2  # dk, dv (, dk_rope); dq (, dq_rope) is one fewer
+    outs, dq_outs, accs, dq_accs = take(n), take(n - 1), take(n), take(n - 1)
+    _dkv_kernel(
+        offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
+        outs[0], outs[1], accs[0], accs[1], seg=seg,
+        rope=qr_kr + (outs[2], accs[2]) if latent else None,
+        dq=(dq_outs, dq_accs), **kw)
 
 
 def _prep_rope(rope, Tq, Tk):
@@ -648,13 +740,34 @@ def _mla_scale(q, rope) -> float:
 _MLA_VMEM_LIMIT = 32 * 1024 * 1024
 
 
-def _mla_call_args(rope) -> dict:
+#: Scoped VMEM the one-kernel backward may use (a v5e's VMEM is 128 MiB):
+#: dQ's float32 rows of a whole (batch, head) come on top of what the dK/dV
+#: kernel holds - 4 MiB at 8,192 x 128, as much again for the latent form's
+#: 64-wide rotary rows (lane-padded), 8 MiB at 16,384 x 128.
+_BWD_VMEM_LIMIT = 64 * 1024 * 1024
+#: The most of it those rows may take: half, the kernel's blocks and its
+#: float32 intermediates (16 MiB of them at 1024 x 1024) keep the rest.  A
+#: longer row's backward stays the two kernels.
+_BWD_ROW_BYTES = _BWD_VMEM_LIMIT // 2
+
+
+def _dq_row_bytes(Tq: int, *widths: int) -> int:
+    """VMEM bytes of dQ's float32 accumulators for one (batch, head)'s
+    whole row, a width (D, and the rotary R) padded to the lanes each."""
+    return sum(Tq * -(-w // _LANES) * _LANES * 4 for w in widths)
+
+
+def _mla_call_args(rope, fused: bool = False) -> dict:
     """What a latent kernel's ``pallas_call`` takes besides the others'
-    arguments; nothing without the rotary operands."""
-    if rope is None:
+    arguments, or the one-kernel backward's (``fused``); nothing without
+    the rotary operands."""
+    if fused:
+        limit = _BWD_VMEM_LIMIT
+    elif rope is not None:
+        limit = _MLA_VMEM_LIMIT
+    else:
         return {}
-    return {"compiler_params": pltpu.CompilerParams(
-        vmem_limit_bytes=_MLA_VMEM_LIMIT)}
+    return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=limit)}
 
 
 def _rope_specs(rope, block_q, block_k, k_block):
@@ -828,34 +941,41 @@ def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts,
     row_spec = pl.BlockSpec(
         (1, 1, block_q, 1), lambda b, h, i, j, *_refs: (b, h, i, 0)
     )
-    dq_in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec,
-                   row_spec]
-    dq_inputs = [qt, kt, vt, dot, lse, delta, dl]
-    if packed:
-        sq_spec, sk_spec = _seg_specs(block_q, block_k)
-        dq_in_specs += [sq_spec, sk_spec]
-        dq_inputs += [_prep_seg(seg_q, Tq), _prep_seg(seg_k, Tk)]
-    dq_out_specs, dq_scratch = q_spec, [pltpu.VMEM((block_q, D), jnp.float32)]
-    dq_shape = jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype)
-    if rope is not None:
-        qr_spec, kr_spec = _rope_specs(rope, block_q, block_k, k_block)
-        dq_in_specs += [qr_spec, kr_spec]
-        dq_inputs += rope_t
-        dq_out_specs = [q_spec, qr_spec]
-        dq_scratch.append(pltpu.VMEM((block_q, R), jnp.float32))
-        dq_shape = [dq_shape, jax.ShapeDtypeStruct((B, H, Tq, R), q.dtype)]
-    dq = named_pallas_call(
-        names[0], functools.partial(dq_kernel, **common),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B, H, Tq // block_q, inner_k),
-            in_specs=dq_in_specs,
-            out_specs=dq_out_specs,
-            scratch_shapes=dq_scratch,
-        ),
-        out_shape=dq_shape,
-        interpret=interpret, **_mla_call_args(rope),
-    )(offsets, *dq_inputs)
+    # One kernel where dQ's float32 rows of a (batch, head) fit the VMEM
+    # it may take, the two kernels past that: read off the shapes alone.
+    dq_widths = (D,) if rope is None else (D, R)
+    fused = _dq_row_bytes(Tq, *dq_widths) <= _BWD_ROW_BYTES
+    if not fused:
+        dq_in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec,
+                       row_spec]
+        dq_inputs = [qt, kt, vt, dot, lse, delta, dl]
+        if packed:
+            sq_spec, sk_spec = _seg_specs(block_q, block_k)
+            dq_in_specs += [sq_spec, sk_spec]
+            dq_inputs += [_prep_seg(seg_q, Tq), _prep_seg(seg_k, Tk)]
+        dq_out_specs = q_spec
+        dq_scratch = [pltpu.VMEM((block_q, D), jnp.float32)]
+        dq_shape = jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype)
+        if rope is not None:
+            qr_spec, kr_spec = _rope_specs(rope, block_q, block_k, k_block)
+            dq_in_specs += [qr_spec, kr_spec]
+            dq_inputs += rope_t
+            dq_out_specs = [q_spec, qr_spec]
+            dq_scratch.append(pltpu.VMEM((block_q, R), jnp.float32))
+            dq_shape = [dq_shape,
+                        jax.ShapeDtypeStruct((B, H, Tq, R), q.dtype)]
+        dq = named_pallas_call(
+            names[0], functools.partial(dq_kernel, **common),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(B, H, Tq // block_q, inner_k),
+                in_specs=dq_in_specs,
+                out_specs=dq_out_specs,
+                scratch_shapes=dq_scratch,
+            ),
+            out_shape=dq_shape,
+            interpret=interpret, **_mla_call_args(rope),
+        )(offsets, *dq_inputs)
 
     # dK/dV: grid transposed so the Q axis is innermost (sequential).
     q_spec_t = pl.BlockSpec(
@@ -903,7 +1023,22 @@ def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts,
             (1, 1, block_k, R), lambda b, h, j, i, *_refs: (b, h, j, 0)))
         dkv_scratch.append(pltpu.VMEM((block_k, R), jnp.float32))
         dkv_shapes.append(jax.ShapeDtypeStruct((B, H, Tk, R), jnp.float32))
-    dk, dv, *dkr = named_pallas_call(
+    if fused:
+        # dQ rides the dK/dV grid: float32 accumulators for the (batch,
+        # head)'s whole row, and an output block that stands at the Q block
+        # being finished (``_dq_block_of``).
+        nqb, nkb = Tq // block_q, Tk // block_k
+        dq_block = _dq_block_of(
+            nqb, nkb, block_q, block_k, common.get("band"), q_block)
+        for width in dq_widths:
+            dkv_out_specs.append(pl.BlockSpec(
+                (1, 1, block_q, width),
+                lambda b, h, j, i, *_refs: (b, h, dq_block(j, i), 0)))
+            dkv_shapes.append(jax.ShapeDtypeStruct((B, H, Tq, width), q.dtype))
+            dkv_scratch.append(pltpu.VMEM((nqb, block_q, width), jnp.float32))
+        dkv_kernel = functools.partial(
+            _bwd_kernel, packed=packed, latent=rope is not None)
+    dk, dv, *more = named_pallas_call(
         names[1], functools.partial(dkv_kernel, **common),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -913,13 +1048,17 @@ def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts,
             scratch_shapes=dkv_scratch,
         ),
         out_shape=dkv_shapes,
-        interpret=interpret, **_mla_call_args(rope),
+        interpret=interpret, **_mla_call_args(rope, fused),
     )(offsets, *dkv_inputs)
+    if rope is not None:
+        dkr, *more = more
+    if fused:
+        dq = more if rope is not None else more[0]
 
     if rope is not None:
         dq, dqr = dq
         dqr = jnp.moveaxis(dqr[:, :, :T], 1, 2)
-        dkr = jnp.sum(dkr[0][:, :, :Tkv], axis=1, keepdims=True)
+        dkr = jnp.sum(dkr[:, :, :Tkv], axis=1, keepdims=True)
         dkr = jnp.moveaxis(dkr, 1, 2).astype(rope[1].dtype)
     if Tq != T:
         dq = dq[:, :, :T]

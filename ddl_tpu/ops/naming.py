@@ -34,6 +34,12 @@ from jax.experimental import pallas as pl
 
 #: The kernels' names as ``breakdown.device_ops`` shows them; the
 #: benchmark's ``flash_device_share`` reads the ``ddl_flash_`` prefix.
+#: Of the three blockwise families ``*_bwd_dkv`` is the WHOLE backward pass
+#: since PR 45 - the dK/dV grid carrying dQ (and the latent form's dQ_rope)
+#: in VMEM, five products a block pair - and ``*_bwd_dq`` appears only where
+#: a row is too long for that (``flash_attention._BWD_ROW_BYTES``: none of
+#: the benchmark's cells): a trace that holds a ``*_bwd_dq`` family took
+#: the two-kernel side.  (The block-sparse kernels keep their own pair.)
 KERNEL_NAMES = (
     "ddl_flash_fwd", "ddl_flash_bwd_dq", "ddl_flash_bwd_dkv",
     # the same kernels with a sliding window's band (``window=``)

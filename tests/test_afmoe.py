@@ -417,14 +417,13 @@ def test_the_benchmarks_reference_is_this_one():
 
 #: sha256 of the 2-step window programs below (``parallel.train.
 #: make_multistep``, adamw, selective remat, bf16 storage, T = 2048) on
-#: PR 33's tree (the child of 116395f), which changed them on purpose —
-#: selective remat saves the blockwise cores' residuals, so the backward
-#: holds no second forward kernel — and re-recorded what cc5f72a had
-#: pinned.  The two ``olmoe`` programs are PR 35's tree (the child of
-#: 8130a3b), changed on purpose: the routed layer's backward pass gathers
-#: where it scatter-added (``moe._take_copies``, ``moe._combine_copies``);
-#: the two ``mistral`` programs are PR 33's still — no routed layer, not a
-#: byte moved:
+#: PR 45's tree (the child of 90a08e5), which changed all four on purpose:
+#: a blockwise flash call's backward pass is ONE kernel, the dK/dV grid
+#: carrying dQ (``ddl_flash_bwd_dkv``; no ``ddl_flash_bwd_dq`` call is
+#: left).  Before: PR 33's tree (selective remat saves the blockwise
+#: cores' residuals, so the backward holds no second forward kernel) and,
+#: for the two ``olmoe`` programs, PR 35's (the routed layer's backward
+#: pass gathers where it scatter-added):
 #: ``tpu``: lowered for the TPU with each Mosaic kernel's serialised body
 #: taken out (it carries the file and line of every operation);
 #: ``interpreted``: with the kernels' bodies as the interpreter's HLO, line
@@ -432,13 +431,13 @@ def test_the_benchmarks_reference_is_this_one():
 PARENT_JAX = "0.9.0"
 PARENT_WINDOW_PROGRAM_SHA256 = {
     ("mistral", "tpu"):
-        "56b98523c10c798a16fdfe39c685a64fff53cc6fef8b2d40c17d0c2f95c28e91",
+        "2df52d1bc6afd15b8ea6da5a28951bea61ff49523e0a10a71a8921f4591330da",
     ("olmoe", "tpu"):
-        "4cffcd575bb1916a5ce94bf2cbe6eefc6401319449cad9273938683c39dd54cb",
+        "fc2f8019e0a81562dae20e21a3e8a9b9e94d604d6076debf70c760599aedb8c5",
     ("mistral", "interpreted"):
-        "255122d44314fd0f3455fba7ef30e9cd8847a478bdf159a8b258c8d7db9a1563",
+        "9d9d9710868eef06ed644a761c10d6f899cfa2af15cbf21569d3f287e2f4d33b",
     ("olmoe", "interpreted"):
-        "462021f792246948f54ce88aeeb3846c3bb9cca50838066026afdb17673a4513",
+        "51e2b0eae69e4d526b8ae435a85f280cd9742a93d4877b83c45ab9dbb7bb03e5",
 }
 
 
@@ -493,16 +492,17 @@ def _window_program(model, how):
 def test_the_window_programs_of_the_other_decoders_are_the_parents(model, how,
                                                                   monkeypatch):
     traced = _window_program(model, how)
-    # The old three kernels and no other, each once a layer in the scanned
-    # step: the forward not a second time for the backward pass.
+    # The causal-full kernels and no other, each once a layer in the scanned
+    # step: the forward not a second time for the backward pass, and ONE
+    # backward kernel (the dK/dV grid carrying dQ, PR 45: no ``bwd_dq``).
     with monkeypatch.context() as on_tpu:
         on_tpu.setattr(jax, "default_backend", lambda: "tpu")
         text = traced().lower(lowering_platforms=("tpu",)).as_text()
     calls = re.findall(r'kernel_name = "(ddl_flash_\w+)"', text)
     assert sorted(calls) == sorted(2 * [
-        "ddl_flash_fwd", "ddl_flash_bwd_dq", "ddl_flash_bwd_dkv"]), calls
+        "ddl_flash_fwd", "ddl_flash_bwd_dkv"]), calls
     if how == "tpu":
-        assert text.count("tpu_custom_call") == 6
+        assert text.count("tpu_custom_call") == 4
         text = re.sub(r'\\22body\\22: \\22[^\\]*\\22', "BODY", text)
     else:
         text = traced().lower().as_text()

@@ -534,14 +534,15 @@ def test_the_benchmarks_reference_is_this_one():
 
 #: sha256 of the 2-step window programs below (``parallel.train.
 #: make_multistep``, adamw, selective remat, bf16 storage, T = 2048) on
-#: PR 33's tree (the child of 116395f), which changed them on purpose —
-#: selective remat saves the blockwise cores' residuals, so the backward
-#: holds no second forward kernel — and re-recorded what 6b7d062 had
-#: pinned.  The ``olmoe`` program is PR 35's tree (the
-#: child of 8130a3b), changed on purpose: the routed layer's backward pass
-#: gathers where it scatter-added (``moe._take_copies``,
-#: ``moe._combine_copies``); the ``mistral`` programs are PR 33's still.
-#: ``trinity`` is PR 40's tree (the child of 2b9d22c), changed on purpose: a
+#: PR 45's tree (the child of 90a08e5), which changed all eight on purpose:
+#: a blockwise flash call's backward pass is ONE kernel, the dK/dV grid
+#: carrying dQ (``ddl_flash_bwd_dkv`` / ``ddl_flash_swa_bwd_dkv``; no
+#: ``*_bwd_dq`` call is left), and nothing else of a program moved.  What
+#: the earlier recordings held each family to still reads off these:
+#: PR 33's tree (selective remat saves the blockwise cores' residuals, so
+#: the backward holds no second forward kernel), PR 35's for ``olmoe`` (the
+#: routed layer's backward pass gathers where it scatter-added).
+#: ``trinity`` was PR 40's tree (the child of 2b9d22c), changed on purpose: a
 #: share of 4 of 16 experts runs its row passes over a static bound of held
 #: rows, one ``cond`` a pass with the full-width code as the other branch
 #: (``moe._held_rows``); ``trinity_half`` is the same stack holding 8 of the
@@ -557,21 +558,21 @@ def test_the_benchmarks_reference_is_this_one():
 PARENT_JAX = "0.9.0"
 PARENT_WINDOW_PROGRAM_SHA256 = {
     ("mistral", "tpu"):
-        "56b98523c10c798a16fdfe39c685a64fff53cc6fef8b2d40c17d0c2f95c28e91",
+        "2df52d1bc6afd15b8ea6da5a28951bea61ff49523e0a10a71a8921f4591330da",
     ("mistral", "interpreted"):
-        "255122d44314fd0f3455fba7ef30e9cd8847a478bdf159a8b258c8d7db9a1563",
+        "9d9d9710868eef06ed644a761c10d6f899cfa2af15cbf21569d3f287e2f4d33b",
     ("olmoe", "tpu"):
-        "4cffcd575bb1916a5ce94bf2cbe6eefc6401319449cad9273938683c39dd54cb",
+        "fc2f8019e0a81562dae20e21a3e8a9b9e94d604d6076debf70c760599aedb8c5",
     ("olmoe", "interpreted"):
-        "462021f792246948f54ce88aeeb3846c3bb9cca50838066026afdb17673a4513",
+        "51e2b0eae69e4d526b8ae435a85f280cd9742a93d4877b83c45ab9dbb7bb03e5",
     ("trinity", "tpu"):
-        "3e76b0d2b2961ea40f1c6cac8aabee21e876ab918e883a27aaa19016ef046d32",
+        "d4d6d4931f660a365b6dce8399679dbbb29cf5067eebf74d1da5615b5ed80c27",
     ("trinity", "interpreted"):
-        "1ad884d400cda7420296dc7863206fb71d7a313e201246cc3df2f0acdbe10b7a",
+        "716266e0900c52e90c29b3a93b1084dd4799ddec5b8f8417838bae7a3bc4ef2d",
     ("trinity_half", "tpu"):
-        "39e8ab4d55b2a3a785c85873ed390b5000f80da38d3ef0c5f8e7904d9754b2e2",
+        "7f4a9c9df70845d67793571579176ac5b5f2b803138e490fa6bf7c540c58af71",
     ("trinity_half", "interpreted"):
-        "ef9c138582c7afd792bb72d68c5a75986c2884b5d1e93322dd6340f6eb1590b4",
+        "996b4a6e441ee5dbf616cd0982f68ae20c2d139a91eae2142c4eee151f06b413",
 }
 
 
@@ -620,7 +621,8 @@ def test_the_three_decoder_cells_window_programs_are_the_parents(model, how,
             (jax.ShapeDtypeStruct((2, 2, 2048), jnp.int32),), True)
     # The parent's kernel families and no other, each kernel once a layer
     # in the scanned step: the forward not a second time for the backward
-    # pass.
+    # pass, and ONE backward kernel (the dK/dV grid carrying dQ, PR 45: no
+    # ``*_bwd_dq`` family).
     with monkeypatch.context() as on_tpu:
         on_tpu.setattr(jax, "default_backend", lambda: "tpu")
         text = traced().lower(lowering_platforms=("tpu",)).as_text()
@@ -628,7 +630,7 @@ def test_the_three_decoder_cells_window_programs_are_the_parents(model, how,
     calls = re.findall(r'kernel_name = "(ddl_flash_\w+)"', text)
     assert sorted(calls) == sorted(
         f"ddl_flash_{kind}{kernel}" for kind in kinds
-        for kernel in ("fwd", "bwd_dq", "bwd_dkv")), calls
+        for kernel in ("fwd", "bwd_dkv")), calls
     if how == "tpu":
         assert text.count("tpu_custom_call") == len(calls)
         text = re.sub(r'\\22body\\22: \\22[^\\]*\\22', "BODY", text)
@@ -646,13 +648,16 @@ def test_the_three_decoder_cells_window_programs_are_the_parents(model, how,
 
 def test_the_benchmarks_kernel_call_counts_are_the_lowered_steps(monkeypatch):
     """The program's own train step under the cell's remat policy, lowered
-    for the TPU: three layers, each latent kernel once a layer — since
-    PR 33 the forward too, its output and logsumexp being what
-    ``selective`` saves.  ``benchmarks/lib/mla_flops.MLA_CALLS_PER_LAYER``
-    (what ``mla_roofline_share`` multiplies by) still says ``fwd: 2``
-    there: a ``benchmark`` PR's to follow (ROADMAP, Measurement gaps);
-    until then that share over-reads, and this test states the count the
-    table has to come to."""
+    for the TPU: three layers, the forward kernel once a layer — since
+    PR 33, its output and logsumexp being what ``selective`` saves — and
+    ONE backward kernel a layer, the dK/dV grid carrying dQ and dQ_rope
+    (PR 45): no ``ddl_flash_mla_bwd_dq`` family.
+    ``benchmarks/lib/mla_flops.MLA_CALLS_PER_LAYER`` (what
+    ``mla_roofline_share`` multiplies by) still says ``fwd: 2`` there,
+    counts a ``bwd_dq`` family no cell runs and gives ``bwd_dkv`` the four
+    products it had: a ``benchmark`` PR's to follow (ROADMAP M9); until
+    then that share misreads, and this test states the counts the table
+    has to come to."""
     import collections
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -670,7 +675,7 @@ def test_the_benchmarks_kernel_call_counts_are_the_lowered_steps(monkeypatch):
     got = collections.Counter(re.findall(r'kernel_name = "(ddl_flash_\w+)"', text))
     assert dict(got) == {
         "ddl_flash_mla_" + kernel: 3 * calls
-        for kernel, calls in {"fwd": 1, "bwd_dq": 1, "bwd_dkv": 1}.items()
+        for kernel, calls in {"fwd": 1, "bwd_dkv": 1}.items()
     }
 
 

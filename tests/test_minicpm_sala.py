@@ -149,6 +149,7 @@ def test_a_short_row_lowers_to_the_dense_program(monkeypatch):
     count = lambda text: collections.Counter(re.findall(r'kernel_name = "(ddl_\w+)"', text))
     short = count(lower(1024))
     assert short["ddl_flash_fwd"] == 1 and short["ddl_flash_bwd_dkv"] == 1, short
+    assert "ddl_flash_bwd_dq" not in short  # one backward kernel (PR 45)
     assert not any("sparse" in name for name in short), short
     # ... and one position more takes the sparse path; under ``selective``
     # every forward kernel runs once (the rule PR 33 / PR 36 set)

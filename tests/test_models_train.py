@@ -775,9 +775,11 @@ class TestRematPolicies:
         assert remat.ATTN_OUT_NAME in str(tagged)
         # ... and with the flash kernels what is saved is what their
         # backward reads: the train step lowered for the TPU holds one
-        # forward kernel a layer under "selective", two under "full".
+        # forward kernel a layer under "selective", two under "full", and
+        # one backward kernel a layer (the dK/dV grid carrying dQ: no
+        # ``ddl_flash_bwd_dq`` family).
         assert self._flash_kernels("selective") == {
-            "ddl_flash_fwd": 2, "ddl_flash_bwd_dq": 2, "ddl_flash_bwd_dkv": 2}
+            "ddl_flash_fwd": 2, "ddl_flash_bwd_dkv": 2}
         assert self._flash_kernels("full")["ddl_flash_fwd"] == 4
 
     def _flash_kernels(self, policy):

@@ -6,6 +6,7 @@ the same code path compiles via Mosaic.
 
 import collections
 import hashlib
+import importlib
 import re
 
 import jax
@@ -581,8 +582,10 @@ def test_tile_under_sharded_local_attention(rng, what):
 
 
 TILE = {"ddl_flash_tile_fwd", "ddl_flash_tile_bwd"}
-BLOCK = {"ddl_flash_fwd", "ddl_flash_bwd_dq", "ddl_flash_bwd_dkv"}
-SWA = {"ddl_flash_swa_fwd", "ddl_flash_swa_bwd_dq", "ddl_flash_swa_bwd_dkv"}
+# Forward once, ONE backward kernel - the dK/dV grid carrying dQ (PR 45) -
+# and no ``*_bwd_dq`` family at any shape a cell or a test below runs.
+BLOCK = {"ddl_flash_fwd", "ddl_flash_bwd_dkv"}
+SWA = {"ddl_flash_swa_fwd", "ddl_flash_swa_bwd_dkv"}
 
 
 def _grad_of(attn):
@@ -677,30 +680,32 @@ def test_which_kernels_a_call_lowers_to(case, want):
 
 
 #: sha256 of the traced train steps below, source locations and function
-#: addresses taken out, on PR 33's tree (the child of 116395f): that PR
-#: changed these programs on purpose — under selective remat the blockwise
-#: cores' residuals are saved, so the rematerialised backward holds no
-#: second forward kernel — and re-recorded what bf3b36b had pinned.
-#: ``olmoe`` is PR 35's tree (the child of 8130a3b), changed on purpose:
-#: the routed layer's two row moves are ``custom_vjp`` calls whose backward
-#: rules gather (``moe._take_copies``, ``moe._combine_copies``); ``mistral``
-#: is PR 33's still.  (The
+#: addresses taken out, on PR 45's tree (the child of 90a08e5), which changed
+#: five of them on purpose: the backward pass of a blockwise flash call is
+#: ONE kernel, the dK/dV grid carrying dQ, where a ``dq`` and a ``dkv``
+#: kernel stood (forward once, one backward kernel a layer, no ``*_bwd_dq``
+#: family; nothing else of a step moved: the parent's text with each pair's
+#: two ``pallas_call``s taken for one).  Before: PR 33's tree (selective
+#: remat saves the blockwise cores' residuals), PR 35's for ``olmoe`` (the
+#: routed layer's row moves), PR 42's parent for the four later families.
+#: ``minicpm_sala`` is that one still: its row takes the block-sparse
+#: kernels, which keep their own pair.  (The
 #: text LOWERED for the TPU will not do: Mosaic serialises each kernel with
 #: the file and line of every operation, so it changes with the checkout's
 #: path.)
 PARENT_JAX = "0.9.0"
 PARENT_STEP_SHA256 = {
     "mistral":
-        "428749c663ca7ab763f822afa339dac426ca3badca2afa6015c76fe3988bbdcd",
+        "8101cf75b6e821e2ebe0206fbd75ff7835aa267cb49e240bdcb73206c1b0b51a",
     "olmoe":
-        "f3558c9f5977a323082bfa3a7ddb1d84cfe6b9b83ee677e1df42a2c90a2df645",
-    # PR 42's parent (6d42264), recorded before PR 42 touched a model file:
+        "b49069002c6ddde1f2397067f7a306940c0d57919c0180ca9f2641309e11ba9a",
+    # (until PR 45: PR 42's parent's, recorded before it touched a model)
     "trinity":
-        "b0912109156b7916ccdf1175054dc849c9f03abbf060a678458c6d1df0c57696",
+        "56bade065d726d9bb3ded3e4722a5fe03b9eaf36e7425f8961e45f50dec8563d",
     "kanana":
-        "a8a3d511777fbc01ef0546386dda9b1a9ebe2ec940108681f534f6d30d3be983",
+        "6140a520a734e28f12c62508e020f0bab6290a69e8e48e419d450bfb3837e540",
     "olmo_hybrid":
-        "b81ccd3656983f59ed2f5033037579091062f40a37280d1d2f28bd686c74dfa2",
+        "2f62853826f3544759a431230e1d0ad7e2f23cfa8d8fdc326f2738c751093719",
     "minicpm_sala":
         "921389e4b214acba64f2eaa4cbdbbb80376b51db6d2f8cbe3b5345ae42d57239",
 }
@@ -760,8 +765,9 @@ def test_decoder_steps_trace_to_what_the_parent_traced(model, monkeypatch):
     """T = 4096 bypasses the one-block path: the train step of a decoder
     shaped like the benchmark's (GQA 2:1 for Mistral; full MHA + QK-norm
     and routed experts for OLMoE; 128-deep heads, selective remat, flash
-    kernels and all) holds the old three kernels and no other, each once
-    a layer and step — the forward too — and is, equation for equation,
+    kernels and all) holds the forward kernel and the one backward kernel
+    (the dK/dV grid carrying dQ: no ``*_bwd_dq`` family) and no other, each
+    once a layer and step — the forward too — and is, equation for equation,
     the program the commit above traced.  So are the four later families'
     (``_decoder_case``): traced, never lowered."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -778,8 +784,7 @@ def test_decoder_steps_trace_to_what_the_parent_traced(model, monkeypatch):
         "mistral": dict.fromkeys(BLOCK, 1),
         "olmoe": dict.fromkeys(BLOCK, 1),
         "trinity": {**dict.fromkeys(BLOCK, 1), **dict.fromkeys(SWA, 3)},
-        "kanana": dict.fromkeys(
-            ("ddl_flash_mla_fwd", "ddl_flash_mla_bwd_dq", "ddl_flash_mla_bwd_dkv"), 3),
+        "kanana": dict.fromkeys(("ddl_flash_mla_fwd", "ddl_flash_mla_bwd_dkv"), 3),
         "olmo_hybrid": {**dict.fromkeys(BLOCK, 1), "ddl_gdn_fwd": 3, "ddl_gdn_bwd": 3},
         "minicpm_sala": dict.fromkeys(
             ("ddl_sparse_select", "ddl_flash_sparse_fwd", "ddl_flash_sparse_bwd_dq",
@@ -917,10 +922,12 @@ def _attn_layer(core, B, T, H, D, policy, **flash_kw):
 @pytest.mark.parametrize("core", list(CORES))
 def test_forward_kernel_calls_a_layer_under_each_remat_policy(
         core, policy, forward_calls):
-    """The backward kernels read the output and the logsumexp, and
+    """The backward kernel reads the output and the logsumexp, and
     ``selective`` saves both: its backward pass runs no forward kernel,
     as with no remat at all; ``full`` and ``dots`` keep neither and run it
-    again.  Counted in two layers' train step lowered for the TPU."""
+    again.  Forward once (twice), one backward kernel a layer, no
+    ``*_bwd_dq`` family: counted in two layers' train step lowered for the
+    TPU."""
     B, T, H, D = 1, 1024, 2, 128
     layer = _attn_layer(core, B, T, H, D, policy, interpret=False)
     x = jax.ShapeDtypeStruct((B, T, H * D), jnp.bfloat16)
@@ -929,8 +936,7 @@ def test_forward_kernel_calls_a_layer_under_each_remat_policy(
         lambda w, x: jnp.sum(layer(layer(x, w), w).astype(jnp.float32))
     ), w, x)
     name = "ddl_flash_" + CORES[core][0]
-    assert got == {name + "fwd": 2 * forward_calls,
-                   name + "bwd_dq": 2, name + "bwd_dkv": 2}
+    assert got == {name + "fwd": 2 * forward_calls, name + "bwd_dkv": 2}
 
 
 @pytest.mark.parametrize("T", [1024, 1000], ids=["on_the_block", "off_the_block"])
@@ -988,3 +994,177 @@ def test_the_logsumexp_cotangent_reaches_q_and_k(rng):
                                    atol=5e-5, rtol=5e-5, err_msg=name)
         if bare is not None:  # the lse term moved it: dlse was not dropped
             assert float(jnp.max(jnp.abs(got - bare))) > 1e-3, name
+
+
+# -- the backward pass as ONE kernel: the dK/dV grid carries dQ ------------------
+
+blockwise = importlib.import_module("ddl_tpu.ops.flash_attention")
+
+
+BACKWARD_CASES = {
+    # name: (q/k/v shape arguments, the call's arguments)
+    "causal": (dict(T=96), dict()),
+    "not_causal": (dict(T=96), dict(causal=False)),
+    "band_16": (dict(T=96), dict(window=16)),
+    "band_nearly_the_row": (dict(T=96), dict(window=80)),
+    "band_wider_than_the_row": (dict(T=96), dict(window=200)),
+    "band_blocks_16x32": (dict(T=96), dict(window=40, block_q=16, block_k=32)),
+    "band_blocks_32x16": (dict(T=96), dict(window=40, block_q=32, block_k=16)),
+    "band_pads": (dict(T=70), dict(window=24)),
+    "blocks_16x32": (dict(T=96), dict(block_q=16, block_k=32)),
+    "blocks_64x16": (dict(T=128), dict(block_q=64, block_k=16)),
+    "gqa_4": (dict(T=96, H=8, Hkv=2), dict(kv_repeat=4)),
+    "packed": (dict(T=96), dict(segment_ids=(40, 30, 26))),
+    "latent": (dict(T=96), dict(rope=16)),
+    "latent_pads": (dict(T=70), dict(rope=16)),
+    "dlse": (dict(T=96, H=4, Hkv=2), dict(kv_repeat=2, lse=True)),
+    "pads": (dict(T=70), dict()),
+    "ring_step_behind": (dict(T=64), dict(lse=True, q_offset=64, k_offset=0)),
+    "ring_step_on_the_diagonal": (
+        dict(T=64), dict(lse=True, q_offset=64, k_offset=64)),
+    "ring_step_ahead": (dict(T=64), dict(lse=True, q_offset=0, k_offset=64)),
+    "ring_step_packed": (dict(T=64), dict(
+        lse=True, q_offset=32, k_offset=0, segment_ids=(20, 44))),
+}
+
+
+def _backward_case(rng, case, dtype, **flash_kw):
+    """(loss, operands) of a case: the weighted sum of the call's output -
+    and, through ``flash_attention_with_lse``, of its logsumexp, so that
+    ``dlse`` is not zero - over q, k, v (and the rotary pair)."""
+    from ddl_tpu.ops import flash_attention_with_lse
+
+    shape, kw = BACKWARD_CASES[case]
+    kw = dict(block_q=32, block_k=32) | kw | flash_kw
+    q, k, v = _qkv(rng, D=32, dtype=dtype, **shape)
+    B, T, H, _ = q.shape
+    operands = [q, k, v]
+    R, with_lse = kw.pop("rope", None), kw.pop("lse", False)
+    if R:
+        operands += [jnp.asarray(rng.standard_normal(s), dtype)
+                     for s in ((B, T, H, R), (B, T, 1, R))]
+    if "segment_ids" in kw:
+        ids = np.repeat(np.arange(len(kw["segment_ids"])), kw["segment_ids"])
+        kw["segment_ids"] = jnp.asarray(ids, jnp.int32)[None].repeat(B, 0)
+    w_out = jnp.asarray(rng.standard_normal(q.shape), jnp.float32)
+    w_lse = jnp.asarray(rng.standard_normal((B, H, T)), jnp.float32)
+
+    def loss(q, k, v, *rope):
+        if with_lse:
+            # offsets as the ring passes them: traced inside its scan
+            offs = {n: jnp.asarray(kw[n]) + 0 * q.shape[0] for n in
+                    ("q_offset", "k_offset") if n in kw}
+            out, lse = flash_attention_with_lse(q, k, v, **(kw | offs))
+            lse = jnp.where(lse > -1e29, lse, 0.0)  # an all-masked row's
+            return jnp.sum(out * w_out) + jnp.sum(lse * w_lse)
+        if rope:
+            return jnp.sum(flash_attention(
+                q, k, v, q_rope=rope[0], k_rope=rope[1], **kw) * w_out)
+        return jnp.sum(flash_attention(q, k, v, **kw) * w_out)
+
+    return loss, operands
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(BACKWARD_CASES))
+def test_the_one_kernel_backward_is_the_two_kernels(rng, monkeypatch, case, dtype):
+    """The backward pass as one kernel on the dK/dV grid, dQ's float32 rows
+    of a whole (batch, head) in VMEM, against the two kernels it replaces
+    (kept for rows too long for that: forced here) on the same operands,
+    interpreted: every gradient EQUAL, bit for bit - the same products in
+    the same order (``j`` ascending for a fixed ``i``), one rounding at the
+    end - and the one kernel's program holds no ``*_bwd_dq`` family."""
+    loss, operands = _backward_case(rng, case, dtype)
+    for_tpu, _ = _backward_case(rng, case, dtype, interpret=False)
+    argnums = tuple(range(len(operands)))
+    one = jax.grad(loss, argnums)(*operands)
+    names = _kernels(jax.grad(for_tpu, argnums), *operands)
+    with monkeypatch.context() as m:
+        # the two kernels at every shape: no VMEM for dQ's rows; and new
+        # function objects, so that nothing traced above is read again
+        m.setattr(blockwise, "_BWD_ROW_BYTES", 0)
+        two = jax.grad(lambda *a: loss(*a), argnums)(*operands)
+        names_pair = _kernels(jax.grad(lambda *a: for_tpu(*a), argnums), *operands)
+    for got, want, name in zip(one, two, ("dq", "dk", "dv", "dq_rope", "dk_rope")):
+        assert got.dtype == want.dtype == dtype and got.shape == want.shape
+        # a ring step wholly in the masked future moves nothing
+        assert (float(jnp.abs(want.astype(jnp.float32)).max()) > 0) == (
+            case != "ring_step_ahead"), name
+        np.testing.assert_array_equal(
+            np.asarray(got.astype(jnp.float32)),
+            np.asarray(want.astype(jnp.float32)), err_msg=name)
+    assert {n.rsplit("_", 1)[1] for n in names} == {"fwd", "dkv"}, names
+    assert {n.rsplit("_", 1)[1] for n in names_pair} == {"fwd", "dq", "dkv"}
+    assert len(names) == 2 and len(names_pair) == 3
+
+
+@pytest.mark.parametrize("T,D,R,want", [
+    (65536, 128, None, "one"),  # 32 MiB of float32 rows: the bound itself
+    (65536 + 1024, 128, None, "pair"),
+    (131072, 64, None, "pair"),  # 64 lanes take a tile's 128
+    (16384, 128, 64, "one"), (32768 + 1024, 128, 64, "pair"),
+])
+def test_a_row_past_the_vmem_bound_keeps_the_two_kernels(T, D, R, want):
+    """Which side a shape takes is read off the operands alone: dQ's
+    float32 rows of one (batch, head) - lane-padded, the latent form's
+    rotary rows beside them - within half the VMEM the kernel asks for."""
+    rows = blockwise._dq_row_bytes(T, *((D, R) if R else (D,)))
+    assert (rows <= blockwise._BWD_ROW_BYTES) == (want == "one")
+    assert blockwise._BWD_ROW_BYTES * 2 == blockwise._BWD_VMEM_LIMIT
+    shape = lambda h, d: jax.ShapeDtypeStruct((1, T, h, d), jnp.bfloat16)  # noqa: E731
+    args = (shape(1, D),) * 3 + ((shape(1, R), shape(1, R)) if R else ())
+
+    def loss(q, k, v, *rope):
+        rope = dict(q_rope=rope[0], k_rope=rope[1]) if rope else {}
+        return jnp.sum(flash_attention(
+            q, k, v, interpret=False, **rope).astype(jnp.float32))
+
+    names = _kernel_calls(jax.grad(loss, tuple(range(len(args)))), *args)
+    infix = "mla_" if R else ""
+    assert names == {
+        f"ddl_flash_{infix}{kernel}": 1 for kernel in
+        (("fwd", "bwd_dkv") if want == "one" else ("fwd", "bwd_dq", "bwd_dkv"))}
+
+
+@pytest.mark.parametrize("T,window,block_q,block_k", [
+    (8192, None, 1024, 1024), (8192, 2048, 512, 512), (8192, 4096, 1024, 1024),
+    (96, None, 32, 32), (96, None, 16, 32), (96, None, 32, 16),
+    (96, 16, 32, 32), (96, 80, 32, 32), (96, 40, 16, 32), (96, 40, 32, 16),
+    (70, 24, 32, 32), (100, 33, 16, 48), (100, 7, 48, 16), (512, 1, 64, 64),
+    (4096, 1024, 512, 512), (4096, 1024, 256, 512), (4096, 1024, 512, 256),
+])
+def test_every_dq_block_leaves_vmem_once_after_its_last_addition(
+        T, window, block_q, block_k):
+    """dQ's output block on the dK/dV grid (``_dq_block_of``), walked as the
+    TPU's pipeline walks it: a block is written back to HBM when the next
+    step's index differs (and after the head's last step).  Interpret mode
+    cannot see this - it stores a block every step - so the walk is done
+    here: every Q block of the row is written back exactly once, holding
+    what ``_dq_final``'s step put there, and no step that can add to a Q
+    block (any step of the grid that reaches it) comes after that step."""
+    nqb, nkb = -(-T // block_q), -(-T // block_k)
+    band = inner = None
+    q_block = lambda j, ii: ii  # noqa: E731
+    if window is not None:
+        band = blockwise.band_grid(T, window, block_q, block_k)
+        q_block = blockwise._q_block_of(block_q, block_k, band)
+    inner = nqb if band is None else band.nq
+    at = blockwise._dq_block_of(nqb, nkb, block_q, block_k, band, q_block)
+    steps = [(j, ii) for j in range(nkb) for ii in range(inner)]
+    touched, final_at, holds, written = {}, {}, {}, []
+    for n, (j, ii) in enumerate(steps):
+        i = ii if band is None else blockwise._band_q_block(
+            j, ii, block_q, block_k)
+        in_row = i < nqb
+        if in_row:
+            touched[i] = n
+            if bool(blockwise._dq_final(j, i, nkb, block_q, block_k, band)):
+                assert i not in final_at
+                final_at[i] = n
+                assert int(at(j, ii)) == i  # the step writes ITS block
+                holds[i] = "final"
+        if n + 1 == len(steps) or int(at(*steps[n + 1])) != int(at(j, ii)):
+            written.append((int(at(j, ii)), holds.get(int(at(j, ii)))))
+    assert sorted(written) == [(i, "final") for i in range(nqb)], written
+    assert final_at == touched  # a block's last visit is its final step

@@ -89,6 +89,10 @@ def v5e():
 # The chip_smoke.py train-leg attention geometry: batch 4 x seq 2048,
 # 16 query / 8 kv heads x 128, bf16.
 B, T, H, HKV, D = 4, 2048, 16, 8, 128
+# What a blockwise call's forward + backward compile to: the forward kernel
+# and ONE backward kernel, the dK/dV grid carrying dQ (PR 45) - no
+# ``ddl_flash_bwd_dq`` family at any shape compiled here.
+BLOCK_KERNELS = {"ddl_flash_fwd", "ddl_flash_bwd_dkv"}
 
 
 def _attn_args(device, packed):
@@ -120,10 +124,8 @@ def test_flash_attention_compiles(v5e, grad, packed):
             )(q, k, v)
 
     text = jax.jit(fn).lower(*_attn_args(v5e[0], packed)).compile().as_text()
-    assert kernel_names(text) == (
-        {"ddl_flash_fwd", "ddl_flash_bwd_dq", "ddl_flash_bwd_dkv"}
-        if grad else {"ddl_flash_fwd"}
-    )
+    # forward once, one backward kernel: the dK/dV grid carrying dQ
+    assert kernel_names(text) == (BLOCK_KERNELS if grad else {"ddl_flash_fwd"})
 
 
 def test_the_blockwise_flash_kernels_compile_at_head_width_64(v5e):
@@ -131,7 +133,7 @@ def test_the_blockwise_flash_kernels_compile_at_head_width_64(v5e):
     runs it: 2 rows x 8,192 positions, 32 query heads over 8 key heads of
     64 - half a lane tile a head, a 64-deep score product, ``kv_repeat`` 4 -
     through the blockwise kernels (T past the one-block path), forward and
-    both backward kernels.  Until PR 43 the blockwise kernels had compiled
+    the one backward kernel.  Until PR 43 the blockwise kernels had compiled
     and run at head widths 128 and 192 / 128 only; a block spec or a scratch
     shape that assumed 128 lanes a head fails here, not on the chip."""
     one = SingleDeviceSharding(v5e[0])
@@ -147,11 +149,10 @@ def test_the_blockwise_flash_kernels_compile_at_head_width_64(v5e):
         )(q, k, v)
 
     lowered = jax.jit(grads).lower(q, kv, kv)
-    assert kernel_names(lowered.compile().as_text()) == {
-        "ddl_flash_fwd", "ddl_flash_bwd_dq", "ddl_flash_bwd_dkv"}
+    assert kernel_names(lowered.compile().as_text()) == BLOCK_KERNELS
     # the row's default blocks: 8 query blocks x 8 key blocks a query head,
-    # in all three kernels
-    assert mosaic_grids(lowered.as_text()) == {(2, 32, 8, 8): 3}
+    # in both kernels
+    assert mosaic_grids(lowered.as_text()) == {(2, 32, 8, 8): 2}
 
 
 def _gdn_calls(lowered_text):
@@ -316,16 +317,15 @@ def test_windowed_flash_attention_compiles_at_trinity_minis_geometry(v5e, grad):
     text = lowered.compile().as_text()
     want = {"ddl_flash_fwd", "ddl_flash_swa_fwd"}
     if grad:
-        want |= {"ddl_flash_bwd_dq", "ddl_flash_bwd_dkv",
-                 "ddl_flash_swa_bwd_dq", "ddl_flash_swa_bwd_dkv"}
+        want |= {"ddl_flash_bwd_dkv", "ddl_flash_swa_bwd_dkv"}
     assert kernel_names(text) == want and want <= set(KERNEL_NAMES)
     # The banded kernels' inner grid axis runs over the band's blocks
     # (5 of 16 at the windowed default of 512 x 512), the causal-full
-    # ones' over the whole row; a kernel a direction each.
+    # ones' over the whole row; a forward and ONE backward kernel each.
     band = importlib.import_module(
         "ddl_tpu.ops.flash_attention").band_grid(8192, 2048, 512, 512)
     assert (band.nqb, band.nk, band.nq, band.steps, band.live) == (16, 5, 5, 80, 70)
-    n = 3 if grad else 1
+    n = 2 if grad else 1
     assert mosaic_grids(lowered.as_text()) == {
         (2, 32, 16, 5): n, (2, 32, 8, 8): n,
     }
@@ -335,10 +335,10 @@ def test_windowed_flash_attention_compiles_at_trinity_minis_geometry(v5e, grad):
 def test_latent_flash_attention_compiles_at_kanana_2s_geometry(v5e, grad):
     """2 x 8192 tokens, 32 heads, 128-wide q/k/v + a 64-wide rotary
     product with one shared key, bf16: the latent kernels under names of
-    their own, at the row's 1024 x 1024 blocks - which need the scoped VMEM
-    limit the latent path asks for (dq passes Mosaic's default 16 MiB by
-    1.3) - on the causal grid; the rotary key's gradient comes back as ONE
-    key a position."""
+    their own, at the row's 1024 x 1024 blocks - which need a scoped VMEM
+    limit past Mosaic's default 16 MiB (the forward's the latent path's own,
+    the one backward kernel's ``_BWD_VMEM_LIMIT``) - on the causal grid; the
+    rotary key's gradient comes back as ONE key a position."""
     one = SingleDeviceSharding(v5e[0])
     shape = lambda h, d: jax.ShapeDtypeStruct(  # noqa: E731
         (2, 8192, h, d), jnp.bfloat16, sharding=one)
@@ -354,11 +354,69 @@ def test_latent_flash_attention_compiles_at_kanana_2s_geometry(v5e, grad):
     compiled = lowered.compile()
     want = {"ddl_flash_mla_fwd"}
     if grad:
-        want |= {"ddl_flash_mla_bwd_dq", "ddl_flash_mla_bwd_dkv"}
+        want |= {"ddl_flash_mla_bwd_dkv"}
         assert [o.shape for o in jax.tree.leaves(compiled.out_info)] == [
             a.shape for a in args]
     assert kernel_names(compiled.as_text()) == want and want <= set(KERNEL_NAMES)
-    assert mosaic_grids(lowered.as_text()) == {(2, 32, 8, 8): 3 if grad else 1}
+    assert mosaic_grids(lowered.as_text()) == {(2, 32, 8, 8): 2 if grad else 1}
+
+
+#: The one-kernel backward at the benchmark's cells' shapes: (B, T, query /
+#: key heads, D, window, rotary width), the grid of its one kernel, and the
+#: least ``vmem_limit_bytes`` in MiB under which Mosaic compiles it for the
+#: described v5e (bisected, PR 45): the kernel's VMEM, read to a MiB.
+BACKWARD_AT_THE_CELLS = {
+    "trinity_mini_full": ((2, 8192, 32, 4, 128, None, None), (2, 32, 8, 8), 17),
+    "trinity_mini_band": ((2, 8192, 32, 4, 128, 2048, None), (2, 32, 16, 5), 9),
+    "kanana_2_latent": ((2, 8192, 32, 32, 128, None, 64), (2, 32, 8, 8), 25),
+    "olmo_hybrid_16k": ((1, 16384, 30, 30, 128, None, None), (1, 30, 16, 16), 22),
+    "lfm2_width_64": ((2, 8192, 32, 8, 64, None, None), (2, 32, 8, 8), 17),
+}
+
+
+@pytest.mark.parametrize("cell", list(BACKWARD_AT_THE_CELLS))
+def test_the_one_kernel_backward_compiles_within_its_vmem_at_the_cells_shapes(
+        v5e, monkeypatch, cell):
+    """The backward pass of one blockwise call alone, bf16, at the row's
+    default blocks: ONE Mosaic kernel under the family's ``*_bwd_dkv`` name
+    on the dK/dV grid, whose dQ rows (4 MiB of float32 a (batch, head) at
+    8,192 x 128, as much again for the latent form's lane-padded rotary
+    rows, 8 MiB at 16,384) fit the VMEM the kernel asks for with room: it
+    compiles under the pinned least limit, is refused a MiB below it for
+    VMEM and nothing else, and that reading is under the limit the kernel
+    sets itself by at least the half it leaves to a longer row."""
+    blockwise = importlib.import_module("ddl_tpu.ops.flash_attention")
+    (B, T, H, Hkv, D, window, R), grid, least_mib = BACKWARD_AT_THE_CELLS[cell]
+    one = SingleDeviceSharding(v5e[0])
+    bf16 = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one)  # noqa: E731
+    row = jax.ShapeDtypeStruct((B, H, T), jnp.float32, sharding=one)
+    q, kv = bf16(B, T, H, D), bf16(B, T, Hkv, D)
+    rope = (bf16(B, T, H, R), bf16(B, T, 1, R)) if R else ()
+    bq, bk = blockwise._default_blocks(T, None, None, window)
+
+    def backward(q, k, v, out, lse, do, dlse, *rope):
+        res = (q, k, v, blockwise._offsets_arr(0, 0), out, lse, False, bq, bk,
+               None, None)
+        return blockwise._bwd_impl(
+            True, H // Hkv, None, None, None, res, (do, dlse), window=window,
+            rope=rope or None)[:-1]  # but the offsets' float0
+
+    def lower():  # anew each time: the limit is read at trace time
+        return jax.jit(lambda *a: backward(*a)).lower(
+            q, kv, kv, q, row, q, row, *rope)
+
+    lowered = lower()
+    family = "ddl_flash_" + ("mla_" if R else "swa_" if window else "")
+    assert kernel_names(lowered.compile().as_text()) == {family + "bwd_dkv"}
+    assert mosaic_grids(lowered.as_text()) == {grid: 1}
+    assert blockwise._dq_row_bytes(T, *((D, R) if R else (D,))) \
+        <= blockwise._BWD_ROW_BYTES
+    assert 2 * least_mib * 2**20 <= blockwise._BWD_VMEM_LIMIT
+    monkeypatch.setattr(blockwise, "_BWD_VMEM_LIMIT", least_mib * 2**20)
+    lower().compile()
+    monkeypatch.setattr(blockwise, "_BWD_VMEM_LIMIT", (least_mib - 1) * 2**20)
+    with pytest.raises(Exception, match="vmem"):
+        lower().compile()
 
 
 def test_flash_names_survive_remat_and_shard_map(v5e):
@@ -394,9 +452,7 @@ def test_flash_names_survive_remat_and_shard_map(v5e):
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv
     ).compile().as_text()
-    assert kernel_names(text) == {
-        "ddl_flash_fwd", "ddl_flash_bwd_dq", "ddl_flash_bwd_dkv"
-    }
+    assert kernel_names(text) == BLOCK_KERNELS
 
 
 # ViT-B/16's attention at the benchmark's batch: 128 rows x 196 patches,
@@ -404,7 +460,6 @@ def test_flash_names_survive_remat_and_shard_map(v5e):
 # (``ops/flash_tile.py``).
 VB, VT, VH, VD = 128, 196, 12, 64
 TILE_KERNELS = {"ddl_flash_tile_fwd", "ddl_flash_tile_bwd"}
-BLOCK_KERNELS = {"ddl_flash_fwd", "ddl_flash_bwd_dq", "ddl_flash_bwd_dkv"}
 
 
 def _value_and_grads(attn):
@@ -679,7 +734,6 @@ def test_a_share_compiles_under_its_remat_plan_and_stays_under_its_budget(
     assert metrics().gauge("remat.budget_bytes") == wanted
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes <= free, (mem.temp_size_in_bytes, free)
-    flash = {"ddl_flash_fwd", "ddl_flash_bwd_dq", "ddl_flash_bwd_dkv"}
     assert kernel_names(compiled.as_text()) == (
-        flash | {k.replace("flash", "flash_swa") for k in flash}
+        BLOCK_KERNELS | {k.replace("flash", "flash_swa") for k in BLOCK_KERNELS}
         | {"ragged-dot-none", "ragged-dot-metadata"})

@@ -12,7 +12,12 @@ of their events in a profiler trace of ten calls of forward + backward,
 reduced by ``benchmarks/lib/tracered.py``); the useful share of peak of
 each, counted as ``mla_roofline_share`` counts it
 (``benchmarks/lib/mla_flops.py``); and the worst difference of the output
-and the gradients from the first shape's.  The last line is the causal
+and the gradients from the first shape's.  Each shape is read twice
+(``--backward``, as ``tools/probe_flash_band.py`` reads its shapes): the
+backward pass as the two kernels (``pair``) and as the ONE kernel on the
+dK/dV grid that carries dQ and dQ_rope (``one``: no ``bwd_dq`` family, the
+``bwd_dkv`` family's share counted at its five products, and its gradients
+held to the pair's, ``max_abs_diff_from_pair``).  The last lines are the causal
 one-product kernels at D = 128 on the same q, k, v: what the rotary product
 costs.  Needs a TPU: a timing from anywhere else is no timing
 (``--rehearsal`` runs the control flow at a tiny size anywhere and prints
@@ -36,7 +41,7 @@ import numpy as np  # noqa: E402
 from ddl_tpu.bringup import bring_up  # noqa: E402
 from ddl_tpu.ops import flash_attention  # noqa: E402
 from benchmarks.lib import mla_flops, peaks  # noqa: E402
-from tools.probe_flash_band import kernel_ms  # noqa: E402
+from tools.probe_flash_band import backward_as, diffs, kernel_ms  # noqa: E402
 
 B, T, H, D, R = 2, 8192, 32, 128, 64
 DEFAULT_BLOCKS = ("1024x1024", "512x1024", "1024x512", "512x512",
@@ -48,6 +53,8 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--blocks", nargs="*", default=list(DEFAULT_BLOCKS))
     ap.add_argument("--seed", type=int, default=2654435769)
+    ap.add_argument("--backward", nargs="*", default=["pair", "one"],
+                    choices=["pair", "one"])
     ap.add_argument("--rehearsal", action="store_true")
     args = ap.parse_args()
     bring_up("cpu" if args.rehearsal else None)  # a TPU, or SystemExit
@@ -62,53 +69,69 @@ def main() -> None:
     pairs = mla_flops.causal_pairs(T) * B * H
     first = None
     for name in [*args.blocks, "one_product_d128"]:
-        latent = name != "one_product_d128"
-        bq, bk = (None, None) if "x" not in name else map(int, name.split("x"))
-
-        def attn(q, k, v, qr, kr, bq=bq, bk=bk, latent=latent):
-            rope = dict(q_rope=qr, k_rope=kr) if latent else {}
-            return flash_attention(q, k, v, block_q=bq, block_k=bk, **rope)
-
-        def loss(*a):
-            return jnp.sum(attn(*a).astype(jnp.float32) * do)
-
-        grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))
-        line = {"blocks": name, "device": dev.device_kind}
-        try:
-            with tempfile.TemporaryDirectory() as tmp:
-                ms = kernel_ms(grads, (q, k, v, qr, kr),
-                               None if args.rehearsal else tmp)
-        except Exception as e:  # Mosaic refusing a shape is a reading too
-            line["refused"] = f"{type(e).__name__}: {str(e)[:300]}"
+        pair = None
+        for backward in args.backward:
+            with backward_as(backward):
+                line, outs = read_shape(
+                    name, backward, (q, k, v, qr, kr, do), pairs,
+                    dev.device_kind, args.rehearsal)
+            if outs is not None:
+                if backward == "pair":
+                    pair = outs
+                elif pair is not None:
+                    line["max_abs_diff_from_pair"] = diffs(outs, pair)
+                if name != "one_product_d128":
+                    if first is None:
+                        first = outs
+                    else:
+                        line["max_abs_diff_from_first"] = diffs(outs, first)
             print(json.dumps(line), flush=True)
-            continue
-        ms = {f.split("_", 2)[2].removeprefix("mla_"): t for f, t in ms.items()}
-        if ms:
-            # the one-product kernels: the same passes with no rotary width
-            widths = mla_flops.kernel_widths({
-                "qk_nope_head_dim": D, "qk_rope_head_dim": R if latent else 0,
-                "v_head_dim": D})
-            line.update(
-                ms={f: round(t, 4) for f, t in ms.items()},
-                # a layer under selective remat: each kernel once
-                ms_layer=round(sum(ms.values()), 4),
-                peak={f: round(100 * 2 * widths[f] * pairs / (t * 1e-3)
-                               / peaks.peak_flops(dev.device_kind), 2)
-                      for f, t in ms.items()},
-            )
-        if latent:
-            outs = [jax.jit(attn)(q, k, v, qr, kr), *grads(q, k, v, qr, kr)]
-            outs = [np.asarray(o.astype(jnp.float32)) for o in outs]
-            line["finite"] = bool(all(np.isfinite(o).all() for o in outs))
-            if first is None:
-                first = outs
-            else:
-                line["max_abs_diff_from_first"] = [
-                    round(float(np.abs(a - b).max()), 5)
-                    for a, b in zip(outs, first)
-                ]
-        print(json.dumps(line), flush=True)
 
+
+def read_shape(name: str, backward: str, operands, pairs: int,
+               device_kind: str, rehearsal: bool):
+    """(the JSON line, [output, the five gradients] as float32 or None if
+    refused) of one block shape, traced here: under the caller's
+    ``backward_as``."""
+    *operands, do = operands
+    latent = name != "one_product_d128"
+    bq, bk = (None, None) if "x" not in name else map(int, name.split("x"))
+
+    def attn(q, k, v, qr, kr):
+        rope = dict(q_rope=qr, k_rope=kr) if latent else {}
+        return flash_attention(q, k, v, block_q=bq, block_k=bk, **rope)
+
+    def loss(*a):
+        return jnp.sum(attn(*a).astype(jnp.float32) * do)
+
+    grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))
+    line = {"blocks": name, "backward": backward, "device": device_kind}
+    try:
+        with tempfile.TemporaryDirectory() as tmp:
+            ms = kernel_ms(grads, operands, None if rehearsal else tmp)
+    except Exception as e:  # Mosaic refusing a shape is a reading too
+        line["refused"] = f"{type(e).__name__}: {str(e)[:300]}"
+        return line, None
+    ms = {f.split("_", 2)[2].removeprefix("mla_"): t for f, t in ms.items()}
+    if ms:
+        # the one-product kernels: the same passes with no rotary width
+        widths = mla_flops.kernel_widths({
+            "qk_nope_head_dim": D, "qk_rope_head_dim": R if latent else 0,
+            "v_head_dim": D})
+        if "bwd_dq" not in ms:  # the one kernel: dq's score-wide product too
+            widths["bwd_dkv"] += widths["bwd_dq"] - widths["fwd"]
+        line.update(
+            ms={f: round(t, 4) for f, t in ms.items()},
+            # a layer under selective remat: each kernel once
+            ms_layer=round(sum(ms.values()), 4),
+            peak={f: round(100 * 2 * widths[f] * pairs / (t * 1e-3)
+                           / peaks.peak_flops(device_kind), 2)
+                  for f, t in ms.items()},
+        )
+    outs = [jax.jit(attn)(*operands), *grads(*operands)]
+    outs = [np.asarray(o.astype(jnp.float32)) for o in outs]
+    line["finite"] = bool(all(np.isfinite(o).all() for o in outs))
+    return line, outs
 
 if __name__ == "__main__":
     main()
