@@ -53,10 +53,13 @@ def rms_norm(x: jax.Array, gain: jax.Array, eps: float) -> jax.Array:
     return (xf * scale * gain).astype(x.dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding; x: (B, T, H, D), positions: (T,)."""
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         freqs: Optional[jax.Array] = None) -> jax.Array:
+    """Rotary embedding; x: (B, T, H, D), positions: (T,).  ``freqs`` (D/2,)
+    stands in for ``theta``'s own frequencies (a scaled RoPE: YaRN)."""
     d_half = x.shape[-1] // 2
-    freqs = theta ** (-jnp.arange(0, d_half, dtype=jnp.float32) / d_half)
+    if freqs is None:
+        freqs = theta ** (-jnp.arange(0, d_half, dtype=jnp.float32) / d_half)
     angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # (T, Dh)
     cos = jnp.cos(angles)[None, :, None, :].astype(x.dtype)
     sin = jnp.sin(angles)[None, :, None, :].astype(x.dtype)
@@ -150,26 +153,54 @@ def forward(
     ``embed_scale`` multiplies the embedded rows, ``head_scale`` the normed
     stream in front of the head (:func:`lm_head`).
     """
+    x = embed(params, tokens, cfg, embed_scale)
+    bodies = [block(kind) for kind in table.kinds(cfg)]
+    logits_bytes = 4 * tokens.size * cfg.vocab
+    with _remat.planned(cfg.remat, bodies, x, params["layers"], logits_bytes) as plan:
+        x, picks, aux = walk(x, params["layers"], bodies, plan, cfg, n_aux)
+    return lm_head(params, x, cfg, head_scale), picks, aux
+
+
+def embed(params: Params, tokens: jax.Array, cfg: Any,
+          embed_scale: Any = None) -> jax.Array:
+    """The embedded rows (B, T, D) in the config's dtype, under ``ddl.embed``."""
     with scope("ddl.embed"):
         x = params["embed"].astype(cfg.dtype)[tokens]  # (B, T, D)
         if embed_scale is not None:
             x = x * embed_scale
+    return x
+
+
+def walk(
+    x: Any,
+    layers: Sequence[Params],
+    bodies: Sequence[Callable[..., Any]],
+    plan: Sequence[Tuple[str, ...]],
+    cfg: Any,
+    n_aux: int = 0,
+) -> Tuple[Any, List[jax.Array], Optional[jax.Array]]:
+    """The walk over a stack's layers: ``bodies[i](x, layers[i])`` under the
+    config's remat policy with ``plan[i]``'s kinds saved -> (what the last
+    layer hands on, the routed layers' picks, the summed auxiliary losses).
+    ``x`` is whatever the family's layers carry - the embedded row (B, T, D)
+    of a plain residual path, the four-row stream of a hyper-connected one
+    (``models/xing4.py``, which opens it behind :func:`embed`, closes it in
+    front of :func:`lm_head` and keeps the closed, un-normed stream for its
+    second head) - and the walk never looks inside it.  Trace it inside the
+    :func:`ddl_tpu.models.remat.planned` context the plan came from."""
     aux = jnp.zeros((n_aux,), jnp.float32) if n_aux else None
     wrap = functools.lru_cache(maxsize=None)(
         lambda body, saved: _remat.wrap(body, cfg.remat, saved))
     picks = []
-    bodies = [block(kind) for kind in table.kinds(cfg)]
-    logits_bytes = 4 * tokens.size * cfg.vocab
-    with _remat.planned(cfg.remat, bodies, x, params["layers"], logits_bytes) as plan:
-        for body, saved, layer in zip(bodies, plan, params["layers"]):
-            out = wrap(body, saved)(x, layer)
-            x, top_e, layer_aux = out if isinstance(out, tuple) else (out, None, None)
-            if layer_aux is not None:
-                with scope("ddl.head"):  # the auxiliary losses' reduction
-                    aux = aux + layer_aux
-            if top_e is not None:
-                picks.append(top_e)
-    return lm_head(params, x, cfg, head_scale), picks, aux
+    for body, saved, layer in zip(bodies, plan, layers):
+        out = wrap(body, saved)(x, layer)
+        x, top_e, layer_aux = out if isinstance(out, tuple) else (out, None, None)
+        if layer_aux is not None:
+            with scope("ddl.head"):  # the auxiliary losses' reduction
+                aux = aux + layer_aux
+        if top_e is not None:
+            picks.append(top_e)
+    return x, picks, aux
 
 
 def stack_picks(picks: List[jax.Array], tokens: jax.Array, topk: int) -> jax.Array:
@@ -302,11 +333,16 @@ class Table(NamedTuple):
     embed_fan_in: Optional[int] = None
     #: The head is the embedding's rows (:func:`lm_head`): no ``lm_head`` row.
     tied: bool = False
+    #: ``cfg ->`` rows beside the stack (a multi-token-prediction module's:
+    #: ``"mtp.w_eh"`` lies under ``"mtp"``), drawn last; their keys count
+    #: into ``n_keys``' base.
+    extra_rows: Optional[Callable[[Any], Sequence[Row]]] = None
 
     def _tree(self, cfg: Any, leaf: Callable[[Row], Any]) -> Params:
         layers = [_tree(self.layer_rows(cfg, k), leaf) for k in self.kinds(cfg)]
         top = _top_rows(cfg, self.embed_fan_in, self.tied)
-        return {**_tree(top, leaf), "layers": layers}
+        extra = self.extra_rows(cfg) if self.extra_rows else []
+        return {**_tree([*top, *extra], leaf), "layers": layers}
 
     def init_params(self, cfg: Any, key: jax.Array) -> Params:
         """The params pytree (``cfg.param_dtype`` storage but where a row

@@ -5,8 +5,9 @@ sigmoid-routed experts.
 Every layer, with ``h = RMSNorm(x)`` and pre-norm residuals
 (``x + Attn(norm(x))``, ``x + MLP(norm(x))``):
 
-- latent attention (MLA), no query low-rank step (``q_lora_rank: null``):
-  ``q = h Wq``, per head ``[q_nope (qk_nope_dim) | q_rope (qk_rope_dim)]``;
+- latent attention (MLA): ``q = h Wq`` - or, with a query low-rank step
+  (``q_lora_rank``; Kanana-2 has none), ``q = RMSNorm(h Wq_a) Wq_b`` -
+  per head ``[q_nope (qk_nope_dim) | q_rope (qk_rope_dim)]``;
   ``[c | k_r] = h Wkv_a`` (``kv_lora_rank`` + ``qk_rope_dim``),
   ``c = RMSNorm(c)``, ``[k_nope | v] = c Wkv_b`` per head; ``k_r`` is ONE
   rotary key a position, shared by all heads.  RoPE on ``q_rope`` and
@@ -20,6 +21,12 @@ Every layer, with ``h = RMSNorm(x)`` and pre-norm residuals
   they are (``attention(q_rope=, k_rope=)``): on a TPU the
   ``ddl_flash_mla_*`` kernels, which read the shared key through their
   index map, so neither a 192-wide q/k nor the H-fold ``k_r`` is written.
+  With ``rope_scaling`` (:class:`Yarn`; Kanana-2 has none) the rotary
+  frequencies are YaRN's blend (:func:`yarn_inv_freq`), cos and sin carry
+  ``m(mscale) / m(mscale_all_dim)`` and the score's scale is multiplied by
+  ``m(mscale_all_dim)^2``, ``m(x) = 0.1 x ln(factor) + 1`` - inside the
+  kernels' one scale (``attention(score_scale=)``): q is not rescaled in
+  bfloat16.
 - MLP: a dense SwiGLU (the first ``n_dense_layers`` layers,
   ``first_k_dense_replace``) or ``moe.sigmoid_expert_mlp`` — the routine
   ``models/afmoe.py`` runs too: ``sc = sigmoid(h Wr)`` in float32, ``sel =
@@ -47,10 +54,12 @@ into the query and the output); neither exists, so
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, List, Optional, Tuple
+import math
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ddl_tpu.models import decoder as _decoder
@@ -59,6 +68,43 @@ from ddl_tpu.models import remat as _remat
 from ddl_tpu.ops.naming import scope
 
 Params = Dict[str, Any]
+
+
+class Yarn(NamedTuple):
+    """``rope_scaling`` of type ``yarn``, as a published ``config.json``
+    states it (the defaults are the family's)."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def m(self, x: float) -> float:
+        """``yarn_get_mscale``: ``0.1 x ln(factor) + 1`` above factor 1."""
+        return 1.0 if self.factor <= 1 else 0.1 * x * math.log(self.factor) + 1.0
+
+
+def yarn_inv_freq(theta: float, dim: int, yarn: Yarn) -> np.ndarray:
+    """The ``dim / 2`` rotary frequencies under YaRN, float32: ``f_i =
+    theta^(-2i/dim)`` blended with ``f_i / factor`` by the ramp ``r_i =
+    clip((i - low) / (high - low), 0, 1)``, ``low = floor(c(beta_fast))``,
+    ``high = ceil(c(beta_slow))`` clamped to ``[0, dim - 1]``, ``c(b) = dim
+    ln(original / (2 pi b)) / (2 ln theta)``: pairs that turn more than
+    ``beta_fast`` times over the original context keep their frequency,
+    those that turn less than ``beta_slow`` times are interpolated."""
+    def c(b: float) -> float:
+        return dim * math.log(
+            yarn.original_max_position_embeddings / (2 * math.pi * b)
+        ) / (2 * math.log(theta))
+
+    low = max(math.floor(c(yarn.beta_fast)), 0)
+    high = min(math.ceil(c(yarn.beta_slow)), dim - 1)
+    i = np.arange(dim // 2, dtype=np.float64)
+    f = theta ** (-2.0 * i / dim)
+    r = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (f * ((1.0 - r) + r / yarn.factor)).astype(np.float32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -73,6 +119,9 @@ class DeepseekV3Config:
     qk_rope_dim: int = 8
     v_head_dim: int = 16
     kv_lora_rank: int = 32
+    #: The query's low-rank step, ``q = RMSNorm(h Wq_a) Wq_b``; ``None``
+    #: (Kanana-2): ``q = h Wq``.
+    q_lora_rank: Optional[int] = None
     d_ff: int = 192  # the dense layers' SwiGLU width
     d_expert: int = 32  # each routed expert's; the shared experts' unit
     n_experts: int = 8  # the router's width, whatever is held here
@@ -89,6 +138,8 @@ class DeepseekV3Config:
     held_experts: Optional[Tuple[int, int]] = None
     max_seq: int = 512
     rope_theta: float = 1e6
+    #: YaRN, as the published config states it; ``None``: plain RoPE.
+    rope_scaling: Optional[Yarn] = None
     norm_eps: float = 1e-6
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
@@ -116,6 +167,13 @@ class DeepseekV3Config:
 
     def is_dense(self, layer: int) -> bool:
         return layer < self.n_dense_layers
+
+    @property
+    def score_scale(self) -> float:
+        """What multiplies the score's ``1/sqrt(qk_nope_dim + qk_rope_dim)``:
+        YaRN's ``m(mscale_all_dim)^2`` (where ``mscale_all_dim`` is set), or 1."""
+        yarn = self.rope_scaling
+        return yarn.m(yarn.mscale_all_dim) ** 2 if yarn and yarn.mscale_all_dim else 1.0
 
     @staticmethod
     def kanana_2_30b_a3b() -> "DeepseekV3Config":
@@ -149,10 +207,16 @@ def _layer_rows(cfg: DeepseekV3Config, dense: bool) -> List[_decoder.Row]:
     shared by all heads, is not head-sharded)."""
     d, H, rank = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
     col = _decoder.COL
+    q_out = H * (cfg.qk_nope_dim + cfg.qk_rope_dim)
+    query = [_decoder.Row("wq", (d, q_out), col)] if cfg.q_lora_rank is None else [
+        _decoder.Row("wq_a", (d, cfg.q_lora_rank), P("fsdp", None)),
+        _decoder.ones("q_a_norm", cfg.q_lora_rank),
+        _decoder.Row("wq_b", (cfg.q_lora_rank, q_out), col),
+    ]
     return [
         _decoder.ones("attn_norm", d),
         _decoder.ones("mlp_norm", d),
-        _decoder.Row("wq", (d, H * (cfg.qk_nope_dim + cfg.qk_rope_dim)), col),
+        *query,
         _decoder.Row("wkv_a", (d, rank + cfg.qk_rope_dim), P("fsdp", None)),
         _decoder.ones("kv_a_norm", rank),
         _decoder.Row("wkv_b", (rank, H * (cfg.qk_nope_dim + cfg.v_head_dim)), col),
@@ -163,41 +227,74 @@ def _layer_rows(cfg: DeepseekV3Config, dense: bool) -> List[_decoder.Row]:
 
 
 #: ``init_params(cfg, key)`` — seeded normal / sqrt(fan_in) matrices, norm
-#: weights 1, ``expert_bias`` 0 — and ``param_specs(cfg)`` of one table.
+#: weights 1, ``expert_bias`` 0 — and ``param_specs(cfg)`` of one table.  A
+#: layer draws 11 keys (the split's width is part of every key, so Kanana-2's
+#: weights stay what they were) and with the query's low-rank step 12.
 _TABLE = _decoder.Table(_kinds, _layer_rows, (2, 11))
-init_params, param_specs = _TABLE.init_params, _TABLE.param_specs
+_TABLE_Q_LORA = _TABLE._replace(n_keys=(2, 12))
 
 
-def _rope_pairs(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def _table(cfg: DeepseekV3Config) -> _decoder.Table:
+    return _TABLE if cfg.q_lora_rank is None else _TABLE_Q_LORA
+
+
+def init_params(cfg: DeepseekV3Config, key: jax.Array) -> Params:
+    return _table(cfg).init_params(cfg, key)
+
+
+param_specs = _TABLE.param_specs
+
+
+def _rope_pairs(x: jax.Array, positions: jax.Array, theta: float,
+                yarn: Optional[Yarn] = None) -> jax.Array:
     """RoPE on adjacent pairs ``(x[2i], x[2i+1])`` of the last axis
     (``rope_interleave``); x: (B, T, H, R).  The pairs are de-interleaved —
     evens first, then odds — and rotated in the half-split form; the result
     stays de-interleaved, in q and in k alike, so their product is the
-    interleaved form's."""
+    interleaved form's.  Under ``yarn`` the frequencies are
+    :func:`yarn_inv_freq`'s and cos and sin carry ``m(mscale) /
+    m(mscale_all_dim)``."""
     x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
-    return _decoder.rope(x, positions, theta)
+    if yarn is None:
+        return _decoder.rope(x, positions, theta)
+    x = _decoder.rope(
+        x, positions, theta, jnp.asarray(yarn_inv_freq(theta, x.shape[-1], yarn)))
+    factor = yarn.m(yarn.mscale) / yarn.m(yarn.mscale_all_dim)
+    return x if factor == 1.0 else (x * factor).astype(x.dtype)
 
 
-def _attn_block(
+def attn(
     layer: Params,
     x: jax.Array,
     cfg: DeepseekV3Config,
     positions: jax.Array,
     mesh: Optional[Any],
+    residual: bool = True,
 ) -> jax.Array:
-    """Latent attention with a pre-norm residual."""
+    """Latent attention on the pre-normed stream: ``x + Attn(RMSNorm(x))``,
+    or ``Attn(RMSNorm(x))`` alone where the caller owns the residual path
+    (``models/xing4.py``)."""
     from ddl_tpu.parallel.ring_attention import attention
 
     B, T = x.shape[:2]
     dt = x.dtype
     H, nope, rank = cfg.n_heads, cfg.qk_nope_dim, cfg.kv_lora_rank
+    # Without YaRN the call is the three-argument one it always was.
+    turn = (positions, cfg.rope_theta) + (
+        (cfg.rope_scaling,) if cfg.rope_scaling else ())
+    scaled = {"score_scale": cfg.score_scale} if cfg.rope_scaling else {}
     with scope("ddl.attn"):
         h = _decoder.rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         with scope("ddl.mla_q"):
-            q = _remat.tag(h @ layer["wq"].astype(dt), _remat.PROJ)
+            if cfg.q_lora_rank is None:
+                q = _remat.tag(h @ layer["wq"].astype(dt), _remat.PROJ)
+            else:
+                q_a = _remat.tag(h @ layer["wq_a"].astype(dt), _remat.PROJ)
+                q_a = _decoder.rms_norm(q_a, layer["q_a_norm"], cfg.norm_eps)
+                q = _remat.tag(q_a @ layer["wq_b"].astype(dt), _remat.LATENT_UP)
             q = q.reshape(B, T, H, -1)
             q_nope = q[..., :nope]
-            q_rope = _rope_pairs(q[..., nope:], positions, cfg.rope_theta)
+            q_rope = _rope_pairs(q[..., nope:], *turn)
         with scope("ddl.mla_kv_up"):
             kv_a = _remat.tag(  # (B, T, rank + rope)
                 h @ layer["wkv_a"].astype(dt), _remat.PROJ)
@@ -208,14 +305,13 @@ def _attn_block(
             kv = kv.reshape(B, T, H, -1)
             k_nope, v = kv[..., :nope], kv[..., nope:]
             # One rotary key a position, for all heads.
-            k_rope = _rope_pairs(
-                kv_a[..., None, rank:], positions, cfg.rope_theta
-            )
-        attn = attention(
+            k_rope = _rope_pairs(kv_a[..., None, rank:], *turn)
+        out = attention(
             q_nope, k_nope, v, mesh=mesh, impl=cfg.attn_impl, causal=True,
-            q_rope=q_rope, k_rope=k_rope,
+            q_rope=q_rope, k_rope=k_rope, **scaled,
         )
-        return x + attn.reshape(B, T, -1) @ layer["wo"].astype(dt)
+        out = out.reshape(B, T, -1) @ layer["wo"].astype(dt)
+        return x + out if residual else out
 
 
 def _layer_apply(
@@ -228,7 +324,7 @@ def _layer_apply(
 ):
     """One block → (x, the router's picks (B, T, topk), or ``None`` from a
     dense layer, no auxiliary loss)."""
-    x = _attn_block(layer, x, cfg, positions, mesh)
+    x = attn(layer, x, cfg, positions, mesh)
     if dense:  # the llama block's norm, SwiGLU and residual
         return _decoder.mlp_block(layer, x, cfg), None, None
     with scope("ddl.moe"):
@@ -251,7 +347,7 @@ def forward_with_choices(
     def block(dense: bool):
         return lambda x, layer: _layer_apply(layer, x, cfg, positions, dense, mesh)
 
-    logits, picks, _ = _decoder.forward(params, tokens, cfg, _TABLE, block)
+    logits, picks, _ = _decoder.forward(params, tokens, cfg, _table(cfg), block)
     return logits, _decoder.stack_picks(picks, tokens, cfg.topk)
 
 

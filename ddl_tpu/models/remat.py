@@ -44,6 +44,13 @@ its contraction's width over the rate the matmul runs at, so a product out
 of a wide input comes before one out of the model's width, and that before
 one out of a narrow latent:
 
+0. :data:`HC` — what a hyper-connected residual path's mixing matrices
+   are made of (``models/hyper_connections.py:hc_pre``: the 24 folded
+   projections of the four-row stream and its sum of squares, float32): 100
+   bytes a token and wrap for a pass over the stream (28.7 KB a token at
+   4 x 3,584) - with them kept, a rematerialised wrap makes ``Hpre``,
+   ``Hpost`` and ``Hres`` from 25 numbers a token and reads the stream once,
+   for ``h = Hpre X``.
 1. :data:`ROUTER` — a router's results: ``top_w``, ``top_e``
    (``moe.sigmoid_expert_tokens``, ``moe._ragged_mlp``), ``order``,
    ``group_sizes``, ``is_held`` (``moe.ragged_experts``) and ``inv``
@@ -112,7 +119,9 @@ Those tables are keyed by the policy's name.
 One wrap site for the seven decoder families (:func:`wrap` around a
 layer's body in ``models/decoder.py:forward``) and one in each pipelined
 forward (``llama.forward_pp``, ``moe._forward_pp``: no cell runs them, and
-they keep the always-saved rule alone).  A value is tagged once, where it
+they keep the always-saved rule alone).  A stack whose layers carry a wider
+value than the embedded row (``models/xing4.py``: four rows a token) is
+counted as it is: a layer's ``inputs`` are the bytes of what it is handed.  A value is tagged once, where it
 is made, never by a model's layer loop — a second tag on the same value
 would save it twice — so the policy cannot drift between the families.
 """
@@ -136,6 +145,7 @@ ATTN_OUT_NAME = "ddl_attn_out"
 #: Every accepted policy name, in cheapest-memory-first order.
 POLICIES = ("none", "full", "selective", "dots")
 
+HC = "ddl_hc"
 ROUTER = "ddl_router"
 NORMED = "ddl_normed"
 PROJ = "ddl_proj"
@@ -145,7 +155,7 @@ LATENT_UP = "ddl_latent_up"
 
 #: What ``selective`` saves where the bytes are free, most milliseconds a
 #: MiB first (the module's docstring has what each names and why the order).
-KINDS = (ROUTER, NORMED, PROJ, SWIGLU, GATE, LATENT_UP)
+KINDS = (HC, ROUTER, NORMED, PROJ, SWIGLU, GATE, LATENT_UP)
 
 #: The share of a device's HBM a step may fill: the 12.6 GiB of a v5e's 16
 #: that the benchmark's depths were cut to (PERF.md section 4), which

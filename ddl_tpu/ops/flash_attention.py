@@ -727,10 +727,11 @@ def _seg_specs(block_q, block_k, transposed: bool = False):
     return sq, sk
 
 
-def _mla_scale(q, rope) -> float:
-    """``1/sqrt`` of the score's whole width: the heads' own plus the
-    rotary one."""
-    return 1.0 / ((q.shape[-1] + rope[0].shape[-1]) ** 0.5)
+def _mla_scale(q, rope, score_scale: float = 1.0) -> float:
+    """``1/sqrt`` of the score's whole width - the heads' own plus the
+    rotary one - times the caller's ``score_scale`` (YaRN's ``mscale^2``;
+    1 leaves the float what it was)."""
+    return score_scale / ((q.shape[-1] + rope[0].shape[-1]) ** 0.5)
 
 
 #: Scoped VMEM the latent kernels may use.  Their two rotary blocks, the
@@ -783,7 +784,8 @@ def _rope_specs(rope, block_q, block_k, k_block):
 
 
 def _fwd_impl(q, k, v, offsets, causal, kv_repeat, block_q, block_k,
-              interpret, seg_q=None, seg_k=None, window=None, rope=None):
+              interpret, seg_q=None, seg_k=None, window=None, rope=None,
+              score_scale=1.0):
     assert q.shape[2] == k.shape[2] * kv_repeat, (q.shape, k.shape, kv_repeat)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -806,7 +808,7 @@ def _fwd_impl(q, k, v, offsets, causal, kv_repeat, block_q, block_k,
         name = "ddl_flash_swa_fwd"
     if rope is not None:
         kernel, name = _fwd_kernel_mla, "ddl_flash_mla_fwd"
-        common["scale"] = _mla_scale(q, rope)
+        common["scale"] = _mla_scale(q, rope, score_scale)
     kernel = functools.partial(kernel, **common)
     kv_spec = pl.BlockSpec(
         (1, 1, block_k, D),
@@ -880,7 +882,7 @@ def _row_operand(x, Tq):
 
 
 def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts,
-              window=None, rope=None):
+              window=None, rope=None, score_scale=1.0):
     do, dlse = cts
     # Resolved block sizes / interpret flag ride in the residuals so both
     # passes use identical values (the nondiff args are pre-resolution).
@@ -927,7 +929,7 @@ def _bwd_impl(causal, kv_repeat, _block_q, _block_k, _interpret, res, cts,
     if rope is not None:
         dq_kernel, dkv_kernel = _dq_kernel_mla, _dkv_kernel_mla
         names = ("ddl_flash_mla_bwd_dq", "ddl_flash_mla_bwd_dkv")
-        common["scale"] = _mla_scale(q, rope)
+        common["scale"] = _mla_scale(q, rope, score_scale)
         R = rope[0].shape[-1]
         rope_t = _prep_rope(rope, Tq, Tk)
     q_spec = pl.BlockSpec(
@@ -1175,21 +1177,21 @@ _flash_core_win.defvjp(_vjp_fwd_win, _bwd_impl_win)
 # head): the same kernels with the rotary product in the score, under the
 # names ``ddl_flash_mla_*``.  A custom_vjp of its own for the reason the
 # windowed core has one.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
 def _flash_core_mla(q, k, v, q_rope, k_rope, offsets, block_q, block_k,
-                    interpret):
+                    interpret, score_scale):
     out, lse, _ = _fwd_impl(
         q, k, v, offsets, True, 1, block_q, block_k, interpret,
-        rope=(q_rope, k_rope),
+        rope=(q_rope, k_rope), score_scale=score_scale,
     )
     return out, lse
 
 
 def _vjp_fwd_mla(q, k, v, q_rope, k_rope, offsets, block_q, block_k,
-                 interpret):
+                 interpret, score_scale):
     out, lse, resolved = _fwd_impl(
         q, k, v, offsets, True, 1, block_q, block_k, interpret,
-        rope=(q_rope, k_rope),
+        rope=(q_rope, k_rope), score_scale=score_scale,
     )
     out, lse = _saved(out, lse)
     return (out, lse), (
@@ -1198,9 +1200,10 @@ def _vjp_fwd_mla(q, k, v, q_rope, k_rope, offsets, block_q, block_k,
     )
 
 
-def _bwd_impl_mla(block_q, block_k, interpret, res, cts):
+def _bwd_impl_mla(block_q, block_k, interpret, score_scale, res, cts):
     res, rope = res
-    return _bwd_impl(True, 1, block_q, block_k, interpret, res, cts, rope=rope)
+    return _bwd_impl(True, 1, block_q, block_k, interpret, res, cts, rope=rope,
+                     score_scale=score_scale)
 
 
 _flash_core_mla.defvjp(_vjp_fwd_mla, _bwd_impl_mla)
@@ -1251,6 +1254,7 @@ def flash_attention(
     window: Optional[int] = None,
     q_rope: Optional[jax.Array] = None,
     k_rope: Optional[jax.Array] = None,
+    score_scale: float = 1.0,
 ) -> jax.Array:
     """Flash attention over (B, T, H, D) queries.
 
@@ -1288,7 +1292,10 @@ def flash_attention(
     position for all heads, which the kernels read through their index
     map, so its H-fold broadcast is never written; v and the output keep
     width D.  The kernels are named ``ddl_flash_mla_*`` on the trace.
-    Absent, every program is what it was before.
+    Absent, every program is what it was before.  ``score_scale`` (a static
+    float, the latent form only) multiplies that ``1/sqrt(D + R)`` inside
+    the kernels' one scale - YaRN's ``mscale^2``, so that q is not rescaled
+    in bfloat16; 1 is the same float, and program, as without it.
     """
     if (q_rope is None) != (k_rope is None):
         raise ValueError("q_rope and k_rope come together")
@@ -1312,7 +1319,7 @@ def flash_attention(
         block_q, block_k = _default_blocks(T, block_q, block_k)
         out, _ = _flash_core_mla(
             q, k, v, q_rope, k_rope, _offsets_arr(0, 0), block_q, block_k,
-            interpret,
+            interpret, float(score_scale),
         )
         return out
     if window is not None:

@@ -78,6 +78,7 @@ KERNEL_NAMES = (
 #: no op, no run-time cost, no knob.
 SCOPE_NAMES = (
     "ddl.embed", "ddl.patchify",
+    "ddl.hc_pre", "ddl.hc_post",
     "ddl.attn", "ddl.attn_gate", "ddl.mla_q", "ddl.mla_kv_up",
     "ddl.gdn_proj", "ddl.gdn_conv", "ddl.gdn_scan", "ddl.gdn_out",
     "ddl.lightning_proj", "ddl.lightning_scan", "ddl.lightning_out",
@@ -86,13 +87,13 @@ SCOPE_NAMES = (
     "ddl.mlp",
     "ddl.moe", "ddl.moe_route", "ddl.moe_experts", "ddl.moe_combine",
     "ddl.moe_overflow", "ddl.moe_shared",
-    "ddl.head", "ddl.optimizer",
+    "ddl.head", "ddl.mtp", "ddl.optimizer",
 )
 
 #: Raise when a scope moves to other ops and the table stays as it is:
 #: the compile cache is keyed by the table (:func:`scope_table_digest`),
 #: not by where its names are used.
-SCOPE_PLACEMENT_REV = 1
+SCOPE_PLACEMENT_REV = 2
 
 
 def scope(name: str) -> contextlib.AbstractContextManager:

@@ -185,6 +185,7 @@ def sharded_local_attention(
     window: Optional[int] = None,
     q_rope: Optional[jax.Array] = None,
     k_rope: Optional[jax.Array] = None,
+    score_scale: float = 1.0,
 ) -> jax.Array:
     """Batch/head-sharded attention for meshes WITHOUT a sequence axis.
 
@@ -203,7 +204,7 @@ def sharded_local_attention(
 
     def impl(q, k, v, seg, q_rope=None, k_rope=None):
         return _local_attention(q, k, v, use_flash, causal, kv_repeat, seg,
-                                window, q_rope, k_rope)
+                                window, q_rope, k_rope, score_scale)
 
     B, _, H, _ = q.shape
     Hkv = k.shape[2]
@@ -258,7 +259,7 @@ def sharded_local_attention(
 
 
 def _local_attention(q, k, v, use_flash, causal, kv_repeat, segment_ids,
-                     window, q_rope=None, k_rope=None):
+                     window, q_rope=None, k_rope=None, score_scale=1.0):
     """One device's whole attention: the Pallas flash kernels or the dense
     oracle."""
     if use_flash:
@@ -266,10 +267,12 @@ def _local_attention(q, k, v, use_flash, causal, kv_repeat, segment_ids,
 
         return flash_attention(q, k, v, causal=causal, kv_repeat=kv_repeat,
                                segment_ids=segment_ids, window=window,
-                               q_rope=q_rope, k_rope=k_rope)
+                               q_rope=q_rope, k_rope=k_rope,
+                               score_scale=score_scale)
     return attention_reference(q, k, v, causal=causal, kv_repeat=kv_repeat,
                                segment_ids=segment_ids, window=window,
-                               q_rope=q_rope, k_rope=k_rope)
+                               q_rope=q_rope, k_rope=k_rope,
+                               score_scale=score_scale)
 
 
 def attention(
@@ -288,6 +291,7 @@ def attention(
     q_rope: Optional[jax.Array] = None,
     k_rope: Optional[jax.Array] = None,
     selection: Optional[Any] = None,
+    score_scale: float = 1.0,
 ) -> jax.Array:
     """The single attention dispatcher — one source of truth for impl/mesh
     routing (models call this, not the individual strategies):
@@ -309,7 +313,9 @@ def attention(
       one a position for all heads, v and the output of q's width — on
       the local strategies (causal, ``kv_repeat`` 1).  The ``sp`` ring
       refuses it by name: the shared key would have to ride the ring
-      beside k and v, and no ring step takes it.
+      beside k and v, and no ring step takes it.  ``score_scale`` (a
+      static float, this form only) multiplies the ``1/sqrt(D + R)``:
+      YaRN's ``mscale^2``, carried by the kernels' one scale.
 
     - ``selection`` (``ops.sparse_attention.Selection``, which carries
       its sizes): block-sparse attention - a query sees the key blocks
@@ -366,21 +372,23 @@ def attention(
             q, k, v, mesh, causal=causal, kv_repeat=kv_repeat,
             use_flash=use_flash, dp_axis=dp_axis, tp_axis=tp_axis,
             segment_ids=segment_ids, window=window, q_rope=q_rope,
-            k_rope=k_rope,
+            k_rope=k_rope, score_scale=score_scale,
         )
     else:
         out = _local_attention(q, k, v, use_flash, causal, kv_repeat,
-                               segment_ids, window, q_rope, k_rope)
+                               segment_ids, window, q_rope, k_rope,
+                               score_scale)
     # ``flash_attention`` tags what it returns itself, beside the
     # logsumexp its backward reads; a second tag here would save the
     # output twice.
     return out if use_flash else tag_attn_out(out)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "kv_repeat", "window"))
+@functools.partial(
+    jax.jit, static_argnames=("causal", "kv_repeat", "window", "score_scale"))
 def attention_reference(q, k, v, causal: bool = True, kv_repeat: int = 1,
                         segment_ids=None, window=None, q_rope=None,
-                        k_rope=None):
+                        k_rope=None, score_scale: float = 1.0):
     """Single-device full attention — the correctness oracle for tests.
 
     ``segment_ids`` (B, T): packed-sequence masking, tokens attend only
@@ -389,7 +397,7 @@ def attention_reference(q, k, v, causal: bool = True, kv_repeat: int = 1,
     (on top of causality).
     ``q_rope`` (B, T, H, R) / ``k_rope`` (B, T, 1, R): latent attention's
     rotary product, one key for all heads, added to the scores under the
-    scale ``1/sqrt(D + R)``.
+    scale ``score_scale / sqrt(D + R)``.
     """
     if kv_repeat > 1:
         k = jnp.repeat(k, kv_repeat, axis=2)
@@ -401,7 +409,7 @@ def attention_reference(q, k, v, causal: bool = True, kv_repeat: int = 1,
         s = (
             jnp.einsum("bqhd,bkhd->bhqk", q, k)
             + jnp.einsum("bqhr,bkr->bhqk", q_rope, k_rope[:, :, 0])
-        ) / ((D + q_rope.shape[-1]) ** 0.5)
+        ) / ((D + q_rope.shape[-1]) ** 0.5 / score_scale)
     if causal:
         mask = jnp.arange(T)[None, :] > jnp.arange(T)[:, None]
         s = jnp.where(mask[None, None], _NEG_INF, s)

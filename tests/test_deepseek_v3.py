@@ -701,3 +701,75 @@ def test_the_cell_rehearses_through_trainer_fit_on_the_cpu():
     steady = next(ln for ln in lines if ln.get("line") == "steady")
     assert steady["windows"] >= 1 and steady["problems"] == []
     assert lines[-1]["correct"] is True and lines[-1]["failed"] == 0
+
+
+# -- the query's low-rank step and YaRN (PR 46) ---------------------------------------
+
+
+def _low_rank_case(**kw):
+    from ddl_tpu.models.deepseek_v3 import Yarn
+
+    base = dict(
+        vocab=64, d_model=32, n_layers=2, n_heads=2, qk_nope_dim=16, qk_rope_dim=8,
+        v_head_dim=16, kv_lora_rank=16, q_lora_rank=24, d_ff=48, d_expert=16,
+        n_experts=8, topk=2, n_shared_experts=1, n_dense_layers=1, max_seq=24,
+        rope_theta=1e4, rope_scaling=Yarn(8.0, 8, 4.0, 1.0, 1.0, 1.0),
+        dtype=jnp.float32, param_dtype=jnp.float32, attn_impl="dense")
+    base.update(kw)
+    return deepseek_v3.DeepseekV3Config(**base)
+
+
+@pytest.mark.parametrize("impl", ["dense", "flash"])
+def test_the_query_low_rank_step_and_yarn_are_the_references(impl):
+    """``q = RMSNorm(h Wq_a) Wq_b``, YaRN's frequencies and the score's scale
+    times ``m^2``: the attention sub-block against the plain float32 stages of
+    ``tests/reference_xing4.py`` (the dispatcher's dense form, and the latent
+    kernels under ``score_scale``)."""
+    import reference_xing4 as ref4
+
+    cfg = _low_rank_case(attn_impl=impl)
+    params = deepseek_v3.init_params(cfg, jax.random.key(3))
+    layer = params["layers"][1]
+    assert {"wq_a", "q_a_norm", "wq_b"} <= set(layer) and "wq" not in layer
+    assert layer["wq_a"].shape == (32, 24) and layer["wq_b"].shape == (24, 2 * 24)
+    layer["q_a_norm"] = layer["q_a_norm"] + 0.3 * jax.random.normal(
+        jax.random.key(4), layer["q_a_norm"].shape)
+    x = jax.random.normal(jax.random.key(5), (2, 24, 32))
+    got = deepseek_v3.attn(layer, x, cfg, jnp.arange(24), None, residual=False)
+    c = ref4.Config(
+        n_heads=2, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16, kv_lora_rank=16,
+        n_experts=8, topk=2, n_dense_layers=1, held=(0, 8),
+        yarn=tuple(cfg.rope_scaling), rope_theta=1e4, query_block=8)
+    with jax.default_matmul_precision("highest"):
+        h = ref4._norm(x, layer["attn_norm"], cfg.norm_eps)
+        want = ref4._attn_out(ref4._attention(
+            *ref4.latent_qkv(h, layer, c), ref4.score_scale(c), 8), layer, ref4._same)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    with_residual = deepseek_v3.attn(layer, x, cfg, jnp.arange(24), None)
+    np.testing.assert_allclose(np.asarray(with_residual), np.asarray(x + got), atol=1e-6)
+    # ... and each of the three is felt
+    for without in (dict(rope_scaling=None),
+                    dict(rope_scaling=cfg.rope_scaling._replace(mscale_all_dim=0.0))):
+        other = deepseek_v3.attn(
+            layer, x, dataclasses.replace(cfg, **without), jnp.arange(24), None,
+            residual=False)
+        assert float(jnp.max(jnp.abs(other - got))) > 1e-3, without
+
+
+def test_without_the_new_fields_a_layer_is_kananas():
+    """``q_lora_rank`` and ``rope_scaling`` absent: the parameters, the keys
+    drawn and the dispatcher's call are what they were (the pinned program
+    hashes of ``tests/test_ops.py`` hold the traced step)."""
+    cfg = _low_rank_case(q_lora_rank=None, rope_scaling=None)
+    assert cfg.score_scale == 1.0
+    layer = deepseek_v3.init_params(cfg, jax.random.key(3))["layers"][1]
+    assert "wq" in layer and "wq_a" not in layer
+    text = str(jax.make_jaxpr(
+        lambda l, x: deepseek_v3.attn(l, x, cfg, jnp.arange(24), None))(
+            layer, jnp.zeros((1, 24, 32))))
+    assert "score_scale" not in text
+    full = deepseek_v3.DeepseekV3Config.kanana_2_30b_a3b()
+    assert full.q_lora_rank is None and full.rope_scaling is None
+    # a low-rank layer draws one key more: its table's, not Kanana's
+    assert deepseek_v3._table(cfg).n_keys == (2, 11)
+    assert deepseek_v3._table(_low_rank_case()).n_keys == (2, 12)

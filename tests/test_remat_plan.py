@@ -212,8 +212,8 @@ def test_the_pipelined_forwards_keep_the_always_saved_rule():
 
 def test_the_kinds_and_their_order_are_stated_once():
     assert remat.KINDS == (
-        remat.ROUTER, remat.NORMED, remat.PROJ, remat.SWIGLU, remat.GATE,
-        remat.LATENT_UP)
+        remat.HC, remat.ROUTER, remat.NORMED, remat.PROJ, remat.SWIGLU,
+        remat.GATE, remat.LATENT_UP)
     assert remat.ATTN_OUT_NAME not in remat.KINDS
     assert remat.POLICIES == ("none", "full", "selective", "dots")
 

@@ -30,7 +30,8 @@ REPO = os.path.dirname(cells.HERE)
 def _family(name):
     """(module, tiny config under selective remat, loss(params, batch))."""
     from ddl_tpu.models import (
-        afmoe, deepseek_v3, lfm2_moe, llama, minicpm_sala, moe, olmo_hybrid, vit)
+        afmoe, deepseek_v3, lfm2_moe, llama, minicpm_sala, moe, olmo_hybrid, vit,
+        xing4)
     from ddl_tpu.ops.sparse_attention import SparseConfig
 
     common = dict(vocab=64, d_model=32, n_layers=2, n_heads=2, max_seq=16,
@@ -56,6 +57,9 @@ def _family(name):
                     block=16, kernel=8, stride=4, topk=4, local_blocks=2))),
         "lfm2_moe": lambda: (
             lfm2_moe, lfm2_moe.Lfm2MoeConfig(remat="selective", held_experts=(0, 2))),
+        "xing4": lambda: (xing4, xing4.Xing4Config(
+            remat="selective", q_lora_rank=16, held_experts=(0, 4),
+            rope_scaling=xing4.Yarn(8.0, 8, 4.0, 1.0, 1.0, 1.0))),
     }[name]()
     return mod, cfg, lambda p, b: mod.next_token_loss(p, b[0], cfg)
 
@@ -95,7 +99,7 @@ OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 @pytest.mark.parametrize(
     "family", ["llama", "moe", "afmoe", "deepseek_v3", "vit", "olmo_hybrid",
-               "minicpm_sala", "lfm2_moe"])
+               "minicpm_sala", "lfm2_moe", "xing4"])
 def test_every_matmul_of_a_train_step_stands_under_a_scope(family):
     text = _compiled_step_text(family)
     seen = {}
@@ -146,6 +150,15 @@ def test_every_matmul_of_a_train_step_stands_under_a_scope(family):
             assert "ddl.moe_shared" not in seen[which], seen
         assert "ddl.shortconv_proj" in seen["forward"] & seen["backward"], seen
         assert "ddl.shortconv_proj" not in seen["recompute"], seen
+    if family == "xing4":
+        # A wrap's projections (the pass in front of the matrices) forward,
+        # backward (the weights' cotangent over the tokens) and again where
+        # the layer is rematerialised - XLA:CPU reports no HBM limit, so the
+        # mixing matrices are not kept; the module's ``W_eh`` under its own
+        # frame forward and backward.
+        for which in passes:
+            assert "ddl.hc_pre" in seen[which], seen
+        assert "ddl.mtp" in seen["forward"] & seen["backward"], seen
     # The module's name is what the reduction looks for.
     assert "HloModule jit__run" in text
 
@@ -163,10 +176,12 @@ def test_the_table_is_whole():
     grouped = [s for scopes_ in S.GROUPS.values() for s in scopes_]
     # The benchmark's groups, and the scopes its reader counts as ``other``:
     # exactly the eleven the linear-attention, selection and short-convolution
-    # readers select themselves, and the routed layer's full-width fallback.
+    # readers select themselves, the routed layer's full-width fallback, the
+    # hyper-connected path's two and the multi-token-prediction module's.
     from benchmarks.layers import (
-        gdn_dense_device_share, gdn_device_share, lightning_dense_device_share,
-        lightning_device_share, moe_overflow_device_share,
+        gdn_dense_device_share, gdn_device_share, hc_device_share,
+        lightning_dense_device_share, lightning_device_share,
+        moe_overflow_device_share, mtp_device_share,
         shortconv_dense_device_share, shortconv_device_share,
         sparse_select_device_share)
 
@@ -176,7 +191,8 @@ def test_the_table_is_whole():
     overflow = (moe_overflow_device_share.OVERFLOW_SCOPE,)
     conv = shortconv_dense_device_share.DENSE_SCOPES + (
         shortconv_device_share.CONV_SCOPE,)
-    assert sorted(grouped + list(gdn + sala + overflow + conv)) == sorted(
+    xing = hc_device_share.HC_SCOPES + (mtp_device_share.MTP_SCOPE,)
+    assert sorted(grouped + list(gdn + sala + overflow + conv + xing)) == sorted(
         naming.SCOPE_NAMES)
     with pytest.raises(AssertionError):
         naming.scope("ddl.not_in_the_table")

@@ -737,3 +737,45 @@ def test_a_share_compiles_under_its_remat_plan_and_stays_under_its_budget(
     assert kernel_names(compiled.as_text()) == (
         BLOCK_KERNELS | {k.replace("flash", "flash_swa") for k in BLOCK_KERNELS}
         | {"ragged-dot-none", "ragged-dot-metadata"})
+
+
+def test_a_hyper_connected_wrap_compiles_without_a_float32_stream(v5e):
+    """One wrap, forward and backward, at the Xing4.0 cell's shape (1 x 4 x
+    8,192 x 3,584, bfloat16) for a described v5e: XLA's fusions run every
+    pass, the float32 projections are bfloat16 matmuls over the stream itself
+    (72 columns: the weights split three ways), and NO float32 array of the
+    stream's or of a row's size is written - ``models/hyper_connections.py``'s
+    claim, read off the compiled program."""
+    from unittest import mock
+
+    from ddl_tpu.models import hyper_connections as hc
+
+    sharding = SingleDeviceSharding(v5e[0])
+    n, T, C = 4, 8192, 3584
+    settings = hc.HyperConnections()
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    wrap = {row.name.split(".")[1]: spec(row.shape, row.dtype)
+            for row in hc.wrap_rows("w", n, C)}
+
+    def step(X, y, wrap):
+        def loss(X, y, wrap):
+            h, post, res = hc.hc_pre(X, wrap, settings)
+            out = hc.hc_post(X, (y * h).astype(y.dtype), post, res)
+            return jnp.sum(out.astype(jnp.float32) ** 2)
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(X, y, wrap)
+
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        compiled = jax.jit(step).lower(
+            spec((1, n, T, C), jnp.bfloat16), spec((1, T, C), jnp.bfloat16), wrap
+        ).compile()
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    wide = [ln.strip()[:160] for ln in entry.splitlines()
+            if re.search(r"= f32\[[^\]]*8192,3584\]", ln)]
+    assert not wide, wide
+    assert re.search(r"f32\[(1,)?72,8192\]", entry)  # the split projections
+    assert "tpu_custom_call" not in text  # no kernel yet: XLA's fusions
+    # the stream in and its cotangent out, a row each way, and under half a
+    # GiB of temporaries beside them
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**29
