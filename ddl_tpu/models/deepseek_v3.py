@@ -21,6 +21,15 @@ Every layer, with ``h = RMSNorm(x)`` and pre-norm residuals
   they are (``attention(q_rope=, k_rope=)``): on a TPU the
   ``ddl_flash_mla_*`` kernels, which read the shared key through their
   index map, so neither a 192-wide q/k nor the H-fold ``k_r`` is written.
+  The per-head cuts are made in the WEIGHTS (:func:`_head_columns`: ``Wq``
+  or ``Wq_b`` into its ``q_nope`` and ``q_rope`` columns, ``Wkv_b`` into
+  ``k_nope``'s and ``v``'s), so each of the four comes out of a product of
+  its own as ``(B, T, H * width)`` and is only reshaped: no ``(B, T, H,
+  192)`` or ``(B, T, H, 256)`` activation exists to be sliced per head in
+  the forward pass or padded and added per head in the backward - strided
+  copies of 134-201 MB arrays a layer that XLA did not fuse (PERF.md
+  section 6, PR 48).  The parameters, their names and their initial values
+  are what they were.
   With ``rope_scaling`` (:class:`Yarn`; Kanana-2 has none) the rotary
   frequencies are YaRN's blend (:func:`yarn_inv_freq`), cos and sin carry
   ``m(mscale) / m(mscale_all_dim)`` and the score's scale is multiplied by
@@ -263,6 +272,20 @@ def _rope_pairs(x: jax.Array, positions: jax.Array, theta: float,
     return x if factor == 1.0 else (x * factor).astype(x.dtype)
 
 
+def _head_columns(w: jax.Array, H: int, at: int) -> Tuple[jax.Array, jax.Array]:
+    """A ``(d, H * width)`` weight whose columns are ``H`` heads' blocks, cut
+    at column ``at`` of every block: ``(d, H * at)`` and ``(d, H * (width -
+    at))``.  The WEIGHT is split so that the activations never are: each
+    product comes out as all heads' columns of one kind side by side,
+    ``(B, T, H * D)``, which XLA lets the matmul write in the attention
+    kernels' head-major layout itself - where a split of ``(B, T, H,
+    width)`` per head is a strided copy of an activation, forward, and a
+    pad-and-add of one, backward."""
+    w = w.reshape(w.shape[0], H, -1)
+    return (w[:, :, :at].reshape(w.shape[0], -1),
+            w[:, :, at:].reshape(w.shape[0], -1))
+
+
 def attn(
     layer: Params,
     x: jax.Array,
@@ -287,23 +310,24 @@ def attn(
         h = _decoder.rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         with scope("ddl.mla_q"):
             if cfg.q_lora_rank is None:
-                q = _remat.tag(h @ layer["wq"].astype(dt), _remat.PROJ)
+                q_in, w, kind = h, layer["wq"].astype(dt), _remat.PROJ
             else:
                 q_a = _remat.tag(h @ layer["wq_a"].astype(dt), _remat.PROJ)
-                q_a = _decoder.rms_norm(q_a, layer["q_a_norm"], cfg.norm_eps)
-                q = _remat.tag(q_a @ layer["wq_b"].astype(dt), _remat.LATENT_UP)
-            q = q.reshape(B, T, H, -1)
-            q_nope = q[..., :nope]
-            q_rope = _rope_pairs(q[..., nope:], *turn)
+                q_in = _decoder.rms_norm(q_a, layer["q_a_norm"], cfg.norm_eps)
+                w, kind = layer["wq_b"].astype(dt), _remat.LATENT_UP
+            q_nope, q_rope = (
+                _remat.tag(q_in @ part, kind).reshape(B, T, H, -1)
+                for part in _head_columns(w, H, nope))
+            q_rope = _rope_pairs(q_rope, *turn)
         with scope("ddl.mla_kv_up"):
             kv_a = _remat.tag(  # (B, T, rank + rope)
                 h @ layer["wkv_a"].astype(dt), _remat.PROJ)
             c = _decoder.rms_norm(
                 kv_a[..., :rank], layer["kv_a_norm"], cfg.norm_eps
             )
-            kv = _remat.tag(c @ layer["wkv_b"].astype(dt), _remat.LATENT_UP)
-            kv = kv.reshape(B, T, H, -1)
-            k_nope, v = kv[..., :nope], kv[..., nope:]
+            k_nope, v = (
+                _remat.tag(c @ part, _remat.LATENT_UP).reshape(B, T, H, -1)
+                for part in _head_columns(layer["wkv_b"].astype(dt), H, nope))
             # One rotary key a position, for all heads.
             k_rope = _rope_pairs(kv_a[..., None, rank:], *turn)
         out = attention(

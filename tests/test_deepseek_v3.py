@@ -773,3 +773,45 @@ def test_without_the_new_fields_a_layer_is_kananas():
     # a low-rank layer draws one key more: its table's, not Kanana's
     assert deepseek_v3._table(cfg).n_keys == (2, 11)
     assert deepseek_v3._table(_low_rank_case()).n_keys == (2, 12)
+
+
+# -- the weights are cut by head, the activations never (PR 48) ------------------
+
+
+@pytest.mark.parametrize("H,width,at", [(4, 24, 16), (32, 192, 128), (2, 256, 128)])
+def test_head_columns_are_each_heads_columns_side_by_side(H, width, at):
+    """``_head_columns``: the two products against its parts are, head for
+    head, the two slices of the one product against the whole weight."""
+    rng = np.random.default_rng(0)
+    w = jnp.asarray(rng.standard_normal((8, H * width)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((3, 8)), jnp.float32)
+    first, rest = deepseek_v3._head_columns(w, H, at)
+    assert first.shape == (8, H * at) and rest.shape == (8, H * (width - at))
+    whole = (x @ w).reshape(3, H, width)
+    for part, cut in ((first, slice(None, at)), (rest, slice(at, None))):
+        np.testing.assert_array_equal(  # the same numbers, moved
+            part.reshape(8, H, -1), w.reshape(8, H, width)[..., cut])
+        np.testing.assert_allclose(  # (a CPU matmul's sums move with its width)
+            (x @ part).reshape(3, H, -1), whole[..., cut], rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("q_lora_rank", [None, 24], ids=["kanana", "xing4"])
+def test_no_activation_is_split_or_joined_by_head_around_the_latent_kernels(
+        q_lora_rank):
+    """The traced train step of a layer: no ``slice``, ``pad`` or
+    ``concatenate`` of a ``(B, T, H, nope + rope)`` or ``(B, T, H, nope +
+    v)`` activation or cotangent - the strided copies of 134-201 MB arrays a
+    layer that XLA made of them, forward and backward (PERF.md section 6, PR
+    48) - is left: q_nope, q_rope, k_nope and v come out of products against
+    the weights' own columns and their cotangents go back into them."""
+    cfg = tiny(n_layers=1, n_dense_layers=1, q_lora_rank=q_lora_rank)
+    layer = deepseek_v3.init_params(cfg, jax.random.key(0))["layers"][0]
+    x = jnp.zeros((B, T, cfg.d_model))
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda l, x: deepseek_v3.attn(l, x, cfg, jnp.arange(T), None).sum(),
+        (0, 1)))(layer, x))
+    H, nope = cfg.n_heads, cfg.qk_nope_dim
+    whole = {f"f32[{B},{T},{H},{nope + w}]" for w in (cfg.qk_rope_dim, cfg.v_head_dim)}
+    assert not any(shape in text for shape in whole), text
+    # the parts are there, as the products' own reshapes
+    assert f"f32[{B},{T},{H * nope}]" in text and f"f32[{B},{T},{H},{nope}]" in text

@@ -680,7 +680,13 @@ def test_which_kernels_a_call_lowers_to(case, want):
 
 
 #: sha256 of the traced train steps below, source locations and function
-#: addresses taken out, on PR 45's tree (the child of 90a08e5), which changed
+#: addresses taken out.  ``kanana`` is PR 48's tree (the child of 41492d9),
+#: changed on purpose: latent attention cuts its projections' WEIGHTS by
+#: head (``deepseek_v3._head_columns``) where it sliced the activations, so
+#: the step holds two products for q and two for k / v and no per-head
+#: slice, pad or concatenate of a ``(B, T, H, 192 | 256)`` array; the
+#: kernels and their calls are the parent's.  The others are PR 45's tree
+#: (the child of 90a08e5), which changed
 #: five of them on purpose: the backward pass of a blockwise flash call is
 #: ONE kernel, the dK/dV grid carrying dQ, where a ``dq`` and a ``dkv``
 #: kernel stood (forward once, one backward kernel a layer, no ``*_bwd_dq``
@@ -703,7 +709,7 @@ PARENT_STEP_SHA256 = {
     "trinity":
         "56bade065d726d9bb3ded3e4722a5fe03b9eaf36e7425f8961e45f50dec8563d",
     "kanana":
-        "6140a520a734e28f12c62508e020f0bab6290a69e8e48e419d450bfb3837e540",
+        "cb4b4b4c0eb0ce81a7a069cefebc9de5fbb3db2fd7e8d3b9b8796492179a0b9a",
     "olmo_hybrid":
         "2f62853826f3544759a431230e1d0ad7e2f23cfa8d8fdc326f2738c751093719",
     "minicpm_sala":
