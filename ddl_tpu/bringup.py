@@ -14,6 +14,7 @@ Producer workers never call this module (they stay off JAX).
 from __future__ import annotations
 
 import os
+import time
 from typing import Optional
 
 #: The checkout's own cache directory (git-ignored).  A FIXED path: the
@@ -80,19 +81,27 @@ def bring_up(request: Optional[str] = None) -> str:
     ``request`` is what the caller was asked for BY NAME: ``"cpu"``
     selects the CPU backend; ``None``/``""``/``"tpu"`` require a TPU and
     raise ``SystemExit`` with the reason when JAX finds anything else
-    (exit code 1, nothing measured).  Also places the compile cache.
+    (exit code 1, nothing measured).  Also places the compile cache, and
+    starts the start-up record's listening (``profiling.startup_record``:
+    the whole of this call is its ``ddl.bring_up`` row).
     """
     if request not in (None, "", "cpu", "tpu"):
         raise ValueError(f"platform request must be cpu|tpu, got {request!r}")
+    entered = time.monotonic()
     import jax
 
-    if request == "cpu":
-        jax.config.update("jax_platforms", "cpu")
-    configure_compile_cache()
-    try:
-        platform = jax.devices()[0].platform
-    except RuntimeError as e:  # no backend could be initialised
-        raise SystemExit(f"JAX found no usable backend: {e}") from e
+    from ddl_tpu import profiling
+    from ddl_tpu.observability import metrics
+
+    profiling.listen_for_builds()
+    with profiling.stage("ddl.bring_up", metrics(), started=entered):
+        if request == "cpu":
+            jax.config.update("jax_platforms", "cpu")
+        configure_compile_cache()
+        try:
+            platform = jax.devices()[0].platform
+        except RuntimeError as e:  # no backend could be initialised
+            raise SystemExit(f"JAX found no usable backend: {e}") from e
     if request != "cpu" and platform != "tpu":
         raise SystemExit(
             f"this run needs a TPU and JAX found only {platform!r}; a CPU "

@@ -81,6 +81,7 @@ LOCK_ORDER: Tuple[str, ...] = (
     "faults.plan",
     "obs.metrics",
     "obs.spans",
+    "obs.startup",
     "obs.recorder.dump",
 )
 

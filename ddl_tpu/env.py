@@ -36,6 +36,8 @@ from typing import Any, Callable, List, Optional
 from ddl_tpu import envspec
 from ddl_tpu.exceptions import ShutdownRequested, TransportError
 from ddl_tpu.faults import fault_point
+from ddl_tpu.observability import metrics as default_metrics
+from ddl_tpu.profiling import stage
 from ddl_tpu.transport.connection import (
     ConsumerConnection,
     PipeChannel,
@@ -583,18 +585,21 @@ def distributed_dataloader(
     def deco(f: Callable[..., Any]) -> Callable[..., Any]:
         @functools.wraps(f)
         def wrapper(*args: Any, **kwargs: Any) -> Any:
-            _export_cluster_knobs(config)
-            topology = detect_topology(n_producers, mode, host_id, n_hosts)
-            depth = nslots or envspec.get("DDL_TPU_NSLOTS")
-            _export_cache_knobs(config)
-            _export_wire_knobs(config)
-            _export_shuffle_knobs(config)
-            _export_tune_knobs(config)
-            workers = WorkerSet(topology, depth, shuffler_factory)
-            env = DDL_Env(
-                topology=topology, connection=workers.connection,
-                workers=workers,
-            )
+            with stage("ddl.pool_start", default_metrics()):
+                _export_cluster_knobs(config)
+                topology = detect_topology(
+                    n_producers, mode, host_id, n_hosts
+                )
+                depth = nslots or envspec.get("DDL_TPU_NSLOTS")
+                _export_cache_knobs(config)
+                _export_wire_knobs(config)
+                _export_shuffle_knobs(config)
+                _export_tune_knobs(config)
+                workers = WorkerSet(topology, depth, shuffler_factory)
+                env = DDL_Env(
+                    topology=topology, connection=workers.connection,
+                    workers=workers,
+                )
             logger.info(
                 "ddl_tpu: %s mode, %d producer(s), instance %d/%d, %d slot(s)",
                 topology.mode.value,
@@ -609,8 +614,9 @@ def distributed_dataloader(
                 # Idempotent: wakes producers still blocked anywhere —
                 # pre-handshake (ABORT sentinel) or in a ring wait
                 # (shutdown flag). Producers already exited ignore both.
-                workers.abort()
-                workers.join(timeout_s=30.0)
+                with stage("ddl.pool_stop", default_metrics()):
+                    workers.abort()
+                    workers.join(timeout_s=30.0)
             return result
 
         return wrapper

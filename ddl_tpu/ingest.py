@@ -688,6 +688,16 @@ def north_star_report(
         src: m.counter(f"tune.cost_source.{src}")
         for src in ("measured", "declared", "default")
     }
+    # Start-up (ddl_tpu.profiling.startup_record, PROCESS-wide like the
+    # pp gauges: a build has no registry to land in): seconds by phase
+    # up to the last fit's first dispatch — backend bring-up, the four
+    # build kinds, the Trainer's share of them, a fit's mean time to its
+    # first window and to stop — and the slowest programs with their
+    # kind and the compile cache's verdict.  "My first step takes
+    # minutes" is answered here (docs/OBSERVABILITY.md).
+    from ddl_tpu.profiling import startup_record
+
+    report["startup"] = startup_record().summary()
     if link_bytes_per_sec:
         report["link_bytes_per_sec"] = link_bytes_per_sec
         report["bandwidth_utilization"] = (

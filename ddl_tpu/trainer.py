@@ -32,7 +32,7 @@ from jax.sharding import PartitionSpec as P
 
 from ddl_tpu.datasetwrapper import ProducerFunctionSkeleton
 from ddl_tpu.observability import Metrics, metrics as default_metrics
-from ddl_tpu.profiling import stage
+from ddl_tpu.profiling import listen_for_builds, stage, startup_record
 
 logger = logging.getLogger("ddl_tpu")
 
@@ -139,6 +139,9 @@ class Trainer:
         ``FitResult.preempted`` set."""
         from ddl_tpu.parallel.train import make_train_step
 
+        # Every program this Trainer builds lands in the start-up record
+        # with the stage that caused it (idempotent).
+        listen_for_builds()
         if accum_steps is None:
             accum_steps = (
                 train_config.accum_steps if train_config is not None else 1
@@ -557,6 +560,7 @@ class Trainer:
             loader, stream, state, multi_for, col_splits, window_hook,
             hook_state, epoch_losses, start_epoch,
         )
+        startup_record().stamp("last_readback")
         for i, mean in enumerate(epoch_losses):
             logger.info(
                 "trainer: epoch %d/%d mean loss %.6f (windowed)",
@@ -866,7 +870,8 @@ class Trainer:
         )
         def _main(env):
             trainer._preempted = False
-            state, start_epoch = trainer._restore_or_init()
+            with stage("ddl.state_init", trainer.metrics):
+                state, start_epoch = trainer._restore_or_init()
             lkw = dict(loader_kwargs or {})
             if output == "jax" and "sharding" not in lkw:
                 # Batches land directly sharded over the mesh instead of
@@ -881,18 +886,19 @@ class Trainer:
                     else trainer._batch_spec
                 )
                 lkw["sharding"] = _named(trainer.mesh, spec)
-            loader = DistributedDataLoader(
-                producer_function,
-                batch_size=batch_size,
-                connection=env.connection,
-                n_epochs=n_epochs,
-                output=output,
-                metrics=trainer.metrics,
-                global_shuffle_fraction_exchange=(
-                    global_shuffle_fraction_exchange
-                ),
-                **lkw,
-            )
+            with stage("ddl.loader_attach", trainer.metrics):
+                loader = DistributedDataLoader(
+                    producer_function,
+                    batch_size=batch_size,
+                    connection=env.connection,
+                    n_epochs=n_epochs,
+                    output=output,
+                    metrics=trainer.metrics,
+                    global_shuffle_fraction_exchange=(
+                        global_shuffle_fraction_exchange
+                    ),
+                    **lkw,
+                )
             if start_epoch >= n_epochs:
                 # Nothing left to run (fit re-invoked with fewer epochs
                 # than the checkpoint already completed).
@@ -947,9 +953,10 @@ class Trainer:
                         stream_lookahead=stream_lookahead, fused=fused,
                     )
                 finally:
-                    trainer._finish_checkpoints()
-                    if wd is not None:
-                        wd.stop()
+                    with stage("ddl.pool_stop", trainer.metrics):
+                        trainer._finish_checkpoints()
+                        if wd is not None:
+                            wd.stop()
             try:
                 for epoch in range(start_epoch, n_epochs):
                     batch_losses: List[Any] = []
@@ -988,9 +995,10 @@ class Trainer:
                         trainer._preempt_drain(state, loader)
                         break
             finally:
-                trainer._finish_checkpoints()
-                if wd is not None:
-                    wd.stop()
+                with stage("ddl.pool_stop", trainer.metrics):
+                    trainer._finish_checkpoints()
+                    if wd is not None:
+                        wd.stop()
             return FitResult(
                 state=state,
                 losses=epoch_losses,
@@ -1004,4 +1012,11 @@ class Trainer:
                 preempted=trainer._preempted,
             )
 
-        return _main()
+        # The start-up record's fit: stamped at entry, at the first
+        # window and the first dispatch (by their stages), after the
+        # last read-back and here, once the pool is down.
+        fit_row = startup_record().begin_fit()
+        try:
+            return _main()
+        finally:
+            startup_record().end_fit(fit_row)

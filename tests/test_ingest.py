@@ -242,8 +242,13 @@ class TestNorthStarReport:
             # self-tuning extras (ISSUE 20: ddl_tpu/tune —
             # calibration/controller decision counts + provenance)
             "tune_decisions", "tune_reverts", "tune_cost_source",
+            # start-up (PR 49: profiling.startup_record().summary())
+            "startup",
         }
         assert r["samples_per_sec"] > 0
+        assert set(r["startup"]) == {
+            "fits", "seconds", "slow_compiles", "stages", "slowest_programs"
+        }
         # The per-tenant stall block is a DICT keyed by tenant name
         # (empty when no tenancy ran), not a flat float.
         assert isinstance(r["serve_tenant_stall"], dict)

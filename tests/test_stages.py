@@ -17,7 +17,7 @@ from ddl_tpu import (
 )
 from ddl_tpu import obs
 from ddl_tpu.obs import spans as obs_spans
-from ddl_tpu.observability import Metrics
+from ddl_tpu.observability import Metrics, metrics as default_metrics
 from ddl_tpu.profiling import STAGES, stage
 from ddl_tpu.readers import ArrayProducer
 
@@ -219,16 +219,34 @@ def _fit(m):
     assert len(res.losses) == 3
 
 
+#: The stages whose sites have no registry of their own (`bringup.py`,
+#: `env.py`'s wrapper before the user's main): they time into the
+#: process default.  Every other row honours the `metrics` it is handed.
+ON_THE_DEFAULT_REGISTRY = ("ddl.bring_up", "ddl.pool_start")
+
+
 @pytest.fixture(scope="module")
 def tour(tmp_path_factory):
     """One THREAD-mode tour of the data plane — staged stream, inline
     stream with attached sources, batch iteration, an ICI-distributed
-    stream on the virtual mesh, a fused ``Trainer.fit`` — under an armed
-    SpanLog and a CPU ``jax.profiler`` trace."""
+    stream on the virtual mesh, a fused ``Trainer.fit`` (and with it the
+    start-up stages) — under an armed SpanLog and a CPU ``jax.profiler``
+    trace."""
+    from ddl_tpu import bringup
+
     trace_dir = str(tmp_path_factory.mktemp("stages"))
     m = Metrics()
+    before = {
+        name: default_metrics().timer(STAGES[name].timer).count
+        for name in ON_THE_DEFAULT_REGISTRY
+    }
     dp8 = NamedSharding(Mesh(np.array(jax.devices()), ("dp",)), P("dp"))
+    cache_dir = jax.config.jax_compilation_cache_dir
     with obs_spans.tracing() as slog, profiling.trace(trace_dir):
+        # A process's first call; here only for its stage (the process-
+        # wide compile-cache placement is put back at once).
+        bringup.bring_up("cpu")
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
         _stream(m, staged=True)
         _stream(m, staged=False, pin_attached=True)
         _stream(m, batches=True)
@@ -239,6 +257,7 @@ def tour(tmp_path_factory):
     return {
         "annotations": host_annotations(trace_dir),
         "metrics": m,
+        "default_before": before,
         "spans": {e[2] for e in slog.events()},
         "keyed": {e[2] for e in slog.events() if e[3] is not None},
     }
@@ -248,7 +267,11 @@ def tour(tmp_path_factory):
 def test_every_stage_row_is_reachable(tour, name):
     row = STAGES[name]
     assert name in tour["annotations"], "not on the profiler's host plane"
-    if row.timer:
+    if name in ON_THE_DEFAULT_REGISTRY:
+        # Shared by every test of the process: the TOUR has to have timed.
+        count = default_metrics().timer(row.timer).count
+        assert count > tour["default_before"][name]
+    elif row.timer:
         assert tour["metrics"].timer(row.timer).count >= 1
     if row.span:
         assert row.span in tour["spans"]

@@ -236,7 +236,7 @@ class LintConfig:
     #: ``deque(maxlen=...)``-bounded attribute — an armed log on a
     #: week-long run must drop oldest events, never eat the host.
     obs_event_buffer_classes: List[str] = dataclasses.field(
-        default_factory=lambda: ["SpanLog", "FlightRecorder"]
+        default_factory=lambda: ["SpanLog", "FlightRecorder", "StartupRecord"]
     )
     #: Per-SAMPLE hot functions (DDL023 half 2): span emission inside
     #: their loops is a finding — per-window spans are sanctioned,
