@@ -11,8 +11,11 @@ The stack carries ``X (B, n, T, C)``, ``n = hc_mult`` streams (the layout is
   RMSNorm(x_L) W_head``.
 - **a layer** is two wraps, each with mixing parameters of its own::
 
-      h, Hpost, Hres = hc_pre(X; hc_attn);  X = hc_post(X, Attn(RMSNorm(h)), Hpost, Hres)
-      h, Hpost, Hres = hc_pre(X; hc_mlp);   X = hc_post(X, FFN(RMSNorm(h)), Hpost, Hres)
+      h, Hpost, Hres, X = hc_pre(X; hc_attn);  X = hc_post(X, Attn(RMSNorm(h)), Hpost, Hres)
+      h, Hpost, Hres, X = hc_pre(X; hc_mlp);   X = hc_post(X, FFN(RMSNorm(h)), Hpost, Hres)
+
+  (``hc_pre`` hands ``X`` on to ``hc_post``: the stream's two cotangents
+  then meet in one pass of the wrap's backward.)
 
   ``Attn`` is ``models/deepseek_v3.py``'s latent attention with the query's
   low-rank step and YaRN (:func:`ddl_tpu.models.deepseek_v3.attn`), without
@@ -153,12 +156,12 @@ def _layer_apply(layer: Params, X: jax.Array, cfg: Xing4Config,
     auxiliary loss)."""
     hc = cfg.hc
     with scope("ddl.hc_pre"):
-        h, post, res = _hc.hc_pre(X, layer["hc_attn"], hc)
+        h, post, res, X = _hc.hc_pre(X, layer["hc_attn"], hc)
     y = _deepseek.attn(layer, h, cfg, positions, mesh, residual=False)
     with scope("ddl.hc_post"):
         X = _hc.hc_post(X, y, post, res)
     with scope("ddl.hc_pre"):
-        h, post, res = _hc.hc_pre(X, layer["hc_mlp"], hc)
+        h, post, res, X = _hc.hc_pre(X, layer["hc_mlp"], hc)
     with scope("ddl.mlp" if dense else "ddl.moe"):
         h = _decoder.rms_norm(h, layer["mlp_norm"], cfg.norm_eps)
         if dense:

@@ -62,6 +62,11 @@ KERNEL_NAMES = (
     # flash kernels whose key blocks come from scalar-prefetched lists
     "ddl_sparse_select",
     "ddl_flash_sparse_fwd", "ddl_flash_sparse_bwd_dq", "ddl_flash_sparse_bwd_dkv",
+    # a hyper-connected wrap's passes over the four-row stream, one read of
+    # it each (``ops/hyper_connections.py``); the benchmark's
+    # ``hc_device_share`` / ``hc_roofline_share`` read ``ddl_hc_`` beside the
+    # ``ddl.hc_pre`` / ``ddl.hc_post`` scopes
+    "ddl_hc_pre_fwd", "ddl_hc_pre_bwd", "ddl_hc_post_fwd", "ddl_hc_post_bwd",
     "ddl_ici_bcast", "ddl_ici_scatter", "ddl_shuffle_exchange",
 )
 

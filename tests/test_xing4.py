@@ -12,6 +12,7 @@ the norm weights moved off 1 and the router scaled up.
 """
 
 import collections
+import contextlib
 import dataclasses
 import json
 import os
@@ -27,6 +28,9 @@ import reference_xing4 as ref
 from ddl_tpu.models import decoder, deepseek_v3, moe, remat, xing4
 from ddl_tpu.models import hyper_connections as hc
 from ddl_tpu.models.deepseek_v3 import Yarn
+
+from hcsupport import (
+    HC_KERNELS, TILED, TOY, _wrap, close, kernel_names, plain_wrap, xla_passes)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -104,13 +108,6 @@ def seeded(cfg):
 @pytest.fixture(scope="module")
 def tokens():
     return jnp.asarray(np.random.default_rng(46).integers(0, 128, (B, T)), jnp.int32)
-
-
-def close(got, want, tol, what, floor=0.0):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    scale = max(float(np.max(np.abs(want))), floor, 1e-30)
-    err = float(np.max(np.abs(got - want))) / scale
-    assert err <= tol, f"{what}: {err:.3g} of the largest magnitude, limit {tol}"
 
 
 def reference_side(cfg, params, tokens, c=None):
@@ -203,68 +200,44 @@ def test_a_bf16_reference_fails_the_float32_tolerance(tokens):
 # -- the wraps ----------------------------------------------------------------------
 
 
-def _wrap(n=4, C=24, seed=0, off=0.2):
-    keys = iter(jax.random.split(jax.random.key(seed), 32))
-    wrap = {}
-    for row in hc.wrap_rows("w", n, C):
-        name = row.name.split(".")[1]
-        if row.fill is not None:
-            value = jnp.full(row.shape, row.fill, row.dtype)
-        elif row.draw is not None:
-            value = row.draw(next(keys), row.shape)
-        else:
-            value = decoder.dense_init(next(keys), row.shape[-2], row.shape, row.dtype)
-        wrap[name] = 30.0 * value if name.startswith("alpha") else (
-            value + off * jax.random.normal(next(keys), value.shape))
-    return wrap
-
-
-def plain_wrap(X, wrap, settings, F):
-    """One wrap written out for ``jax.grad`` - no ``custom_vjp``, the float32
-    matmuls at ``highest``: what the routines are held to."""
-    Bx, n, Tx, C = X.shape
-    Xf = X.astype(jnp.float32)
-    flat = jnp.moveaxis(Xf, 1, 2).reshape(Bx, Tx, n * C)
-    xb = flat * jax.lax.rsqrt(
-        jnp.mean(flat * flat, axis=-1, keepdims=True) + settings.norm_eps
-    ) * wrap["norm"].astype(jnp.float32)
-    phi = jnp.concatenate(
-        [wrap["phi_pre"], wrap["phi_post"], wrap["phi_res"]], axis=-1)
-    z = jnp.einsum("btk,km->bmt", xb, phi.astype(jnp.float32),
-                   precision=jax.lax.Precision.HIGHEST)
-    pre, post, res = hc.matrices(z, wrap, settings)
-    h = jnp.einsum("bit,bitc->btc", pre, Xf).astype(X.dtype)
-    y = F(h).astype(jnp.float32)
-    out = jnp.einsum("bijt,bjtc->bitc", res, Xf) + post[..., None] * y[:, None]
-    return out.astype(X.dtype)
-
-
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 2e-2)],
                          ids=["float32", "bfloat16"])
-def test_the_wraps_own_backward_is_autodiffs_of_the_plain_form(dtype, tol):
+@pytest.mark.parametrize("shape,path", [
+    (TOY, "xla"), (TILED, "kernels"), (TILED, "xla")], ids=["toy", "kernels", "tiled-xla"])
+def test_the_wraps_own_backward_is_autodiffs_of_the_plain_form(shape, path, dtype, tol):
     """``hc_pre`` / ``hc_post`` (``custom_vjp``, the stream's passes written
-    out, the projections over the bfloat16 stream with split weights) against
-    ``jax.grad`` of :func:`plain_wrap`: the result and every
-    cotangent.  In bfloat16 both sides round the stream's cotangent once."""
-    n, C, Tn = 4, 24, 11
+    out or as the ``ddl_hc_*`` kernels in interpret mode, the projections over
+    the bfloat16 stream with split weights) against ``jax.grad`` of
+    :func:`plain_wrap`: the result and every cotangent - the stream's, ``F``'s
+    weight's (which is ``y``'s) and all ten wrap leaves'.  In bfloat16 both
+    sides round the stream's cotangent once.  ``hc_pre`` hands the stream on
+    as ``xing4._layer_apply`` takes it, so that ``hc_post``'s cotangent of it
+    is added inside ``_hc_pre_bwd``'s pass."""
+    (Bn, Tn, C), n = shape, 4
     wrap, settings = _wrap(n, C), hc.HyperConnections()
-    X = jax.random.normal(jax.random.key(1), (2, n, Tn, C)).astype(dtype)
+    X = jax.random.normal(jax.random.key(1), (Bn, n, Tn, C)).astype(dtype)
     W = jax.random.normal(jax.random.key(2), (C, C)) / np.sqrt(C)
-    F = lambda h: jnp.tanh(h.astype(jnp.float32) @ W).astype(h.dtype)
+    F = lambda h, W: jnp.tanh(h.astype(jnp.float32) @ W).astype(h.dtype)
     ct = jax.random.normal(jax.random.key(3), X.shape)
 
-    def system(X, wrap):
-        h, post, res = hc.hc_pre(X, wrap, settings)
-        return hc.hc_post(X, F(h), post, res)
+    def system(X, wrap, W):
+        h, post, res, X = hc.hc_pre(X, wrap, settings)
+        return hc.hc_post(X, F(h, W), post, res)
 
-    plain = lambda X, wrap: plain_wrap(X, wrap, settings, F)
-    close(system(X, wrap), plain(X, wrap), tol, "X'")
-    value = lambda f: lambda X, w: jnp.sum(f(X, w).astype(jnp.float32) * ct)
-    got = jax.grad(value(system), argnums=(0, 1))(X, wrap)
-    want = jax.grad(value(plain), argnums=(0, 1))(X, wrap)
-    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
-                            jax.tree.leaves(want)):
-        close(g, w, tol, "d" + jax.tree_util.keystr(path))
+    plain = lambda X, wrap, W: plain_wrap(X, wrap, settings, lambda h: F(h, W))
+    value = lambda f: lambda *a: jnp.sum(f(*a).astype(jnp.float32) * ct)
+    with xla_passes() if path == "xla" else contextlib.nullcontext():
+        want_kernels = HC_KERNELS if path == "kernels" else set()
+        assert kernel_names(jax.grad(value(system)), X, wrap, W) == want_kernels
+        out = system(X, wrap, W)
+        got = jax.grad(value(system), argnums=(0, 1, 2))(X, wrap, W)
+    close(out, plain(X, wrap, W), tol, "X'")
+    want = jax.grad(value(plain), argnums=(0, 1, 2))(X, wrap, W)
+    # a wide stream's weights' cotangents are sums over 512 tokens of products
+    # rounded to bfloat16 once a side
+    for (path_, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                             jax.tree.leaves(want)):
+        close(g, w, tol, "d" + jax.tree_util.keystr(path_))
 
 
 def test_hres_is_doubly_stochastic_after_twenty_rounds():
@@ -279,13 +252,13 @@ def test_hres_is_doubly_stochastic_after_twenty_rounds():
     X = jax.random.normal(jax.random.key(4), (2, n, 9, C))
     fresh = {**_wrap(n, C, off=0.0)}
     fresh.update({k: v / 30.0 for k, v in fresh.items() if k.startswith("alpha")})
-    _, post, res = hc.hc_pre(X, fresh, settings)
+    _, post, res, _ = hc.hc_pre(X, fresh, settings)
     assert res.shape == (2, n, n, 9) and post.shape == (2, n, 9)
     np.testing.assert_allclose(np.asarray(res.sum(axis=2)), 1.0, atol=1e-5)  # rows
     np.testing.assert_allclose(np.asarray(res.sum(axis=1)), 1.0, atol=2e-4)  # columns
     assert float(jnp.max(jnp.std(res, axis=-1))) > 1e-5  # a token's own matrix
     wrap = _wrap(n, C, off=0.02)
-    _, post, res = hc.hc_pre(X, wrap, settings)
+    _, post, res, _ = hc.hc_pre(X, wrap, settings)
     np.testing.assert_allclose(np.asarray(res.sum(axis=2)), 1.0, atol=1e-5)
     np.testing.assert_allclose(np.asarray(res.sum(axis=1)), 1.0, atol=2e-2)
     c = ref_config(tiny())
@@ -330,10 +303,10 @@ def test_the_shares_add_up_to_the_uncut_layer():
     positions = jnp.arange(T)
 
     # the layer up to its second wrap's sub-block, once
-    h, post, res = hc.hc_pre(X, layer["hc_attn"], whole.hc)
+    h, post, res, _ = hc.hc_pre(X, layer["hc_attn"], whole.hc)
     X_mid = hc.hc_post(
         X, deepseek_v3.attn(layer, h, whole, positions, None, residual=False), post, res)
-    h, post, res = hc.hc_pre(X_mid, layer["hc_mlp"], whole.hc)
+    h, post, res, _ = hc.hc_pre(X_mid, layer["hc_mlp"], whole.hc)
     h = decoder.rms_norm(h, layer["mlp_norm"], whole.norm_eps)
     shared = decoder.swiglu(layer["shared"], h)
     routed, held_choices = jnp.zeros_like(h), 0
@@ -454,60 +427,91 @@ def test_one_window_step_moves_the_parameters_as_plain_adamw_does(tokens):
     assert size(want_moved) > 0.1
 
 
+#: (d_model, T) of the tiny model: the toy's, whose stream takes XLA's passes,
+#: and one token tile of one lane's width, which takes the kernels.
+STREAMS = {"toy": (32, T), "tiled": (128, 128)}
+
+
+def hc_passes(text: str) -> collections.Counter:
+    """The wraps' jitted passes in a traced program, by name; ``pre_fwd`` is
+    the pass over the stream for ``h``: XLA's ``_hc_read`` or the kernel's
+    ``_hc_pre_fwd``, which makes the 25 numbers in the same read."""
+    calls = collections.Counter(re.findall(r"name=_hc_(\w+)", text))
+    assert not (calls["read"] and calls["pre_fwd"]), calls
+    calls["pre_fwd"] += calls["read"]
+    return calls
+
+
+@pytest.mark.parametrize("stream", list(STREAMS))
 @pytest.mark.parametrize("policy", ["none", "selective", "full", "dots"])
-def test_the_benchmarks_pass_counts_are_the_traced_steps(policy):
+def test_the_benchmarks_pass_counts_are_the_traced_steps(policy, stream):
     """``benchmarks/lib/xing4_flops.py:HC_PASSES_PER_LAYER`` - what the
     residual path's bandwidth floor multiplies - is the number of passes in
-    the program's own train step, a layer that carries a stream."""
+    the program's own train step, a layer that carries a stream: XLA's passes
+    and the kernels alike."""
     from benchmarks.lib import xing4_flops
 
-    cfg = tiny(held_experts=(2, 4), remat=policy)
+    d_model, Tn = STREAMS[stream]
+    cfg = tiny(held_experts=(2, 4), remat=policy, d_model=d_model, max_seq=Tn)
     params = jax.eval_shape(lambda: xing4.init_params(cfg, jax.random.key(0)))
     text = str(jax.make_jaxpr(jax.value_and_grad(
         lambda p, t: xing4.next_token_loss(p, t, cfg)
-    ))(params, jax.ShapeDtypeStruct((B, T), jnp.int32)))
-    calls = collections.Counter(re.findall(r"name=_hc_(\w+)", text))
+    ))(params, jax.ShapeDtypeStruct((B, Tn), jnp.int32)))
+    calls = hc_passes(text)
     layers = cfg.n_layers + cfg.n_mtp
-    got = {
-        "pre_fwd": calls["read"] / layers, "post_fwd": calls["post_fwd"] / layers,
-        "pre_bwd": calls["pre_bwd"] / layers, "post_bwd": calls["post_bwd"] / layers,
-    }
+    got = {which: calls[which] / layers
+           for which in ("pre_fwd", "post_fwd", "pre_bwd", "post_bwd")}
     assert got == xing4_flops.HC_PASSES_PER_LAYER[policy], calls
     # nothing kept: a rematerialised wrap reads the stream for the 25 numbers
     # the matrices are made of again, and makes the matrices again
-    assert calls["project"] == calls["matrices"] == calls["read"]
+    assert calls["matrices"] == calls["pre_fwd"]
+    kernels = set(re.findall(r"ddl_hc_\w+", text))
+    if stream == "toy":
+        assert calls["project"] == calls["read"] == calls["pre_fwd"] and not kernels
+    else:
+        assert calls["project"] == calls["read"] == 0 and kernels == HC_KERNELS
 
 
-def test_the_mixing_matrices_are_the_first_kind_a_plan_keeps(tokens):
+@pytest.mark.parametrize("stream", list(STREAMS))
+def test_the_mixing_matrices_are_the_first_kind_a_plan_keeps(stream):
     """Under ``selective`` with a budget the 25 numbers a token the wraps'
     matrices are made of are tagged ``remat.HC``, 100 bytes a token and wrap,
     first in the order of worth; kept, a rematerialised wrap makes the
-    matrices from them and reads the stream once, for ``h``; loss and
-    gradients are what they were."""
+    matrices from them and reads the stream once, for ``h`` - XLA's
+    ``_hc_read``, or the kernel again, whose read is the same one; loss and
+    gradients are what they were (run at the toy's size; the tiled stream's
+    step is traced and counted)."""
     assert remat.KINDS[0] == remat.HC
-    cfg = tiny(held_experts=(2, 4), remat="selective", n_mtp=1)
+    d_model, Tn = STREAMS[stream]
+    cfg = tiny(held_experts=(2, 4), remat="selective", n_mtp=1, d_model=d_model,
+               max_seq=Tn)
     params = seeded(cfg)
+    tokens = jnp.asarray(np.random.default_rng(46).integers(0, 128, (B, Tn)), jnp.int32)
     loss = lambda p: xing4.next_token_loss(p, tokens, cfg)
-    want_loss, want = jax.value_and_grad(loss)(params)
-    positions = jnp.arange(T)
+    positions = jnp.arange(Tn)
     body = lambda X, layer: xing4._layer_apply(layer, X, cfg, positions, False, None)
-    X = jnp.zeros((B, cfg.hc_mult, T, cfg.d_model), cfg.dtype)
+    X = jnp.zeros((B, cfg.hc_mult, Tn, cfg.d_model), cfg.dtype)
     token = remat._TAGGING.set(True)
     try:
         counted = remat.measure(body, X, params["layers"][1])
     finally:
         remat._TAGGING.reset(token)
     n = cfg.hc_mult
-    assert counted.by_name[remat.HC] == 2 * B * T * 4 * (2 * n + n * n + 1)
+    assert counted.by_name[remat.HC] == 2 * B * Tn * 4 * (2 * n + n * n + 1)
     assert counted.inputs == X.size * X.dtype.itemsize  # the four-row stream
     with remat.free_hbm(10**9):
         text = str(jax.make_jaxpr(jax.value_and_grad(loss))(params))
-        got_loss, got = jax.value_and_grad(loss)(params)
-    calls = collections.Counter(re.findall(r"name=_hc_(\w+)", text))
+        if stream == "toy":
+            got_loss, got = jax.value_and_grad(loss)(params)
+    calls = hc_passes(text)
     layers = cfg.n_layers + cfg.n_mtp
-    assert calls["project"] == 2 * layers, calls  # the forward pass's alone
-    assert calls["matrices"] == calls["read"] == 4 * layers, calls
+    # XLA's pass in front of the matrices: the forward pass's alone
+    assert calls["project"] == (2 * layers if stream == "toy" else 0), calls
+    assert calls["matrices"] == calls["pre_fwd"] == 4 * layers, calls
     assert remat.HC in text
+    if stream != "toy":
+        return
+    want_loss, want = jax.value_and_grad(loss)(params)
     assert float(got_loss) == float(want_loss)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
